@@ -10,6 +10,7 @@ from typing import List, Sequence
 import numpy as np
 
 import flexflow_tpu as ff
+from flexflow_tpu.runtime.compile_cache import place_compile_cache
 
 
 def synthetic_inputs(model: ff.FFModel, num_samples: int, seed: int = 0) -> List[np.ndarray]:
@@ -62,6 +63,7 @@ def synthetic_labels(model: ff.FFModel, num_samples: int, loss: str, seed: int =
 def run_example(model: ff.FFModel, name: str, loss: str = "sparse_categorical_crossentropy",
                 metrics: Sequence[str] = ("accuracy",), num_samples: int = 0,
                 optimizer=None, recompile_state=None, skip_compile=False):
+    place_compile_cache()
     cfg = model.config
     num_samples = num_samples or cfg.batch_size * 8
     if not skip_compile:

@@ -15,21 +15,21 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import flexflow_tpu as ff
 from examples.common import lm_sequence_data
 from flexflow_tpu.models import build_gpt
+from flexflow_tpu.runtime.compile_cache import place_compile_cache
 
 
 def main():
-    config = ff.FFConfig.parse_args()
-    on_tpu = False
-    try:
-        import jax
+    import jax
 
-        on_tpu = jax.devices()[0].platform != "cpu"
-    except Exception:
-        pass
-    if on_tpu:
-        vocab, layers, hidden, heads, ff_dim, seq = 32000, 12, 768, 12, 3072, 512
-    else:  # CI-sized
+    place_compile_cache()
+    config = ff.FFConfig.parse_args()
+    platform = jax.devices()[0].platform
+    if platform == "cpu":  # CI-sized
         vocab, layers, hidden, heads, ff_dim, seq = 512, 2, 64, 4, 128, 32
+    else:
+        vocab, layers, hidden, heads, ff_dim, seq = 32000, 12, 768, 12, 3072, 512
+    print(f"[gpt] platform={platform}: {layers} layers x hidden {hidden}, "
+          f"seq {seq}, vocab {vocab}")
 
     model = build_gpt(config, vocab=vocab, num_layers=layers, hidden=hidden,
                       num_heads=heads, ff_dim=ff_dim, seq_len=seq)

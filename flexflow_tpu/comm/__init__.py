@@ -2,10 +2,9 @@
 
 Two roles:
 
-* ``compat`` — the one place the shard_map API drift between jax
-  versions is absorbed (``jax.shard_map`` + ``check_vma`` on new jax,
-  ``jax.experimental.shard_map`` + ``check_rep`` on 0.4.x).  Every
-  explicit-SPMD lowering in the tree imports shard_map from here.
+* ``compat`` — ``jax.shard_map`` with the replication checker off by
+  default, and the virtual-CPU-mesh switch.  Every explicit-SPMD
+  lowering in the tree imports shard_map from here.
 * ``quantized`` — EQuARX-style compressed gradient collectives
   (arXiv:2506.17615): per-chunk-scaled int8 (and bf16) quantize →
   reduce-scatter → requantize → all-gather, with an exact-fp32 psum

@@ -1,67 +1,28 @@
-"""jax version-drift compat: shard_map spelling + CPU device-count.
-
-Newer jax exposes ``jax.shard_map`` whose replication-checker kwarg is
-``check_vma``; 0.4.x has ``jax.experimental.shard_map.shard_map`` with
-``check_rep``.  The semantics we rely on (manual-collective regions
-with the checker off) are identical, so this is pure spelling.
-``force_cpu_devices`` absorbs the second drift axis: the virtual-CPU
-device count is a config option on newer jax and an XLA flag on 0.4.x.
-"""
+"""Start-up helpers shared by tests and entry scripts: the virtual CPU
+mesh and ``shard_map`` with the replication checker off by default.
+Written for the one installation there is (jax 0.9, pyproject.toml)."""
 
 from __future__ import annotations
 
-import inspect
-import os
+import jax
 
 
 def force_cpu_devices(n: int) -> None:
     """Select the CPU platform with ``n`` virtual devices — callable
-    only BEFORE the jax backend initializes (conftest/boot time).
-    Newer jax: the jax_num_cpu_devices config option; 0.4.x: the
-    --xla_force_host_platform_device_count XLA flag, which the backend
-    reads at first use."""
-    import jax
-
+    only BEFORE the jax backend initializes (conftest/boot time)."""
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={n}"
-        )
-
-
-def _resolve():
-    try:
-        from jax import shard_map as sm  # jax >= 0.6
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    kw = "check_vma" if "check_vma" in params else "check_rep"
-    return sm, kw
-
-
-_SHARD_MAP, _CHECK_KW = _resolve()
+    jax.config.update("jax_num_cpu_devices", n)
 
 
 def shard_map(f, mesh, in_specs, out_specs, check=False, axis_names=None):
-    """``jax.shard_map`` with the replication checker spelled portably
+    """``jax.shard_map`` with the replication checker off by default
     (every call site here runs hand-written collectives the checker
-    cannot verify, so the default is off).
+    cannot verify).
 
     ``axis_names`` — the MANUAL axes for a partial-manual region (the
-    pipeline lowering: collectives over ``pp`` only, GSPMD elsewhere).
-    Newer jax takes them directly; 0.4.x spells the same thing as the
-    complementary ``auto`` set."""
-    kw = {_CHECK_KW: check}
-    if axis_names is not None:
-        manual = frozenset(axis_names)
-        params = inspect.signature(_SHARD_MAP).parameters
-        if "axis_names" in params:
-            kw["axis_names"] = manual
-        else:
-            kw["auto"] = frozenset(mesh.axis_names) - manual
-    return _SHARD_MAP(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw,
+    pipeline lowering: collectives over ``pp`` only, GSPMD elsewhere);
+    None means every mesh axis."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check, axis_names=frozenset(axis_names or ()),
     )

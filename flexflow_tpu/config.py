@@ -433,12 +433,11 @@ class FFConfig:
             # price candidates with plans the lowering never executes
             self.sync_schedule = "search"
         if self.num_devices == 0:
-            try:
-                import jax
+            # a backend that fails to initialize raises here: a failed
+            # TPU must not turn into a one-device run
+            import jax
 
-                self.num_devices = len(jax.devices())
-            except Exception:
-                self.num_devices = 1
+            self.num_devices = len(jax.devices())
         if self.machine_spec is None:
             if self.machine_model_file:
                 self.machine_spec = MachineSpec.from_file(self.machine_model_file)

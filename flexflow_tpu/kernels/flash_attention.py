@@ -33,16 +33,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-try:  # pallas may be unavailable on some backends; the XLA paths in
-    # this module must stay importable without it
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pl = None
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -51,16 +43,13 @@ def _mosaic_params(interpret: bool):
     """Grid dims (BH, outer-block) are independent; only the innermost
     accumulation dim carries scratch state — telling Mosaic lets it
     pipeline block loads across grid steps."""
-    if interpret or pltpu is None:
+    if interpret:
         return {}
-    try:
-        return {
-            "compiler_params": pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")
-            )
-        }
-    except Exception:  # pragma: no cover - older pallas API
-        return {}
+    return {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        )
+    }
 
 
 def _flash_kernel(
@@ -575,7 +564,7 @@ def _fap_fwd(q, k, v, causal, scale, block_q, block_k):
     sq, sk = q.shape[1], k.shape[1]
     bq = _pick_block(sq, block_q)
     bk = _pick_block(sk, block_k)
-    if not _HAS_PLTPU or bq is None or bk is None or q.shape[-1] % 8 != 0:
+    if bq is None or bk is None or q.shape[-1] % 8 != 0:
         out = _xla_attention_partial(q, k, v, causal, scale)
     else:
         out = _flash_forward_partial(q, k, v, causal, scale, bq, bk, interpret)
@@ -670,6 +659,41 @@ def flash_attention(
     return _flash_attention_vjp(q, k, v, causal, scale, block_q, block_k)
 
 
+def flash_profitable(sq: int, sk: int) -> bool:
+    """THE shape rule that picks the flash kernel over XLA's fused
+    attention (measured on v5e, BENCH_LONGCTX.md): below ~512 keys the
+    [Sq, Sk] tile fits comfortably and XLA's fused attention beats the
+    kernel's launch + lse/delta traffic; above it flash wins (3x at
+    4k, and XLA falls off a memory cliff by 8k).  Long-Sq
+    cross-attention also wants flash (the materialized logits scale
+    with Sq*Sk)."""
+    return sk >= 512 or sq * sk >= 512 * 2048
+
+
+def flash_attention_sharded(
+    q, k, v, mesh, batch_axes: Tuple[str, ...] = (),
+    head_axes: Tuple[str, ...] = (), causal: bool = False,
+    scale: float | None = None,
+):
+    """``flash_attention`` on a multi-device mesh: q, k, v [B, S, H, D]
+    sharded on dim 0 over ``batch_axes`` and dim 2 over ``head_axes``
+    (either may be empty), sequence whole.  GSPMD refuses to partition
+    a Mosaic call ("Mosaic kernels cannot be automatically
+    partitioned"), so the kernel runs per shard under ``shard_map`` —
+    attention is independent across batch rows and heads, so no
+    collective is needed."""
+    from jax.sharding import PartitionSpec as P
+
+    from flexflow_tpu.comm.compat import shard_map
+
+    spec = P(tuple(batch_axes) or None, None, tuple(head_axes) or None,
+             None)
+    return shard_map(
+        functools.partial(flash_attention, causal=causal, scale=scale),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+    )(q, k, v)
+
+
 def _pick_block(size: int, want: int):
     """Largest power-of-two block <= want that divides size (None if
     size has no power-of-two divisor >= 8 small enough to tile)."""
@@ -688,8 +712,8 @@ def _fa_fwd(q, k, v, causal, scale, block_q, block_k):
     sq, sk = q.shape[1], k.shape[1]
     bq = _pick_block(sq, block_q)
     bk = _pick_block(sk, block_k)
-    if not _HAS_PLTPU or bq is None or bk is None or q.shape[-1] % 8 != 0:
-        out = _xla_attention(q, k, v, causal, scale)  # shape fallback
+    if bq is None or bk is None or q.shape[-1] % 8 != 0:
+        out = _xla_attention(q, k, v, causal, scale)  # shape rule
         return out, (q, k, v, None, None)
     out, lse = _flash_forward(q, k, v, causal, scale, bq, bk, interpret,
                               save_lse=True)

@@ -1,4 +1,4 @@
-"""Ragged paged decode attention — Pallas TPU kernel + XLA fallback.
+"""Ragged paged decode attention — Pallas TPU kernel + XLA gather path.
 
 The serving-side sibling of ``kernels/flash_attention``: one decode
 step attends a single fresh query token per sequence against that
@@ -16,18 +16,21 @@ dense [B, S_max] buffer:
   is exclusive (lengths, not indices).
 
 The Pallas kernel runs a flash-style online softmax with the PAGE as
-the KV block: grid (B, H, pages_per_seq), pages innermost so the
+the KV block: grid (B, pages_per_seq), pages innermost so the
 (m, l, acc) scratch accumulators carry across a sequence's pages, and
 the page indirection rides the BlockSpec index_map — the scalar-
 prefetched ``page_table`` picks which pool page each grid step loads,
 so only the sequence's OWN pages ever move HBM→VMEM (the ragged win;
-a dense layout would stream B × S_max tokens).  Pages past
-``ceil(len/page_size)`` are skipped with ``pl.when`` (they still DMA —
-the index map pins them to page 0 — but cost no FLOPs; the tail page's
-dead rows are masked at NEG_INF exactly like flash attention's causal
-mask).  On non-TPU backends the kernel runs in interpreter mode; any
-failure falls back to the gather/masked XLA path so CPU-mesh tests
-cover the same call sites.
+a dense layout would stream B × S_max tokens).  Blocks span all heads
+(q [1, H, D], pool [1, page, H, D]): the TPU lowering takes a block
+whose last two dims equal the array's, and the head loop is the
+vectorized [page, H, ·] arithmetic inside the kernel.  Pages past
+``ceil(len/page_size)`` are skipped with ``pl.when`` (their index map
+pins them to page 0, so they cost no FLOPs and consecutive dead steps
+keep one resident block; the tail page's dead rows are masked at
+NEG_INF exactly like flash attention's causal mask).  On non-TPU backends the kernel runs in interpreter mode.
+Kernel or XLA gather path is chosen by ``paged_kernel_applies`` (a
+shape rule), never by a caught error.
 
 ``dense_decode_reference`` is the oracle: materialize every sequence's
 KV densely, mask past ``seq_lens``, plain softmax — the parity target
@@ -39,9 +42,9 @@ operands to fp32, a no-op on the fp32 path, so the historical numerics
 are bit-identical.  An int8 pool carries per-(page, slot) fp32 scales
 and enters through ``ragged_paged_attention_quant``: the Pallas
 variant dequantizes INSIDE the page loop (the scales ride the same
-scalar-prefetched page indirection as the payload, one [page_size]
-row per grid step), so only quantized bytes ever stream HBM→VMEM —
-that smaller stream is the whole point of the lane.
+scalar-prefetched page indirection as the payload, ``_SCALE_ROWS``
+[page_size] rows per grid step), so only quantized bytes ever stream
+HBM→VMEM — that smaller stream is the whole point of the lane.
 """
 
 from __future__ import annotations
@@ -51,17 +54,8 @@ import math
 
 import jax
 import jax.numpy as jnp
-
-try:  # pallas may be unavailable on some backends; the XLA paths in
-    # this module must stay importable without it
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pl = None
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -78,13 +72,17 @@ def dense_decode_reference(q, k_dense, v_dense, seq_lens, scale=None):
     the paged kernel and the gather fallback must match."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    # HIGHEST: the TPU's default fp32 einsum rounds its operands to
+    # bf16; an oracle for an fp32 kernel has to multiply in fp32
+    hi = jax.lax.Precision.HIGHEST
     s = jnp.einsum("bhd,bshd->bhs", q.astype(jnp.float32),
-                   k_dense.astype(jnp.float32)) * scale
+                   k_dense.astype(jnp.float32), precision=hi) * scale
     pos = jnp.arange(k_dense.shape[1], dtype=jnp.int32)
     mask = pos[None, None, :] < seq_lens[:, None, None]
     s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhs,bshd->bhd", p, v_dense.astype(jnp.float32))
+    out = jnp.einsum("bhs,bshd->bhd", p, v_dense.astype(jnp.float32),
+                     precision=hi)
     return out.astype(q.dtype)
 
 
@@ -129,20 +127,41 @@ def _xla_ragged_paged_quant(q, k_pages, v_pages, k_scale, v_scale,
 # ---------------------------------------------------------------------------
 # Pallas kernel
 # ---------------------------------------------------------------------------
+# int8 scale rows reach the kernel in blocks of this many pool pages:
+# the TPU lowering wants the second-to-last block dim divisible by 8
+_SCALE_ROWS = 8
+
+
 def _rpa_kernel(
     page_table_ref, seq_lens_ref,  # scalar-prefetch operands
-    q_ref, k_ref, v_ref, o_ref,
-    m_scratch, l_scratch, acc_scratch,
-    *, page_size: int, scale: float,
+    q_ref, k_ref, v_ref, *refs,
+    page_size: int, scale: float, quant: bool,
 ):
-    """Grid (B, H, pages_per_seq), pages innermost (sequential on TPU)
-    so the online-softmax scratch carries across one sequence's pages.
+    """Grid (B, pages_per_seq), pages innermost (sequential on TPU) so
+    the online-softmax scratch carries across one sequence's pages.
     The k/v BlockSpec index maps already routed THIS grid step's block
     to pool page ``page_table[b, j]`` — the kernel only masks the
-    ragged tail and skips fully-dead pages."""
+    ragged tail and skips fully-dead pages.
+
+    Every block spans ALL heads — q [H, D], k/v [page, H, D] — which
+    is the pool's own minor layout, so Mosaic takes the blocks as they
+    sit in HBM.  The per-head dots run on the VPU with the page on the
+    MAJOR axis throughout ([page, H, 1] scores): no value ever has to
+    move between the sublane and the lane axis.
+
+    With ``quant`` the page's K/V arrive int8 and are DEQUANTIZED
+    here, in the page loop — ``ks_ref``/``vs_ref`` hold the
+    per-(page, slot) fp32 scale rows of the ``_SCALE_ROWS`` pool pages
+    around this one, routed by the same scalar-prefetched page
+    indirection as the payload.  HBM→VMEM moves 1 byte per element
+    plus the scale rows; the fp32 values exist only in vregs."""
+    if quant:
+        ks_ref, vs_ref, o_ref, m_scratch, l_scratch, acc_scratch = refs
+    else:
+        o_ref, m_scratch, l_scratch, acc_scratch = refs
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    npp = pl.num_programs(2)
+    j = pl.program_id(1)
+    npp = pl.num_programs(1)
     n = seq_lens_ref[b]
 
     @pl.when(j == 0)
@@ -155,29 +174,35 @@ def _rpa_kernel(
     # no live token for this sequence
     @pl.when(j * page_size < n)
     def _step():
-        q = q_ref[0]        # [1, D] — the lone decode token's row
-        # fp32 casts are no-ops on the historical fp32 pool (numerics
-        # bit-identical) and make the SAME kernel serve a bf16 pool
-        k = k_ref[0, :, 0].astype(jnp.float32)  # [page_size, D]
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [1, page_size] fp32
-        cols = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(cols < n, s, NEG_INF)
-        m_prev = m_scratch[:]  # [1, 1]
-        l_prev = l_scratch[:]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
+        q = q_ref[0].astype(jnp.float32)  # [H, D]
+        # fp32 casts are no-ops on the fp32 pool and make the SAME
+        # kernel serve bf16 and int8 pools
+        k = k_ref[0].astype(jnp.float32)  # [page, H, D]
+        v = v_ref[0].astype(jnp.float32)
+        s = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
+        if quant:
+            # this page's row of the scale block, turned so the slot
+            # index sits on the major axis like the scores
+            row = page_table_ref[b, j] % _SCALE_ROWS
+
+            def slot_scale(ref):
+                t = ref[...].T  # [page, _SCALE_ROWS]
+                lane = jax.lax.broadcasted_iota(jnp.int32, t.shape, 1)
+                col = jnp.sum(jnp.where(lane == row, t, 0.0),
+                              axis=1, keepdims=True)
+                return col.reshape(page_size, 1, 1)
+
+            s = s * slot_scale(ks_ref)
+            v = v * slot_scale(vs_ref)
+        slots = j * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 0)
+        s = jnp.where(slots < n, s, NEG_INF)  # [page, H, 1] fp32
+        m_prev = m_scratch[:]  # [H, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+        p = jnp.exp(s - m_new[None])
         alpha = jnp.exp(m_prev - m_new)
-        l_scratch[:] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scratch[:] = acc_scratch[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        l_scratch[:] = l_scratch[:] * alpha + jnp.sum(p, axis=0)
+        acc_scratch[:] = acc_scratch[:] * alpha + jnp.sum(p * v, axis=0)
         m_scratch[:] = m_new
 
     @pl.when(j == npp - 1)
@@ -187,152 +212,68 @@ def _rpa_kernel(
 
 
 def _pallas_ragged_paged(q, k_pages, v_pages, page_table, seq_lens, scale,
-                         interpret: bool):
+                         interpret: bool, k_scale=None, v_scale=None):
     b, h, d = q.shape
     num_pages, page_size, hp, dp = k_pages.shape
     assert (hp, dp) == (h, d), (k_pages.shape, q.shape)
     pages_per_seq = page_table.shape[1]
-    grid = (b, h, pages_per_seq)
+    quant = k_scale is not None
 
-    def kv_map(bi, hi, j, pt_ref, sl_ref):
+    def page_of(bi, j, pt_ref, sl_ref):
         # dead pages (page slot past ceil(len/page_size)) pin to pool
         # page 0 — the DMA still runs but pl.when skips the math and
         # the tail mask kills any live-page partial rows
         live = (j * page_size) < sl_ref[bi]
-        page = jnp.where(live, pt_ref[bi, j], 0)
-        return (page, 0, hi, 0)
+        return jnp.where(live, pt_ref[bi, j], 0)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, d), lambda bi, hi, j, pt, sl: (bi, hi, 0)),
-            pl.BlockSpec((1, page_size, 1, d), kv_map),
-            pl.BlockSpec((1, page_size, 1, d), kv_map),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, d), lambda bi, hi, j, pt, sl: (bi, hi, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        _rpa_kernel, page_size=page_size, scale=scale)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        interpret=interpret,
-    )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      q, k_pages, v_pages)
-    return out
+    def q_map(bi, j, pt_ref, sl_ref):
+        return (bi, 0, 0)
 
+    def kv_map(bi, j, pt_ref, sl_ref):
+        return (page_of(bi, j, pt_ref, sl_ref), 0, 0, 0)
 
-def _rpa_kernel_quant(
-    page_table_ref, seq_lens_ref,  # scalar-prefetch operands
-    q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-    m_scratch, l_scratch, acc_scratch,
-    *, page_size: int, scale: float,
-):
-    """The int8-pool twin of ``_rpa_kernel``: identical online softmax,
-    but the page's K/V arrive quantized and are DEQUANTIZED here, in
-    the page loop — ``ks_ref``/``vs_ref`` hold this page's
-    per-(page, slot) fp32 scale rows, routed by the same
-    scalar-prefetched page indirection as the payload.  HBM→VMEM moves
-    1 byte per element + 8 scale bytes per token; the fp32 values
-    exist only in registers/VMEM."""
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    npp = pl.num_programs(2)
-    n = seq_lens_ref[b]
-
-    @pl.when(j == 0)
-    def _init():
-        m_scratch[:] = jnp.full_like(m_scratch, NEG_INF)
-        l_scratch[:] = jnp.zeros_like(l_scratch)
-        acc_scratch[:] = jnp.zeros_like(acc_scratch)
-
-    @pl.when(j * page_size < n)
-    def _step():
-        q = q_ref[0]  # [1, D]
-        k = k_ref[0, :, 0].astype(jnp.float32) * ks_ref[0][:, None]
-        v = v_ref[0, :, 0].astype(jnp.float32) * vs_ref[0][:, None]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        cols = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(cols < n, s, NEG_INF)
-        m_prev = m_scratch[:]
-        l_prev = l_scratch[:]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scratch[:] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scratch[:] = acc_scratch[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scratch[:] = m_new
-
-    @pl.when(j == npp - 1)
-    def _finish():
-        l = jnp.maximum(l_scratch[:], 1e-30)
-        o_ref[0] = (acc_scratch[:] / l).astype(o_ref.dtype)
-
-
-def _pallas_ragged_paged_quant(q, k_pages, v_pages, k_scale, v_scale,
-                               page_table, seq_lens, scale,
-                               interpret: bool):
-    b, h, d = q.shape
-    num_pages, page_size, hp, dp = k_pages.shape
-    assert (hp, dp) == (h, d), (k_pages.shape, q.shape)
-    pages_per_seq = page_table.shape[1]
-    grid = (b, h, pages_per_seq)
-
-    def kv_map(bi, hi, j, pt_ref, sl_ref):
-        live = (j * page_size) < sl_ref[bi]
-        page = jnp.where(live, pt_ref[bi, j], 0)
-        return (page, 0, hi, 0)
-
-    def scale_map(bi, hi, j, pt_ref, sl_ref):
+    def scale_map(bi, j, pt_ref, sl_ref):
         # the scale rows ride the SAME page indirection as the payload
-        live = (j * page_size) < sl_ref[bi]
-        page = jnp.where(live, pt_ref[bi, j], 0)
-        return (page, 0)
+        return (page_of(bi, j, pt_ref, sl_ref) // _SCALE_ROWS, 0)
 
+    kv_spec = pl.BlockSpec((1, page_size, h, d), kv_map)
+    in_specs = [pl.BlockSpec((1, h, d), q_map), kv_spec, kv_spec]
+    operands = [q, k_pages, v_pages]
+    if quant:
+        s_spec = pl.BlockSpec((_SCALE_ROWS, page_size), scale_map)
+        in_specs += [s_spec, s_spec]
+        operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, d), lambda bi, hi, j, pt, sl: (bi, hi, 0)),
-            pl.BlockSpec((1, page_size, 1, d), kv_map),
-            pl.BlockSpec((1, page_size, 1, d), kv_map),
-            pl.BlockSpec((1, page_size), scale_map),
-            pl.BlockSpec((1, page_size), scale_map),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, d), lambda bi, hi, j, pt, sl: (bi, hi, 0)),
+        grid=(b, pages_per_seq),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, h, d), q_map),
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, d), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _rpa_kernel_quant, page_size=page_size, scale=scale)
-    out = pl.pallas_call(
+        _rpa_kernel, page_size=page_size, scale=scale, quant=quant)
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        # sequences are independent; only the page axis carries scratch
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      q, k_pages, v_pages, k_scale, v_scale)
-    return out
+        name="ragged_paged_attention",
+    )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32), *operands)
+
+
+def paged_kernel_applies(head_dim: int, page_size: int) -> bool:
+    """THE shape rule that picks the Pallas kernel over the XLA gather
+    path: head_dim and page_size multiples of 8 (whole sublane tiles
+    for the [H, D] blocks and the scale-row transpose).  Anything else
+    — tiny CPU test shapes — is served by the gather path."""
+    return head_dim % 8 == 0 and page_size % 8 == 0
 
 
 def ragged_paged_attention_quant(
@@ -343,19 +284,13 @@ def ragged_paged_attention_quant(
     ``ragged_paged_attention`` but ``k_pages``/``v_pages`` are int8 and
     ``k_scale``/``v_scale`` [P, page_size] fp32 carry each token's
     symmetric per-(page, slot) scale (shared across heads).  Same
-    kernel gating and fallback contract as the fp32 entry point."""
+    kernel rule as the fp32 entry point."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    d = q.shape[-1]
-    page_size = k_pages.shape[1]
-    if _HAS_PLTPU and d % 8 == 0 and page_size % 8 == 0:
-        interpret = jax.default_backend() != "tpu"
-        try:
-            return _pallas_ragged_paged_quant(
-                q, k_pages, v_pages, k_scale, v_scale, page_table,
-                seq_lens, float(scale), interpret)
-        except Exception:
-            pass  # fall through to the XLA path (e.g. unsupported jax)
+    if paged_kernel_applies(q.shape[-1], k_pages.shape[1]):
+        return _pallas_ragged_paged(
+            q, k_pages, v_pages, page_table, seq_lens, float(scale),
+            jax.default_backend() != "tpu", k_scale, v_scale)
     return _xla_ragged_paged_quant(q, k_pages, v_pages, k_scale, v_scale,
                                    page_table, seq_lens, float(scale))
 
@@ -367,26 +302,17 @@ def ragged_paged_attention(
     sequence), k_pages/v_pages [P, page_size, H, D], page_table
     [B, pages_per_seq] int32, seq_lens [B] int32 -> [B, H, D].
 
-    Takes the Pallas kernel when available (interpreter mode off-TPU,
-    like flash_attention), falling back to the gather/masked XLA path
-    on any failure so the CPU mesh exercises identical call sites.
+    Takes the Pallas kernel when ``paged_kernel_applies`` (interpreter
+    mode off-TPU, like flash_attention), the gather/masked XLA path
+    otherwise — chosen by shape alone, never by a caught error.
     Decode is forward-only (no gradients flow into a serving step), so
-    no custom VJP is defined — autodiff through the fallback works for
+    no custom VJP is defined — autodiff through the XLA path works for
     the tests that want it."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    d = q.shape[-1]
-    page_size = k_pages.shape[1]
-    # the kernel wants MXU/VPU-friendly tails: head_dim a multiple of 8
-    # and at least one full lane-worth of page; anything else (tiny CPU
-    # test shapes) is served by the fallback, same contract
-    if _HAS_PLTPU and d % 8 == 0 and page_size % 8 == 0:
-        interpret = jax.default_backend() != "tpu"
-        try:
-            return _pallas_ragged_paged(
-                q, k_pages, v_pages, page_table, seq_lens, float(scale),
-                interpret)
-        except Exception:
-            pass  # fall through to the XLA path (e.g. unsupported jax)
+    if paged_kernel_applies(q.shape[-1], k_pages.shape[1]):
+        return _pallas_ragged_paged(
+            q, k_pages, v_pages, page_table, seq_lens, float(scale),
+            jax.default_backend() != "tpu")
     return _xla_ragged_paged(q, k_pages, v_pages, page_table, seq_lens,
                              float(scale))

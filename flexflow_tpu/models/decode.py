@@ -50,7 +50,7 @@ SERVE_FRAME_SLOTS = 32  # config.batch_size the serve sweep uses
 
 def decode_layer(model, t, page_table, seq_lens, hidden, num_heads,
                  ff_dim, name, page_size, pages_per_seq, num_pages=0,
-                 layer_norm=True):
+                 layer_norm=True, use_kernel=True):
     """One decode-step transformer layer: paged-cache attention +
     residual + LN + FFN (the decode twin of transformer.encoder_layer,
     which this must mirror so prefill/decode weights correspond
@@ -58,7 +58,7 @@ def decode_layer(model, t, page_table, seq_lens, hidden, num_heads,
     a = model.decode_attention(
         t, page_table, seq_lens, embed_dim=hidden, num_heads=num_heads,
         page_size=page_size, pages_per_seq=pages_per_seq,
-        num_pages=num_pages, name=f"{name}_mha",
+        num_pages=num_pages, use_kernel=use_kernel, name=f"{name}_mha",
     )
     t = model.add(a, t, name=f"{name}_res1")
     if layer_norm:
@@ -75,7 +75,7 @@ def build_gpt_decode(config: FFConfig, vocab: int = 2048,
                      num_layers: int = 2, hidden: int = 256,
                      num_heads: int = 8, ff_dim: int = 512,
                      page_size: int = 16, pages_per_seq: int = 16,
-                     num_pages: int = 0):
+                     num_pages: int = 0, use_kernel: bool = True):
     """The single-token decode-step graph: token ids [B, 1] -> next-token
     logits [B, 1, vocab], where B = config.batch_size is the decode
     frame's sequence-slot count (max concurrent sequences).
@@ -84,7 +84,9 @@ def build_gpt_decode(config: FFConfig, vocab: int = 2048,
     [B, pages_per_seq] i32, ``seq_lens`` [B] i32.  Every layer's
     attention reads/writes its OWN page-pool KV cache (model state);
     all layers share one page-table geometry, so one allocator serves
-    the whole stack."""
+    the whole stack.  ``use_kernel=False`` lowers every layer's
+    attention through the XLA gather path instead of the Pallas kernel
+    — the reference a kernel run is compared against."""
     model = FFModel(config)
     b = config.batch_size
     ids = model.create_tensor([b, 1], dtype="int32", name="token_ids")
@@ -102,7 +104,7 @@ def build_gpt_decode(config: FFConfig, vocab: int = 2048,
         t = decode_layer(
             model, t, page_table, seq_lens, hidden, num_heads, ff_dim,
             f"layer{i}", page_size=page_size, pages_per_seq=pages_per_seq,
-            num_pages=num_pages, layer_norm=True,
+            num_pages=num_pages, layer_norm=True, use_kernel=use_kernel,
         )
     t = model.layer_norm(t, name="final_ln")
     t = model.dense(t, vocab, use_bias=False, name="lm_head")

@@ -24,6 +24,7 @@ _LIB_PATH = os.path.join(_NATIVE_DIR, "build", "libflexflow_native.so")
 
 _lib = None
 _lib_tried = False
+_lib_error: Optional[str] = None  # why get_lib() returned None
 
 
 def _configure(lib) -> None:
@@ -97,28 +98,41 @@ def _lib_stale() -> bool:
 
 def get_lib():
     """The loaded native library, (re)building it when missing or stale;
-    None when disabled or unbuildable."""
-    global _lib, _lib_tried
+    None when disabled or unbuildable (``engine_status`` says which)."""
+    global _lib, _lib_tried, _lib_error
     if _lib_tried:
         return _lib
     _lib_tried = True
     if os.environ.get("FLEXFLOW_TPU_NO_NATIVE"):
+        _lib_error = "FLEXFLOW_TPU_NO_NATIVE is set"
         return None
     if _lib_stale():
         try:
             subprocess.run(["make", "-C", _NATIVE_DIR, "-B"], check=True,
-                           capture_output=True, timeout=120)
-        except (subprocess.SubprocessError, OSError):
+                           capture_output=True, text=True, timeout=120)
+        except subprocess.CalledProcessError as e:
+            _lib_error = f"make failed: {e.stderr.strip()[-2000:]}"
+            return None
+        except (subprocess.SubprocessError, OSError) as e:
+            _lib_error = f"make failed: {e!r}"
             return None
     try:
         lib = ctypes.CDLL(_LIB_PATH)
         _configure(lib)
         _lib = lib
-    except (OSError, AttributeError):
+    except (OSError, AttributeError) as e:
         # AttributeError: a symbol missing from a stale/foreign .so —
         # fall back to the pure-Python paths rather than crash
+        _lib_error = f"load failed: {e!r}"
         _lib = None
     return _lib
+
+
+def engine_status() -> str:
+    """``"native"`` when the C++ search library is built and loaded,
+    else ``"python: <why>"`` — the build or load error the fallback
+    would otherwise hide behind a slower search."""
+    return "native" if get_lib() is not None else f"python: {_lib_error}"
 
 
 def _i32(a: np.ndarray):

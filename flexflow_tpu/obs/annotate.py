@@ -162,9 +162,9 @@ def lane_stamp(lane_id: str, marker: str, dep):
     ``time.perf_counter`` into ``LANES`` and emits a marker
     ``TraceAnnotation`` so an active ``device_trace`` capture carries
     the tag.  ``pure_callback`` rather than the ordered ``io_callback``
-    on purpose: the ordered-effect token changes the jitted program's
-    entry parameters, which the 0.4.x SPMD sharding-propagation pass
-    rejects on the sharded train step.  Call only from lowering code
+    on purpose: the data dependence on ``dep`` already orders the
+    stamp, and an ordered-effect token would add entry parameters to
+    the sharded train step.  Call only from lowering code
     that is itself gated (``FFConfig.device_trace_dir``)."""
     import jax
     import jax.numpy as jnp

@@ -245,23 +245,27 @@ class MultiHeadAttentionOp(Operator):
                 causal=a["causal"], scale=scale,
                 batch_axes=(ctx.slot_axes or {}).get(0, ()),
             )
-        # Shape heuristic (measured on v5e, see kernels/flash_attention):
-        # below ~512 keys the [Sq,Sk] tile fits comfortably and XLA's
-        # fused attention beats the Pallas kernel's launch + lse/delta
-        # traffic; above it flash wins (3x at 4k, and XLA falls off a
-        # memory cliff by 8k).  Long-Sq cross-attention also wants flash
-        # (the materialized logits scale with Sq*Sk).
-        sq_, sk_ = qh.shape[1], kh.shape[1]
-        flash_profitable = sk_ >= 512 or sq_ * sk_ >= 512 * 2048
-        if a["use_flash"] and flash_profitable and not dropout_active:
-            try:
-                from flexflow_tpu.kernels.flash_attention import flash_attention
+        from flexflow_tpu.kernels.flash_attention import (
+            _xla_attention,
+            flash_attention,
+            flash_attention_sharded,
+            flash_profitable,
+        )
 
-                return flash_attention(qh, kh, vh, causal=a["causal"], scale=scale)
-            except Exception:
-                pass  # fall back to the XLA path (e.g. CPU tests)
-        from flexflow_tpu.kernels.flash_attention import _xla_attention
-
+        if (a["use_flash"] and not dropout_active
+                and flash_profitable(qh.shape[1], kh.shape[1])):
+            if ctx.mesh is None:
+                return flash_attention(
+                    qh, kh, vh, causal=a["causal"], scale=scale)
+            # multi-device mesh: the kernel runs per shard, batch over
+            # the view's slot-0 axes and heads over its replica axes
+            # (a seq split went to the ring/ulysses paths above)
+            slot_axes = ctx.slot_axes or {}
+            return flash_attention_sharded(
+                qh, kh, vh, ctx.mesh,
+                batch_axes=slot_axes.get(0, ()),
+                head_axes=slot_axes.get(REPLICA_SLOT, ()),
+                causal=a["causal"], scale=scale)
         if not dropout_active:
             return _xla_attention(qh, kh, vh, a["causal"], scale)
         return _xla_attention(

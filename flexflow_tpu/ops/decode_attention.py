@@ -218,6 +218,23 @@ class DecodeAttentionOp(Operator):
         return out
 
     # ---- lowering --------------------------------------------------------
+    def attention_path(self, multi_device: bool) -> str:
+        """Which attention implementation ``forward`` lowers to:
+        ``"pallas"`` (kernels/ragged_paged_attention) or ``"xla"`` (the
+        gather/masked path).  The kernel runs when ``use_kernel`` asks
+        for it, the shapes satisfy ``paged_kernel_applies``, and the
+        mesh is ONE device: GSPMD cannot partition a Mosaic call, and
+        the kernel has not been run sharded."""
+        from flexflow_tpu.kernels.ragged_paged_attention import (
+            paged_kernel_applies,
+        )
+
+        if (self.attrs["use_kernel"] and not multi_device
+                and paged_kernel_applies(self.head_dim,
+                                         self.attrs["page_size"])):
+            return "pallas"
+        return "xla"
+
     def forward(self, ctx: LoweringContext, inputs, weights):
         from flexflow_tpu.kernels.ragged_paged_attention import (
             _xla_ragged_paged,
@@ -284,18 +301,18 @@ class DecodeAttentionOp(Operator):
         scale = 1.0 / math.sqrt(self.head_dim)
         lens = seq_lens + 1  # the fresh token attends to itself too
         qf = q.astype(jnp.float32)
-        if kvd == "int8":
-            if a["use_kernel"]:
+        if self.attention_path(ctx.mesh is not None) == "pallas":
+            if kvd == "int8":
                 out = ragged_paged_attention_quant(
                     qf, k_cache, v_cache, k_scale, v_scale,
                     page_table, lens, scale)
             else:
-                out = _xla_ragged_paged_quant(
-                    qf, k_cache, v_cache, k_scale, v_scale,
-                    page_table, lens, scale)
-        elif a["use_kernel"]:
-            out = ragged_paged_attention(
-                qf, k_cache, v_cache, page_table, lens, scale)
+                out = ragged_paged_attention(
+                    qf, k_cache, v_cache, page_table, lens, scale)
+        elif kvd == "int8":
+            out = _xla_ragged_paged_quant(
+                qf, k_cache, v_cache, k_scale, v_scale,
+                page_table, lens, scale)
         else:
             out = _xla_ragged_paged(
                 qf, k_cache, v_cache, page_table, lens, scale)

@@ -97,12 +97,6 @@ def pipeline_spmd(
     def local(params_l, x_l, const_l):
         # params_l leaves: [L/S, ...] — this stage's block slices.
         p = params_l
-        # NOTE jax 0.4.x: this axis_index lowers to a PartitionId the
-        # SPMD partitioner rejects when auto (dp/tp) axes are present —
-        # the pipelined TRAIN step therefore needs a newer jax.  Routing
-        # the index in as pp-sharded data fixes the forward but makes
-        # the scanned backward abort inside 0.4.x jaxlib, which is
-        # worse; keep the clean failure until the toolchain moves.
         s = jax.lax.axis_index(axis_name)
         zero = jnp.zeros(x_l.shape[1:], x_l.dtype)
         outbuf = jnp.zeros((M,) + x_l.shape[1:], x_l.dtype)

@@ -33,24 +33,17 @@ from jax.sharding import PartitionSpec as P
 
 def _full_attention(q, k, v, causal: bool, scale: float):
     """Full-sequence attention for the local head slice — the flash
-    kernel when it applies, the XLA einsum path otherwise (CPU mesh).
-
-    The fallback is only for the errors an unsupported platform/shape
-    actually raises (Pallas lowering NotImplementedError, tiling
-    ValueError, backend JaxRuntimeError — the cases ops/attention.py
-    documents as 'e.g. CPU tests'); a genuine bug inside the kernel
-    must surface, not be silently masked by the slower XLA path."""
-    import jax.errors
-
+    kernel where ``flash_profitable`` says so (the rule
+    ops/attention.py applies), XLA's fused attention otherwise."""
     from flexflow_tpu.kernels.flash_attention import (
         _xla_attention,
         flash_attention,
+        flash_profitable,
     )
 
-    try:
+    if flash_profitable(q.shape[1], k.shape[1]):
         return flash_attention(q, k, v, causal=causal, scale=scale)
-    except (NotImplementedError, ValueError, jax.errors.JaxRuntimeError):
-        return _xla_attention(q, k, v, causal, scale)
+    return _xla_attention(q, k, v, causal, scale)
 
 
 def ulysses_attention(

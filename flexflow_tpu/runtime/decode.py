@@ -950,6 +950,10 @@ class ContinuousBatchingExecutor:
             "measured_p50_s": q(0.5),
             "measured_p99_s": q(0.99),
             "predicted_step_s": self.predicted_step_s,
+            # which decode-attention implementation served the frames
+            # (compiled_decode_step names it; None for a bare step_fn)
+            "attention_path": getattr(self.step_fn, "attention_path",
+                                      None),
         }
         if self.prefix_sharing:
             # prefix-sharing roll-up (keys appear only when the mode is
@@ -1040,13 +1044,24 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
     (runtime/prefill.py — one parameter set by construction, the cache
     scatter lands in the placed state arrays), attached as
     ``step.prefill(ids [1,C], positions [1,C], page_table [1,P])`` for
-    the executor's ``prefill_fn``."""
+    the executor's ``prefill_fn``.
+
+    ``step.frame_fn`` is the jitted frame itself (``(params, state,
+    [ids, page_table, seq_lens])``) and ``step.attention_path`` names
+    what its decode attention lowered to — ``"pallas"`` or ``"xla"``,
+    by ``DecodeAttentionOp.attention_path``'s rule."""
     import jax
+
+    from flexflow_tpu.core.optype import OperatorType
 
     compiled = model.compiled
     fn = jax.jit(
         lambda p, s, ins: compiled.apply(p, s, ins, None, False))
     box = {"state": model.state}
+    paths = {
+        n.op.attention_path(compiled._multi_device)
+        for n in model.graph.topo_order()
+        if n.op.op_type == OperatorType.DECODE_ATTENTION}
 
     def step(ids, page_table, seq_lens):
         logits, new_state = fn(
@@ -1055,6 +1070,8 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
         return logits
 
     step.state = box  # tests inspect the threaded cache
+    step.frame_fn = fn
+    step.attention_path = "+".join(sorted(paths)) or None
 
     def copy_page(src: int, dst: int) -> None:
         """CoW page copy for the prefix-sharing executor

@@ -40,6 +40,7 @@ import gc
 import heapq
 import math
 import random
+import re
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -60,7 +61,10 @@ from flexflow_tpu.search.dp import (
     encode_strategy_rows,
 )
 from flexflow_tpu.search.simulator import Simulator
-from flexflow_tpu.search.substitution import generate_all_pcg_xfers
+from flexflow_tpu.search.substitution import (
+    _renamed,
+    generate_all_pcg_xfers,
+)
 from flexflow_tpu.search.views import boundary_views
 
 _SEG_STAMPS = METRICS.counter("search.segments_stamped")
@@ -286,6 +290,7 @@ class _UnityOptimizer:
             for sg, cg in zip(s_rest, c_rest):
                 mapping[sg] = cg
         g2, full = g_opt.remap(mapping, fresh_start=graph._next_guid)
+        _keep_own_ops(g2, graph)
         strat2 = {full[g]: v for g, v in strategy.items() if g in full}
         # the per-group pairing may not follow a single isomorphism when
         # hash groups have >1 member — re-simulate so the returned cost
@@ -1241,6 +1246,31 @@ def _lint_findings(graph, strategy, num_devices):
         check_graph(graph) + lint_strategy(graph, strategy, num_devices))
 
 
+_XFER_SUFFIX = re.compile(r"_x\d+$")  # substitution._uname's counter
+
+
+def _keep_own_ops(transplant: Graph, target: Graph) -> None:
+    """Make a transplanted optimized graph carry ``target``'s OWN ops.
+
+    ``Graph.remap`` renames guids but keeps the donor's op objects, and
+    the lowering keys weights (and ``export_strategy`` keys views) by
+    op NAME: a solved transformer layer stamped onto its eleven
+    siblings left twelve layers sharing one layer's names — one set of
+    weights, a different model from the one the user built.  So, in
+    place: a node that landed on a target guid and is structurally the
+    target's op (equal ``signature()``, which excludes the name)
+    becomes the target's node; every other node — created or replaced
+    by a rewrite — gets a clone of its op under a fresh unique name."""
+    for guid, node in list(transplant.nodes.items()):
+        own = target.nodes.get(guid)
+        if own is not None and own.op.signature() == node.op.signature():
+            transplant.nodes[guid] = own
+        else:
+            base = _XFER_SUFFIX.sub("", node.op.name)
+            transplant.nodes[guid] = Node(guid, _renamed(node.op, base))
+    transplant._invalidate()
+
+
 def _serve_cached_search(cache, graph: Graph, config: FFConfig):
     """Remap a cached search result onto the caller's graph.  The
     digest key is guid-free (stable_graph_digest), so the stored
@@ -1262,6 +1292,7 @@ def _serve_cached_search(cache, graph: Graph, config: FFConfig):
         return graph, strat2, cost
     mapping = {og: cg for og, cg in pos.items() if og in best_graph.nodes}
     g2, full = best_graph.remap(mapping, fresh_start=graph._next_guid)
+    _keep_own_ops(g2, graph)
     strat2 = {full[g]: v for g, v in strategy.items() if g in full}
     return g2, strat2, cost
 

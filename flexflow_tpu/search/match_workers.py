@@ -22,6 +22,12 @@ discipline as delta simulation and the seed index.
 Any pool failure (spawn, pickle, worker crash) degrades to the serial
 path and disables the pool for the rest of the process — matching can
 never be less available than before.
+
+The pool ``fork``s.  A chip belongs to one process and a forked child
+inherits the parent's live jax backend, so on an accelerator the pool
+must be started BEFORE the backend initializes (before the first
+``jax.devices()`` / ``FFConfig(num_devices=0)``); workers themselves
+never touch jax.  ``chip_smoke.py`` leaves the pool off.
 """
 
 from __future__ import annotations

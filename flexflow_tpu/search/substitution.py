@@ -498,6 +498,17 @@ def _uname(base: str) -> str:
     return f"{base}_x{_xfer_counter[0]}"
 
 
+def _renamed(op, base: str):
+    """A clone of ``op`` under a fresh unique name.  Safe because
+    operators are immutable descriptors (ops/base docstring); the attrs
+    dict is still copied per clone as insurance."""
+    clone = object.__new__(type(op))
+    clone.__dict__.update(op.__dict__)
+    clone.name = _uname(base)
+    clone.attrs = dict(op.attrs)
+    return clone
+
+
 _PROTO_CACHE: Dict[Tuple, object] = {}
 
 
@@ -508,8 +519,7 @@ def _proto_op(cls, base: str, shape, **kw):
     slice of the search — but every instance of (class, logical input
     shape, attrs) is structurally identical except for its unique debug
     name, so later instances clone a cached prototype and stamp a fresh
-    name.  Safe because operators are immutable descriptors (ops/base
-    docstring); the attrs dict is still copied per clone as insurance."""
+    name (``_renamed``)."""
     key = (cls, shape.sizes, shape.dtype.value,
            tuple(sorted(kw.items())))
     proto = _PROTO_CACHE.get(key)
@@ -517,11 +527,7 @@ def _proto_op(cls, base: str, shape, **kw):
         proto = cls(_uname(base), [shape], **kw)
         _PROTO_CACHE[key] = proto
         return proto
-    clone = object.__new__(cls)
-    clone.__dict__.update(proto.__dict__)
-    clone.name = _uname(base)
-    clone.attrs = dict(proto.attrs)
-    return clone
+    return _renamed(proto, base)
 
 
 # ---------------------------------------------------------------------------

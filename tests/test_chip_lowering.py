@@ -1,0 +1,329 @@
+"""What the CPU can prove about the chip path before a chip is spent.
+
+``chip_smoke.py`` is the proof that the system runs on the TPU; these
+are the refusals it met on the way, held down from the CPU: every
+Pallas entry cross-lowers for the TPU (and, where libtpu serves a
+compile-only topology, compiles under Mosaic) at the smoke's shapes;
+the flash kernel lowers under ``shard_map`` on a multi-device mesh; no
+catch-all hides a kernel failure; the smoke refuses to run without a
+TPU; the compile cache lands where it was placed; and the searched
+multi-chip GPT is still the model the user built.  Named to sort early:
+the suite is cut off by a wall clock.
+"""
+
+import ast
+import collections
+import importlib
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+# the package re-exports functions under the modules' own names
+fa = importlib.import_module("flexflow_tpu.kernels.flash_attention")
+rpa = importlib.import_module("flexflow_tpu.kernels.ragged_paged_attention")
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+MOSAIC = "tpu_custom_call"
+S = jax.ShapeDtypeStruct
+
+# chip_smoke.py's shapes: default-width GPT attention at batch 8, and
+# the GPT_DECODE_SERVE_KW frame (32 slots, 8 heads x 64, page 32 x 128)
+QKV = S((8, 1024, 12, 64), jnp.bfloat16)
+BQ, BK = 512, 1024
+B, H, D, PAGE, PPS = 32, 8, 64, 32, 128
+POOL = B * PPS
+SCALE = 0.125
+
+
+def _flash_entries():
+    lse = S((8 * 12, 1024, 1), jnp.float32)
+    return {
+        "flash_fwd": (
+            lambda q, k, v: fa._flash_forward(
+                q, k, v, True, SCALE, BQ, BK, False, save_lse=True),
+            (QKV, QKV, QKV)),
+        "flash_dq_dkv": (
+            lambda q, k, v, o, lse, do: fa._flash_backward(
+                q, k, v, o, lse, do, True, SCALE, BQ, BK, False),
+            (QKV, QKV, QKV, QKV, lse, QKV)),
+        "flash_partial": (
+            lambda q, k, v: fa._flash_forward_partial(
+                q, k, v, True, SCALE, BQ, BK, False),
+            (QKV, QKV, QKV)),
+    }
+
+
+def _paged_entries():
+    q = S((B, H, D), jnp.float32)
+    table, lens = S((B, PPS), jnp.int32), S((B,), jnp.int32)
+    scales = S((POOL, PAGE), jnp.float32)
+    out = {}
+    for name, dt in (("fp32", jnp.float32), ("bf16", jnp.bfloat16)):
+        pool = S((POOL, PAGE, H, D), dt)
+        out[f"paged_{name}"] = (
+            lambda q, k, v, t, n: rpa._pallas_ragged_paged(
+                q, k, v, t, n, SCALE, False),
+            (q, pool, pool, table, lens))
+    pool = S((POOL, PAGE, H, D), jnp.int8)
+    out["paged_int8"] = (
+        lambda q, k, v, ks, vs, t, n: rpa._pallas_ragged_paged(
+            q, k, v, t, n, SCALE, False, ks, vs),
+        (q, pool, pool, scales, scales, table, lens))
+    return out
+
+
+ENTRIES = {**_flash_entries(), **_paged_entries()}
+# Mosaic calls each entry must hold (the backward is dq + dkv)
+CALLS = {name: 2 if name == "flash_dq_dkv" else 1 for name in ENTRIES}
+
+
+@pytest.mark.parametrize("name", sorted(ENTRIES))
+def test_pallas_entry_cross_lowers_for_tpu(name):
+    """The Pallas→Mosaic lowering (block-shape rule, scalar prefetch,
+    kernel body) accepts the entry with interpret mode OFF."""
+    fn, args = ENTRIES[name]
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count(MOSAIC) == CALLS[name], name
+
+
+@pytest.fixture(scope="module")
+def v5e_device():
+    """One device of a compile-only v5e topology — libtpu serves it
+    with no hardware.  Skips where it cannot."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+    except Exception as e:  # no libtpu / no compile-only support here
+        pytest.skip(f"no compile-only TPU topology: {e!r}")
+    return topo.devices[0]
+
+
+@pytest.mark.parametrize("name", sorted(ENTRIES))
+def test_pallas_entry_compiles_under_mosaic(name, v5e_device):
+    """The chip's own compiler (Mosaic inside XLA:TPU) takes the entry:
+    what cross-lowering cannot see — layouts, relayouts, VMEM/SMEM
+    budgets."""
+    fn, args = ENTRIES[name]
+    sh = jax.sharding.SingleDeviceSharding(v5e_device)
+    args = [S(a.shape, a.dtype, sharding=sh) for a in args]
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert hlo.count(f'custom_call_target="{MOSAIC}"') == CALLS[name]
+
+
+def test_sharded_flash_lowers_and_matches_on_cpu_mesh(mesh8, monkeypatch):
+    """GSPMD refuses a bare Mosaic call on a multi-device mesh; under
+    shard_map (batch over two axes, heads over the third) the wrapper
+    lowers for the TPU with the kernel inside, and on the CPU mesh its
+    values and gradients equal the unsharded kernel's."""
+    batch_axes, head_axes = mesh8.axis_names[:2], mesh8.axis_names[2:]
+
+    def sharded(q, k, v):
+        return fa.flash_attention_sharded(
+            q, k, v, mesh8, batch_axes=batch_axes, head_axes=head_axes,
+            causal=True)
+
+    def loss(f):
+        return lambda q, k, v: jnp.sum(f(q, k, v) ** 2)
+
+    rng = np.random.default_rng(0)
+    q, k, v = (jnp.asarray(rng.normal(size=(4, 512, 2, 16)), jnp.float32)
+               for _ in range(3))
+    plain = lambda q, k, v: fa.flash_attention(q, k, v, causal=True)  # noqa: E731
+    np.testing.assert_allclose(jax.jit(sharded)(q, k, v), plain(q, k, v),
+                               atol=1e-5)
+    got = jax.jit(jax.grad(loss(sharded), argnums=(0, 1, 2)))(q, k, v)
+    want = jax.grad(loss(plain), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=1e-4)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = jax.jit(jax.grad(loss(sharded), argnums=(0, 1, 2))).trace(
+        QKV, QKV, QKV).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count(MOSAIC) == 3  # fwd + dq + dkv, inside shard_map
+
+
+def _broad(handler: ast.ExceptHandler) -> bool:
+    types = (handler.type.elts if isinstance(handler.type, ast.Tuple)
+             else [handler.type])
+    return handler.type is None or any(
+        isinstance(t, ast.Name) and t.id in ("Exception", "BaseException")
+        for t in types)
+
+
+def _catch_alls_around_kernel_calls(repo):
+    roots = [os.path.join(repo, "flexflow_tpu"),
+             os.path.join(repo, "examples")]
+    files = [os.path.join(repo, f) for f in os.listdir(repo)
+             if f.endswith(".py")]
+    for root in roots:
+        for d, _dirs, names in os.walk(root):
+            files += [os.path.join(d, f) for f in names if f.endswith(".py")]
+    bad = []
+    for path in files:
+        with open(path) as f:
+            tree = ast.parse(f.read(), filename=path)
+        kernel_names = {
+            a.asname or a.name
+            for n in ast.walk(tree) if isinstance(n, ast.ImportFrom)
+            and (n.module or "").startswith("flexflow_tpu.kernels")
+            for a in n.names}
+        if os.path.basename(os.path.dirname(path)) == "kernels":
+            # inside the package every function of the module counts
+            kernel_names |= {n.name for n in tree.body
+                             if isinstance(n, ast.FunctionDef)}
+        for node in ast.walk(tree):
+            if not (isinstance(node, ast.Try)
+                    and any(_broad(h) for h in node.handlers)):
+                continue
+            for inner in (x for stmt in node.body for x in ast.walk(stmt)):
+                hit = (
+                    isinstance(inner, ast.ImportFrom)
+                    and (inner.module or "").startswith(
+                        "flexflow_tpu.kernels")
+                ) or (
+                    isinstance(inner, ast.Call)
+                    and isinstance(inner.func, ast.Name)
+                    and inner.func.id in kernel_names)
+                if hit:
+                    bad.append(f"{os.path.relpath(path, repo)}:"
+                               f"{inner.lineno}")
+    return bad
+
+
+def test_no_catch_all_around_a_kernel_call():
+    """A kernel is chosen by a visible rule (shape, mesh size), never by
+    a caught exception: no bare ``except`` / ``except Exception`` may
+    enclose an import of, or a call into, ``flexflow_tpu.kernels``."""
+    bad = _catch_alls_around_kernel_calls(REPO)
+    assert not bad, "catch-all around a kernel call: " + ", ".join(bad)
+
+
+def test_chip_smoke_refuses_to_run_without_a_tpu():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    r = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
+                       capture_output=True, text=True, timeout=240,
+                       env=env, cwd=REPO)
+    assert r.returncode != 0
+    assert "no TPU found" in r.stderr
+    assert '"ok"' not in r.stdout  # no result line
+
+
+def test_chip_smoke_alone_in_a_directory_fails_without_a_result(tmp_path):
+    shutil.copy(os.path.join(REPO, "chip_smoke.py"), tmp_path)
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    r = subprocess.run([sys.executable, "chip_smoke.py"],
+                       capture_output=True, text=True, timeout=240,
+                       env=env, cwd=tmp_path)
+    assert r.returncode != 0
+    assert "flexflow_tpu" in r.stderr and r.stdout.strip() == ""
+
+
+def test_chip_smoke_result_line_is_exactly_the_contract(monkeypatch, capsys):
+    """The last line of stdout is ``{"ok", "device": {"platform", "kind",
+    "count"}}`` and nothing else; the facts ride the line before it.  The
+    legs are stubbed — the line's shape is what is under test."""
+    import types
+
+    from flexflow_tpu.runtime import compile_cache
+
+    sys.path.insert(0, REPO)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(REPO)
+    chip = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite",
+                                 memory_stats=lambda: collections.defaultdict(int))
+    monkeypatch.setattr(jax, "devices", lambda: [chip])
+    monkeypatch.setattr(compile_cache, "place_compile_cache", lambda: "/x")
+    monkeypatch.setattr(chip_smoke, "train_leg",
+                        lambda: ({"losses": [2.0, 1.0]}, {}))
+    monkeypatch.setattr(chip_smoke, "serve_leg", lambda: ({}, {}))
+    want = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+
+    assert chip_smoke.main() == 0
+    lines = capsys.readouterr().out.strip().splitlines()
+    assert json.loads(lines[-1]) == {"ok": True, "device": want}
+    assert json.loads(lines[-2])["train"]["losses"] == [2.0, 1.0]
+
+    def failing_leg():
+        raise RuntimeError("a leg failed")
+
+    monkeypatch.setattr(chip_smoke, "serve_leg", failing_leg)
+    with pytest.raises(RuntimeError, match="a leg failed"):
+        chip_smoke.main()
+    lines = capsys.readouterr().out.strip().splitlines()
+    assert json.loads(lines[-1]) == {"ok": False, "device": want}
+
+
+def test_compile_cache_is_placed(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is touched; unset, the
+    cache is the fixed in-checkout directory."""
+    from flexflow_tpu.runtime.compile_cache import place_compile_cache
+
+    updates = []  # recorded, not applied: the suite runs without a cache
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: updates.append((name, value)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/x")
+    assert place_compile_cache() == "/x" and updates == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    want = os.path.join(REPO, ".jax_cache")
+    assert place_compile_cache() == want
+    assert updates == [("jax_compilation_cache_dir", want)]
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+def test_searched_multichip_gpt_is_the_model_the_user_built():
+    """On four chips the search solves one transformer layer and stamps
+    the solution onto its eleven siblings.  The stamped graph must keep
+    every layer's OWN ops: the lowering keys weights by op name, and a
+    stamp that carried the donor's names trained a weight-tied model
+    (found by chip_smoke's four-chip loss trajectory)."""
+    import flexflow_tpu as ff
+    from flexflow_tpu.models import build_gpt
+    from flexflow_tpu.search.driver import optimize_strategy
+
+    cfg = ff.FFConfig(batch_size=8, num_devices=4, cost_cache_file="")
+    model = build_gpt(cfg)
+    weighted = sorted(n.op.name for n in model.graph.nodes.values()
+                      if n.op._weight_specs)
+    graph, strategy = optimize_strategy(model.graph, cfg, return_graph=True)
+    assert any(mv.num_parts > 1 for mv in strategy.values())
+    names = [n.op.name for n in graph.nodes.values()]
+    assert len(names) == len(set(names)), "duplicate op names after search"
+    assert sorted(n.op.name for n in graph.nodes.values()
+                  if n.op._weight_specs) == weighted
+    by_name = {n.op.name: n.op for n in model.graph.nodes.values()}
+    assert all(n.op is by_name[n.op.name] for n in graph.nodes.values()
+               if n.op.name in by_name)
+
+
+def test_bench_names_its_device_and_refuses_an_unknown_chip():
+    """bench.py is one process: a CPU dry run is labelled as one (own
+    metric name, platform cpu, no MFU) and no peak is ever assumed."""
+    sys.path.insert(0, REPO)
+    try:
+        import bench
+    finally:
+        sys.path.remove(REPO)
+    assert "TPU v5 lite" in bench.PEAK_BF16_FLOPS
+    with open(os.path.join(REPO, "bench.py")) as f:
+        src = f.read()
+    assert "subprocess" not in src and "LASTGOOD" not in src
+    r = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
+                       capture_output=True, text=True, timeout=240,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=REPO)
+    assert r.returncode == 0, r.stderr[-1500:]
+    rec = json.loads(r.stdout.strip().splitlines()[-1])
+    assert rec["platform"] == "cpu" and rec["device_count"] >= 1
+    assert "mfu" not in rec and rec["metric"].endswith("cpu_dry_run")

@@ -129,9 +129,10 @@ def test_error_feedback_tightens_accumulated_error(mesh8):
                 acc = acc + y
             return acc
 
-        return np.asarray(shard_map(
+        # jitted: eager shard_map dispatches the unrolled steps op by op
+        return np.asarray(jax.jit(shard_map(
             local, mesh=mesh8, in_specs=(P(axes),), out_specs=P(),
-        )(xs))
+        ))(xs))
 
     want = np.sum(np.asarray(xs), axis=0) * steps
     err_plain = float(np.max(np.abs(run(False) - want)))

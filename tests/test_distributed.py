@@ -53,14 +53,6 @@ def _single_process_reference() -> float:
     return hist[-1]["loss"]
 
 
-_OLD_JAX = tuple(map(int, __import__("jax").__version__.split(".")[:2])) < (0, 5)
-_OLD_JAX_XFAIL = pytest.mark.xfail(
-    condition=_OLD_JAX, strict=False,
-    reason="jax 0.4.x CPU backend: multiprocess computations are "
-           "unimplemented; heals on a newer toolchain")
-
-
-@_OLD_JAX_XFAIL
 def test_two_process_training_matches_single_process():
     port = _free_port()
     worker = os.path.join(os.path.dirname(__file__), "dist_worker.py")
@@ -119,7 +111,6 @@ def _single_process_reference_8(tmp_path=None) -> float:
     return hist[-1]["loss"]
 
 
-@_OLD_JAX_XFAIL
 def test_four_process_training_with_multihost_checkpoint(tmp_path):
     """4 processes x 2 devices: the dp mesh axes span hosts (gradient
     sync crosses the 'DCN' process boundary), training runs 2 epochs,

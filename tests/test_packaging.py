@@ -75,12 +75,9 @@ def test_package_smoke_import():
 
 
 def test_explicit_spmd_imports_shard_map_from_compat():
-    """ROADMAP carry-over rule, now a guard: every explicit-SPMD module
-    must import shard_map from flexflow_tpu/comm/compat.py (the one
-    place the jax version drift — jax.shard_map/check_vma vs
-    jax.experimental.shard_map/check_rep — is absorbed), never from
-    jax directly.  A direct import works on one jax and breaks on the
-    other, exactly the drift the compat shim exists to kill."""
+    """Every explicit-SPMD module imports shard_map from
+    flexflow_tpu/comm/compat.py (one spelling of the replication
+    checker, off by default), never from jax directly."""
     import ast
 
     pkg = os.path.join(REPO, "flexflow_tpu")

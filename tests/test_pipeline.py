@@ -21,14 +21,6 @@ from flexflow_tpu.parallel.pipeline import (
 )
 
 
-_OLD_JAX = tuple(map(int, __import__("jax").__version__.split(".")[:2])) < (0, 5)
-_OLD_JAX_XFAIL = pytest.mark.xfail(
-    condition=_OLD_JAX, strict=False,
-    reason="jax 0.4.x: partial-manual shard_map axis_index lowers to a "
-           "PartitionId the SPMD partitioner rejects (parallel/pipeline.py "
-           "NOTE); heals on a newer toolchain")
-
-
 def _pp_mesh(n):
     from jax.sharding import Mesh
 
@@ -144,7 +136,6 @@ class TestPipelinedModel:
         )
         np.testing.assert_allclose(y1, y2, atol=1e-5)
 
-    @_OLD_JAX_XFAIL
     def test_pipelined_train_step_runs_and_learns(self):
         m = self._build(4, PipelineConfig(num_stages=2, num_microbatches=4))
         rng = np.random.default_rng(2)
@@ -191,7 +182,6 @@ class TestPipelinedModel:
 # ---------------------------------------------------------------------------
 
 
-@_OLD_JAX_XFAIL
 def test_search_proposes_pipeline_on_memory_bound_model():
     """The GPipe case, search-discovered: hidden dim 1021 is PRIME (no
     tensor-parallel divisor <= 8) and the weights + optimizer state of

@@ -1,7 +1,6 @@
 """Keras preprocessing utilities + activation rematerialization."""
 
 import numpy as np
-import pytest
 
 import flexflow_tpu as ff
 from flexflow_tpu.keras import preprocessing as pp
@@ -64,15 +63,6 @@ def test_remat_matches_baseline_numerics():
     np.testing.assert_allclose(base, remat, rtol=1e-6)
 
 
-_OLD_JAX = tuple(map(int, __import__("jax").__version__.split(".")[:2])) < (0, 5)
-_OLD_JAX_XFAIL = pytest.mark.xfail(
-    condition=_OLD_JAX, strict=False,
-    reason="jax 0.4.x: partial-manual shard_map axis_index lowers to a "
-           "PartitionId the SPMD partitioner rejects (parallel/pipeline.py "
-           "NOTE); heals on a newer toolchain")
-
-
-@_OLD_JAX_XFAIL
 def test_remat_pipeline():
     from flexflow_tpu.models import build_transformer
     from flexflow_tpu.parallel import PipelineConfig

@@ -1,0 +1,73 @@
+"""What the harness asks of the device and of compiled programs."""
+
+from __future__ import annotations
+
+import contextlib
+import shutil
+import tempfile
+
+MOSAIC_TARGET = 'custom_call_target="tpu_custom_call"'
+
+
+def mosaic_calls(compiled) -> int:
+    """Mosaic (Pallas TPU) custom calls in a compiled program — what
+    proves a kernel ran compiled, not interpreted or replaced."""
+    return compiled.as_text().count(MOSAIC_TARGET)
+
+
+def memory_analysis_bytes(compiled) -> dict:
+    """XLA's own accounting of one program: arguments, outputs,
+    temporaries (``memory_stats`` misses a step's temporaries)."""
+    m = compiled.memory_analysis()
+    return {"argument": m.argument_size_in_bytes,
+            "output": m.output_size_in_bytes,
+            "temp": m.temp_size_in_bytes,
+            "alias": m.alias_size_in_bytes}
+
+
+def memory_stats() -> list:
+    import jax
+
+    return [{k: (d.memory_stats() or {}).get(k)
+             for k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")}
+            for d in jax.devices()]
+
+
+class Tracer:
+    """One short profiler capture inside a run, reduced in the run.  The
+    Python tracer is off (it slows the host and swamps the file); host
+    TraceAnnotation spans and the device planes stay on."""
+
+    def __init__(self):
+        self.dir = None
+
+    def start(self) -> None:
+        import jax
+
+        self.dir = tempfile.mkdtemp(prefix="bench_trace_")
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(self.dir, profiler_options=options)
+
+    def stop(self) -> None:
+        import jax
+
+        jax.profiler.stop_trace()
+
+    def reduce(self):
+        """The trace as ``trace_reduce.load`` gives it; the files go."""
+        from benchmarks.harness import trace_reduce
+
+        try:
+            return trace_reduce.load_file(trace_reduce.find_xplane(self.dir))
+        finally:
+            shutil.rmtree(self.dir, ignore_errors=True)
+
+
+def annotation(name: str, on: bool):
+    """A host span on the profiler's clock while ``on``; nothing else."""
+    if not on:
+        return contextlib.nullcontext()
+    import jax
+
+    return jax.profiler.TraceAnnotation(name)

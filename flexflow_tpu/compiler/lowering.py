@@ -31,6 +31,7 @@ from flexflow_tpu.core.optype import OperatorType
 from flexflow_tpu.core.ptensor import DataType
 from flexflow_tpu.losses import LossType, compute_loss
 from flexflow_tpu.metrics import MetricsType, compute_metrics
+from flexflow_tpu.obs.annotate import FIRST_CALL_PHASE, phase_span
 from flexflow_tpu.ops.base import LoweringContext, OpSharding, ShardAnnot
 from flexflow_tpu.ops.inout import InputOp
 from flexflow_tpu.optimizers import Optimizer
@@ -726,6 +727,10 @@ class CompiledModel:
         metrics stacked over N)."""
         if getattr(self, "_train_steps_fn", None) is None:
             self._train_steps_fn = self._build_train_steps()
+            with phase_span(FIRST_CALL_PHASE + "train_steps"):
+                return self._train_steps_fn(
+                    params, opt_state, state, rng,
+                    tuple(inputs_stacked), labels_stacked)
         return self._train_steps_fn(params, opt_state, state, rng,
                                     tuple(inputs_stacked), labels_stacked)
 
@@ -755,6 +760,11 @@ class CompiledModel:
     def train_step(self, params, opt_state, state, rng, inputs, labels):
         if self._train_step_fn is None:
             self._train_step_fn = self._build_train_step()
+            # the first call traces, lowers and compiles (or loads from
+            # the persistent cache): on the timeline under its own tag
+            with phase_span(FIRST_CALL_PHASE + "train_step"):
+                return self._train_step_fn(params, opt_state, state, rng,
+                                           inputs, labels)
         return self._train_step_fn(params, opt_state, state, rng, inputs, labels)
 
     def eval_step(self, params, state, inputs, labels):

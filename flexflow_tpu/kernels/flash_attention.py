@@ -172,6 +172,7 @@ def _flash_forward(q, k, v, causal: bool, scale: float,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_fwd",
         **_mosaic_params(interpret),
     )(qt, kt, vt)
     if save_lse:
@@ -312,6 +313,7 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale,
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
         **_mosaic_params(interpret),
     )(qt, kt, vt, dot, lse, delta)
 
@@ -329,6 +331,7 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv",
         **_mosaic_params(interpret),
     )(qt, kt, vt, dot, lse, delta)
 
@@ -533,6 +536,7 @@ def _flash_forward_partial(q, k, v, causal, scale, block_q, block_k, interpret):
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd_partial",
         **_mosaic_params(interpret),
     )(qt, kt, vt)
     return (

@@ -469,7 +469,10 @@ class FFModel:
         capability the reference only stubbed: OP_PIPELINE,
         ffconst.h:148)."""
         from flexflow_tpu.compiler.lowering import CompiledModel, data_parallel_strategy
+        from flexflow_tpu.obs.annotate import PHASE_PREFIX, phase_span
+        from flexflow_tpu.runtime.compile_cache import watch_jax_compiles
 
+        watch_jax_compiles()
         if comp_mode not in ("training", "inference"):
             raise ValueError(
                 f"comp_mode must be 'training' or 'inference', got {comp_mode!r}"
@@ -829,9 +832,16 @@ class FFModel:
                 # narrow-block solves run on it (rewrites bake
                 # full-mesh repartition views narrow blocks can't host)
                 _disagg_base_graph = self.graph
-                best_graph, strategy = optimize_strategy(
-                    self.graph, self.config, return_graph=True
-                )
+                from flexflow_tpu import native as _native
+
+                # the search loads the native engine on first use, and
+                # builds it (make) in a fresh checkout: its own span
+                with phase_span(PHASE_PREFIX + "setup.native_build"):
+                    _native.get_lib()
+                with phase_span(PHASE_PREFIX + "setup.search"):
+                    best_graph, strategy = optimize_strategy(
+                        self.graph, self.config, return_graph=True
+                    )
                 self.graph = best_graph
                 searched_strategy = True
                 from flexflow_tpu.search import driver as _kvdriver
@@ -1363,110 +1373,111 @@ class FFModel:
 
         from flexflow_tpu.compiler.placement_lowering import placeable
 
-        if pipeline is None and mesh is None and strategy and placeable(
-                self.graph, strategy, self.config):
-            # mesh is None: a user-supplied mesh commits the whole graph
-            # to one submesh program, which a 2-block placed strategy
-            # cannot honor — fall through to the flat lowering (which
-            # respects mesh=) instead of silently ignoring it
-            # disjoint start_part device blocks that the placed lowering
-            # can express: EXECUTED inter-op placement (reference:
-            # mapper.cc:371-475 places ops on disjoint device sets and
-            # Legion runs them).  Multi-block strategies OUTSIDE its
-            # support (>2 blocks, multi-tensor cuts, grad accumulation)
-            # keep the historical behavior: offsets are inert and the
-            # single SPMD program replicates small-degree ops.
-            from flexflow_tpu.compiler.placement_lowering import (
-                PlacedCompiledModel,
-            )
+        with phase_span(PHASE_PREFIX + "setup.lower"):
+            if pipeline is None and mesh is None and strategy and placeable(
+                    self.graph, strategy, self.config):
+                # mesh is None: a user-supplied mesh commits the whole graph
+                # to one submesh program, which a 2-block placed strategy
+                # cannot honor — fall through to the flat lowering (which
+                # respects mesh=) instead of silently ignoring it
+                # disjoint start_part device blocks that the placed lowering
+                # can express: EXECUTED inter-op placement (reference:
+                # mapper.cc:371-475 places ops on disjoint device sets and
+                # Legion runs them).  Multi-block strategies OUTSIDE its
+                # support (>2 blocks, multi-tensor cuts, grad accumulation)
+                # keep the historical behavior: offsets are inert and the
+                # single SPMD program replicates small-degree ops.
+                from flexflow_tpu.compiler.placement_lowering import (
+                    PlacedCompiledModel,
+                )
 
-            # always-on legality gate on the cut about to execute
-            # (search proposals were gated at proposal time; this also
-            # covers caller-supplied placed strategies with findings
-            # instead of opaque lowering errors).  Shares the export
-            # path's one-shot lint cache.
-            from flexflow_tpu.analysis import (
-                AnalysisError,
-                emit_findings,
-            )
+                # always-on legality gate on the cut about to execute
+                # (search proposals were gated at proposal time; this also
+                # covers caller-supplied placed strategies with findings
+                # instead of opaque lowering errors).  Shares the export
+                # path's one-shot lint cache.
+                from flexflow_tpu.analysis import (
+                    AnalysisError,
+                    emit_findings,
+                )
 
-            _bad = _placed_lint_errors()
-            if _bad:
-                emit_findings(_bad)
-                raise AnalysisError(
-                    "placed strategy is illegal for this graph/mesh",
-                    _bad)
-            self.compiled = PlacedCompiledModel(
-                self.graph,
-                strategy,
-                self.config,
-                LossType.from_any(loss_type),
-                list(metrics),
-                self.optimizer,
-            )
-        elif pipeline is not None:
-            from flexflow_tpu.compiler.pipeline_lowering import PipelinedCompiledModel
-
-            self.compiled = PipelinedCompiledModel(
-                self.graph,
-                strategy,
-                self.config,
-                LossType.from_any(loss_type),
-                list(metrics),
-                self.optimizer,
-                pipeline=pipeline,
-                block_of=block_of,
-            )
-        elif (
-            self.pipeline_proposal is not None
-            and mesh is None
-            and comp_mode == "training"
-        ):
-            # (multi-process raises inside the constructor and falls
-            # back to flat via the except below)
-            # flat is infeasible and the general staged proposal won:
-            # lower it via the heterogeneous staged executor (GPipe over
-            # arbitrary graph cuts — compiler/staged_pipeline_lowering)
-            from flexflow_tpu.compiler.staged_pipeline_lowering import (
-                StagedPipelinedModel,
-            )
-
-            try:
-                self.compiled = StagedPipelinedModel(
+                _bad = _placed_lint_errors()
+                if _bad:
+                    emit_findings(_bad)
+                    raise AnalysisError(
+                        "placed strategy is illegal for this graph/mesh",
+                        _bad)
+                self.compiled = PlacedCompiledModel(
                     self.graph,
-                    self.pipeline_proposal.stage_guids,
-                    self.pipeline_proposal.num_microbatches,
+                    strategy,
                     self.config,
                     LossType.from_any(loss_type),
                     list(metrics),
                     self.optimizer,
                 )
-            except (NotImplementedError, ValueError):
-                # stateful stages etc.: keep the flat lowering (the
-                # proposal stays surfaced on self.pipeline_proposal)
-                self.compiled = None
-            if self.compiled is None:
+            elif pipeline is not None:
+                from flexflow_tpu.compiler.pipeline_lowering import PipelinedCompiledModel
+
+                self.compiled = PipelinedCompiledModel(
+                    self.graph,
+                    strategy,
+                    self.config,
+                    LossType.from_any(loss_type),
+                    list(metrics),
+                    self.optimizer,
+                    pipeline=pipeline,
+                    block_of=block_of,
+                )
+            elif (
+                self.pipeline_proposal is not None
+                and mesh is None
+                and comp_mode == "training"
+            ):
+                # (multi-process raises inside the constructor and falls
+                # back to flat via the except below)
+                # flat is infeasible and the general staged proposal won:
+                # lower it via the heterogeneous staged executor (GPipe over
+                # arbitrary graph cuts — compiler/staged_pipeline_lowering)
+                from flexflow_tpu.compiler.staged_pipeline_lowering import (
+                    StagedPipelinedModel,
+                )
+
+                try:
+                    self.compiled = StagedPipelinedModel(
+                        self.graph,
+                        self.pipeline_proposal.stage_guids,
+                        self.pipeline_proposal.num_microbatches,
+                        self.config,
+                        LossType.from_any(loss_type),
+                        list(metrics),
+                        self.optimizer,
+                    )
+                except (NotImplementedError, ValueError):
+                    # stateful stages etc.: keep the flat lowering (the
+                    # proposal stays surfaced on self.pipeline_proposal)
+                    self.compiled = None
+                if self.compiled is None:
+                    self.compiled = CompiledModel(
+                        self.graph, strategy, self.config,
+                        LossType.from_any(loss_type), list(metrics),
+                        self.optimizer, mesh=mesh,
+                        sync_precision=self.sync_precision_map,
+                        sync_schedule=self.sync_schedule,
+                        zero_groups=self.zero_groups,
+                    )
+            else:
                 self.compiled = CompiledModel(
-                    self.graph, strategy, self.config,
-                    LossType.from_any(loss_type), list(metrics),
-                    self.optimizer, mesh=mesh,
+                    self.graph,
+                    strategy,
+                    self.config,
+                    LossType.from_any(loss_type),
+                    list(metrics),
+                    self.optimizer,
+                    mesh=mesh,
                     sync_precision=self.sync_precision_map,
                     sync_schedule=self.sync_schedule,
                     zero_groups=self.zero_groups,
                 )
-        else:
-            self.compiled = CompiledModel(
-                self.graph,
-                strategy,
-                self.config,
-                LossType.from_any(loss_type),
-                list(metrics),
-                self.optimizer,
-                mesh=mesh,
-                sync_precision=self.sync_precision_map,
-                sync_schedule=self.sync_schedule,
-                zero_groups=self.zero_groups,
-            )
         from flexflow_tpu.compiler.staged_pipeline_lowering import (
             StagedPipelinedModel as _Staged,
         )
@@ -1526,9 +1537,11 @@ class FFModel:
             staged=(self.pipeline_proposal
                     if isinstance(self.compiled, _Staged) else None),
         )
-        self.params, self.state = self.compiled.init_params(self.config.seed)
-        self.opt_state = self.optimizer.init_state(self.params)
-        self.opt_state = self.compiled.shard_opt_state(self.opt_state)
+        with phase_span(PHASE_PREFIX + "setup.init_params"):
+            self.params, self.state = self.compiled.init_params(
+                self.config.seed)
+            self.opt_state = self.optimizer.init_state(self.params)
+            self.opt_state = self.compiled.shard_opt_state(self.opt_state)
         return self.compiled
 
     def recompile(self):
@@ -1923,6 +1936,10 @@ class FFModel:
         # lowering threaded the markers because device_trace_dir was
         # set at compile); after the run the capture is ingested and
         # tag-matched against the predicted comm lanes.
+        from flexflow_tpu.obs import annotate
+        from flexflow_tpu.obs.metrics import METRICS
+
+        fit_steps = METRICS.counter("fit.steps")
         capture_dir = self.config.device_trace_dir
         trace_active = False
         self.lane_drift_report = None
@@ -1956,55 +1973,49 @@ class FFModel:
                 loader.iter_traced(trace_n) if use_trace else
                 (("single", i, l) for i, l in loader)
             )
-            for kind, inputs, labels in batch_iter:
+            # ff.phase/fit.data: the wait for the loader's next batch
+            for kind, inputs, labels in annotate.spanned(
+                    annotate.PHASE_PREFIX + "fit.data", batch_iter):
                 self._rng_counter += 1
                 rng = jax.random.key(self._rng_counter)
-                step_span = None
-                if trace_active:
-                    from flexflow_tpu.obs import annotate as _annot
-
-                    # one ff.phase/step annotation per optimizer step:
-                    # the window trace_ingest assigns lane markers to
-                    step_span = _annot.phase_span(_annot.STEP_PHASE)
-                    step_span.__enter__()
-                if profiler is not None:
-                    profiler.start_step()
-                    profiler.start_phase("dispatch")
-                if kind == "stack":
-                    (self.params, self.opt_state, self.state, losses, ms) = (
-                        self.compiled.train_steps(
+                # one ff.phase/step annotation per dispatch: how long
+                # the host is held in it — and, inside a
+                # device_trace_dir capture, the window trace_ingest
+                # assigns lane markers to
+                with annotate.phase_span(annotate.STEP_PHASE):
+                    if profiler is not None:
+                        profiler.start_step()
+                        profiler.start_phase("dispatch")
+                    if kind == "stack":
+                        (self.params, self.opt_state, self.state, losses,
+                         ms) = self.compiled.train_steps(
                             self.params, self.opt_state, self.state, rng,
-                            inputs, labels
-                        )
-                    )
-                    loss = losses[-1]
-                    # summing the stacked per-step metric trees equals
-                    # the single-step accumulation below
-                    m = jax.tree.map(lambda a: a.sum(axis=0), ms)
-                    n_this = len(losses)
-                else:
-                    (self.params, self.opt_state, self.state, loss, m) = (
-                        self.compiled.train_step(
+                            inputs, labels)
+                        loss = losses[-1]
+                        # summing the stacked per-step metric trees
+                        # equals the single-step accumulation below
+                        m = jax.tree.map(lambda a: a.sum(axis=0), ms)
+                        n_this = len(losses)
+                    else:
+                        (self.params, self.opt_state, self.state, loss,
+                         m) = self.compiled.train_step(
                             self.params, self.opt_state, self.state, rng,
-                            inputs, labels
-                        )
-                    )
-                    n_this = 1
-                if profiler is not None:
-                    # host phases: enqueue (dispatch) vs device
-                    # completion (wait) — the measured side of the
-                    # DriftReport; the fence makes the step time real
-                    profiler.end_phase("dispatch")
-                    profiler.start_phase("wait")
-                    float(loss)
-                    profiler.end_phase("wait")
-                    profiler.end_step()
-                elif step_span is not None:
-                    # the step annotation must cover the device work,
-                    # so a capture without profiling still fences
-                    float(loss)
-                if step_span is not None:
-                    step_span.__exit__(None, None, None)
+                            inputs, labels)
+                        n_this = 1
+                    if profiler is not None:
+                        # host phases: enqueue (dispatch) vs device
+                        # completion (wait) — the measured side of the
+                        # DriftReport; the fence makes the step time real
+                        profiler.end_phase("dispatch")
+                        profiler.start_phase("wait")
+                        float(loss)
+                        profiler.end_phase("wait")
+                        profiler.end_step()
+                    elif trace_active:
+                        # inside a capture the step annotation must
+                        # cover the device work, so it fences
+                        float(loss)
+                fit_steps.inc(n_this)
                 if recompile_state is not None and recompile_state.check(self):
                     # drop the accumulator AND this step's metrics: the
                     # re-lowered program may emit a different metric tree
@@ -2023,21 +2034,22 @@ class FFModel:
                         try:
                             import os as _os
 
-                            from flexflow_tpu.obs import annotate as _annot
-
                             _os.makedirs(capture_dir, exist_ok=True)
                             jax.profiler.start_trace(capture_dir)
-                            _annot.arm()
-                            _annot.LANES.clear()
                             trace_active = True
                         except Exception:
                             pass  # telemetry must never fail a fit
-            if acc is not None:  # None if a recompile landed on the last batch
-                metrics.update(acc)
+            # ff.phase/fit.epoch_sync: the epoch-end readback, where the
+            # host waits for every step it dispatched ahead
+            with annotate.phase_span(
+                    annotate.PHASE_PREFIX + "fit.epoch_sync"):
+                if acc is not None:  # None if a recompile landed on the last batch
+                    metrics.update(acc)
+                epoch_loss = float(loss)
             if verbose:
-                print(f"epoch {epoch}: loss={float(loss):.4f} {metrics}")
+                print(f"epoch {epoch}: loss={epoch_loss:.4f} {metrics}")
             logs = metrics.report()
-            logs["loss"] = float(loss)
+            logs["loss"] = epoch_loss
             if validation_data is not None:
                 vx, vy = validation_data
                 val = self.evaluate(x=vx, y=vy, batch_size=batch_size)
@@ -2064,9 +2076,6 @@ class FFModel:
         for cb in callbacks:
             cb.on_train_end()
         if trace_active:
-            from flexflow_tpu.obs import annotate as _annot
-
-            _annot.disarm()
             try:
                 float(loss)  # fence: the last step must land in-trace
                 jax.profiler.stop_trace()
@@ -2164,7 +2173,6 @@ class FFModel:
             # honesty flag to carry the caveat
             METRICS.gauge("fit.step_mean_s").set(s["mean_s"])
             METRICS.gauge("fit.step_p95_s").set(s["p95_s"])
-            METRICS.counter("fit.steps").inc(int(s["steps"]))
             hist = METRICS.histogram("fit.step_s")
             for t in profiler.step_times[1:]:
                 hist.observe(t)

@@ -10,16 +10,21 @@ identifiers onto the EXECUTED program so a real
 ``obs/trace_ingest.py`` can match measured events to predicted lanes
 by TAG EQUALITY — never by fuzzy kernel names:
 
-* ``phase_span(tag)`` — a host-side ``jax.profiler.TraceAnnotation``
-  around dispatch-level phases (``ff.phase/step``,
-  ``ff.phase/decode_frame``); armed only while a capture is active
-  (``arm()``/``disarm()``, driven by ``runtime.profiler.device_trace``
-  and ``model.fit``'s capture window), one boolean check otherwise.
-* ``lane_stamp(tag, dep)`` — an ordered ``io_callback`` INSIDE the
-  jitted step that (a) emits a zero-length ``TraceAnnotation`` marker
-  into the live trace at the moment the runtime reaches that point of
-  the dataflow and (b) records the host timestamp in ``LANES``.  A
-  bucket's collective is bracketed by ``<tag>#issue``/``<tag>#done``
+* ``phase_span(tag)`` — THE span primitive of the program: a host-side
+  ``jax.profiler.TraceAnnotation`` around a dispatch-level phase
+  (``ff.phase/step``, ``ff.phase/decode_frame``, ``ff.phase/serve.wait``,
+  ``ff.phase/setup.search`` ...) AND one sample of its duration in a
+  ``METRICS`` histogram (``hist_name(tag)``: ``serve.wait_s``).  Always
+  on: a TraceMe records only while some profiler session is live,
+  whoever started it (``runtime.profiler.device_trace``, ``fit``'s
+  ``device_trace_dir`` capture, a benchmark's own ``start_trace``, an
+  operator's ``jax.profiler.start_server``); the whole span costs
+  about 2 µs with none live.  The histogram is what
+  ``obs/exposition.py`` shows an operator with no capture at all.
+* ``lane_stamp(tag, dep)`` — a host callback INSIDE the jitted step
+  that emits a zero-length ``TraceAnnotation`` marker into the live
+  trace at the moment the runtime reaches that point of the dataflow.
+  A bucket's collective is bracketed by ``<tag>#issue``/``<tag>#done``
   markers whose data dependences (payload → issue → collective →
   done) pin them to the lane's real execution window.  Stamps are
   lowered only when ``FFConfig.device_trace_dir`` is set — the default
@@ -29,47 +34,36 @@ by TAG EQUALITY — never by fuzzy kernel names:
 CPU-mesh caveat (honesty rule): the host trace carries these named
 scopes and the markers measure host-observed issue/completion of the
 lane's thunks; ICI/DCN wire behavior stays simulated until the same
-capture runs on a real TPU, where ``scope()``'s ``jax.named_scope``
-additionally prefixes the lane tag onto the lowered HLO (visible in
-the xplane device rows).
+capture runs on a real TPU.
 """
 
 from __future__ import annotations
 
-import contextlib
-import threading
 import time
-from typing import Dict, List
+from typing import Dict
+
+from flexflow_tpu.obs.metrics import METRICS
 
 LANE_PREFIX = "ff.lane/"
 PHASE_PREFIX = "ff.phase/"
 STEP_PHASE = PHASE_PREFIX + "step"
 DECODE_PHASE = PHASE_PREFIX + "decode_frame"
 PREFILL_PHASE = PHASE_PREFIX + "prefill_chunk"
+# + program name: the first call of a jitted program (trace + lower +
+# compile, or load from the persistent cache)
+FIRST_CALL_PHASE = PHASE_PREFIX + "setup.first_call."
 ISSUE_MARK = "#issue"
 DONE_MARK = "#done"
 
-# host-annotation arming: flipped by the device_trace context manager /
-# fit's capture window.  The disarmed fast path is one module-global
-# load + branch — the same contract as the event bus.
-_ARMED = False
-
-
-def arm() -> None:
-    global _ARMED
-    _ARMED = True
-
-
-def disarm() -> None:
-    global _ARMED
-    _ARMED = False
-
-
-def armed() -> bool:
-    return _ARMED
-
-
-_NULL = contextlib.nullcontext()
+# histograms of the three tags that predate the naming rule (a tag
+# without its prefix, plus ``_s``) keep the meaning their readers know:
+# ``decode.frame_s`` is dispatch + wait only, ``fit.step_s`` a fenced
+# step under ``profiling`` — so the spans get names of their own
+_HIST_NAMES = {
+    STEP_PHASE: "fit.dispatch_s",
+    DECODE_PHASE: "serve.step_s",
+    PREFILL_PHASE: "serve.prefill_chunk_s",
+}
 
 
 def lane_tag(lane_id: str) -> str:
@@ -91,65 +85,64 @@ def parse_tag(name: str):
     return body, None
 
 
-def phase_span(tag: str):
-    """Context manager: a host TraceAnnotation when a capture is
-    armed, a shared null context otherwise (one boolean on the off
-    path)."""
-    if not _ARMED:
-        return _NULL
-    import jax
-
-    return jax.profiler.TraceAnnotation(tag)
+def hist_name(tag: str) -> str:
+    """The ``METRICS`` histogram a phase tag's durations land in:
+    ``ff.phase/serve.admit`` -> ``serve.admit_s``."""
+    return _HIST_NAMES.get(tag) or tag[len(PHASE_PREFIX):] + "_s"
 
 
-def scope(lane_id: str):
-    """Tracing-time ``jax.named_scope`` carrying the lane tag — zero
-    runtime cost (HLO metadata only); a TPU xplane capture shows the
-    lane's ops under this prefix."""
-    import jax
-
-    return jax.named_scope(lane_tag(lane_id))
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation, imported on first use
+_HISTS: Dict[str, object] = {}  # tag -> its Histogram, looked up once
 
 
-class LaneRecorder:
-    """Host-side lane stamp buffer: (tag, perf_counter seconds) rows in
-    arrival order, appended by the ``lane_stamp`` callbacks.  The
-    trace-file ingest is the primary consumer of lane timings; this
-    buffer is the in-process cross-check (and the only measured side
-    when no capture is running)."""
+class phase_span:
+    """Context manager: ``tag`` as a host TraceAnnotation on the
+    profiler's clock, and its ``perf_counter_ns`` duration (seconds)
+    into the tag's histogram.  One fresh object per use, so spans nest
+    and threads share nothing.  Setting ``keep = False`` inside the
+    block leaves the histogram without this sample."""
 
-    def __init__(self):
-        self._lock = threading.Lock()
-        self.rows: List[tuple] = []
+    __slots__ = ("_ann", "_hist", "_t0", "keep")
 
-    def record(self, tag: str, t: float) -> None:
-        with self._lock:
-            self.rows.append((tag, t))
+    def __init__(self, tag: str):
+        global _TraceAnnotation
+        hist = _HISTS.get(tag)
+        if hist is None:
+            if _TraceAnnotation is None:
+                from jax.profiler import TraceAnnotation
 
-    def clear(self) -> None:
-        with self._lock:
-            self.rows.clear()
+                _TraceAnnotation = TraceAnnotation
+            hist = _HISTS[tag] = METRICS.histogram(hist_name(tag))
+        self._hist = hist
+        self._ann = _TraceAnnotation(tag)
+        self.keep = True
 
-    def spans(self) -> Dict[str, List[tuple]]:
-        """lane_id -> [(issue_t, done_t), ...] paired in arrival
-        order; unpaired stamps are dropped."""
-        with self._lock:
-            rows = list(self.rows)
-        open_t: Dict[str, float] = {}
-        out: Dict[str, List[tuple]] = {}
-        for tag, t in rows:
-            parsed = parse_tag(tag)
-            if parsed is None:
-                continue
-            lane, marker = parsed
-            if marker == "issue":
-                open_t[lane] = t
-            elif marker == "done" and lane in open_t:
-                out.setdefault(lane, []).append((open_t.pop(lane), t))
-        return out
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        dt = time.perf_counter_ns() - self._t0
+        self._ann.__exit__(exc_type, exc, tb)
+        if self.keep:
+            self._hist.observe(dt * 1e-9)
+        return False
 
 
-LANES = LaneRecorder()
+def spanned(tag: str, iterable):
+    """Yield ``iterable``'s items with every FETCH under
+    ``phase_span(tag)`` — the wait for a loader's next batch.  The fetch
+    that finds the iterator exhausted is no sample."""
+    it = iter(iterable)
+    while True:
+        with phase_span(tag) as span:
+            try:
+                item = next(it)
+            except StopIteration:
+                span.keep = False
+                return
+        yield item
 
 
 def lane_stamp(lane_id: str, marker: str, dep):
@@ -158,8 +151,7 @@ def lane_stamp(lane_id: str, marker: str, dep):
     thread the result into downstream live values — that data
     dependence both pins the stamp's execution point (after ``dep``,
     before its consumers) and keeps it from being dead-code
-    eliminated.  At run time the callback records
-    ``time.perf_counter`` into ``LANES`` and emits a marker
+    eliminated.  At run time the callback emits a marker
     ``TraceAnnotation`` so an active ``device_trace`` capture carries
     the tag.  ``pure_callback`` rather than the ordered ``io_callback``
     on purpose: the data dependence on ``dep`` already orders the
@@ -174,7 +166,6 @@ def lane_stamp(lane_id: str, marker: str, dep):
                                else DONE_MARK)
 
     def _cb(_x):
-        LANES.record(tag, time.perf_counter())
         with jax.profiler.TraceAnnotation(tag):
             pass
         return np.float32(0.0)

@@ -37,11 +37,8 @@ EVENT_KINDS = {
     "search.substitution": {"xfer", "action"},
     "search.candidate": {"cost_s", "best_s", "improved"},
     "search.split": {"op", "pre_nodes", "post_nodes"},
-    # k-way chain decomposition (production-scale graphs, PR 7) —
-    # emitted since the chain search landed but never registered, so
-    # ffobs validate rejected logs containing them
+    # k-way chain decomposition (production-scale graphs, PR 7)
     "search.chain": {"nodes", "segments"},
-    "search.chain_done": {"bound_s", "cost_s"},
     # series-parallel decomposition (PR 12, search/decompose.py): one
     # event per oversized (sub)graph naming the chosen decomposition —
     # mode "chain" (width-1 bottleneck cuts, the PR 7 degenerate case),
@@ -107,32 +104,18 @@ EVENT_KINDS = {
     # prompt that went through the batched KV writer — tokens written,
     # chunk passes paid (vs one decode frame per token without it)
     "decode.prefill": {"rid", "tokens", "chunks"},
-    # radix prefix sharing (runtime/decode.py PageAllocator): one
-    # prefix_hit per admission that claimed trie-cached pages by
-    # refcount instead of allocating (pages claimed, prompt tokens
-    # skipped); one cow per copy-on-write page copy at a mid-page
-    # divergence (the reserve-on-divergence path)
-    "decode.prefix_hit": {"rid", "pages", "tokens"},
-    "decode.cow": {"rid", "src_page", "dst_page", "tokens"},
     # device-trace ingestion + lane matching (obs/trace_ingest.py):
     # one trace.ingest per parsed capture, one trace.lane_match per
     # predicted sync-bucket lane (matched by annotation tag, never by
     # fuzzy kernel name)
     "trace.ingest": {"path", "events", "lanes"},
     "trace.lane_match": {"lane", "matched"},
-    # Prometheus exposition endpoint start (obs/exposition.py,
-    # FLEXFLOW_TPU_METRICS_PORT)
-    "metrics.exposition": {"port"},
     # DP inner loop (search/dp.py)
     "dp.split": {"op", "pre_nodes", "post_nodes", "cost_s"},
     "dp.summary": {"memo_hits", "memo_misses"},
     # calibration / cost-model provenance
     "calibration.ignored": {"backend", "machine"},
     "calibration.staleness": {"ratio", "threshold"},
-    # the automatic re-probe policy acting on a drift-stale table:
-    # deferred=False re-probed on the live backend, True fell back to
-    # the roofline (live backend cannot probe for the machine model)
-    "calibration.reprobe": {"backend", "deferred"},
     # compile-time strategy explanation (model.py)
     "strategy.table": {"rows"},
     # static analysis (flexflow_tpu/analysis): one event per finding —

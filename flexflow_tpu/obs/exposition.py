@@ -171,25 +171,11 @@ _SERVER: Optional[MetricsServer] = None
 
 
 def start_metrics_server(port: int, registry=None) -> MetricsServer:
-    """Start (or return the already-running) exposition endpoint and
-    emit a ``metrics.exposition`` event when the bus is armed."""
+    """Start (or return the already-running) exposition endpoint."""
     global _SERVER
-    if _SERVER is not None:
-        return _SERVER
-    _SERVER = MetricsServer(port, registry=registry)
-    from flexflow_tpu.obs.events import BUS
-
-    if BUS.enabled:
-        BUS.emit("metrics.exposition", port=_SERVER.port,
-                 host=_SERVER.host)
+    if _SERVER is None:
+        _SERVER = MetricsServer(port, registry=registry)
     return _SERVER
-
-
-def stop_metrics_server() -> None:
-    global _SERVER
-    if _SERVER is not None:
-        _SERVER.close()
-        _SERVER = None
 
 
 def maybe_start_from_env() -> Optional[MetricsServer]:

@@ -63,8 +63,30 @@ from typing import Callable, Dict, List, Optional, Sequence
 import numpy as np
 
 from flexflow_tpu.obs import annotate
+from flexflow_tpu.obs.annotate import phase_span
 from flexflow_tpu.obs.events import BUS
+from flexflow_tpu.obs.metrics import METRICS
 from flexflow_tpu.obs.tracing import TRACER
+
+# the phases of one ``ContinuousBatchingExecutor.step``, children of
+# ``ff.phase/decode_frame`` (histograms ``serve.<phase>_s``)
+_ADMIT = annotate.PHASE_PREFIX + "serve.admit"
+_COMPOSE = annotate.PHASE_PREFIX + "serve.compose"
+_DISPATCH = annotate.PHASE_PREFIX + "serve.dispatch"
+_WAIT = annotate.PHASE_PREFIX + "serve.wait"
+_HARVEST = annotate.PHASE_PREFIX + "serve.harvest"
+_EVICT = annotate.PHASE_PREFIX + "serve.evict"
+
+# always-on counters (registry objects survive ``METRICS.reset()``)
+_FRAMES = METRICS.counter("decode.frames")
+_ACTIVE_SLOT_FRAMES = METRICS.counter("decode.active_slot_frames")
+_SLOT_FRAMES = METRICS.counter("decode.slot_frames")
+_TOKENS_GENERATED = METRICS.counter("decode.tokens_generated")
+_PREFILL_CHUNKS = METRICS.counter("decode.prefill_chunks")
+_PREFILL_TOKENS = METRICS.counter("decode.prefill_tokens")
+_PROMPT_TOKENS = METRICS.counter("decode.prompt_tokens")
+_PREFIX_HIT_TOKENS = METRICS.counter("decode.prefix_hit_tokens")
+_FRAME_S = METRICS.histogram("decode.frame_s")
 
 
 @dataclass
@@ -140,8 +162,7 @@ class _Live:
     seq: int = 0
     deadline_frames: int = 0
     enqueue_frame: int = 0
-    # request lifecycle span stamps (perf_counter seconds) — populated
-    # only while the obs bus is armed (see step()'s one-check contract).
+    # request lifecycle span stamps (perf_counter seconds), always taken.
     # prefill_done_t closes the PREFILL span: the cache holds every
     # prompt token but the last, so TTFT decomposes exactly into
     # queue (enqueue→admit) + prefill (admit→prefill_done) +
@@ -418,11 +439,12 @@ class ContinuousBatchingExecutor:
         self.shared_pages = 0    # pages claimed by refcount, cumulative
         self.cow_copies = 0      # mid-page divergences copied at admission
         self.prefix_tokens = 0   # prompt tokens served from shared cache
-        # per-request lifecycle telemetry (enqueue→admit→prefill→first
-        # token→EOS/evict spans; TTFT/TPOT/e2e + the TTFT split),
-        # recorded only while the obs bus is armed — the hot path
-        # checks BUS.enabled ONCE per frame (and once per submit
-        # batch) and skips every stamp when it is off
+        # per-request lifecycle records (enqueue→admit→prefill→first
+        # token→EOS/evict; TTFT/TPOT/e2e + the TTFT split).  The stamps
+        # and the ``decode.*_s`` histograms are always taken; the record
+        # dicts and their ``decode.request`` events only while the obs
+        # bus is armed — BUS.enabled is read ONCE per frame (and once
+        # per submit batch)
         self.request_records: List[dict] = []
 
     # ------------------------------------------------------------------
@@ -448,8 +470,7 @@ class ContinuousBatchingExecutor:
                 tokens=list(r.prompt),
             )
             self._seq += 1
-            if obs:
-                entry.enqueue_t = time.perf_counter()
+            entry.enqueue_t = time.perf_counter()
             if tr:
                 # trace minted at enqueue (idempotent: the fleet router
                 # minted it at route time, then this opens children);
@@ -552,16 +573,17 @@ class ContinuousBatchingExecutor:
             return
         from flexflow_tpu.runtime.prefill import run_chunked_prefill
 
-        with annotate.phase_span(annotate.PREFILL_PHASE):
-            chunks = run_chunked_prefill(
-                self.prefill_fn, live.tokens, live.pages,
-                chunk=self.prefill_chunk,
-                cap=self.page_size * self.pages_per_seq,
-                start=start,
-                trace_id=TRACER.trace_of(live.req.rid) if tr else None)
+        chunks = run_chunked_prefill(
+            self.prefill_fn, live.tokens, live.pages,
+            chunk=self.prefill_chunk,
+            cap=self.page_size * self.pages_per_seq,
+            start=start,
+            trace_id=TRACER.trace_of(live.req.rid) if tr else None)
         live.cached = n_pre
         self.prefill_chunks += chunks
         self.prefill_tokens += n_pre - start
+        _PREFILL_CHUNKS.inc(chunks)
+        _PREFILL_TOKENS.inc(n_pre - start)
         if obs:
             BUS.emit("decode.prefill", rid=live.req.rid,
                      tokens=n_pre - start,
@@ -623,16 +645,11 @@ class ContinuousBatchingExecutor:
                 self.copy_page_fn(src, dst)
                 matched += extra
                 self.cow_copies += 1
-                if obs:
-                    BUS.emit("decode.cow", rid=entry.req.rid,
-                             src_page=src, dst_page=dst, tokens=extra)
             if matched:
                 self.prefix_hits += 1
                 self.shared_pages += len(shared)
                 self.prefix_tokens += matched
-                if obs:
-                    BUS.emit("decode.prefix_hit", rid=entry.req.rid,
-                             pages=len(shared), tokens=matched)
+                _PREFIX_HIT_TOKENS.inc(matched)
             if self.prefix_sharing:
                 self.allocator.assert_divergence_reserved(
                     pages, matched // self.page_size)
@@ -647,11 +664,13 @@ class ContinuousBatchingExecutor:
                          preempted=entry.preempted, seq=entry.seq,
                          deadline_frames=entry.deadline_frames,
                          enqueue_frame=entry.enqueue_frame)
-            if obs:
-                live.enqueue_t = entry.enqueue_t
-                live.admit_t = entry.admit_t or time.perf_counter()
-                live.prefill_done_t = entry.prefill_done_t
-                live.first_token_t = entry.first_token_t
+            # what the sequence arrives with: its prompt and, after a
+            # preemption, the tokens it had generated (prefilled again)
+            _PROMPT_TOKENS.inc(len(entry.tokens))
+            live.enqueue_t = entry.enqueue_t
+            live.admit_t = entry.admit_t or time.perf_counter()
+            live.prefill_done_t = entry.prefill_done_t
+            live.first_token_t = entry.first_token_t
             tid = TRACER.trace_of(entry.req.rid) if tr else None
             if tid is not None:
                 # admission edge: the queue window closes, the prefill
@@ -669,7 +688,7 @@ class ContinuousBatchingExecutor:
                 # ones are already in the trie and skip out)
                 self.allocator.register_prefix(
                     live.tokens, self.page_size, live.pages, live.cached)
-            if obs and live.prefill_done_t is None:
+            if live.prefill_done_t is None:
                 # the prefill span closes here for the chunked lane,
                 # for single-token prompts, and for prompts fully
                 # served from a shared prefix (nothing left to
@@ -691,8 +710,9 @@ class ContinuousBatchingExecutor:
         self.total_admitted += admitted
         return admitted
 
-    def _evict(self, obs: bool = False, tr: bool = False) -> int:
-        """Free finished sequences' pages and reopen their slots."""
+    def _evict(self, obs: bool, tr: bool, now: float) -> int:
+        """Free finished sequences' pages and reopen their slots;
+        ``now`` is when the frame's tokens reached the host."""
         evicted = 0
         for i, live in enumerate(self.slots):
             if live is None:
@@ -705,8 +725,7 @@ class ContinuousBatchingExecutor:
                 self.allocator.free(live.pages)
                 self.slots[i] = None
                 evicted += 1
-                if obs:
-                    self._record_request(live)
+                self._record_request(live, now, obs)
                 if tr:
                     tid = TRACER.trace_of(live.req.rid)
                     if tid is not None:
@@ -719,16 +738,14 @@ class ContinuousBatchingExecutor:
         self.total_evicted += evicted
         return evicted
 
-    def _record_request(self, live: _Live) -> None:
-        """Close a finished request's lifecycle span: queue wait
+    def _record_request(self, live: _Live, now: float, obs: bool) -> None:
+        """Close a finished request's lifecycle: queue wait
         (enqueue→admit), TTFT (enqueue→first generated token), TPOT
-        (steady per-token after the first), e2e — observed into the
-        metrics registry histograms and emitted as one
-        ``decode.request`` event.  Called only when the bus was armed
-        at eviction time (the caller's one-check-per-frame gate)."""
-        from flexflow_tpu.obs.metrics import METRICS
-
-        now = time.perf_counter()
+        (steady per-token after the first), e2e — always observed into
+        the metrics registry's histograms; kept as a record and emitted
+        as one ``decode.request`` event only when the bus was armed at
+        eviction time (``obs``, the caller's one-check-per-frame
+        gate)."""
         enq, adm, first = live.enqueue_t, live.admit_t, live.first_token_t
         pre = live.prefill_done_t
         queue_s = (adm - enq) if (enq is not None and adm is not None) \
@@ -746,27 +763,11 @@ class ContinuousBatchingExecutor:
         tpot_s = None
         if first is not None and live.generated > 1:
             tpot_s = (now - first) / (live.generated - 1)
-        rec = {
-            "rid": live.req.rid,
-            "phase": "finish",
-            "slo": live.req.slo,
-            "queue_s": queue_s,
-            "prefill_s": prefill_s,
-            "first_frame_s": first_frame_s,
-            "ttft_s": ttft_s,
-            "tpot_s": tpot_s,
-            "e2e_s": e2e_s,
-            "tokens": live.generated,
-            "frames": self.frame - live.started_frame + 1,
-            "preempted": live.preempted,
-        }
-        self.request_records.append(rec)
         # labeled series: the global aggregates stay (back-compat), and
         # the request-latency histograms are ALSO observed per
         # (replica, SLO class) so /metrics can tell fleet members and
         # priority lanes apart (obs/exposition.py parses the |k=v
-        # suffix into Prometheus labels).  Same obs gate as the flat
-        # series — no new BUS.enabled reads.
+        # suffix into Prometheus labels).
         slo = live.req.slo or "standard"
         lab = (f"slo={slo}" if self.replica_label is None
                else f"replica={self.replica_label},slo={slo}")
@@ -782,6 +783,23 @@ class ContinuousBatchingExecutor:
                 METRICS.histogram(key).observe(v)
                 if key in labeled:
                     METRICS.histogram(f"{key}|{lab}").observe(v)
+        if not obs:
+            return
+        rec = {
+            "rid": live.req.rid,
+            "phase": "finish",
+            "slo": live.req.slo,
+            "queue_s": queue_s,
+            "prefill_s": prefill_s,
+            "first_frame_s": first_frame_s,
+            "ttft_s": ttft_s,
+            "tpot_s": tpot_s,
+            "e2e_s": e2e_s,
+            "tokens": live.generated,
+            "frames": self.frame - live.started_frame + 1,
+            "preempted": live.preempted,
+        }
+        self.request_records.append(rec)
         BUS.emit("decode.request", **rec)
 
     # ------------------------------------------------------------------
@@ -815,23 +833,58 @@ class ContinuousBatchingExecutor:
         return ids, table, lens, active
 
     def step(self) -> dict:
-        """One decode frame: admit, compose, run, harvest, evict.
-        Returns the frame record (also emitted as ``decode.frame``).
-        The request-span instrumentation costs exactly this one
-        ``BUS.enabled`` read per frame when telemetry is off
-        (test-enforced)."""
-        obs = BUS.enabled  # ONE check per frame gates every span stamp
+        """One decode frame: admit, compose, dispatch, wait, harvest,
+        evict — each a ``ff.phase/serve.*`` child of the frame's
+        ``ff.phase/decode_frame`` span.  Returns the frame record (also
+        emitted as ``decode.frame``).  ``frame_seconds`` is dispatch +
+        wait.  Events and the request span tree cost exactly one
+        ``BUS.enabled`` and one ``TRACER.enabled`` read per frame when
+        they are off (test-enforced)."""
+        obs = BUS.enabled  # ONE check per frame gates every event
         tr = TRACER.enabled  # ditto for the request span tree
-        admitted = self._admit(obs, tr)
-        ids, table, lens, active = self._compose_frame()
-        t0 = time.perf_counter()
-        with annotate.phase_span(annotate.DECODE_PHASE):
-            logits = np.asarray(self.step_fn(ids, table, lens))
-        dt = time.perf_counter() - t0
-        self.frame_seconds.append(dt)
+        with phase_span(annotate.DECODE_PHASE):
+            with phase_span(_ADMIT):
+                admitted = self._admit(obs, tr)
+            with phase_span(_COMPOSE):
+                ids, table, lens, active = self._compose_frame()
+            t0 = time.perf_counter()
+            with phase_span(_DISPATCH):
+                out = self.step_fn(ids, table, lens)
+            with phase_span(_WAIT):
+                logits = np.asarray(out)
+            now = time.perf_counter()  # the frame's tokens are on the host
+            dt = now - t0
+            self.frame_seconds.append(dt)
+            _FRAME_S.observe(dt)
+            with phase_span(_HARVEST):
+                generated = self._harvest(logits, active, now, tr)
+            with phase_span(_EVICT):
+                evicted = self._evict(obs, tr, now)
+        _FRAMES.inc()
+        _ACTIVE_SLOT_FRAMES.inc(len(active))
+        _SLOT_FRAMES.inc(self.max_seqs)
+        _TOKENS_GENERATED.inc(generated)
+        rec = {
+            "frame": self.frame,
+            "active": len(active),
+            "admitted": admitted,
+            "evicted": evicted,
+            "pages_in_use": self.allocator.pages_in_use,
+            "queued": len(self.queue),
+            "measured_s": dt,
+            "predicted_s": self.predicted_step_s,
+        }
+        if obs:
+            BUS.emit("decode.frame", **rec)
+        self.frame += 1
+        return rec
+
+    def _harvest(self, logits, active, now: float, tr: bool) -> int:
+        """Greedy-sample the frame's logits on the host and advance
+        every active slot by one token; returns the tokens generated."""
         next_tokens = logits[:, 0].argmax(axis=-1).astype(np.int32) \
             if logits.ndim == 3 else logits[:, 0].astype(np.int32)
-        now = time.perf_counter() if (obs or tr) else 0.0
+        generated = 0
         for i in active:
             live = self.slots[i]
             live.cached += 1
@@ -848,7 +901,7 @@ class ContinuousBatchingExecutor:
                 # prompt token remains (the frame that feeds it is the
                 # first decode frame — it produces the first token).
                 if live.cached >= len(live.tokens) - 1:
-                    if obs and live.prefill_done_t is None:
+                    if live.prefill_done_t is None:
                         live.prefill_done_t = now
                     if tr:
                         tid = TRACER.trace_of(live.req.rid)
@@ -860,26 +913,10 @@ class ContinuousBatchingExecutor:
             # the model's prediction extends the sequence
             live.tokens.append(int(next_tokens[i]))
             live.generated += 1
-            if obs and live.first_token_t is None:
+            generated += 1
+            if live.first_token_t is None:
                 live.first_token_t = now  # TTFT closes here
-        evicted = self._evict(obs, tr)
-        rec = {
-            "frame": self.frame,
-            "active": len(active),
-            "admitted": admitted,
-            "evicted": evicted,
-            "pages_in_use": self.allocator.pages_in_use,
-            "queued": len(self.queue),
-            "measured_s": dt,
-            "predicted_s": self.predicted_step_s,
-        }
-        if obs:
-            from flexflow_tpu.obs.metrics import METRICS
-
-            METRICS.histogram("decode.frame_s").observe(dt)
-            BUS.emit("decode.frame", **rec)
-        self.frame += 1
-        return rec
+        return generated
 
     def run(self, requests: Sequence[DecodeRequest] = (),
             max_frames: int = 10_000) -> Dict[str, List[int]]:
@@ -1063,8 +1100,21 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
         for n in model.graph.topo_order()
         if n.op.op_type == OperatorType.DECODE_ATTENTION}
 
+    cold = {"decode_frame", "prefill_chunk"}  # programs never called yet
+
+    def call(program, jitted, *args):
+        """``jitted(*args)``; its FIRST call — trace, lower and compile
+        or load from the cache — shows on the timeline as
+        ``ff.phase/setup.first_call.<program>``."""
+        if program not in cold:
+            return jitted(*args)
+        cold.discard(program)
+        with phase_span(annotate.FIRST_CALL_PHASE + program):
+            return jitted(*args)
+
     def step(ids, page_table, seq_lens):
-        logits, new_state = fn(
+        logits, new_state = call(
+            "decode_frame", fn,
             model.params, box["state"], [ids, page_table, seq_lens])
         box["state"] = new_state
         return logits
@@ -1096,8 +1146,8 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
                                          compiled.compute_dtype))
 
         def prefill(ids, positions, page_table):
-            box["state"] = pf(model.params, box["state"], ids,
-                              positions, page_table)
+            box["state"] = call("prefill_chunk", pf, model.params,
+                                box["state"], ids, positions, page_table)
 
         step.prefill = prefill
     return step

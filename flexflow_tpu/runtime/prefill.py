@@ -42,6 +42,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from flexflow_tpu.core.optype import OperatorType
+from flexflow_tpu.obs.annotate import PREFILL_PHASE, phase_span
 from flexflow_tpu.ops.base import LoweringContext
 from flexflow_tpu.ops.inout import InputOp
 
@@ -86,7 +87,8 @@ def run_chunked_prefill(prefill_fn: Callable, tokens: Sequence[int],
         valid = min(chunk, n_pre - c0)
         ids[0, :valid] = tokens[c0:c0 + valid]
         pos = np.minimum(c0 + np.arange(chunk), cap - 1)
-        prefill_fn(ids, pos[None, :].astype(np.int32), table)
+        with phase_span(PREFILL_PHASE):  # the chunk's dispatch
+            prefill_fn(ids, pos[None, :].astype(np.int32), table)
         if tracer is not None:
             tracer.end(trace_id, "prefill.chunk", tokens=valid)
         chunks += 1

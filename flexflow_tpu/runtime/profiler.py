@@ -102,20 +102,16 @@ class StepProfiler:
 def device_trace(logdir: str):
     """XLA device timeline trace (TensorBoard `Profile` tab / Perfetto).
     The TPU analog of the reference's `-lg:prof` external tooling.
-    While the capture is live, the obs phase/lane annotations are armed
-    (obs/annotate.py) so the trace carries the ``ff.phase/*`` /
-    ``ff.lane/*`` tags ``obs/trace_ingest.py`` matches back to the
+    The program's ``ff.phase/*`` spans and ``ff.lane/*`` stamps
+    (obs/annotate.py) are TraceAnnotations, always on, so the capture
+    carries the tags ``obs/trace_ingest.py`` matches back to the
     simulator's predicted lanes."""
     import jax
 
-    from flexflow_tpu.obs import annotate
-
     jax.profiler.start_trace(logdir)
-    annotate.arm()
     try:
         yield
     finally:
-        annotate.disarm()
         jax.profiler.stop_trace()
 
 

@@ -420,7 +420,7 @@ class _UnityOptimizer:
         # the last segment has no out boundary, so the final state is
         # the single un-pinned lane; path[i] is the in-view of segment
         # i (= the pin at cut i-1), path[0] the None chain start
-        bound, path = prev[None]
+        _, path = prev[None]
         pins = path[1:] + (None,)
 
         merged_g, merged_s = None, {}
@@ -435,8 +435,6 @@ class _UnityOptimizer:
             if out_guid is not None:
                 merged_s[out_guid] = v
         c_true = self.helper._price(merged_g, merged_s)
-        if BUS.enabled:
-            BUS.emit("search.chain_done", bound_s=bound, cost_s=c_true)
         return merged_g, c_true, merged_s
 
     # -- series-parallel decomposition (bounded-width cuts) ----------------
@@ -1415,8 +1413,6 @@ def _optimize_strategy(
                 f"gap — using the analytic roofline; re-probe manually "
                 f"with --calibrate if the machine changed"
             )
-            BUS.emit("calibration.reprobe", backend=live, ratio=ratio,
-                     deferred=True, attempts=attempts)
             calibration = None
         elif live == target:
             log.log(
@@ -1425,8 +1421,6 @@ def _optimize_strategy(
                 f"re-probing on the live backend "
                 f"(attempt {attempts + 1}/{cap})"
             )
-            BUS.emit("calibration.reprobe", backend=live, ratio=ratio,
-                     deferred=False, attempts=attempts)
             calibration.begin_reprobe()
             reprobe = True
         else:
@@ -1436,8 +1430,6 @@ def _optimize_strategy(
                 f"{config.machine_spec.name!r}: using the analytic "
                 f"roofline until a re-probe runs on the modeled backend"
             )
-            BUS.emit("calibration.reprobe", backend=live, ratio=ratio,
-                     deferred=True)
             calibration = None
     can_probe = False
     if config.calibrate or reprobe:

@@ -390,14 +390,14 @@ def test_search_fit_telemetry_smoke(tmp_path):
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "ffobs.py"),
          "report", log],
-        capture_output=True, text=True)
+        capture_output=True, text=True, timeout=120)
     assert proc.returncode == 0, proc.stderr
     assert "Chosen strategy" in proc.stdout
     assert "Drift" in proc.stdout
     val = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "ffobs.py"),
          "validate", log],
-        capture_output=True, text=True)
+        capture_output=True, text=True, timeout=120)
     assert val.returncode == 0, val.stdout + val.stderr
 
 

@@ -121,6 +121,9 @@ def test_tf_embedding_transformer_trains():
     """Embedding -> MHA -> pooled head: imports, transfers weights, and
     trains through fit() — the full tf.keras-to-framework path."""
     V, D, H, S, B = 100, 16, 2, 6, 8
+    # unseeded, about one draw of the tf.keras weights in twenty leaves
+    # the five noisy epoch-end losses without a fall (seen in tier-1)
+    tf.keras.utils.set_random_seed(0)
     inp = tf.keras.Input((S,), dtype="int32")
     e = L.Embedding(V, D, name="emb")(inp)
     a = L.MultiHeadAttention(num_heads=H, key_dim=D // H, name="mha2")(e, e)

@@ -314,6 +314,18 @@ def test_decode_request_lifecycle_telemetry(tmp_path):
 
 # ---------------------------------------------------------------------------
 # tier-1 smoke: the full measured-lane pipeline on the 8-dev CPU mesh
+def _stop_stray_capture():
+    """fit stops the capture it started; a fit that RAISED inside it did
+    not, and a capture left running would fail every later capture of
+    this worker instead of this one test."""
+    import jax
+
+    try:
+        jax.profiler.stop_trace()
+    except RuntimeError:
+        pass  # no capture was left running: the normal case
+
+
 def test_lane_capture_smoke_e2e(tmp_path, mesh8):
     """fit with device_trace_dir: a REAL capture on the CPU mesh
     round-trips into a LaneDriftReport with every annotated sync
@@ -343,7 +355,10 @@ def test_lane_capture_smoke_e2e(tmp_path, mesh8):
     rng = np.random.default_rng(0)
     x = rng.normal(size=(24, 8, 512)).astype(np.float32)
     y = rng.normal(size=(24, 8, 512)).astype(np.float32)
-    m.fit(x=x, y=y, verbose=False, shuffle=False)
+    try:
+        m.fit(x=x, y=y, verbose=False, shuffle=False)
+    finally:
+        _stop_stray_capture()
 
     report = m.lane_drift_report
     assert report is not None, "capture did not ingest"
@@ -384,16 +399,16 @@ def test_lane_capture_smoke_e2e(tmp_path, mesh8):
 
     ffobs = os.path.join(REPO, "tools", "ffobs.py")
     rep = subprocess.run([sys.executable, ffobs, "report", log],
-                        capture_output=True, text=True)
+                        capture_output=True, text=True, timeout=120)
     assert rep.returncode == 0, rep.stderr
     assert "Measured lanes (device-trace capture)" in rep.stdout
     assert "bucket:b0:sync" in rep.stdout
     assert "Per-request telemetry" in rep.stdout
     val = subprocess.run([sys.executable, ffobs, "validate", log],
-                        capture_output=True, text=True)
+                        capture_output=True, text=True, timeout=120)
     assert val.returncode == 0, val.stdout + val.stderr
     met = subprocess.run([sys.executable, ffobs, "metrics", log],
-                        capture_output=True, text=True)
+                        capture_output=True, text=True, timeout=120)
     assert met.returncode == 0, met.stdout + met.stderr
     assert "flexflow_tpu_decode_ttft_s_count" in met.stdout
     assert "# TYPE" in met.stdout

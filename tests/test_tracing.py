@@ -388,7 +388,7 @@ def test_ffobs_trace_renders_and_flags_orphans(tmp_path):
         os.path.abspath(__file__))), "tools", "ffobs.py")
     proc = subprocess.run(
         [sys.executable, ffobs, "trace", str(log)],
-        capture_output=True, text=True)
+        capture_output=True, text=True, timeout=120)
     assert proc.returncode == 0, proc.stderr
     assert "trace r0#1" in proc.stdout and "queue" in proc.stdout
     assert "0 orphan span(s)" in proc.stdout
@@ -399,6 +399,6 @@ def test_ffobs_trace_renders_and_flags_orphans(tmp_path):
     log.write_text("".join(json.dumps(r) + "\n" for r in rows))
     proc = subprocess.run(
         [sys.executable, ffobs, "trace", str(log)],
-        capture_output=True, text=True)
+        capture_output=True, text=True, timeout=120)
     assert proc.returncode == 1
     assert "ORPHAN" in proc.stdout

@@ -1297,8 +1297,8 @@ def kv_sweep(n_devices):
     from flexflow_tpu.ops.decode_attention import _quantize_kv
 
     P, ps, H, D, B, pps = 16, 8, 4, 16, 4, 4
-    k = jnp.asarray(rng.normal(size=(P, ps, H, D)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(P, ps, H, D)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(P, ps, H * D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(P, ps, H * D)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
     table = jnp.asarray(
         rng.permutation(P)[:B * pps].reshape(B, pps), jnp.int32)

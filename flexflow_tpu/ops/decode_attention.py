@@ -53,14 +53,14 @@ from flexflow_tpu.ops.base import (
 
 def _quantize_kv(x):
     """Per-token symmetric int8 quantization of fresh K or V rows:
-    x [..., H, D] fp32 -> (int8 payload, fp32 scale over the trailing
-    (H, D) axes).  One scale per token (the pool's per-(page, slot)
-    "page_slot" layout) — amax/127 symmetric, the EQuARX-style scheme
-    whose drift bound the accuracy-contract test asserts."""
-    amax = jnp.max(jnp.abs(x), axis=(-2, -1))
+    x [..., H·D] fp32 (the pool's fused rows) -> (int8 payload, fp32
+    scale over the trailing axis).  One scale per token (the pool's
+    per-(page, slot) "page_slot" layout) — amax/127 symmetric, the
+    EQuARX-style scheme whose drift bound the accuracy-contract test
+    asserts."""
+    amax = jnp.max(jnp.abs(x), axis=-1)
     s = jnp.maximum(amax, 1e-30) / 127.0
-    q = jnp.clip(jnp.round(x / s[..., None, None]),
-                 -127, 127).astype(jnp.int8)
+    q = jnp.clip(jnp.round(x / s[..., None]), -127, 127).astype(jnp.int8)
     return q, s.astype(jnp.float32)
 
 
@@ -154,6 +154,11 @@ class DecodeAttentionOp(Operator):
     def kv_dtype(self) -> str:
         return self.attrs.get("kv_dtype", "fp32")
 
+    @property
+    def pool_dtype(self):
+        return {"fp32": jnp.float32, "bf16": jnp.bfloat16,
+                "int8": jnp.int8}[self.kv_dtype]
+
     def weight_specs(self) -> Sequence[WeightSpec]:
         a = self.attrs
         e, h = a["embed_dim"], a["num_heads"]
@@ -174,39 +179,40 @@ class DecodeAttentionOp(Operator):
         lane, an int8 pool carrying per-(page, slot) fp32 scales —
         the "page_slot" layout, one symmetric scale per cached token
         shared across heads, so scattering a fresh token never
-        rescales already-written slots."""
+        rescales already-written slots.
+
+        A pool is [num_pages, page_size, heads · head_dim], heads and
+        head_dim FUSED on the minor axis.  Kept apart, a 64-wide minor
+        axis pads to the TPU's 128 lanes, so XLA:TPU stores
+        [P, page, H, 64] with the page axis minor-most instead, and
+        the scatter and the kernel — which compute row-major —
+        transposed every pool in and out again on every call (four
+        fifths of a decode frame's device time).  The fused axis is
+        lane-dense: its default layout is the one its users compute
+        in, and a call updates the pool in place."""
         a = self.attrs
-        shape = (a["num_pages"], a["page_size"], a["num_heads"],
-                 self.head_dim)
-        kvd = self.kv_dtype
-        if kvd == "bf16":
-            return [
-                ("k_cache", shape, jnp.bfloat16, 0.0),
-                ("v_cache", shape, jnp.bfloat16, 0.0),
-            ]
-        if kvd == "int8":
+        shape = (a["num_pages"], a["page_size"],
+                 a["num_heads"] * self.head_dim)
+        pool = self.pool_dtype
+        specs = [("k_cache", shape, pool, 0), ("v_cache", shape, pool, 0)]
+        if self.kv_dtype == "int8":
             sshape = (a["num_pages"], a["page_size"])
-            return [
-                ("k_cache", shape, jnp.int8, 0),
-                ("v_cache", shape, jnp.int8, 0),
-                ("k_scale", sshape, jnp.float32, 0.0),
-                ("v_scale", sshape, jnp.float32, 0.0),
-            ]
-        return [
-            ("k_cache", shape, jnp.float32, 0.0),
-            ("v_cache", shape, jnp.float32, 0.0),
-        ]
+            specs += [("k_scale", sshape, jnp.float32, 0.0),
+                      ("v_scale", sshape, jnp.float32, 0.0)]
+        return specs
 
     def state_shardings(self, mv: MachineView):
         """ShardAnnot per state var under ``mv`` — the lowering places
         the page pool with it (compiler/lowering.py init_params), so
         the residency ``kv_cache_bytes`` credits is residency the
         compiled program realizes: page dim over the batch axes (each
-        device holds its own sequences' pages), head dim over the
-        replica axes (decode TP)."""
+        device holds its own sequences' pages), the fused head axis
+        over the replica axes (decode TP: a split of heads · head_dim
+        into r contiguous parts is a split of heads, because the
+        replica degree divides ``num_heads`` — max_replica_degree)."""
         b = max(mv.dim_degrees[0], 1) if mv.dim_degrees else 1
         r = max(mv.replica_degree, 1)
-        annot = ShardAnnot((b, 1, r, 1), idx=(0, -1, REPLICA_SLOT, -1))
+        annot = ShardAnnot((b, 1, r), idx=(0, -1, REPLICA_SLOT))
         out = {"k_cache": annot, "v_cache": annot}
         if self.kv_dtype == "int8":
             # the scales shard with the page dim but REPLICATE over the
@@ -252,8 +258,11 @@ class DecodeAttentionOp(Operator):
         wq, wk, wv, wo = (weights[n].astype(cd)
                           for n in ("wq", "wk", "wv", "wo"))
         q = jnp.einsum("be,ehd->bhd", x, wq)
-        k_new = jnp.einsum("be,ehd->bhd", x, wk).astype(jnp.float32)
-        v_new = jnp.einsum("be,ehd->bhd", x, wv).astype(jnp.float32)
+        # fresh K/V rows as the pool holds them: heads fused, [B, H·D]
+        k_new = jnp.einsum("be,ehd->bhd", x, wk).astype(
+            jnp.float32).reshape(x.shape[0], -1)
+        v_new = jnp.einsum("be,ehd->bhd", x, wv).astype(
+            jnp.float32).reshape(x.shape[0], -1)
 
         ps = a["page_size"]
         k_cache = ctx.state_in[f"{self.name}/k_cache"]
@@ -355,8 +364,11 @@ class DecodeAttentionOp(Operator):
         wq, wk, wv, wo = (weights[n].astype(cd)
                           for n in ("wq", "wk", "wv", "wo"))
         q = jnp.einsum("bce,ehd->bchd", x, wq)
-        k_new = jnp.einsum("bce,ehd->bchd", x, wk).astype(jnp.float32)
-        v_new = jnp.einsum("bce,ehd->bchd", x, wv).astype(jnp.float32)
+        # fresh K/V rows as the pool holds them: [B, C, H·D]
+        k_new = jnp.einsum("bce,ehd->bchd", x, wk).astype(
+            jnp.float32).reshape(*x.shape[:2], -1)
+        v_new = jnp.einsum("bce,ehd->bchd", x, wv).astype(
+            jnp.float32).reshape(*x.shape[:2], -1)
 
         ps = a["page_size"]
         k_cache = ctx.state_in[f"{self.name}/k_cache"]
@@ -368,7 +380,7 @@ class DecodeAttentionOp(Operator):
         if kvd == "int8":
             # batched quantize-on-scatter, same per-token scheme as the
             # decode step — the chunked path populates the SAME pool
-            k_q, k_s = _quantize_kv(k_new)  # [B, C, H, D] / [B, C]
+            k_q, k_s = _quantize_kv(k_new)  # [B, C, H·D] / [B, C]
             v_q, v_s = _quantize_kv(v_new)
             k_scale = ctx.state_in[f"{self.name}/k_scale"]
             v_scale = ctx.state_in[f"{self.name}/v_scale"]
@@ -390,13 +402,15 @@ class DecodeAttentionOp(Operator):
         # the prefix written by earlier chunks plus the intra-chunk
         # causal triangle (this chunk's K/V are already in the pool)
         scale = 1.0 / math.sqrt(self.head_dim)
+        h = a["num_heads"]
         if kvd == "int8":
             k_dense = gather_kv_pages_quant(k_cache, k_scale,
-                                            page_table)  # [B, S, H, D]
-            v_dense = gather_kv_pages_quant(v_cache, v_scale, page_table)
+                                            page_table, h)  # [B, S, H, D]
+            v_dense = gather_kv_pages_quant(v_cache, v_scale,
+                                            page_table, h)
         else:
-            k_dense = gather_kv_pages(k_cache, page_table)  # [B, S, H, D]
-            v_dense = gather_kv_pages(v_cache, page_table)
+            k_dense = gather_kv_pages(k_cache, page_table, h)  # [B, S, H, D]
+            v_dense = gather_kv_pages(v_cache, page_table, h)
         qf = q.astype(jnp.float32)
         s = jnp.einsum("bchd,bshd->bchs", qf, k_dense) * scale
         pos_k = jnp.arange(k_dense.shape[1], dtype=jnp.int32)
@@ -456,7 +470,7 @@ class DecodeAttentionOp(Operator):
     def _kv_payload_bytes_per_token(self) -> float:
         """K + V PAYLOAD bytes per cached token in the pool dtype
         (scales excluded — they shard differently)."""
-        itemsize = {"fp32": 4.0, "bf16": 2.0, "int8": 1.0}[self.kv_dtype]
+        itemsize = jnp.dtype(self.pool_dtype).itemsize
         return 2.0 * self.attrs["num_heads"] * self.head_dim * itemsize
 
     def _kv_scale_bytes_per_token(self) -> float:

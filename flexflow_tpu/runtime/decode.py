@@ -56,6 +56,7 @@ across calls).
 
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence
@@ -1070,21 +1071,47 @@ class ContinuousBatchingExecutor:
         return report
 
 
+class _LiveState:
+    """``step.state``: a read-only window on the model's live state
+    dict under the historical key (``step.state["state"]``)."""
+
+    def __init__(self, model):
+        self._model = model
+
+    def __getitem__(self, key):
+        if key != "state":
+            raise KeyError(key)
+        return self._model.state
+
+
+_KV_LEAVES = ("k_cache", "v_cache", "k_scale", "v_scale")
+
+
 def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
     """A ``step_fn`` over a COMPILED decode model: one jitted forward
-    per frame, the KV-cache state dict threaded across calls (the
-    caches are model state — compiler/lowering.py init_params placed
-    them under the strategy's view).
+    per frame over the model's state dict (the caches are model state —
+    compiler/lowering.py init_params placed them under the strategy's
+    view).
+
+    The state is DONATED into every program here, as the train step
+    donates its own: the scatter writes the KV pool where it sits and
+    the program holds no second pool.  So the live pool has ONE owner,
+    ``model.state`` — each call reads it there and puts the program's
+    output back, the arrays it consumed are dead, and any number of
+    steps built over one model (and ``model.state`` read after serving)
+    see the same live pool.  ``step.state["state"]`` is that dict.
 
     ``prefill_chunk > 0`` additionally builds the chunked prefill
-    writer over the SAME graph, params and threaded state
-    (runtime/prefill.py — one parameter set by construction, the cache
-    scatter lands in the placed state arrays), attached as
+    writer over the SAME graph, params and state (runtime/prefill.py —
+    one parameter set by construction, the cache scatter lands in the
+    placed state arrays), attached as
     ``step.prefill(ids [1,C], positions [1,C], page_table [1,P])`` for
     the executor's ``prefill_fn``.
 
     ``step.frame_fn`` is the jitted frame itself (``(params, state,
-    [ids, page_table, seq_lens])``) and ``step.attention_path`` names
+    [ids, page_table, seq_lens])``), ``step.chunk_fn`` the jitted
+    prefill chunk (``(params, state, ids, positions, page_table)``),
+    and ``step.attention_path`` names
     what its decode attention lowered to — ``"pallas"`` or ``"xla"``,
     by ``DecodeAttentionOp.attention_path``'s rule."""
     import jax
@@ -1093,8 +1120,8 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
 
     compiled = model.compiled
     fn = jax.jit(
-        lambda p, s, ins: compiled.apply(p, s, ins, None, False))
-    box = {"state": model.state}
+        lambda p, s, ins: compiled.apply(p, s, ins, None, False),
+        donate_argnums=(1,))
     paths = {
         n.op.attention_path(compiled._multi_device)
         for n in model.graph.topo_order()
@@ -1113,41 +1140,43 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
             return jitted(*args)
 
     def step(ids, page_table, seq_lens):
-        logits, new_state = call(
+        logits, model.state = call(
             "decode_frame", fn,
-            model.params, box["state"], [ids, page_table, seq_lens])
-        box["state"] = new_state
+            model.params, model.state, [ids, page_table, seq_lens])
         return logits
 
-    step.state = box  # tests inspect the threaded cache
+    step.state = _LiveState(model)  # tests inspect the live cache
     step.frame_fn = fn
     step.attention_path = "+".join(sorted(paths)) or None
+
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def copy_kv_page(state, src, dst):
+        return {key: (val.at[dst].set(val[src])
+                      if key.rsplit("/", 1)[-1] in _KV_LEAVES else val)
+                for key, val in state.items()}
 
     def copy_page(src: int, dst: int) -> None:
         """CoW page copy for the prefix-sharing executor
         (``copy_page_fn``): duplicate page ``src`` of every layer's
         paged KV state — k/v pools and, under an int8 pool, their
         per-slot scales — into page ``dst``, which the divergent
-        sequence then owns.  Rare (once per mid-page divergence at
-        admission), so plain dispatch is fine."""
-        st = box["state"]
-        out = dict(st)
-        for key, val in st.items():
-            leaf = key.rsplit("/", 1)[-1]
-            if leaf in ("k_cache", "v_cache", "k_scale", "v_scale"):
-                out[key] = val.at[dst].set(val[src])
-        box["state"] = out
+        sequence then owns.  One program whatever the pages (they are
+        traced scalars), the state donated: one page a leaf moves."""
+        model.state = copy_kv_page(model.state, np.int32(src),
+                                   np.int32(dst))
 
     step.copy_page = copy_page
     if prefill_chunk:
         from flexflow_tpu.runtime.prefill import build_chunk_forward
 
         pf = jax.jit(build_chunk_forward(model.graph,
-                                         compiled.compute_dtype))
+                                         compiled.compute_dtype),
+                     donate_argnums=(1,))
 
         def prefill(ids, positions, page_table):
-            box["state"] = call("prefill_chunk", pf, model.params,
-                                box["state"], ids, positions, page_table)
+            model.state = call("prefill_chunk", pf, model.params,
+                               model.state, ids, positions, page_table)
 
         step.prefill = prefill
+        step.chunk_fn = pf
     return step

@@ -16,6 +16,7 @@ import collections
 import importlib
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -66,12 +67,12 @@ def _paged_entries():
     scales = S((POOL, PAGE), jnp.float32)
     out = {}
     for name, dt in (("fp32", jnp.float32), ("bf16", jnp.bfloat16)):
-        pool = S((POOL, PAGE, H, D), dt)
+        pool = S((POOL, PAGE, H * D), dt)
         out[f"paged_{name}"] = (
             lambda q, k, v, t, n: rpa._pallas_ragged_paged(
                 q, k, v, t, n, SCALE, False),
             (q, pool, pool, table, lens))
-    pool = S((POOL, PAGE, H, D), jnp.int8)
+    pool = S((POOL, PAGE, H * D), jnp.int8)
     out["paged_int8"] = (
         lambda q, k, v, ks, vs, t, n: rpa._pallas_ragged_paged(
             q, k, v, t, n, SCALE, False, ks, vs),
@@ -117,6 +118,94 @@ def test_pallas_entry_compiles_under_mosaic(name, v5e_device):
     args = [S(a.shape, a.dtype, sharding=sh) for a in args]
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert hlo.count(f'custom_call_target="{MOSAIC}"') == CALLS[name]
+
+
+def _pool_sized_producers(hlo: str, pool_elems: int):
+    """HLO instructions of a compiled program whose result is an array
+    of at least a pool leaf's element count, as (opcode, jax op name)."""
+    found = []
+    for line in hlo.splitlines():
+        m = re.search(
+            r"= [a-z0-9]+\[([0-9,]+)\][^ ]* ([a-z][a-z0-9-]*)\(", line)
+        if m and np.prod([int(d) for d in m.group(1).split(",")]) >= pool_elems:
+            name = re.search(r'op_name="([^"]*)"', line)
+            found.append((m.group(2), name.group(1) if name else ""))
+    return found
+
+
+def test_decode_frame_and_chunk_update_the_pool_in_place(
+        v5e_device, monkeypatch):
+    """THE structural guard of the in-place KV pool: at the serving
+    cell's head geometry (16 x 64, page 32, 16 slots x 32 pages) the
+    frame and the prefill chunk, compiled for the described v5e, alias
+    every pool leaf to its output, hold no copy / transpose / convert
+    (or any other op but the scatter's in-place update) that produces
+    a pool-sized array, and need less than one pool leaf of
+    temporaries.  A [P, page, H, 64] pool failed all three: XLA:TPU
+    stores it page-minor and transposes it in and out on every call."""
+    import flexflow_tpu as ff
+    from flexflow_tpu.models import build_gpt_decode
+    from flexflow_tpu.runtime.decode import compiled_decode_step
+
+    slots, chunk, layers = 16, 64, 2
+    kw = dict(vocab=512, num_layers=layers, hidden=1024, num_heads=16,
+              ff_dim=256, page_size=32, pages_per_seq=32)
+    cfg = ff.FFConfig(batch_size=slots, num_devices=1, cost_cache_file="")
+    model = build_gpt_decode(cfg, **kw)
+    model.compile(loss_type="sparse_categorical_crossentropy", metrics=[],
+                  comp_mode="inference")
+    step = compiled_decode_step(model, prefill_chunk=chunk)
+    assert step.attention_path == "pallas"
+
+    sh = jax.sharding.SingleDeviceSharding(v5e_device)
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: S(a.shape, a.dtype, sharding=sh), tree)
+
+    def ints(*shape):
+        return S(shape, jnp.int32, sharding=sh)
+
+    params, state = described(model.params), described(model.state)
+    pools = sorted(k for k in state if k.endswith(("/k_cache", "/v_cache")))
+    assert len(pools) == 2 * layers
+    leaf = state[pools[0]]
+    assert leaf.shape == (slots * 32, 32, 16 * 64)
+    # the kernel picks interpreter mode off-TPU by the default backend;
+    # this compile is FOR the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    programs = {
+        "frame": step.frame_fn.lower(
+            params, state, [ints(slots, 1), ints(slots, 32), ints(slots)]),
+        "chunk": step.chunk_fn.lower(
+            params, state, ints(1, chunk), ints(1, chunk), ints(1, 32)),
+    }
+    pool_elems = int(np.prod(leaf.shape))
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        hlo = compiled.as_text()
+        # the compiled program numbers only the arguments it kept (the
+        # chunk reads no lm_head): find the pool leaves by their type
+        entry = hlo[hlo.index("ENTRY "):]
+        pool_type = f"f32[{','.join(map(str, leaf.shape))}]"
+        pool_params = {int(i) for i in re.findall(
+            re.escape(pool_type) + r"\S* parameter\((\d+)\)", entry)}
+        assert len(pool_params) == len(pools), (name, pool_params)
+        alias = hlo[hlo.index("input_output_alias={"):]
+        alias = alias[:alias.index("\n")]
+        aliased = {int(i) for i in re.findall(r"\((\d+), \{\}", alias)}
+        assert pool_params <= aliased, (name, pool_params, alias[:400])
+        # what may produce a pool-sized array: the argument itself and
+        # the scatter's in-place update of it (a fusion around it on
+        # the TPU)
+        made = [m for m in _pool_sized_producers(hlo, pool_elems)
+                if m[0] != "parameter"]
+        assert made and all(op in ("scatter", "fusion") and jax_op.endswith("/scatter")
+                   for op, jax_op in made), (name, made)
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < pool_elems * leaf.dtype.itemsize, (name, temp)
+        if name == "frame":
+            assert hlo.count(f'custom_call_target="{MOSAIC}"') == layers
 
 
 def test_sharded_flash_lowers_and_matches_on_cpu_mesh(mesh8, monkeypatch):

@@ -277,8 +277,8 @@ def test_int8_accuracy_contract_and_kernel_parity():
 
     rng = np.random.default_rng(11)
     P, ps, H, D, B, pps = 16, 8, 4, 16, 4, 4
-    k = jnp.asarray(rng.normal(size=(P, ps, H, D)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(P, ps, H, D)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(P, ps, H * D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(P, ps, H * D)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
     table = jnp.asarray(
         rng.permutation(P)[:B * pps].reshape(B, pps), jnp.int32)

@@ -72,9 +72,10 @@ def _compiled_small(num_devices=1, batch=4, **overrides):
 @pytest.fixture(scope="module")
 def small_model():
     """One compiled small decode model shared by the executor-level
-    tests: each ``compiled_decode_step`` call snapshots ``model.state``
-    into its own box, so every lane starts from the same fresh caches
-    without recompiling the model."""
+    tests.  Every ``compiled_decode_step`` over it serves from the ONE
+    live pool in ``model.state`` (the state is donated), so a lane
+    starts on the pages the lane before it left — which no sequence
+    reads before writing them — without recompiling the model."""
     return _compiled_small()
 
 

@@ -55,48 +55,78 @@ def _decode_views(graph, strategy):
 # kernel parity vs the dense masked reference
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize(
-    "B,H,D,page_size,pages_per_seq,lens",
+    "B,H,D,page_size,pages_per_seq,lens,pool",
     [
-        (4, 2, 16, 8, 3, (1, 8, 17, 24)),   # single-token + full-page
-        (2, 4, 32, 16, 2, (16, 32)),        # exact page boundaries
-        (3, 1, 8, 8, 4, (1, 9, 31)),        # ragged mid-page
-        (2, 2, 8, 4, 2, (3, 7)),            # sub-lane tiny pages
+        (4, 2, 16, 8, 3, (1, 8, 17, 24), "fp32"),  # single-token + full-page
+        (2, 4, 32, 16, 2, (16, 32), "fp32"),       # exact page boundaries
+        (3, 1, 8, 8, 4, (1, 9, 31), "fp32"),       # ragged mid-page
+        (2, 2, 8, 4, 2, (3, 7), "fp32"),           # sub-lane tiny pages
+        (2, 4, 96, 8, 2, (5, 16), "fp32"),         # head_dim no power of two
+        # the serving cell's geometry (16 heads x 64, page 32): a
+        # one-token, a mid-page, a page-boundary and a full sequence —
+        # the shapes the TPU kernel rule admits, in every pool dtype
+        (4, 16, 64, 32, 4, (1, 37, 64, 128), "fp32"),
+        (4, 16, 64, 32, 4, (1, 37, 64, 128), "bf16"),
+        (4, 16, 64, 32, 4, (1, 37, 64, 128), "int8"),
     ],
 )
 def test_ragged_kernel_matches_dense_reference(B, H, D, page_size,
-                                               pages_per_seq, lens):
+                                               pages_per_seq, lens, pool):
     import jax.numpy as jnp
 
     from flexflow_tpu.kernels.ragged_paged_attention import (
         _pallas_ragged_paged,
         _xla_ragged_paged,
+        _xla_ragged_paged_quant,
         dense_decode_reference,
         gather_kv_pages,
+        paged_kernel_applies,
         ragged_paged_attention,
+        ragged_paged_attention_quant,
     )
+    from flexflow_tpu.ops.decode_attention import _quantize_kv
 
     rng = np.random.default_rng(0)
     P = B * pages_per_seq + 2  # pool larger than the allotment
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, page_size, H, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, page_size, H, D)), jnp.float32)
+    # the pool as the op stores it: heads x head_dim fused, [P, page, H*D]
+    kp = jnp.asarray(rng.normal(size=(P, page_size, H * D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P, page_size, H * D)), jnp.float32)
     pt = jnp.asarray(
         rng.permutation(P)[:B * pages_per_seq].reshape(B, pages_per_seq),
         jnp.int32)
     sl = jnp.asarray(lens, jnp.int32)
     scale = 1.0 / math.sqrt(D)
+    scales = ()
+    if pool == "bf16":
+        kp, vp = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
+    if pool == "int8":
+        (kp, ks), (vp, vs) = _quantize_kv(kp), _quantize_kv(vp)
+        scales = (ks, vs)
+        # the oracle sees the values the pool holds: dequantized
+        k_held, v_held = kp * ks[..., None], vp * vs[..., None]
+    else:
+        k_held, v_held = kp, vp
     ref = dense_decode_reference(
-        q, gather_kv_pages(kp, pt), gather_kv_pages(vp, pt), sl)
-    got = ragged_paged_attention(q, kp, vp, pt, sl)
+        q, gather_kv_pages(k_held, pt, H), gather_kv_pages(v_held, pt, H),
+        sl)
+    if pool == "int8":
+        got = ragged_paged_attention_quant(q, kp, vp, *scales, pt, sl)
+        fb = _xla_ragged_paged_quant(q, kp, vp, *scales, pt, sl, scale)
+    else:
+        got = ragged_paged_attention(q, kp, vp, pt, sl)
+        fb = _xla_ragged_paged(q, kp, vp, pt, sl, scale)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
-    fb = _xla_ragged_paged(q, kp, vp, pt, sl, scale)
     np.testing.assert_allclose(np.asarray(fb), np.asarray(ref),
                                rtol=1e-6, atol=1e-6)
-    if D % 8 == 0 and page_size % 8 == 0:
-        pk = _pallas_ragged_paged(q, kp, vp, pt, sl, scale, True)
-        np.testing.assert_allclose(np.asarray(pk), np.asarray(ref),
-                                   rtol=1e-5, atol=1e-5)
+    # THE shape rule; what fails it took the gather path above
+    assert paged_kernel_applies(D, page_size) == (page_size % 8 == 0)
+    # the kernel's arithmetic (each head's sum as a matmul with the 0/1
+    # head-membership matrix) holds at every size
+    pk = _pallas_ragged_paged(q, kp, vp, pt, sl, scale, True, *scales)
+    np.testing.assert_allclose(np.asarray(pk), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
 
 
 def test_decode_op_incremental_matches_dense():
@@ -651,6 +681,111 @@ def test_executor_on_compiled_decode_model():
         import os
 
         os.remove(log)
+
+
+def test_donated_state_has_one_live_owner():
+    """The frame, the prefill chunk and the page copy DONATE the KV
+    state (a call updates the pool in place), so the arrays a call
+    consumed are dead: the live pool is ``model.state``, whichever
+    step ran last.  Two steps over one model, ``model.state`` and
+    ``step.state`` read between and after calls, and a page copy all
+    keep working and never touch a deleted buffer."""
+    import jax
+
+    from flexflow_tpu.models import build_gpt_decode
+    from flexflow_tpu.runtime.decode import (
+        ContinuousBatchingExecutor,
+        DecodeRequest,
+        compiled_decode_step,
+    )
+
+    kw = dict(vocab=256, num_layers=2, hidden=64, num_heads=4,
+              ff_dim=64, page_size=4, pages_per_seq=4)
+    cfg = ff.FFConfig(batch_size=4, num_devices=1, cost_cache_file="")
+    m = build_gpt_decode(cfg, **kw)
+    m.compile(loss_type="sparse_categorical_crossentropy", metrics=[],
+              comp_mode="inference", strategy=_trivial_strategy(m.graph))
+    chunked = compiled_decode_step(m, prefill_chunk=4)
+    plain = compiled_decode_step(m)
+
+    # the state, and only the state, is donated into both programs
+    ints = lambda *shape: np.zeros(shape, np.int32)  # noqa: E731
+    for lowered in (
+            chunked.frame_fn.lower(m.params, m.state,
+                                   [ints(4, 1), ints(4, 4), ints(4)]),
+            chunked.chunk_fn.lower(m.params, m.state, ints(1, 4),
+                                   ints(1, 4), ints(1, 4))):
+        p_info, s_info = lowered.args_info[0][:2]
+        assert all(a.donated for a in jax.tree.leaves(s_info))
+        assert not any(a.donated for a in jax.tree.leaves(p_info))
+
+    def serve(step):
+        ex = ContinuousBatchingExecutor(
+            step, max_seqs=4, page_size=4, pages_per_seq=4,
+            prefill_fn=getattr(step, "prefill", None),
+            prefill_chunk=4 if hasattr(step, "prefill") else 0)
+        return ex.run([DecodeRequest(rid=f"r{i}", prompt=[1 + i, 2, 3, 4, 5, 6],
+                                     max_new_tokens=3) for i in range(3)],
+                      max_frames=120)
+
+    def live():
+        assert chunked.state["state"] is m.state
+        assert plain.state["state"] is m.state
+        assert not any(v.is_deleted() for v in m.state.values())
+        return {k: np.asarray(v) for k, v in m.state.items()}
+
+    first = m.state
+    assert all(np.all(v == 0) for v in live().values())
+    want = serve(chunked)
+    # on a backend that honours donation the consumed arrays are gone;
+    # the live ones are wherever model.state points
+    assert first is not m.state
+    assert any(np.any(v != 0) for v in live().values())
+    assert serve(plain) == want      # the other step, same model
+    live()
+    assert serve(chunked) == want    # and back, over the pool it left
+    before = live()
+    chunked.copy_page(1, 9)
+    after = live()
+    for key, val in after.items():
+        np.testing.assert_array_equal(val[9], before[key][1])
+        np.testing.assert_array_equal(np.delete(val, 9, axis=0),
+                                      np.delete(before[key], 9, axis=0))
+
+
+def test_paged_kernel_rule_and_pool_shape():
+    """THE shape rule and the pool's spec: head sizes and pages of
+    whole 8-row tiles take the kernel; every pool
+    is [pages, page, heads * head_dim] — heads fused on the minor axis
+    — in the searched pool dtype, an int8 pool with [pages, page] fp32
+    scales beside it."""
+    import jax.numpy as jnp
+
+    from flexflow_tpu.core.ptensor import ParallelTensorShape
+    from flexflow_tpu.kernels.ragged_paged_attention import (
+        paged_kernel_applies,
+    )
+    from flexflow_tpu.ops.decode_attention import DecodeAttentionOp
+
+    assert paged_kernel_applies(64, 32) and paged_kernel_applies(8, 8)
+    assert paged_kernel_applies(96, 32)
+    assert not paged_kernel_applies(4, 8)
+    assert not paged_kernel_applies(64, 4)
+    for kvd, dtype in (("fp32", jnp.float32), ("bf16", jnp.bfloat16),
+                       ("int8", jnp.int8)):
+        op = DecodeAttentionOp(
+            "dec",
+            [ParallelTensorShape.make((2, 1, 128), "float32"),
+             ParallelTensorShape.make((2, 3), "int32"),
+             ParallelTensorShape.make((2,), "int32")],
+            embed_dim=128, num_heads=2, page_size=8, pages_per_seq=3,
+            kv_dtype=kvd)
+        specs = {name: (shape, dt) for name, shape, dt, _ in op.state_specs()}
+        assert specs["k_cache"] == specs["v_cache"] == ((6, 8, 128), dtype)
+        assert set(specs) - {"k_cache", "v_cache"} == (
+            {"k_scale", "v_scale"} if kvd == "int8" else set())
+        assert op.attention_path(multi_device=False) == "pallas"
+        assert op.attention_path(multi_device=True) == "xla"
 
 
 def test_decode_graph_searched_strategy_executes():

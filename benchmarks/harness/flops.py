@@ -60,3 +60,40 @@ def ragged_live_kv_bytes(seq_lens_read, sizes: dict,
 def mfu(tokens_per_s: float, flops_per_token: float, chips: int,
         peak_flops_per_s: float) -> float:
     return tokens_per_s * flops_per_token / (chips * peak_flops_per_s)
+
+
+# ---- the ``work`` contract ------------------------------------------------
+# A configuration names a module under ``work``; the drivers and readers
+# call these four on it, with the configuration's own dict, and nothing
+# else.  This module is the one of the zoo's OPT-style block, so it knows
+# what ``build_gpt`` and ``build_gpt_decode`` call their sizes.
+
+
+def trained_token_flops(config: dict, seq_len: int) -> float:
+    """FLOPs one trained token needs, forward + backward."""
+    return train_flops_per_token(config["builder_kwargs"], seq_len)
+
+
+def attention_kernel_flops(config: dict, batch: int, seq_len: int) -> float:
+    """FLOPs the configuration's attention kernels (``harness.
+    attention_kernels``) have to compute in one optimizer step."""
+    return flash_flops_per_step(config["builder_kwargs"], batch, seq_len)
+
+
+def served_token_flops(config: dict, context, logits: bool = True):
+    """FLOPs of one token's forward pass behind ``context`` cached tokens
+    (its own among them): 2 a multiplied weight, and QK^T and PV over the
+    context, 2 * context * hidden each a layer.  A prompt token that is
+    not the last needs no logits: ``logits=False`` leaves the head out.
+    ``context`` may be an array; the result then has its shape."""
+    sizes = config["builder_kwargs"]
+    weights = matmul_params(sizes)
+    if not logits:
+        weights -= sizes["hidden"] * sizes["vocab"]
+    return (2.0 * weights
+            + 4.0 * context * sizes["hidden"] * sizes["num_layers"])
+
+
+def cached_token_bytes(config: dict, itemsize: int) -> int:
+    """K and V of one cached token over all layers."""
+    return kv_bytes_per_token(config["builder_kwargs"], itemsize)

@@ -11,10 +11,10 @@ from __future__ import annotations
 
 import statistics
 
-from benchmarks.harness import flops, trace_reduce
+from benchmarks.harness import trace_reduce
 from benchmarks.harness.peaks import peaks_for
+from benchmarks.harness.spec import resolve_module
 
-RAGGED_KERNEL = "ragged_paged_attention"
 PREFILL_MODULE = "jit_fwd"  # jax.jit(build_chunk_forward(...)'s `fwd`)
 
 
@@ -32,14 +32,13 @@ def busy(ctx) -> dict:
     return ctx["busy"]
 
 
-def _flash_seconds(trace) -> float:
-    """Device time of the flash kernels.  Their ``pallas_call``s carry no
-    name (the op is ``jvp__.N`` forward, ``transpose_jvp___.N`` for dq and
-    dkv), so they are told by what they are: the train step's Mosaic
-    calls, which the driver's check holds to 3 a layer — flash forward,
-    dq and dkv and nothing else."""
-    return trace_reduce.op_seconds(trace,
-                                   lambda op: op in trace["mosaic_ops"])
+def _attention_seconds(ctx) -> float:
+    """Device time of the configuration's attention kernels: the ops
+    named after the ``pallas_call``s its ``harness.attention_kernels``
+    lists (training: flash forward, dq and dkv; serving: the ragged
+    paged kernel), not every Mosaic call of the program."""
+    names = ctx["cell"].config["harness"]["attention_kernels"]
+    return trace_reduce.op_seconds(ctx["trace"], _named(*names))
 
 
 def compile_s(ctx):
@@ -58,23 +57,24 @@ def device_idle_share(ctx):
 
 
 def flash_time_share(ctx):
-    flash = _flash_seconds(ctx["trace"])
+    flash = _attention_seconds(ctx)
     if flash == 0.0:
         return None
     return flash / busy(ctx)["busy_s"] * 100.0
 
 
 def flash_roofline_share(ctx):
-    """FLOPs the causal flash kernels had to compute in the traced steps
-    (fwd + bwd, re-computation not counted) over their device time, as a
-    share of the chip's bf16 peak.  The bound is FLOPs."""
-    facts = ctx["facts"]
-    flash = _flash_seconds(ctx["trace"])
+    """FLOPs the attention kernels had to compute in the traced steps
+    (the configuration's ``work`` module: fwd + bwd, re-computation not
+    counted) over their device time, as a share of the chip's bf16 peak.
+    The bound is FLOPs."""
+    facts, config = ctx["facts"], ctx["cell"].config
+    flash = _attention_seconds(ctx)
     steps = facts.get("traced_steps")
     if flash == 0.0 or not steps:
         return None
-    need = steps * flops.flash_flops_per_step(
-        facts["sizes"], facts["batch"], facts["seq_len"])
+    need = steps * resolve_module(config["work"]).attention_kernel_flops(
+        config, facts["batch"], facts["seq_len"])
     peak = peaks_for(ctx["device_kind"])["flops_bf16_per_s"]
     return need / flash / peak * 100.0
 
@@ -95,16 +95,17 @@ def prefill_device_share(ctx):
 
 
 def ragged_roofline_share(ctx):
-    """Bytes of LIVE K and V the traced frames had to read over the
-    ragged kernel's device time, as a share of the chip's HBM
+    """Bytes of LIVE K and V the traced frames had to read (cached
+    tokens attended to x the ``work`` module's bytes a cached token) over
+    the attention kernel's device time, as a share of the chip's HBM
     bandwidth.  The bound is bytes."""
-    facts = ctx["facts"]
-    kernel = trace_reduce.op_seconds(ctx["trace"], _named(RAGGED_KERNEL))
+    facts, config = ctx["facts"], ctx["cell"].config
+    kernel = _attention_seconds(ctx)
     lens = facts.get("traced_live_seq_lens")
     if kernel == 0.0 or not lens:
         return None
-    need = flops.ragged_live_kv_bytes(lens, facts["sizes"],
-                                      facts["pool_itemsize"])
+    need = float(sum(lens)) * resolve_module(
+        config["work"]).cached_token_bytes(config, facts["pool_itemsize"])
     peak = peaks_for(ctx["device_kind"])["hbm_bytes_per_s"]
     return need / kernel / peak * 100.0
 

@@ -29,9 +29,12 @@ def device_facts(trace: Optional[dict] = None) -> dict:
 
 def result_line(*, correct: bool, attempted: int, failed: int,
                 values: Dict[str, float], units: Dict[str, str],
-                device: dict, breakdown: Optional[dict] = None) -> str:
+                device: dict, breakdown: Optional[dict] = None,
+                compared: Optional[dict] = None) -> str:
     """``values`` maps metric name -> number as measured (all digits);
-    a metric whose reader found nothing to read is simply absent."""
+    a metric whose reader found nothing to read is simply absent.
+    ``compared`` — each number ``correct`` rests on beside its limit —
+    comes last, so the end of the line shows it."""
     obj = {
         "correct": bool(correct),
         "attempted": int(attempted),
@@ -42,4 +45,12 @@ def result_line(*, correct: bool, attempted: int, failed: int,
     }
     if breakdown is not None:
         obj["breakdown"] = breakdown
+    if compared is not None:
+        obj["compared"] = compared
     return json.dumps(obj)
+
+
+def compared_lines(compared: dict) -> str:
+    """The compared numbers as the last lines of standard error."""
+    return "\n".join(f"[compared] {name} = {c['value']!r} (limit "
+                     f"{c['limit']!r})" for name, c in compared.items())

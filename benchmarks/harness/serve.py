@@ -16,11 +16,19 @@ instant its last one finished.  The first request of client i asks for
 server that has been up for a while finds them.  Open loop: requests
 arrive on the traffic file's schedule whatever the server does; how late
 the generator ran is printed.
+
+Of the model the driver knows what the configuration's ``harness`` block
+says by the harness's own names — ``vocab`` (ids are drawn below it),
+``context`` = ``page_size`` x ``pages_per_seq`` tokens a sequence,
+``layers``, ``kv_pools`` (patterns of the state keys that are KV pools),
+``mosaic_calls.decode_frame`` — and the modules it names under
+``reference`` and ``work``.  The builder is called with
+``**builder_kwargs`` and nothing else looks inside them.
 """
 
 from __future__ import annotations
 
-import importlib
+import fnmatch
 import itertools
 import math
 import time
@@ -28,7 +36,7 @@ import time
 import numpy as np
 
 from benchmarks.harness import device, traffic as traffic_gen
-from benchmarks.harness.spec import resolve_dotted
+from benchmarks.harness.spec import resolve_dotted, resolve_module
 
 # bf16 compute against a float32 "highest" reference.  Logits of the
 # seeded, untrained model have a standard deviation of 0.2 (largest about
@@ -38,6 +46,12 @@ from benchmarks.harness.spec import resolve_dotted
 # a seventh of the logits' own spread, which a wrong cache position, a
 # dropped token or a wrong page moves a logit by.
 PROBE_LOGIT_ATOL = 0.03
+# A served token is the largest of the program's logits; where those lie
+# within PROBE_LOGIT_ATOL of the reference's, the reference's logit of
+# that token lies within twice that of the reference's best.  A token
+# altered after the logits (sampling, harvest, the stream handed back)
+# lies about a whole logit (1.0) below it.
+PROBE_TOKEN_GAP = 2 * PROBE_LOGIT_ATOL
 TRACE_FOR_S = 2.5  # the traced tail after the window: some tens of frames
 
 
@@ -67,10 +81,10 @@ def build_server(config: dict, seed: int):
     import flexflow_tpu as ff
     from flexflow_tpu.runtime.decode import compiled_decode_step
 
-    kw, slots = config["builder_kwargs"], config["slots"]
-    chunk = config["prefill_chunk"]
+    slots, chunk = config["slots"], config["prefill_chunk"]
     ffc = ff.FFConfig(batch_size=slots, seed=seed, **config["ffconfig"])
-    model = resolve_dotted(config["builder"])(ffc, **kw)
+    model = resolve_dotted(config["builder"])(ffc,
+                                              **config["builder_kwargs"])
     t0 = time.perf_counter()
     model.compile(loss_type=config["loss"], metrics=[],
                   comp_mode="inference")
@@ -80,7 +94,7 @@ def build_server(config: dict, seed: int):
     # prefill chunk and one all-idle frame.  Both write only where no
     # live sequence reads (slot 0's own pages; each idle row's own range)
     t0 = time.perf_counter()
-    ids, table, lens = idle_frame(slots, kw["pages_per_seq"])
+    ids, table, lens = idle_frame(slots, config["harness"]["pages_per_seq"])
     step.prefill(np.zeros((1, chunk), np.int32),
                  np.arange(chunk, dtype=np.int32)[None, :], table[:1])
     np.asarray(step(ids, table, lens))
@@ -97,29 +111,32 @@ def idle_frame(slots: int, pages_per_seq: int):
 def new_executor(config: dict, step, step_fn=None):
     from flexflow_tpu.runtime.decode import ContinuousBatchingExecutor
 
-    kw = config["builder_kwargs"]
+    harness = config["harness"]
     extra = {}
     if config.get("prefix_sharing"):
         extra = dict(prefix_sharing=True, copy_page_fn=step.copy_page)
     return ContinuousBatchingExecutor(
         step_fn or step, max_seqs=config["slots"],
-        page_size=kw["page_size"], pages_per_seq=kw["pages_per_seq"],
+        page_size=harness["page_size"],
+        pages_per_seq=harness["pages_per_seq"],
         prefill_fn=step.prefill, prefill_chunk=config["prefill_chunk"],
         **extra)
 
 
-def probe(config: dict, model, step, seed: int, log) -> dict:
+def probe(config: dict, model, step, seed: int, log):
     """One seeded request through chunked prefill and the executor; the
     logits of its decode frames against the plain float32 reference's
-    full forward at those positions.  Logits, not tokens: with random
-    weights the largest logit changes on rounding."""
+    full forward at those positions, and each token the request was
+    handed against the reference's best at its position (not token for
+    token: with random weights the largest logit changes on rounding).
+    Returns the two widest gaps and the probe's checks."""
     from flexflow_tpu.runtime.decode import DecodeRequest
 
-    reference = importlib.import_module(config["reference"])
+    reference = resolve_module(config["reference"])
     n_prompt = config["probe"]["prompt_tokens"]
     n_new = config["probe"]["max_new_tokens"]
     rng = np.random.default_rng(seed + 7)
-    prompt = rng.integers(1, config["builder_kwargs"]["vocab"],
+    prompt = rng.integers(1, config["harness"]["vocab"],
                           size=n_prompt).tolist()
     tap = StepTap(step)
     tap.logits = []
@@ -131,15 +148,20 @@ def probe(config: dict, model, step, seed: int, log) -> dict:
     want = np.asarray(reference.forward(model.params, ids))[
         0, n_prompt - 1:n_prompt - 1 + n_new]
     gap = float(np.max(np.abs(got - want)))
+    token_gap = float(np.max(want.max(axis=-1)
+                             - want[np.arange(len(want)), out[:len(want)]]))
     log(f"[serve] probe: {n_prompt}-token prompt, {len(out)} tokens; decode-"
         f"frame logits vs float32 reference max|diff| {gap:.4g} (bound "
         f"{PROBE_LOGIT_ATOL}; reference std {float(want.std()):.3g}, "
-        f"max|.| {float(np.abs(want).max()):.3g}); path "
-        f"{ex.summary()['attention_path']}")
-    return {"tokens": len(out) == n_new, "frames": len(got) == n_new,
-            "logits_equal_reference": gap <= PROBE_LOGIT_ATOL,
-            "attention_path": (ex.summary()["attention_path"]
-                               == config.get("attention_path", "pallas"))}
+        f"max|.| {float(np.abs(want).max()):.3g}); the served tokens lie "
+        f"at most {token_gap:.4g} under the reference's best (bound "
+        f"{PROBE_TOKEN_GAP}); path {ex.summary()['attention_path']}")
+    return (gap, token_gap), {
+        "tokens": len(out) == n_new, "frames": len(got) == n_new,
+        "logits_equal_reference": gap <= PROBE_LOGIT_ATOL,
+        "tokens_are_the_references_best": token_gap <= PROBE_TOKEN_GAP,
+        "attention_path": (ex.summary()["attention_path"]
+                           == config.get("attention_path", "pallas"))}
 
 
 class Ledger:
@@ -147,12 +169,14 @@ class Ledger:
 
     def __init__(self):
         self.issued = {}    # rid -> when the client issued it
+        self.prompt = {}    # rid -> prompt tokens
         self.asked = {}     # rid -> max_new_tokens
         self.tokens = {}    # rid -> stamps of its generated tokens
         self.finished = {}  # rid -> (when, tokens delivered)
 
     def issue(self, req, when: float) -> None:
         self.issued[req.rid] = when
+        self.prompt[req.rid] = len(req.prompt)
         self.asked[req.rid] = req.max_new_tokens
         self.tokens[req.rid] = []
 
@@ -187,6 +211,26 @@ class Ledger:
                  if self.finished[rid][1] != self.asked[rid]]
         return {"tokens": tokens, "gaps": gaps, "ttft": ttft,
                 "completed": len(done), "short": len(short)}
+
+    def served_contexts(self, t0: float, t1: float):
+        """The forward passes the window's tokens needed, as two arrays
+        of contexts (cached tokens attended to, the token's own among
+        them).  Generated: token k of a prompt of n comes from a pass at
+        context n + k (the first from the prompt's LAST token).
+        Prefilled: the n - 1 prompt tokens before the last, contexts
+        1..n-1, none of which needs logits; they count for the window
+        that holds the request's first token.  A shared prefix served
+        from cache still counts: this is the algorithm's need."""
+        generated, prefilled = [], []
+        for rid, stamps in self.tokens.items():
+            n = self.prompt[rid]
+            inside = [k for k, t in enumerate(stamps) if t0 < t <= t1]
+            generated.append(n + np.asarray(inside, np.int64))
+            if inside and inside[0] == 0:
+                prefilled.append(np.arange(1, n, dtype=np.int64))
+        none = [np.zeros(0, np.int64)]
+        return (np.concatenate(generated or none),
+                np.concatenate(prefilled or none))
 
 
 def drive(ex, traffic: dict, vocab: int, seed: int, seconds: float,
@@ -260,8 +304,12 @@ def run(cell, seed: int, seconds: float, trace: bool, t_proc0: float,
     import jax
 
     config, traffic = cell.config, cell.traffic
-    kw = config["builder_kwargs"]
-    cap = kw["page_size"] * kw["pages_per_seq"]
+    harness = config["harness"]
+    cap = harness["context"]
+    if harness["page_size"] * harness["pages_per_seq"] != cap:
+        raise ValueError(f"the configuration's context {cap} is not its "
+                         f"page_size x pages_per_seq")
+    work = resolve_module(config["work"])
     longest = max(p + n for p, n in traffic_gen.length_pool(traffic))
     longest += int(traffic.get("shared_prefix_tokens", 0))
     if longest > cap:
@@ -271,18 +319,19 @@ def run(cell, seed: int, seconds: float, trace: bool, t_proc0: float,
     t_run = time.perf_counter()
     model, step, compile_s, warm_s = build_server(config, seed)
     t_built = time.perf_counter()
-    checks = probe(config, model, step, seed, log)
+    (probe_gap, token_gap), checks = probe(config, model, step, seed, log)
     t_probed = time.perf_counter()
 
     tracer = device.Tracer() if trace else None
     tap = StepTap(step) if trace else None
     ex = new_executor(config, step, tap)
-    run_ = drive(ex, traffic, kw["vocab"], seed, seconds, tracer, tap)
+    run_ = drive(ex, traffic, harness["vocab"], seed, seconds, tracer, tap)
     setup_s = run_["t_start"] - t_proc0
     window_s = run_["t_end"] - run_["t_start"]
     w = run_["ledger"].window(run_["t_start"], run_["t_end"])
 
-    log(f"[serve] {config['slots']} slots x {cap} tokens; set-up "
+    log(f"[serve] {harness['layers']} layers, {config['slots']} slots x "
+        f"{cap} tokens; set-up "
         f"{setup_s:.2f}s: start + imports {t_run - t_proc0:.2f}, compile() "
         f"{compile_s:.2f}, chunk + frame (program load or compile) "
         f"{warm_s:.2f}, rest of the build {t_built - t_run - compile_s - warm_s:.2f}, "
@@ -301,19 +350,31 @@ def run(cell, seed: int, seconds: float, trace: bool, t_proc0: float,
         log(f"[serve] generator lateness: median "
             f"{np.median(run_['late']) * 1e3:.3f} ms, max "
             f"{max(run_['late']) * 1e3:.3f} ms over {len(run_['late'])}")
-    ids, table, lens = idle_frame(config["slots"], kw["pages_per_seq"])
+    ids, table, lens = idle_frame(config["slots"], harness["pages_per_seq"])
     # the second compile of a program this process ran is a cache hit
     frame = step.frame_fn.lower(model.params, step.state["state"],
                                 [ids, table, lens]).compile()
     calls = device.mosaic_calls(frame)
-    layers = kw["num_layers"]
-    expect_calls = config.get("mosaic_calls_per_layer", 0) * layers
+    expect_calls = harness["mosaic_calls"]["decode_frame"]
     log(f"[serve] Mosaic calls in the frame: {calls} (expected "
         f"{expect_calls}); frame memory_analysis "
         f"{device.memory_analysis_bytes(frame)}; memory_stats "
         f"{device.memory_stats()}")
-    pool = next(v for k, v in step.state["state"].items()
-                if k.endswith("/k_cache"))
+    pool_itemsizes = {v.dtype.itemsize for k, v in step.state["state"].items()
+                      if any(fnmatch.fnmatchcase(k, pattern)
+                             for pattern in harness["kv_pools"])}
+    if len(pool_itemsizes) != 1:
+        raise ValueError(f"the state keys {harness['kv_pools']} name no KV "
+                         f"pool, or pools of several types: "
+                         f"{sorted(step.state['state'])[:4]}...")
+    generated, prefilled = run_["ledger"].served_contexts(run_["t_start"],
+                                                          run_["t_end"])
+    window_flops = float(
+        np.sum(work.served_token_flops(config, generated))
+        + np.sum(work.served_token_flops(config, prefilled, logits=False)))
+    log(f"[serve] the window's work: {len(generated)} tokens generated, "
+        f"{len(prefilled)} prefilled, {window_flops / 1e12:.4f} TFLOP "
+        f"({config['work']}:served_token_flops)")
     on_tpu = jax.devices()[0].platform == "tpu"
     checks.update({
         "mosaic_calls": calls == expect_calls or not on_tpu,
@@ -335,6 +396,15 @@ def run(cell, seed: int, seconds: float, trace: bool, t_proc0: float,
                                {**end_to_end, **latency}.items()))
     return {
         "correct": all(checks.values()),
+        "compared": {
+            "probe_logit_gap": {"value": probe_gap,
+                                "limit": PROBE_LOGIT_ATOL},
+            "probe_token_under_best": {"value": token_gap,
+                                       "limit": PROBE_TOKEN_GAP},
+            "mosaic_calls_in_frame": {"value": calls, "limit": expect_calls},
+            "requests_short_of_their_tokens": {"value": w["short"],
+                                               "limit": 0},
+        },
         "attempted": w["completed"],
         "failed": w["short"],
         "end_to_end": end_to_end,
@@ -344,7 +414,8 @@ def run(cell, seed: int, seconds: float, trace: bool, t_proc0: float,
             "traced_live_seq_lens": [int(n) + 1 for frame_lens in
                                      run_["traced_seq_lens"]
                                      for n in frame_lens if n > 0],
-            "sizes": kw, "pool_itemsize": pool.dtype.itemsize,
+            "pool_itemsize": pool_itemsizes.pop(),
+            "window_flops": window_flops, "window_s": window_s,
             "latency_ms": latency, "checks": checks,
         },
         "trace": tracer.reduce() if tracer is not None else None,

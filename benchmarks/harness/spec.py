@@ -38,6 +38,15 @@ def resolve_dotted(path: str) -> Callable:
         raise SpecError(f"cannot resolve {path!r}: {e}") from e
 
 
+def resolve_module(path: str):
+    """A module a configuration names: its plain ``reference``, its
+    ``work`` (the algorithm's operations and bytes)."""
+    try:
+        return importlib.import_module(path)
+    except ImportError as e:
+        raise SpecError(f"cannot import {path!r}: {e}") from e
+
+
 @dataclass
 class Cell:
     """One entry of ``workloads`` with everything it names loaded."""
@@ -49,6 +58,44 @@ class Cell:
     end_to_end: List[dict]  # BENCHMARK.json entries this cell reports
     per_layer: List[dict]   # layer_metrics/<name>.json of this cell's metrics
     run_seconds: int
+
+
+def check_against_source(config: dict) -> None:
+    """What a configuration's file says of its source: ``published``
+    holds the source's values and the top level the values as run; they
+    differ only in the keys ``reduced`` lists (whose note states the
+    published value beside the one held), never in one of ``widths``;
+    and every builder argument and ``harness`` key that ``from_source``
+    maps to a key holds that key's value as run.  A preset of no source
+    (``"preset": true``) has nothing published."""
+    def refuse(what):
+        raise SpecError(f"configuration {config.get('name')!r}: {what}")
+
+    published, reduced = config["published"], config["reduced"]
+    if bool(config.get("preset")) == bool(published):
+        refuse("either a preset of no source or something published")
+    if not set(config["widths"]) <= set(published):
+        refuse(f"widths {config['widths']} are not all published keys")
+    for key in reduced:
+        if key in config["widths"] or key not in published:
+            refuse(f"{key!r} is reduced: a width, or not a published key")
+        if (config[key] == published[key]
+                or str(published[key]) not in reduced[key]):
+            refuse(f"the note on {key!r} has to state the published value "
+                   f"{published[key]!r} beside another one held")
+    for key, value in published.items():
+        if key not in reduced and config.get(key) != value:
+            refuse(f"{key!r} is {config.get(key)!r}, published {value!r}, "
+                   f"and not in reduced")
+    for block, mapping in config["from_source"].items():
+        for name, key in mapping.items():
+            if config[block].get(name) != config[key]:
+                refuse(f"{block}.{name} is {config[block].get(name)!r}, "
+                       f"{key!r} is {config[key]!r}")
+    if published and not (config["widths"]
+                          and all(config["from_source"].values())):
+        refuse("a published configuration names its widths and maps its "
+               "builder arguments and harness keys to the source's keys")
 
 
 def _reported_by(metric: dict, cell_name: str) -> bool:
@@ -74,6 +121,7 @@ def resolve_cell(root: str, workload: str) -> Cell:
         raise SpecError(f"workload {workload!r} names config "
                         f"{entry['config']!r}, which BENCHMARK.json lacks")
     config = load_json(os.path.join(root, cfg_entry["file"]))
+    check_against_source(config)
     home = os.path.dirname(os.path.dirname(
         os.path.join(root, cfg_entry["file"])))
     traffic = load_json(os.path.join(home, "traffic",

@@ -8,17 +8,23 @@ tokens a step, a dataset of ``batches_per_epoch`` distinct batches made
 from the seed, ``warmup_epochs`` whole epochs before the window.  The
 window starts at the last warm-up epoch's end and closes at the first
 epoch end past ``seconds``; only whole epochs count.
+
+Of the model the driver knows what the configuration's ``harness`` block
+says by the harness's own names — ``vocab`` (ids are drawn below it),
+``seq_len``, ``layers``, ``mosaic_calls.train_step`` (Mosaic calls the
+compiled step must hold) — and the modules it names under ``reference``
+and ``work``.  The builder is called with ``**builder_kwargs`` and
+nothing else looks inside them.
 """
 
 from __future__ import annotations
 
-import importlib
 import time
 
 import numpy as np
 
-from benchmarks.harness import device, flops
-from benchmarks.harness.spec import resolve_dotted
+from benchmarks.harness import device, mfu_readers
+from benchmarks.harness.spec import resolve_dotted, resolve_module
 
 # bf16 compute against a float32 "highest" reference.  The step-0 loss of
 # the seeded, untrained model is ln(vocab) plus a model-dependent part of
@@ -104,23 +110,22 @@ def run(cell, seed: int, seconds: float, trace: bool, t_proc0: float,
 
     t_run = time.perf_counter()
     config, traffic = cell.config, cell.traffic
-    sizes = config["builder_kwargs"]
-    seq_len, vocab = sizes["seq_len"], sizes["vocab"]
+    harness = config["harness"]
+    seq_len, vocab = harness["seq_len"], harness["vocab"]
     batch, nb = traffic["batch"], traffic["batches_per_epoch"]
     if traffic.get("seq_len", seq_len) != seq_len:
         raise ValueError(f"traffic seq_len {traffic['seq_len']} != the "
                          f"configuration's {seq_len}")
-    reference = importlib.import_module(config["reference"])
+    reference = resolve_module(config["reference"])
+    work = resolve_module(config["work"])
 
     model = build_model(config, batch, seed)
     compile_s = compile_for_training(model, config)
-    layers = sum(n.op.op_type.name == "MULTIHEAD_ATTENTION"
-                 for n in model.graph.nodes.values())
     n_params = sum(int(np.prod(w.shape)) for ws in model.params.values()
                    for w in ws.values())
-    log(f"[train] compile() incl. search {compile_s:.2f}s; {layers} layers, "
-        f"{n_params / 1e6:.1f} M parameters, executor "
-        f"{type(model.compiled).__name__}")
+    log(f"[train] compile() incl. search {compile_s:.2f}s; "
+        f"{harness['layers']} layers, {n_params / 1e6:.1f} M parameters, "
+        f"executor {type(model.compiled).__name__}")
 
     x, y = lm_sequence_data(batch * nb, seq_len, vocab, seed)
     t_data = time.perf_counter()
@@ -162,21 +167,21 @@ def run(cell, seed: int, seconds: float, trace: bool, t_proc0: float,
             model.fit(x=x, y=y, epochs=1, shuffle=False, verbose=False)
         tracer.stop()
     kind = jax.devices()[0].device_kind
-    flops_per_token = flops.train_flops_per_token(sizes, seq_len)
+    flops_per_token = work.trained_token_flops(config, seq_len)
+    window_flops = tokens * flops_per_token
     log(f"[train] window {window_s:.3f}s, {len(epochs_s)} whole epochs x "
         f"{nb} steps x {batch * seq_len} tokens; losses "
         f"{clock.losses[0]:.4f} -> {clock.losses[-1]:.4f}")
     log(f"[train] flops/token {flops_per_token / 1e9:.4f} G "
-        f"(matmuls {flops.train_matmul_flops_per_token(sizes) / 1e9:.4f}, "
-        f"causal attention "
-        f"{flops.train_attention_flops_per_token(sizes, seq_len) / 1e9:.4f})")
+        f"({config['work']}:trained_token_flops)")
     if jax.devices()[0].platform == "tpu":
         from benchmarks.harness.peaks import peaks_for
 
         peak = peaks_for(kind)["flops_bf16_per_s"]
-        log(f"[train] MFU {flops.mfu(tokens_per_s, flops_per_token, cell.chips, peak):.4f} "
-            f"= {tokens_per_s:.1f} tokens/s x flops/token / "
-            f"({cell.chips} x {peak:.3g})")
+        share = mfu_readers.share_of_peak(window_flops, window_s, cell.chips,
+                                          peak)
+        log(f"[train] MFU {share:.4f} = {tokens_per_s:.1f} tokens/s x "
+            f"flops/token / ({cell.chips} x {peak:.3g})")
     compiled = model.compiled
     # the second compile of a program this process ran is a cache hit
     program = compiled._train_step_fn.lower(
@@ -184,7 +189,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_proc0: float,
         [jax.device_put(x[:batch], compiled.input_sharding(0))],
         jax.device_put(y[:batch], compiled.batch_sharding())).compile()
     calls = device.mosaic_calls(program)
-    expect_calls = config.get("mosaic_calls_per_layer", 0) * layers
+    expect_calls = harness["mosaic_calls"]["train_step"]
     log(f"[train] Mosaic calls in the step: {calls} (expected {expect_calls}); "
         f"memory_analysis {device.memory_analysis_bytes(program)}; "
         f"memory_stats {device.memory_stats()}")
@@ -205,13 +210,18 @@ def run(cell, seed: int, seconds: float, trace: bool, t_proc0: float,
     steps = len(epochs_s) * nb
     return {
         "correct": all(checks.values()),
+        "compared": {
+            "step0_loss_rel_gap": {"value": rel, "limit": STEP0_LOSS_RTOL},
+            "mosaic_calls_in_step": {"value": calls, "limit": expect_calls},
+        },
         "attempted": steps,
         "failed": 0 if checks["losses_finite"] else steps,
         "end_to_end": {"train_tokens_per_s": tokens_per_s,
                        "setup_s": setup_s},
         "facts": {"compile_s": compile_s, "epoch_seconds": epochs_s.tolist(),
                   "steps_per_epoch": nb, "batch": batch, "seq_len": seq_len,
-                  "sizes": sizes, "checks": checks,
+                  "window_flops": window_flops,
+                  "window_s": window_s, "checks": checks,
                   "traced_steps": nb if trace else 0},
         "trace": tracer.reduce() if tracer is not None else None,
     }

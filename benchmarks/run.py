@@ -6,8 +6,10 @@
 runs one cell of ``BENCHMARK.json`` on the machine it is started on and
 prints, as the LAST line of stdout, one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (and ``breakdown`` in
-a traced run).  ``--trace 0`` reports the cell's end-to-end metrics,
-``--trace 1`` its per-layer metrics.  Earlier lines are facts for a
+a traced run), then ``compared``: each number ``correct`` rests on
+beside its limit, which are also the last lines of stderr.  ``--trace 0``
+reports the cell's end-to-end metrics, ``--trace 1`` its per-layer
+metrics.  Earlier lines are facts for a
 reader (device, versions, sample counts, MFU, memory); nothing parses
 them.  No TPU, or fewer chips than the cell asks for: exit non-zero, no
 result line — there is no CPU fallback.
@@ -102,8 +104,9 @@ def main(argv=None) -> int:
     line = result.result_line(
         correct=out["correct"], attempted=out["attempted"],
         failed=out["failed"], values=values, units=units, device=device,
-        breakdown=breakdown)
+        breakdown=breakdown, compared=out["compared"])
     print(line, flush=True)
+    print(result.compared_lines(out["compared"]), file=sys.stderr, flush=True)
     return 0
 
 

@@ -57,6 +57,18 @@ def test_train_reference_agrees_and_checks_pass(train_out):
     assert checks["step0_loss_equals_reference"]
     assert checks["losses_finite"] and checks["loss_fell"]
     assert train_out["correct"]
+    gap = train_out["compared"]["step0_loss_rel_gap"]
+    assert 0 <= gap["value"] <= gap["limit"] == train.STEP0_LOSS_RTOL
+
+
+def test_train_driver_prices_the_window_by_the_work_module(train_out):
+    facts = train_out["facts"]
+    config = tiny_cell("tiny-train", "tiny-train").config
+    per_token = spec.resolve_module(config["work"]).trained_token_flops(
+        config, 128)
+    tokens = len(facts["epoch_seconds"]) * 3 * 2 * 128
+    assert facts["window_flops"] == tokens * per_token
+    assert facts["window_s"] == pytest.approx(sum(facts["epoch_seconds"]))
 
 
 def test_epoch_clock_opens_and_closes_the_window():
@@ -90,6 +102,47 @@ def test_serve_reference_agrees_and_checks_pass(serve_out):
     assert checks["logits_equal_reference"] and checks["tokens"]
     assert checks["every_finished_request_has_its_tokens"]
     assert serve_out["correct"]
+    assert set(serve_out["compared"]) == {
+        "probe_logit_gap", "probe_token_under_best",
+        "mosaic_calls_in_frame", "requests_short_of_their_tokens"}
+    gap = serve_out["compared"]["probe_logit_gap"]
+    assert 0 <= gap["value"] <= gap["limit"] == serve.PROBE_LOGIT_ATOL
+
+
+def test_a_token_altered_where_it_is_produced_is_not_correct(monkeypatch):
+    """The timed path broken underneath: the executor hands every slot
+    the token NEXT to the one its logits put first.  The frames' logits
+    still follow the altered stream, so only the tokens tell."""
+    from flexflow_tpu.runtime.decode import ContinuousBatchingExecutor
+
+    harvest = ContinuousBatchingExecutor._harvest
+
+    def altered(self, logits, active, now, tr):
+        tokens = (logits[:, 0].argmax(axis=-1) + 1) % logits.shape[-1]
+        return harvest(self, tokens[:, None].astype("int32"), active, now,
+                       tr)
+
+    monkeypatch.setattr(ContinuousBatchingExecutor, "_harvest", altered)
+    out = serve.run(tiny_cell("tiny-serve", "tiny-closed"), SEED, 0.3,
+                    False, time.perf_counter(), log=lambda *_: None)
+    checks = out["facts"]["checks"]
+    assert checks["logits_equal_reference"]           # the frames are fine
+    assert not checks["tokens_are_the_references_best"]
+    assert not out["correct"]
+    gap = out["compared"]["probe_token_under_best"]
+    assert gap["value"] > 5 * gap["limit"]
+
+
+def test_a_step_that_leaves_its_state_unchanged_is_not_correct():
+    """Adam at step size 0: every step returns the parameters it got, the
+    step-0 loss still equals the reference's, and the loss never falls."""
+    cell = tiny_cell("tiny-train", "tiny-train")
+    cell.config = dict(cell.config, optimizer={"type": "adam", "alpha": 0.0})
+    out = train.run(cell, SEED, 0.2, False, time.perf_counter(),
+                    log=lambda *_: None)
+    checks = out["facts"]["checks"]
+    assert checks["step0_loss_equals_reference"] and not checks["loss_fell"]
+    assert not out["correct"]
 
 
 def test_serve_driver_open_loop_bursts_and_shared_prefixes():
@@ -216,6 +269,13 @@ def test_result_line_is_the_contracts_object():
     assert set(obj) == {"correct", "attempted", "failed", "metrics",
                         "device", "breakdown"}
     assert obj["metrics"] == {"setup_s": {"value": 1.25, "unit": "s"}}
+    compared = {"gap": {"value": 0.5, "limit": 1.0}}
+    last = result.result_line(
+        correct=True, attempted=1, failed=0, values={}, units={}, device={},
+        breakdown={"device_ops": [], "idle_gaps": []}, compared=compared)
+    assert list(json.loads(last))[-1] == "compared"   # the line's end
+    assert json.loads(last)["compared"] == compared
+    assert result.compared_lines(compared) == "[compared] gap = 0.5 (limit 1.0)"
     plain = json.loads(result.result_line(
         correct=False, attempted=0, failed=0, values={}, units={},
         device={}))

@@ -119,18 +119,42 @@ def test_every_cell_resolves_and_its_files_agree(bench):
         assert callable(spec.resolve_dotted(config["builder"]))
 
 
-def test_no_width_is_cut(bench):
-    published = {"hidden_size": 1024, "num_attention_heads": 16,
-                 "ffn_dim": 4096, "vocab_size": 50272,
-                 "num_hidden_layers": 24, "word_embed_proj_dim": 512}
-    for c in bench["configs"]:
-        with open(os.path.join(ROOT, c["file"])) as f:
-            config = json.load(f)
-        for key, value in published.items():
-            assert config[key] == value, (c["name"], key)
-        kw = config["builder_kwargs"]
-        assert (kw["hidden"], kw["num_heads"], kw["ff_dim"], kw["vocab"],
-                kw["num_layers"]) == (1024, 16, 4096, 50272, 24)
+def config_files():
+    folder = os.path.join(ROOT, "benchmarks", "configs")
+    return sorted(os.path.join(folder, f) for f in os.listdir(folder))
+
+
+@pytest.mark.parametrize("path", config_files(), ids=os.path.basename)
+def test_no_width_is_cut(path, bench):
+    config = spec.load_json(path)
+    spec.check_against_source(config)
+    named = [c for c in bench["configs"]
+             if os.path.join(ROOT, c["file"]) == path]
+    # a preset of no source is never a cell's configuration
+    assert bool(named) != bool(config.get("preset"))
+    for c in named:
+        assert sorted(c["reduced"]) == sorted(config["reduced"])
+
+
+@pytest.mark.parametrize("break_it,why", [
+    (lambda c: c.update(hidden_size=512), "a width differs"),
+    (lambda c: c["reduced"].update(ffn_dim="4096 -> 1024"), "a width listed"),
+    (lambda c: c.update(vocab_size=8), "a key differs, not in reduced"),
+    (lambda c: c["builder_kwargs"].update(num_layers=2), "a builder argument"),
+    (lambda c: c["harness"].update(vocab=100), "a harness key"),
+    (lambda c: c.update(preset=True), "a preset with something published"),
+    (lambda c: c.update(widths=[]), "no width named"),
+    (lambda c: c["reduced"].update(max_position_embeddings="halved"),
+     "the note lacks the published value"),
+])
+def test_the_width_check_fails_what_it_should(break_it, why):
+    """On OPT's own serving file, the one with a key in ``reduced``."""
+    config = spec.load_json(os.path.join(ROOT, "benchmarks", "configs",
+                                         "opt-350m-serve.json"))
+    spec.check_against_source(config)
+    break_it(config)
+    with pytest.raises(spec.SpecError):
+        spec.check_against_source(config)
 
 
 def test_unknown_names_fail(bench):

@@ -3,13 +3,33 @@ time, module attribution and gap attribution come out as computed by
 hand (the numbers are in data/handbuilt_trace.textproto's header)."""
 
 import os
+import sys
+import types
 
 import pytest
 from jax.profiler import ProfileData
 
-from benchmarks.harness import readers, trace_reduce
+from benchmarks.harness import readers, spec, trace_reduce
 
 MS = 1e-3
+
+
+@pytest.fixture
+def cell_of(monkeypatch):
+    """A cell whose configuration names a hand-made ``work`` module with
+    round prices, and the attention kernels the caller gives."""
+    work = types.ModuleType("handmade_work")
+    work.attention_kernel_flops = (
+        lambda config, batch, seq_len: 1e9 * batch * seq_len)
+    work.cached_token_bytes = lambda config, itemsize: 1000 * itemsize
+    monkeypatch.setitem(sys.modules, "handmade_work", work)
+
+    def make(*kernels):
+        config = {"work": "handmade_work",
+                  "harness": {"attention_kernels": list(kernels)}}
+        return spec.Cell(name="handmade", chips=1, config=config, traffic={},
+                         end_to_end=[], per_layer=[], run_seconds=1)
+    return make
 
 
 @pytest.fixture(scope="module")
@@ -41,7 +61,7 @@ def test_busy_is_the_union_not_the_sum(trace):
 
 def test_kernel_time_and_module_attribution(trace):
     kernel = trace_reduce.op_seconds(
-        trace, readers._named(readers.RAGGED_KERNEL))
+        trace, readers._named("ragged_paged_attention"))
     assert kernel == pytest.approx(5 * MS)
     inside = trace_reduce.ops_inside_modules(
         trace, lambda m: m.startswith("jit_fwd("))
@@ -50,16 +70,23 @@ def test_kernel_time_and_module_attribution(trace):
         6 / 9 * 100)
 
 
-def test_flash_share_and_roofline_from_the_mosaic_calls(trace):
-    # the train step's Mosaic calls are the flash kernels; in this trace
-    # the ragged kernel counts with them: 4 + 2 + 1 = 7 ms of 9 ms busy
+def test_flash_share_and_roofline_from_the_kernels_the_config_names(
+        trace, cell_of):
+    # the trace holds two Mosaic kernels; the configuration names ONE as
+    # attention, so the other (another kernel of the same program) does
+    # not count: 2 ms of 9 ms busy, not 4 + 2 + 1 = 7
     ctx = {"trace": trace, "device_kind": "TPU v5 lite",
-           "facts": {"traced_steps": 2, "batch": 2, "seq_len": 2048,
-                     "sizes": {"hidden": 1024, "num_layers": 24}}}
-    assert readers.flash_time_share(ctx) == pytest.approx(7 / 9 * 100)
-    need = 2 * 6 * 2048 * 1024 * 24 * 2 * 2048  # steps x flops/token x tokens
+           "cell": cell_of("jvp__"),
+           "facts": {"traced_steps": 2, "batch": 2, "seq_len": 100}}
+    assert readers.flash_time_share(ctx) == pytest.approx(2 / 9 * 100)
+    need = 2 * 1e9 * 2 * 100  # steps x the work module's FLOPs a step
     assert readers.flash_roofline_share(ctx) == pytest.approx(
-        need / (7 * MS) / 197e12 * 100)
+        need / (2 * MS) / 197e12 * 100)
+    ctx["cell"] = cell_of("jvp__", "ragged_paged_attention")
+    assert readers.flash_time_share(ctx) == pytest.approx(7 / 9 * 100)
+    ctx["cell"] = cell_of("no_such_kernel")
+    assert readers.flash_time_share(ctx) is None       # nothing to read
+    assert readers.flash_roofline_share(ctx) is None   # never 0
 
 
 def test_idle_gaps_are_charged_to_the_span_that_covers_most(trace):
@@ -78,12 +105,11 @@ def test_idle_gaps_are_charged_to_the_span_that_covers_most(trace):
         "custom-call(%x)") == "jvp__ bf16[32,2048,64]"
 
 
-def test_ragged_roofline_share_from_live_bytes(trace):
-    sizes = {"num_layers": 24, "hidden": 1024}
+def test_ragged_roofline_share_from_live_bytes(trace, cell_of):
     ctx = {"trace": trace, "device_kind": "TPU v5 lite",
-           "facts": {"traced_live_seq_lens": [100, 300], "sizes": sizes,
-                     "pool_itemsize": 4}}
-    want = 400 * 196608 / (5 * MS) / 819e9 * 100
+           "cell": cell_of("ragged_paged_attention"),
+           "facts": {"traced_live_seq_lens": [100, 300], "pool_itemsize": 4}}
+    want = 400 * 4000 / (5 * MS) / 819e9 * 100
     assert readers.ragged_roofline_share(ctx) == pytest.approx(want)
     ctx["facts"]["traced_live_seq_lens"] = []
     assert readers.ragged_roofline_share(ctx) is None  # nothing to read

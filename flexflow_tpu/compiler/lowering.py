@@ -18,6 +18,7 @@ out of params' shardings.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -252,7 +253,15 @@ class CompiledModel:
                 x = self._constrain(x, osh.inputs[e.dst_idx], axes)
             ins.append(x)
         ctx.slot_axes = axes
-        ws = params.get(node.op.name, {})
+        ws = params.get(node.op.weights_key, {})
+        # device time of the op's instructions can be charged to
+        # ``ff.mtp/ff.moe.experts`` ...; an op without a scope lowers to
+        # the program it always did
+        scope = "/".join(s for s in (node.op.block_scope, node.op.scope) if s)
+        with jax.named_scope(scope) if scope else contextlib.nullcontext():
+            self._lower_op(node, ctx, ins, ws, osh, axes, values)
+
+    def _lower_op(self, node, ctx, ins, ws, osh, axes, values):
         if self._multi_device:
             # ops with an explicit-SPMD lowering (shard_map +
             # collectives) take it when the sharding calls for it —

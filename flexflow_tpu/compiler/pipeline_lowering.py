@@ -215,7 +215,7 @@ class PipelinedCompiledModel(CompiledModel):
                     ins.append(values[(e.src, e.src_idx)])
                 else:
                     ins.append(x)
-            outs = node.op.forward(ctx, ins, params_one.get(node.op.name, {}))
+            outs = node.op.forward(ctx, ins, params_one.get(node.op.weights_key, {}))
             for i, y in enumerate(outs):
                 values[(node.guid, i)] = y
         assert not ctx.state_out, "stateful ops inside pipeline blocks"

@@ -33,6 +33,9 @@ class OperatorType(enum.Enum):
     DROPOUT = "dropout"
     SOFTMAX = "softmax"
     LAYERNORM = "layernorm"
+    RMSNORM = "rmsnorm"
+    # latent (low-rank, rotary) attention: ops/latent_attention.py
+    LATENT_ATTENTION = "latent_attention"
     CONCAT = "concat"
     SPLIT = "split"
     FLAT = "flat"
@@ -76,6 +79,15 @@ class OperatorType(enum.Enum):
     AGGREGATE = "aggregate"
     AGGREGATE_SPEC = "aggregate_spec"
     CACHE = "cache"
+    # an expert layer that is TOLD which experts it holds (ops/moe.py):
+    # route over all of them, compute the held ones' part
+    MOE_ROUTER = "moe_router"
+    EXPERT_DISPATCH = "expert_dispatch"
+    EXPERT_LINEAR = "expert_linear"
+    EXPERT_COMBINE = "expert_combine"
+    # multi-token prediction (ops/mtp.py): shifted ids, a second loss
+    SHIFT = "shift"
+    NEXT_TOKEN_LOSS = "next_token_loss"
 
     # ---- fused -----------------------------------------------------------
     FUSED = "fused"

@@ -133,10 +133,11 @@ def _flash_forward(q, k, v, causal: bool, scale: float,
                    save_lse: bool = False):
     b, sq, h, d = q.shape
     sk = k.shape[1]
+    d_v = v.shape[-1]  # the value heads may be narrower than q/k's
     # [B, S, H, D] -> [B*H, S, D]
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
+    vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d_v)
 
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
@@ -151,13 +152,14 @@ def _flash_forward(q, k, v, causal: bool, scale: float,
     scratch = [
         pltpu.VMEM((block_q, 1), jnp.float32),
         pltpu.VMEM((block_q, 1), jnp.float32),
-        pltpu.VMEM((block_q, d), jnp.float32),
+        pltpu.VMEM((block_q, d_v), jnp.float32),
     ]
     qspec = pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0))
-    out_specs = qspec
-    out_shape = jax.ShapeDtypeStruct((b * h, sq, d), q.dtype)
+    out_specs = pl.BlockSpec((1, block_q, d_v), lambda bh, i, j: (bh, i, 0))
+    out_shape = jax.ShapeDtypeStruct((b * h, sq, d_v), q.dtype)
     if save_lse:
-        out_specs = [qspec, pl.BlockSpec((1, block_q, 1), lambda bh, i, j: (bh, i, 0))]
+        out_specs = [out_specs,
+                     pl.BlockSpec((1, block_q, 1), lambda bh, i, j: (bh, i, 0))]
         out_shape = [out_shape,
                      jax.ShapeDtypeStruct((b * h, sq, 1), jnp.float32)]
     res = pl.pallas_call(
@@ -166,7 +168,7 @@ def _flash_forward(q, k, v, causal: bool, scale: float,
         in_specs=[
             qspec,
             pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh, j, 0)),
+            pl.BlockSpec((1, block_k, d_v), lambda bh, i, j: (bh, j, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -177,8 +179,8 @@ def _flash_forward(q, k, v, causal: bool, scale: float,
     )(qt, kt, vt)
     if save_lse:
         out, lse = res
-        return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3), lse
-    return res.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+        return out.reshape(b, h, sq, d_v).transpose(0, 2, 1, 3), lse
+    return res.reshape(b, h, sq, d_v).transpose(0, 2, 1, 3)
 
 
 def _flash_bwd_dq_kernel(
@@ -288,27 +290,31 @@ def _flash_bwd_dkv_kernel(
 
 def _flash_backward(q, k, v, o, lse, do, causal, scale,
                     block_q, block_k, interpret):
-    """Blocked flash backward: q,k,v,o,do [B,S,H,D], lse [B*H,Sq,1]."""
+    """Blocked flash backward: q,k [B,S,H,D], v,o,do [B,S,H,Dv] (Dv = D
+    unless the value heads are narrower), lse [B*H,Sq,1]."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
+    d_v = v.shape[-1]
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
+    vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d_v)
     # do stays in the inputs' dtype so the kernel's dots run at bf16
     # MXU rate; delta (a reduction) is computed in fp32 outside
-    dot = do.transpose(0, 2, 1, 3).reshape(b * h, sq, d).astype(q.dtype)
-    ot = o.transpose(0, 2, 1, 3).reshape(b * h, sq, d).astype(jnp.float32)
+    dot = do.transpose(0, 2, 1, 3).reshape(b * h, sq, d_v).astype(q.dtype)
+    ot = o.transpose(0, 2, 1, 3).reshape(b * h, sq, d_v).astype(jnp.float32)
     delta = jnp.sum(dot.astype(jnp.float32) * ot, axis=-1, keepdims=True)
 
     qspec = pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0))
     kspec = pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh, j, 0))
+    vspec = pl.BlockSpec((1, block_k, d_v), lambda bh, i, j: (bh, j, 0))
+    dospec = pl.BlockSpec((1, block_q, d_v), lambda bh, i, j: (bh, i, 0))
     rspec = pl.BlockSpec((1, block_q, 1), lambda bh, i, j: (bh, i, 0))
     kernel_kw = dict(scale=scale, causal=causal, block_q=block_q,
                      block_k=block_k, q_k_offset=sk - sq)
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **kernel_kw),
         grid=(b * h, sq // block_q, sk // block_k),
-        in_specs=[qspec, kspec, kspec, qspec, rspec, rspec],
+        in_specs=[qspec, kspec, vspec, dospec, rspec, rspec],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
@@ -320,16 +326,18 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale,
     # roles of the two non-BH grid axes swap: axis1 = kv block, axis2 = q
     qspec2 = pl.BlockSpec((1, block_q, d), lambda bh, j, i: (bh, i, 0))
     kspec2 = pl.BlockSpec((1, block_k, d), lambda bh, j, i: (bh, j, 0))
+    vspec2 = pl.BlockSpec((1, block_k, d_v), lambda bh, j, i: (bh, j, 0))
+    dospec2 = pl.BlockSpec((1, block_q, d_v), lambda bh, j, i: (bh, i, 0))
     rspec2 = pl.BlockSpec((1, block_q, 1), lambda bh, j, i: (bh, i, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, **kernel_kw),
         grid=(b * h, sk // block_k, sq // block_q),
-        in_specs=[qspec2, kspec2, kspec2, qspec2, rspec2, rspec2],
-        out_specs=[kspec2, kspec2],
+        in_specs=[qspec2, kspec2, vspec2, dospec2, rspec2, rspec2],
+        out_specs=[kspec2, vspec2],
         out_shape=[jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * h, sk, d), v.dtype)],
+                   jax.ShapeDtypeStruct((b * h, sk, d_v), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+                        pltpu.VMEM((block_k, d_v), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dkv",
         **_mosaic_params(interpret),
@@ -337,7 +345,7 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale,
 
     dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
     dk = dk.reshape(b, h, sk, d).transpose(0, 2, 1, 3)
-    dv = dv.reshape(b, h, sk, d).transpose(0, 2, 1, 3)
+    dv = dv.reshape(b, h, sk, d_v).transpose(0, 2, 1, 3)
     return dq, dk, dv
 
 
@@ -651,7 +659,8 @@ def flash_attention(
     q, k, v, causal: bool = False, scale: float | None = None,
     block_q: int | None = None, block_k: int | None = None,
 ):
-    """q, k, v: [B, S, H, D] -> [B, Sq, H, D].
+    """q, k: [B, S, H, D], v: [B, S, H, Dv] -> [B, Sq, H, Dv] (Dv may
+    differ from D: latent attention's 192-wide q/k beside 128-wide v).
 
     Default blocks are large (512/1024): per-grid-step overhead on the
     TPU dominates at small blocks — measured on v5e, bq 512 is ~5x
@@ -716,7 +725,8 @@ def _fa_fwd(q, k, v, causal, scale, block_q, block_k):
     sq, sk = q.shape[1], k.shape[1]
     bq = _pick_block(sq, block_q)
     bk = _pick_block(sk, block_k)
-    if bq is None or bk is None or q.shape[-1] % 8 != 0:
+    if (bq is None or bk is None or q.shape[-1] % 8 != 0
+            or v.shape[-1] % 8 != 0):
         out = _xla_attention(q, k, v, causal, scale)  # shape rule
         return out, (q, k, v, None, None)
     out, lse = _flash_forward(q, k, v, causal, scale, bq, bk, interpret,

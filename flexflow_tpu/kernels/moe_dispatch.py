@@ -57,11 +57,39 @@ def dispatch_indices(flat_e: jax.Array, n_experts: int, capacity: int
 
 
 def moe_dispatch(src: jax.Array, flat_e: jax.Array, n_experts: int,
-                 capacity: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """(src [T, D], expert ids [T]) -> (grouped [E, cap, D], pos [T],
-    valid [T]).  Empty slots are zero rows; differentiable."""
-    t, d = src.shape
+                 capacity: int, k: int = 1
+                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """(src [T / k, D], expert ids [T]) -> (grouped [E, cap, D], pos [T],
+    valid [T]): assignment ``i`` is row ``i // k`` of ``src`` (each row's
+    ``k`` assignments are consecutive), so no row is copied ``k`` times
+    before the gather.  Empty slots are zero rows; differentiable."""
+    rows, d = src.shape
     pos, valid, token_for_slot = dispatch_indices(flat_e, n_experts, capacity)
-    padded = jnp.concatenate([src, jnp.zeros((1, d), src.dtype)], axis=0)
-    grouped = padded[token_for_slot].reshape(n_experts, capacity, d)
-    return grouped, pos, valid
+    # an empty slot holds T = rows * k: row index ``rows``, filled with 0
+    grouped = src.at[token_for_slot // k].get(mode="fill", fill_value=0)
+    return grouped.reshape(n_experts, capacity, d), pos, valid
+
+
+def held_rows(flat_e: jax.Array, n_held: int, offset: int, rows: int
+              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """For the experts ``offset .. offset + n_held - 1`` that live here:
+    (source [rows] int32, sizes [n_held] int32, load [n_held] int32).
+    The assignments to held experts fill the first rows SORTED BY EXPERT
+    (arrival order within one): ``source`` names the assignment (index
+    into ``flat_e``) of each row, or ``len(flat_e)`` where the row stays
+    empty; ``sizes`` says how many consecutive rows each expert has.
+    ``rows`` bounds the CHIP, not an expert: one expert may take any
+    share of it, and only where all held experts together draw more is
+    the tail cut (``sizes`` < ``load``, the assignments each received,
+    kept or not).  Assignments to experts that are not held fill
+    nothing.  One stable sort and gathers — no scatter."""
+    t = flat_e.shape[0]
+    local = flat_e.astype(jnp.int32) - offset
+    local = jnp.where((local >= 0) & (local < n_held), local, n_held)
+    order = jnp.argsort(local, stable=True).astype(jnp.int32)
+    bounds = jnp.searchsorted(
+        local[order], jnp.arange(n_held + 1, dtype=jnp.int32)).astype(jnp.int32)
+    kept = jnp.minimum(bounds, rows)
+    row = jnp.arange(rows, dtype=jnp.int32)
+    source = jnp.where(row < kept[-1], order[jnp.minimum(row, t - 1)], t)
+    return source, kept[1:] - kept[:-1], bounds[1:] - bounds[:-1]

@@ -16,6 +16,7 @@ Differences by design (TPU-native):
 
 from __future__ import annotations
 
+import contextlib
 import math as _math
 import time
 from typing import Dict, List, Optional, Sequence, Tuple, Union
@@ -106,6 +107,9 @@ class FFModel:
         self.state = None
         self.optimizer: Optional[Optimizer] = None
         self._rng_counter = 0
+        self._block_scope: Optional[str] = None  # see block_scope()
+        # device counters' totals already published (obs/device_counters.py)
+        self._obs_seen: Dict[str, int] = {}
 
     # ------------------------------------------------------------------
     def _fresh_name(self, base: str, name: Optional[str]) -> str:
@@ -118,7 +122,20 @@ class FFModel:
     def _shape_of(self, t: Tensor) -> ParallelTensorShape:
         return ParallelTensorShape.make(t.sizes, t.dtype)
 
+    @contextlib.contextmanager
+    def block_scope(self, scope: str):
+        """Ops added inside lower under ``jax.named_scope(scope)`` (outside
+        their own ``Operator.scope``): a block of the model — ``ff.mtp`` —
+        that device time can be charged to in a trace."""
+        outer, self._block_scope = self._block_scope, scope
+        try:
+            yield
+        finally:
+            self._block_scope = outer
+
     def _add_op(self, op: O.Operator, inputs: Sequence[Tensor]) -> List[Tensor]:
+        if self._block_scope:
+            op.block_scope = self._block_scope
         node = self.graph.new_node(op)
         for i, t in enumerate(inputs):
             src_node, src_idx = self._producer[t.guid]
@@ -162,11 +179,15 @@ class FFModel:
 
     # ---- layers (reference: model.h layer-method block) ----------------
     def dense(self, input: Tensor, out_dim: int, activation=None, use_bias=True,
-              kernel_initializer=None, bias_initializer=None, name=None) -> Tensor:
+              kernel_initializer=None, bias_initializer=None, name=None,
+              weights_of: Optional[str] = None) -> Tensor:
+        """``weights_of`` names another dense layer whose kernel (and
+        bias) this one reads instead of owning its own."""
         op = O.LinearOp(self._fresh_name("dense", name), [self._shape_of(input)],
                         out_dim=out_dim, activation=activation, use_bias=use_bias,
                         kernel_initializer=kernel_initializer,
-                        bias_initializer=bias_initializer)
+                        bias_initializer=bias_initializer,
+                        weights_of=weights_of)
         return self._add_op(op, [input])[0]
 
     def conv2d(self, input: Tensor, out_channels: int, kernel_h: int, kernel_w: int,
@@ -202,11 +223,34 @@ class FFModel:
                            axes=tuple(axes), elementwise_affine=elementwise_affine, eps=eps)
         return self._add_op(op, [input])[0]
 
+    def rms_norm(self, input: Tensor, eps: float = 1e-6, name=None) -> Tensor:
+        op = O.RMSNormOp(self._fresh_name("rmsnorm", name),
+                         [self._shape_of(input)], eps=eps)
+        return self._add_op(op, [input])[0]
+
     def embedding(self, input: Tensor, num_entries: int, out_dim: int,
-                  aggr: str = "none", kernel_initializer=None, name=None) -> Tensor:
+                  aggr: str = "none", kernel_initializer=None, name=None,
+                  weights_of: Optional[str] = None) -> Tensor:
+        """``weights_of`` names another embedding whose table this one
+        reads instead of owning its own."""
         op = O.EmbeddingOp(self._fresh_name("embedding", name), [self._shape_of(input)],
                            num_entries=num_entries, out_dim=out_dim, aggr=aggr,
-                           kernel_initializer=kernel_initializer)
+                           kernel_initializer=kernel_initializer,
+                           weights_of=weights_of)
+        return self._add_op(op, [input])[0]
+
+    def latent_attention(self, input: Tensor, num_heads: int, q_lora_rank: int,
+                         kv_lora_rank: int, qk_nope_head_dim: int,
+                         qk_rope_head_dim: int, v_head_dim: int,
+                         rope_theta: float = 10000.0, eps: float = 1e-6,
+                         kernel_initializer=None, name=None) -> Tensor:
+        op = O.LatentAttentionOp(
+            self._fresh_name("latent_attention", name), [self._shape_of(input)],
+            num_heads=num_heads, q_lora_rank=q_lora_rank,
+            kv_lora_rank=kv_lora_rank, qk_nope_head_dim=qk_nope_head_dim,
+            qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+            rope_theta=rope_theta, eps=eps,
+            kernel_initializer=kernel_initializer)
         return self._add_op(op, [input])[0]
 
     def multihead_attention(self, query: Tensor, key: Tensor, value: Tensor,
@@ -332,6 +376,63 @@ class FFModel:
             [self._shape_of(t) for t in (gates, expert_idx, pos, valid, expert_out)],
             lambda_bal=lambda_bal)
         return self._add_op(op, [gates, expert_idx, pos, valid, expert_out])[0]
+
+    # an expert layer that is told which experts it holds (ops/moe.py)
+    def moe_router(self, input: Tensor, n_experts: int, k: int,
+                   scale: float = 1.0, experts_held: Optional[int] = None,
+                   kernel_initializer=None, name=None) -> Tuple[Tensor, Tensor]:
+        op = O.MoERouterOp(self._fresh_name("moe_router", name),
+                           [self._shape_of(input)], n_experts=n_experts, k=k,
+                           scale=scale, experts_held=experts_held,
+                           kernel_initializer=kernel_initializer)
+        outs = self._add_op(op, [input])
+        return outs[0], outs[1]
+
+    def expert_dispatch(self, data: Tensor, experts: Tensor, n_experts: int,
+                        experts_held: int, rows: int, expert_offset: int = 0,
+                        name=None) -> Tuple[Tensor, Tensor, Tensor]:
+        op = O.ExpertDispatchOp(
+            self._fresh_name("expert_dispatch", name),
+            [self._shape_of(data), self._shape_of(experts)],
+            n_experts=n_experts, experts_held=experts_held, rows=rows,
+            expert_offset=expert_offset)
+        outs = self._add_op(op, [data, experts])
+        return outs[0], outs[1], outs[2]
+
+    def expert_linear(self, input: Tensor, out_dim: int, activation=None,
+                      use_bias: bool = False, sizes: Optional[Tensor] = None,
+                      kernel_initializer=None, name=None) -> Tensor:
+        """``input`` [E, rows, D], or [rows, D] sorted by expert with the
+        experts' ``sizes`` [E] (``expert_dispatch``'s)."""
+        inputs = [input] if sizes is None else [input, sizes]
+        op = O.ExpertLinearOp(self._fresh_name("expert_linear", name),
+                              [self._shape_of(t) for t in inputs],
+                              out_dim=out_dim, activation=activation,
+                              use_bias=use_bias,
+                              kernel_initializer=kernel_initializer)
+        return self._add_op(op, inputs)[0]
+
+    def expert_combine(self, weights: Tensor, source: Tensor,
+                       expert_out: Tensor, name=None) -> Tensor:
+        op = O.ExpertCombineOp(
+            self._fresh_name("expert_combine", name),
+            [self._shape_of(t) for t in (weights, source, expert_out)])
+        return self._add_op(op, [weights, source, expert_out])[0]
+
+    # multi-token prediction (ops/mtp.py)
+    def shift(self, input: Tensor, by: int = 1, name=None) -> Tensor:
+        op = O.ShiftOp(self._fresh_name("shift", name),
+                       [self._shape_of(input)], by=by)
+        return self._add_op(op, [input])[0]
+
+    def next_token_loss(self, logits: Tensor, ahead_logits: Tensor,
+                        ids: Tensor, shift: int = 2, weight: float = 0.3,
+                        name=None) -> Tensor:
+        op = O.NextTokenLossOp(
+            self._fresh_name("next_token_loss", name),
+            [self._shape_of(t) for t in (logits, ahead_logits, ids)],
+            shift=shift, weight=weight)
+        return self._add_op(op, [logits, ahead_logits, ids])[0]
 
     def cache(self, input: Tensor, use_cached: bool = False, name=None) -> Tensor:
         op = O.CacheOp(self._fresh_name("cache", name), [self._shape_of(input)],
@@ -1540,6 +1641,7 @@ class FFModel:
         with phase_span(PHASE_PREFIX + "setup.init_params"):
             self.params, self.state = self.compiled.init_params(
                 self.config.seed)
+            self._obs_seen = {}  # the device counters start at 0 again
             self.opt_state = self.optimizer.init_state(self.params)
             self.opt_state = self.compiled.shard_opt_state(self.opt_state)
         return self.compiled
@@ -1936,7 +2038,7 @@ class FFModel:
         # lowering threaded the markers because device_trace_dir was
         # set at compile); after the run the capture is ingested and
         # tag-matched against the predicted comm lanes.
-        from flexflow_tpu.obs import annotate
+        from flexflow_tpu.obs import annotate, device_counters
         from flexflow_tpu.obs.metrics import METRICS
 
         fit_steps = METRICS.counter("fit.steps")
@@ -2046,6 +2148,10 @@ class FFModel:
                 if acc is not None:  # None if a recompile landed on the last batch
                     metrics.update(acc)
                 epoch_loss = float(loss)
+                # counters the step accumulates on the device (expert
+                # loads, the second loss): read where the loss just was,
+                # so no step gains a host sync
+                device_counters.publish(self.state, self._obs_seen)
             if verbose:
                 print(f"epoch {epoch}: loss={epoch_loss:.4f} {metrics}")
             logs = metrics.report()

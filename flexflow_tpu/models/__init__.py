@@ -22,6 +22,7 @@ from flexflow_tpu.models.dlrm import build_dlrm
 from flexflow_tpu.models.xdl import build_xdl
 from flexflow_tpu.models.candle_uno import build_candle_uno
 from flexflow_tpu.models.moe import build_moe
+from flexflow_tpu.models.joyai_flash import build_joyai_flash
 from flexflow_tpu.models.mlp import build_mlp_unify
 from flexflow_tpu.models.synthetic import build_moe_trunk, build_multibranch
 
@@ -45,6 +46,7 @@ __all__ = [
     "build_xdl",
     "build_candle_uno",
     "build_moe",
+    "build_joyai_flash",
     "build_moe_trunk",
     "build_multibranch",
     "build_mlp_unify",

@@ -3,8 +3,9 @@ examples/cpp/mixture_of_experts/moe.cc:1-501): top-k gating -> group_by
 dispatch -> per-expert MLPs -> weighted aggregate, with assignment
 caching feeding dynamic recompilation (moe.cc:46-92).
 
-TPU-native: experts are a batched [E, cap, D] computation (one Linear
-over the expert dim is expert-parallel when dim 0 is sharded)."""
+TPU-native: experts are a batched [E, cap, D] computation, every expert
+with weights of its own ([E, D, out] kernels, ops/moe.py ExpertLinearOp);
+the expert dim is expert-parallel when dim 0 is sharded."""
 
 from __future__ import annotations
 
@@ -35,9 +36,10 @@ def build_moe(
     topk_vals, topk_idx = model.top_k(gate, k=num_select, name="gate_topk")
     grouped, eidx, pos, valid = model.group_by(x, topk_idx, n_experts=num_exp,
                                                alpha=alpha, name="dispatch")
-    # experts: batched MLP over [E, cap, D] — dim 0 sharding = EP
-    h = model.dense(grouped, hidden, activation="relu", name="expert_fc1")
-    h = model.dense(h, num_classes, name="expert_fc2")
+    # experts: one MLP EACH, batched over [E, cap, D] — dim 0 sharding = EP
+    h = model.expert_linear(grouped, hidden, activation="relu", use_bias=True,
+                            name="expert_fc1")
+    h = model.expert_linear(h, num_classes, use_bias=True, name="expert_fc2")
     out = model.aggregate(topk_vals, eidx, pos, valid, h,
                           lambda_bal=lambda_bal, name="combine")
     return model

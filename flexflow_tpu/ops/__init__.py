@@ -22,13 +22,30 @@ from flexflow_tpu.ops.shape_ops import (
     SplitOp,
     TransposeOp,
 )
-from flexflow_tpu.ops.norm import BatchNormOp, DropoutOp, LayerNormOp, SoftmaxOp
+from flexflow_tpu.ops.norm import (
+    BatchNormOp,
+    DropoutOp,
+    LayerNormOp,
+    RMSNormOp,
+    SoftmaxOp,
+)
 from flexflow_tpu.ops.conv import Conv2DOp, Pool2DOp
 from flexflow_tpu.ops.embedding import EmbeddingOp
 from flexflow_tpu.ops.attention import BatchMatmulOp, MultiHeadAttentionOp
 from flexflow_tpu.ops.decode_attention import DecodeAttentionOp
 from flexflow_tpu.ops.reductions import GatherOp, MeanOp, TopKOp
-from flexflow_tpu.ops.moe import AggregateOp, AggregateSpecOp, CacheOp, GroupByOp
+from flexflow_tpu.ops.latent_attention import LatentAttentionOp
+from flexflow_tpu.ops.moe import (
+    AggregateOp,
+    AggregateSpecOp,
+    CacheOp,
+    ExpertCombineOp,
+    ExpertDispatchOp,
+    ExpertLinearOp,
+    GroupByOp,
+    MoERouterOp,
+)
+from flexflow_tpu.ops.mtp import NextTokenLossOp, ShiftOp
 
 __all__ = [
     "LoweringContext",
@@ -54,6 +71,14 @@ __all__ = [
     "BatchNormOp",
     "DropoutOp",
     "LayerNormOp",
+    "RMSNormOp",
+    "LatentAttentionOp",
+    "MoERouterOp",
+    "ExpertDispatchOp",
+    "ExpertLinearOp",
+    "ExpertCombineOp",
+    "ShiftOp",
+    "NextTokenLossOp",
     "SoftmaxOp",
     "Conv2DOp",
     "Pool2DOp",

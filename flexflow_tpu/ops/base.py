@@ -175,6 +175,21 @@ class Operator:
         self.output_shapes: Tuple[ParallelTensorShape, ...] = tuple(self.infer())
         self._weight_specs: Tuple[WeightSpec, ...] = tuple(self.weight_specs())
 
+    # ``jax.named_scope`` of the op's lowering (``ff.mla``,
+    # ``ff.moe.route`` ...): device time can be charged to it in a trace
+    scope: Optional[str] = None
+    # ... and of the block of the model the builder put it in
+    # (``FFModel.block_scope``: ``ff.mtp``), outside ``scope``
+    block_scope: Optional[str] = None
+
+    @property
+    def weights_key(self) -> str:
+        """The entry of the parameter tree this op reads: its own name,
+        or — ``weights_of`` — the op whose weights it shares (it then
+        declares none of its own: one copy is initialised, optimised and
+        counted, and both readers' gradients add up in it)."""
+        return self.attrs.get("weights_of") or self.name
+
     # ---- hooks -----------------------------------------------------------
     def infer(self) -> Sequence[ParallelTensorShape]:
         raise NotImplementedError(type(self).__name__)

@@ -49,6 +49,7 @@ class EmbeddingOp(Operator):
         aggr: str = "none",
         kernel_initializer: Initializer | None = None,
         param_dtype: str = "float32",
+        weights_of: str | None = None,
     ):
         assert aggr in ("none", "sum", "avg")
         self._kernel_init = kernel_initializer or NormInitializer(stddev=0.05)
@@ -59,6 +60,9 @@ class EmbeddingOp(Operator):
             out_dim=out_dim,
             aggr=aggr,
             param_dtype=param_dtype,
+            # absent unless set: ops built before the key existed keep
+            # their signature (cost cache, calibration)
+            **({"weights_of": weights_of} if weights_of else {}),
         )
 
     def infer(self) -> Sequence[ParallelTensorShape]:
@@ -72,6 +76,8 @@ class EmbeddingOp(Operator):
 
     def weight_specs(self) -> Sequence[WeightSpec]:
         a = self.attrs
+        if a.get("weights_of"):
+            return ()  # another op's table (MTP re-embeds the next ids)
         return (
             WeightSpec(
                 "table",
@@ -102,7 +108,7 @@ class EmbeddingOp(Operator):
         The gradient of the masked local gather is a local scatter-add
         into the shard, so table grads stay sharded too."""
         vocab_axes = (ctx.slot_axes or {}).get(REPLICA_SLOT, ())
-        if not vocab_axes or ctx.mesh is None:
+        if not vocab_axes or ctx.mesh is None or not self._weight_specs:
             return None
         from flexflow_tpu.comm.compat import shard_map
         from jax.sharding import NamedSharding, PartitionSpec
@@ -173,7 +179,7 @@ class EmbeddingOp(Operator):
                 ShardAnnot(
                     (r, d_deg), replica=batch_parts, idx=(REPLICA_SLOT, out_nd - 1)
                 ),
-            ),
+            )[:len(self._weight_specs)],
             outputs=(ShardAnnot(degs, replica=r, partial=r > 1),),
         )
 
@@ -181,7 +187,8 @@ class EmbeddingOp(Operator):
         return tuple(range(self.output_shapes[0].ndim))
 
     def max_replica_degree(self) -> int:
-        return self.attrs["num_entries"]
+        # a table read from another op is laid out by that op's view
+        return self.attrs["num_entries"] if self._weight_specs else 1
 
     def flops(self) -> float:
         return float(self.output_shapes[0].num_elements)

@@ -43,6 +43,7 @@ _ACTIVATIONS = {
     "sigmoid": jax.nn.sigmoid,
     "tanh": jnp.tanh,
     "gelu": lambda x: jax.nn.gelu(x, approximate=True),
+    "silu": jax.nn.silu,
     "softmax": lambda x: jax.nn.softmax(x, axis=-1),
 }
 
@@ -61,6 +62,7 @@ class LinearOp(Operator):
         kernel_initializer: Initializer | None = None,
         bias_initializer: Initializer | None = None,
         param_dtype: str = "float32",
+        weights_of: str | None = None,
     ):
         if activation not in _ACTIVATIONS:
             # same contract as conv/pool (_check_activation): fail at
@@ -78,6 +80,9 @@ class LinearOp(Operator):
             activation=activation,
             use_bias=use_bias,
             param_dtype=param_dtype,
+            # absent unless set, so every op built before the key
+            # existed keeps its signature (cost cache, calibration)
+            **({"weights_of": weights_of} if weights_of else {}),
         )
 
     # ---- shapes ----------------------------------------------------------
@@ -94,6 +99,8 @@ class LinearOp(Operator):
         return self.input_shapes[0].sizes[-1]
 
     def weight_specs(self) -> Sequence[WeightSpec]:
+        if self.attrs.get("weights_of"):
+            return ()  # another op's kernel (an output head read twice)
         pd = DataType.from_any(self.attrs["param_dtype"])
         specs = [
             WeightSpec("kernel", (self.in_dim, self.attrs["out_dim"]), pd, self._kernel_init)
@@ -130,6 +137,7 @@ class LinearOp(Operator):
         w = [ShardAnnot((r, t), replica=batch_parts, idx=(REPLICA_SLOT, nd - 1))]
         if self.attrs["use_bias"]:
             w.append(ShardAnnot((t,), replica=batch_parts * r, idx=(nd - 1,)))
+        w = w[:len(self._weight_specs)]
         return OpSharding(inputs=(x_annot,), weights=tuple(w), outputs=(out,))
 
     def splittable_output_dims(self) -> Tuple[int, ...]:
@@ -137,7 +145,8 @@ class LinearOp(Operator):
         return tuple(range(self.output_shapes[0].ndim))
 
     def max_replica_degree(self) -> int:
-        return self.in_dim
+        # a kernel read from another op is laid out by that op's view
+        return self.in_dim if self._weight_specs else 1
 
     def flops(self) -> float:
         out = self.output_shapes[0]

@@ -123,6 +123,49 @@ class LayerNormOp(Operator):
 
 
 @register_op
+class RMSNormOp(Operator):
+    """``x * rsqrt(mean(x^2) + eps) * gamma`` over the last axis, in
+    float32; no mean is subtracted and there is no bias."""
+
+    op_type = OperatorType.RMSNORM
+
+    def __init__(self, name, input_shapes, eps: float = 1e-6):
+        super().__init__(name, input_shapes, eps=float(eps))
+
+    def infer(self) -> Sequence[ParallelTensorShape]:
+        return (self.input_shapes[0],)
+
+    def weight_specs(self) -> Sequence[WeightSpec]:
+        width = (self.input_shapes[0].sizes[-1],)
+        return (WeightSpec("gamma", width, DataType.FLOAT32,
+                           ConstantInitializer(1.0)),)
+
+    def forward(self, ctx, inputs, weights):
+        return [rms_norm(inputs[0], weights["gamma"],
+                         self.attrs["eps"]).astype(inputs[0].dtype)]
+
+    def propagate(self, mv: MachineView) -> OpSharding:
+        degs = mv.dim_degrees[:-1] + (1,)  # the normalized dim stays whole
+        a = ShardAnnot(degs, mv.replica_degree)
+        return OpSharding(inputs=(a,), weights=(ShardAnnot((1,), mv.num_parts),),
+                          outputs=(a,))
+
+    def splittable_output_dims(self) -> Tuple[int, ...]:
+        return tuple(range(self.output_shapes[0].ndim - 1))
+
+    def flops(self) -> float:
+        return 4.0 * self.output_shapes[0].num_elements
+
+
+def rms_norm(x, gamma, eps: float):
+    """float32 RMS norm of the last axis (also the latent attention's
+    two inner norms)."""
+    x = x.astype(jnp.float32)
+    scale = jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
+    return x * scale * gamma.astype(jnp.float32)
+
+
+@register_op
 class BatchNormOp(Operator):
     """NHWC batch norm over (N, H, W) per channel; also accepts 2-D
     [N, C]. attrs: relu, momentum, eps. Reference: src/ops/batch_norm.cc."""

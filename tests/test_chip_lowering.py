@@ -120,6 +120,67 @@ def test_pallas_entry_compiles_under_mosaic(name, v5e_device):
     assert hlo.count(f'custom_call_target="{MOSAIC}"') == CALLS[name]
 
 
+def test_flash_kernels_compile_at_192_beside_128(v5e_device):
+    """Latent attention's head widths — query/key 192, value 128 — bf16,
+    the blocks the training cell uses: Mosaic takes the forward, dq and
+    dkv kernels as they are, nothing padded."""
+    import math
+
+    b, s, h, d, d_v = 1, 1024, 2, 192, 128
+    sh = jax.sharding.SingleDeviceSharding(v5e_device)
+
+    def spec(width):
+        return S((b, s, h, width), jnp.bfloat16, sharding=sh)
+
+    def fwd_bwd(q, k, v, g):
+        scale = 1.0 / math.sqrt(d)
+        out, lse = fa._flash_forward(q, k, v, True, scale, 512, 1024, False,
+                                     save_lse=True)
+        return fa._flash_backward(q, k, v, out, lse, g, True, scale, 512,
+                                  1024, False)
+
+    hlo = jax.jit(fwd_bwd).lower(spec(d), spec(d), spec(d_v),
+                                 spec(d_v)).compile().as_text()
+    assert hlo.count(f'custom_call_target="{MOSAIC}"') == 3
+
+
+def test_grouped_expert_products_compile_as_kernels_at_the_cells_widths(
+        v5e_device):
+    """``ExpertLinearOp`` on rows sorted by expert is ``jax.lax.ragged_dot``;
+    XLA:TPU runs it as Mosaic kernels of its own (it shows as
+    ``ragged-dot-*`` in a trace).  One gated expert block at the training
+    cell's widths, forward and backward: 3 + 6 products and two metadata
+    calls — the 11 an expert block adds to ``harness.mosaic_calls`` in
+    benchmarks/configs/joyai-llm-flash-train.json."""
+    from flexflow_tpu.core.ptensor import ParallelTensorShape
+    from flexflow_tpu.ops import ExpertLinearOp, LoweringContext
+
+    rows, held, d, ff = 8192, 16, 2048, 768
+    sh = jax.sharding.SingleDeviceSharding(v5e_device)
+    sizes_shape = ParallelTensorShape.make((held,), "int32")
+
+    def op(name, width, out, activation=None):
+        return ExpertLinearOp(
+            name, [ParallelTensorShape.make((rows, width), "float32"),
+                   sizes_shape], out_dim=out, activation=activation)
+
+    gate, up, down = op("g", d, ff, "silu"), op("u", d, ff), op("w", ff, d)
+    ctx = LoweringContext(compute_dtype=jnp.bfloat16, train=True, state_in={})
+
+    def block(x, sizes, wg, wu, wd):
+        h = (gate.forward(ctx, [x, sizes], {"kernel": wg})[0]
+             * up.forward(ctx, [x, sizes], {"kernel": wu})[0])
+        return jnp.sum(down.forward(ctx, [h, sizes], {"kernel": wd})[0] ** 2)
+
+    hlo = jax.jit(jax.grad(block, argnums=(0, 2, 3, 4))).lower(
+        S((rows, d), jnp.float32, sharding=sh),
+        S((held,), jnp.int32, sharding=sh),
+        S((held, d, ff), jnp.float32, sharding=sh),
+        S((held, d, ff), jnp.float32, sharding=sh),
+        S((held, ff, d), jnp.float32, sharding=sh)).compile().as_text()
+    assert hlo.count(f'custom_call_target="{MOSAIC}"') == 9 + 2
+
+
 def _pool_sized_producers(hlo: str, pool_elems: int):
     """HLO instructions of a compiled program whose result is an array
     of at least a pool leaf's element count, as (opcode, jax op name)."""

@@ -124,6 +124,9 @@ def test_moe_tiny():
     x = rng.normal(size=(16, 32)).astype(np.float32)
     y = rng.integers(0, 4, 16).astype(np.int32)
     fit_one(model, x, y)
+    # every expert has weights of its own
+    fc1 = model.get_weight("expert_fc1", "kernel")
+    assert fc1.shape == (4, 32, 16) and not np.allclose(fc1[0], fc1[1])
 
 
 def test_mlp_unify_tiny():

@@ -19,26 +19,32 @@ dense [B, S_max] buffer:
   padding: the scatter, the gather and the kernel below take the pool
   as it sits in HBM.
 * ``page_table`` — [B, pages_per_seq] int32 page ids (rows padded with
-  any valid id past the sequence's last live page — masked off).
+  any valid id past the sequence's last live page: the kernel never
+  reads those entries, the gather path masks what they fetch).
 * ``seq_lens`` — [B] int32 live token counts; position ``seq_lens[b]``
   is exclusive (lengths, not indices).
 
 The Pallas kernel runs a flash-style online softmax with the PAGE as
-the KV block: grid (B, pages_per_seq), pages innermost so the
-(m, l, acc) scratch accumulators carry across a sequence's pages, and
-the page indirection rides the BlockSpec index_map — the scalar-
-prefetched ``page_table`` picks which pool page each grid step loads,
-so only the sequence's OWN pages ever move HBM→VMEM (the ragged win;
-a dense layout would stream B × S_max tokens).  Blocks span all heads
-(q [1, 1, H·D], pool [1, page, H·D]): the TPU lowering takes a block
-whose last two dims equal the array's, and the per-head reduction is
-a matmul with a 0/1 head-membership matrix inside the kernel
-(``_head_sums``: Mosaic refuses to reshape a [page, H·D] block to
-[page, H, D]).  Pages past
-``ceil(len/page_size)`` are skipped with ``pl.when`` (their index map
-pins them to page 0, so they cost no FLOPs and consecutive dead steps
-keep one resident block; the tail page's dead rows are masked at
-NEG_INF exactly like flash attention's causal mask).  On non-TPU backends the kernel runs in interpreter mode.
+the KV block and WALKS THE PAGES ITSELF: grid (B,), one step a
+sequence, the pools passed whole in ``pl.ANY`` — as they sit in HBM, no
+block, no copy.  Inside, a ``fori_loop`` runs over the sequence's
+``ceil(len / page_size)`` LIVE pages (a dynamic trip count read from
+the scalar-prefetched ``seq_lens``) with (m, l, acc) as its carry, and
+takes page ``page_table[b, j]`` from a ring of VMEM page buffers that
+the kernel fills with hand-issued DMAs, ``depth − 1`` pages ahead of
+the one it weighs and on into the next sequence's first pages.  So
+only the sequence's OWN LIVE pages ever move HBM→VMEM — the ragged win
+twice over: a dense layout would stream B × S_max tokens, and a grid
+over page SLOTS (what this kernel had before: 512 steps a call at 16 ×
+32 slots, three in four of them dead pages pinned to page 0, 0.27 µs a
+step whatever it did) costs a step for every page a sequence does not
+have; a dead page is now neither requested nor waited for nor stepped
+over.  A page's block spans all heads (q [1, H·D], page [page, H·D]):
+the pool's own rows, and the per-head reduction is a matmul with a 0/1
+head-membership matrix inside the kernel (``_head_sums``: Mosaic
+refuses to reshape a [page, H·D] block to [page, H, D]).  The tail
+page's dead rows are masked at NEG_INF exactly like flash attention's
+causal mask.  On non-TPU backends the kernel runs in interpreter mode.
 Kernel or XLA gather path is chosen by ``paged_kernel_applies`` (a
 shape rule), never by a caught error.
 
@@ -50,11 +56,10 @@ Pool dtype (the searched KV-precision lane, ops/decode_attention.py):
 the plain entry points accept fp32 or bf16 pools — every dot casts its
 operands to fp32, a no-op on the fp32 path, so the historical numerics
 are bit-identical.  An int8 pool carries per-(page, slot) fp32 scales
-and enters through ``ragged_paged_attention_quant``: the Pallas
-variant dequantizes INSIDE the page loop (the scales ride the same
-scalar-prefetched page indirection as the payload, ``_SCALE_ROWS``
-[page_size] rows per grid step), so only quantized bytes ever stream
-HBM→VMEM — that smaller stream is the whole point of the lane.
+and enters through ``ragged_paged_attention_quant``: the kernel
+dequantizes INSIDE the page loop (a page's scale row rides the same
+ring of hand-issued DMAs as its payload), so only quantized bytes ever
+stream HBM→VMEM — that smaller stream is the whole point of the lane.
 """
 
 from __future__ import annotations
@@ -141,9 +146,19 @@ def _xla_ragged_paged_quant(q, k_pages, v_pages, k_scale, v_scale,
 # ---------------------------------------------------------------------------
 # Pallas kernel
 # ---------------------------------------------------------------------------
-# int8 scale rows reach the kernel in blocks of this many pool pages:
-# the TPU lowering wants the second-to-last block dim divisible by 8
-_SCALE_ROWS = 8
+# The ring keeps this many bytes of K (and as many of V) on their way
+# from HBM, in whole pages: about what 819 GB/s move in the microsecond
+# a page DMA takes to land.  Measured on a v5e at the serving cell's
+# block ([32, 1024] fp32, 128 KB a page), one call with 117 of its 512
+# page slots live / with all live: a ring of 2 pages 64.7 / 277.7 µs,
+# of 3 pages 48.8 / 200.0, of 4 pages 45.8 / 189.3; deeper rings (6, 8,
+# 12 pages) read within 2 % of 4.  The live bytes alone take 37 / 164 µs.
+_RING_BYTES = 512 * 1024
+_RING_MAX = 8
+
+
+def _ring_depth(page_bytes: int) -> int:
+    return max(2, min(_RING_MAX, -(-_RING_BYTES // page_bytes)))
 
 
 def _head_sums(x, head_dim: int):
@@ -158,9 +173,12 @@ def _head_sums(x, head_dim: int):
     tile.  fp32-exact at bf16 speed: W is exact in bf16, so only x is
     split — into three bf16 parts whose sum is x to 2^-24 — three
     passes with fp32 accumulation where ``Precision.HIGHEST`` would
-    spend six.  (Measured on a v5e at 16 × 64 heads, a call of 512 grid
-    steps: 152 µs against 167 µs for HIGHEST, 227 µs for a butterfly of
-    lane rotations on the XLU and 138 µs with no reduction at all;
+    spend six.  (Measured on a v5e at 16 × 64 heads on the 512-step
+    ``(B, pages_per_seq)`` grid this kernel had until the pages were
+    walked by hand: 152 µs a call against 167 µs for HIGHEST, 227 µs
+    for a butterfly of lane rotations on the XLU and 138 µs with no
+    reduction at all; under a ring of 2 pages 64.7 µs against 54.3 µs
+    with none, under a ring of 3 or more it hides behind the page DMAs.
     Mosaic refuses the reshape to [rows, H, D] that would make it a
     plain sum.)"""
     n = x.shape[-1]
@@ -183,86 +201,146 @@ def _head_sums(x, head_dim: int):
     return jnp.concatenate(out, axis=-1)
 
 
+def _slot_scale(rows, page_size: int):
+    """rows [R, 128] fp32 — one page's per-slot scales on the lane
+    axis, 128 slots a row — turned onto the sublane axis the scores run
+    along: [page_size, 1], by a masked lane sum a row (no transpose of
+    a narrow block for Mosaic to refuse)."""
+    cols = []
+    for i in range(rows.shape[0]):
+        shape = (min(128, page_size - 128 * i), 128)
+        slot = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        cols.append(jnp.sum(jnp.where(slot == lane, rows[i:i + 1], 0.0),
+                            axis=1, keepdims=True))
+    return cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=0)
+
+
 def _rpa_kernel(
     page_table_ref, seq_lens_ref,  # scalar-prefetch operands
-    q_ref, k_ref, v_ref, *refs,
+    q_ref, k_hbm, v_hbm, *refs,
     page_size: int, head_dim: int, scale: float, quant: bool,
 ):
-    """Grid (B, pages_per_seq), pages innermost (sequential on TPU) so
-    the online-softmax scratch carries across one sequence's pages.
-    The k/v BlockSpec index maps already routed THIS grid step's block
-    to pool page ``page_table[b, j]`` — the kernel only masks the
-    ragged tail and skips fully-dead pages.
+    """Grid (B,): one step a sequence.  The pools stay in HBM
+    (``pl.ANY``); the kernel walks sequence ``b``'s
+    ``ceil(seq_lens[b] / page_size)`` LIVE pages itself, in a
+    ``fori_loop`` whose trip count is read from the scalar-prefetched
+    ``seq_lens``, with the online-softmax state (m, l, acc) as the
+    loop's carry.  Page ``page_table[b, j]`` arrives in a ring of VMEM
+    page buffers filled by DMAs the kernel issues by hand; a dead page
+    is never requested, never waited for, never stepped over.
 
-    Every block spans ALL heads on the fused lane axis — q [1, H·D],
-    k/v [page, H·D] — which is the pool's own layout, so Mosaic takes
-    the blocks as they sit in HBM, whole 128-lane rows with no padding.
-    A head's score is ``_head_sums`` of k·q, left on every lane of that
-    head: scores, probabilities and the (m, l, acc) scratch are all
-    [·, H·D], so no value ever moves between the sublane and the lane
-    axis and the weighted V sum needs no spreading back.
+    The ring is kept full ACROSS sequences: ``ring`` (SMEM, carried
+    from grid step to grid step — hence the "arbitrary" grid) holds the
+    cursor of the next page to request, which runs ``depth − 1`` pages
+    ahead of the page being consumed and moves on to the next
+    sequence's first pages while this one's last are weighed, so a
+    sequence does not open on an exposed DMA (measured on a v5e, 16
+    sequences of one page each: 21 µs a call with a ring a sequence,
+    10.5 µs with one ring; 117 pages over 16 sequences: 56 against
+    46 µs).
 
-    With ``quant`` the page's K/V arrive int8 and are DEQUANTIZED
-    here, in the page loop — ``ks_ref``/``vs_ref`` hold the
-    per-(page, slot) fp32 scale rows of the ``_SCALE_ROWS`` pool pages
-    around this one, routed by the same scalar-prefetched page
-    indirection as the payload.  HBM→VMEM moves 1 byte per element
-    plus the scale rows; the fp32 values exist only in vregs."""
+    A page's block spans ALL heads on the fused lane axis — q [1, H·D],
+    k/v [page, H·D] — which is the pool's own layout, so a page DMA
+    moves whole 128-lane rows as they sit in HBM.  A head's score is
+    ``_head_sums`` of k·q, left on every lane of that head: scores,
+    probabilities and the carry are all [·, H·D], so no value ever
+    moves between the sublane and the lane axis and the weighted V sum
+    needs no spreading back.  The tail page's dead rows are masked at
+    NEG_INF, like flash attention's causal mask.
+
+    With ``quant`` the pages arrive int8 and are DEQUANTIZED here, in
+    the loop: each page's fp32 scale row rides the same ring (one more
+    DMA a stream, the page's 128-lane rows of the lane-padded scale
+    table), so HBM→VMEM moves 1 byte an element plus the scale rows and
+    the fp32 values exist only in vregs."""
     if quant:
-        ks_ref, vs_ref, o_ref, m_scratch, l_scratch, acc_scratch = refs
+        ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem, ring = refs
+        bufs = (k_buf, v_buf, ks_buf, vs_buf)
     else:
-        o_ref, m_scratch, l_scratch, acc_scratch = refs
+        o_ref, k_buf, v_buf, sem, ring = refs
+        bufs = (k_buf, v_buf)
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    npp = pl.num_programs(1)
+    nb = pl.num_programs(0)
+    depth = k_buf.shape[0]
     n = seq_lens_ref[b]
 
-    @pl.when(j == 0)
-    def _init():
-        m_scratch[:] = jnp.full_like(m_scratch, NEG_INF)
-        l_scratch[:] = jnp.zeros_like(l_scratch)
-        acc_scratch[:] = jnp.zeros_like(acc_scratch)
+    def live_pages(bi):
+        return pl.cdiv(seq_lens_ref[bi], page_size)
 
-    # a page whose first slot is already past the ragged length holds
-    # no live token for this sequence
-    @pl.when(j * page_size < n)
-    def _step():
-        q = q_ref[0].astype(jnp.float32)  # [1, H·D]
+    def next_live(bi):
+        # the first sequence from ``bi`` on that has a page to walk
+        return jax.lax.while_loop(
+            lambda x: jnp.logical_and(
+                x < nb, live_pages(jnp.minimum(x, nb - 1)) == 0),
+            lambda x: x + 1, bi)
+
+    def copies(bi, j, slot):
+        page = page_table_ref[bi, j]
+        srcs = [k_hbm.at[page], v_hbm.at[page]]
+        if quant:
+            rows = ks_buf.shape[1]  # of the scale table, a page
+            srcs += [t.at[pl.ds(page * rows, rows)] for t in (ks_hbm, vs_hbm)]
+        return [pltpu.make_async_copy(src, buf.at[slot], sem.at[i, slot])
+                for i, (src, buf) in enumerate(zip(srcs, bufs))]
+
+    # ring: [sequence, page] of the next page to request, pages
+    # requested, pages consumed — since the call began
+    def request():
+        rb, rj = ring[0], ring[1]
+
+        @pl.when(rb < nb)
+        def _():
+            for c in copies(rb, rj, ring[2] % depth):
+                c.start()
+            ring[2] = ring[2] + 1
+            last = rj + 1 == live_pages(rb)
+            ring[0] = jnp.where(last, next_live(rb + 1), rb)
+            ring[1] = jnp.where(last, 0, rj + 1)
+
+    @pl.when(b == 0)
+    def _fill():
+        ring[0] = next_live(0)
+        ring[1] = 0
+        ring[2] = 0
+        ring[3] = 0
+        for _ in range(depth - 1):
+            request()
+
+    q = q_ref[0].astype(jnp.float32)  # [1, H·D]
+
+    def page_step(j, carry):
+        m_prev, l_prev, acc_prev = carry
+        # the slot the previous page left is requested for the page
+        # ``depth − 1`` ahead before this one is waited for
+        request()
+        slot = ring[3] % depth
+        for c in copies(b, j, slot):
+            c.wait()
+        ring[3] = ring[3] + 1
         # fp32 casts are no-ops on the fp32 pool and make the SAME
-        # kernel serve bf16 and int8 pools
-        k = k_ref[0].astype(jnp.float32)  # [page, H·D]
-        v = v_ref[0].astype(jnp.float32)
+        # body serve bf16 and int8 pools
+        k = k_buf[slot].astype(jnp.float32)  # [page, H·D]
+        v = v_buf[slot].astype(jnp.float32)
         s = _head_sums(k * q, head_dim) * scale
         if quant:
-            # this page's row of the scale block, turned so the slot
-            # index sits on the major axis like the scores
-            row = page_table_ref[b, j] % _SCALE_ROWS
-
-            def slot_scale(ref):
-                t = ref[...].T  # [page, _SCALE_ROWS]
-                lane = jax.lax.broadcasted_iota(jnp.int32, t.shape, 1)
-                return jnp.sum(jnp.where(lane == row, t, 0.0),
-                               axis=1, keepdims=True)  # [page, 1]
-
-            s = s * slot_scale(ks_ref)
-            v = v * slot_scale(vs_ref)
+            s = s * _slot_scale(ks_buf[slot], page_size)
+            v = v * _slot_scale(vs_buf[slot], page_size)
         slots = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 0)
         s = jnp.where(slots < n, s, NEG_INF)  # [page, H·D] fp32
-        m_prev = m_scratch[:]  # [1, H·D]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_scratch[:] = l_scratch[:] * alpha + jnp.sum(
-            p, axis=0, keepdims=True)
-        acc_scratch[:] = acc_scratch[:] * alpha + jnp.sum(
-            p * v, axis=0, keepdims=True)
-        m_scratch[:] = m_new
+        l_new = l_prev * alpha + jnp.sum(p, axis=0, keepdims=True)
+        acc_new = acc_prev * alpha + jnp.sum(p * v, axis=0, keepdims=True)
+        return m_new, l_new, acc_new
 
-    @pl.when(j == npp - 1)
-    def _finish():
-        l = jnp.maximum(l_scratch[:], 1e-30)
-        o_ref[0] = (acc_scratch[:] / l).astype(o_ref.dtype)
+    zeros = jnp.zeros_like(q)
+    _, l, acc = jax.lax.fori_loop(
+        0, live_pages(b), page_step,
+        (jnp.full_like(q, NEG_INF), zeros, zeros))
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 # jitted so that a model's layers share ONE trace and lowering of the
@@ -274,39 +352,38 @@ def _pallas_ragged_paged(q, k_pages, v_pages, page_table, seq_lens, scale,
     b, h, d = q.shape
     num_pages, page_size, hd = k_pages.shape
     assert hd == h * d, (k_pages.shape, q.shape)
-    pages_per_seq = page_table.shape[1]
     quant = k_scale is not None
+    depth = _ring_depth(page_size * hd * k_pages.dtype.itemsize)
 
-    def page_of(bi, j, pt_ref, sl_ref):
-        # dead pages (page slot past ceil(len/page_size)) pin to pool
-        # page 0 — the DMA still runs but pl.when skips the math and
-        # the tail mask kills any live-page partial rows
-        live = (j * page_size) < sl_ref[bi]
-        return jnp.where(live, pt_ref[bi, j], 0)
-
-    def q_map(bi, j, pt_ref, sl_ref):
+    def q_map(bi, pt_ref, sl_ref):
         return (bi, 0, 0)
 
-    def kv_map(bi, j, pt_ref, sl_ref):
-        return (page_of(bi, j, pt_ref, sl_ref), 0, 0)
-
-    def scale_map(bi, j, pt_ref, sl_ref):
-        # the scale rows ride the SAME page indirection as the payload
-        return (page_of(bi, j, pt_ref, sl_ref) // _SCALE_ROWS, 0)
-
-    kv_spec = pl.BlockSpec((1, page_size, hd), kv_map)
-    in_specs = [pl.BlockSpec((1, 1, hd), q_map), kv_spec, kv_spec]
+    # the pool as it sits in HBM: no block, no copy
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec((1, 1, hd), q_map), in_hbm, in_hbm]
     operands = [q.reshape(b, 1, hd), k_pages, v_pages]
+    scratch = [pltpu.VMEM((depth, page_size, hd), k_pages.dtype),
+               pltpu.VMEM((depth, page_size, hd), v_pages.dtype)]
     if quant:
-        s_spec = pl.BlockSpec((_SCALE_ROWS, page_size), scale_map)
-        in_specs += [s_spec, s_spec]
-        operands += [k_scale, v_scale]
+        # Mosaic slices a DMA out of whole 128-lane rows only, and any
+        # number of them only out of a table exactly 128 wide: a page's
+        # scales are padded to whole rows (what the tiled layout of
+        # [P, page_size] occupies anyway)
+        pad = -page_size % 128
+        rows = (page_size + pad) // 128
+        in_specs += [in_hbm, in_hbm]
+        operands += [
+            jnp.pad(t, ((0, 0), (0, pad))).reshape(num_pages * rows, 128)
+            for t in (k_scale, v_scale)]
+        scratch += [pltpu.VMEM((depth, rows, 128), jnp.float32)] * 2
+    scratch += [pltpu.SemaphoreType.DMA((len(operands) - 1, depth)),
+                pltpu.SMEM((4,), jnp.int32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, pages_per_seq),
+        grid=(b,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, hd), q_map),
-        scratch_shapes=[pltpu.VMEM((1, hd), jnp.float32)] * 3,
+        scratch_shapes=scratch,
     )
     kernel = functools.partial(
         _rpa_kernel, page_size=page_size, head_dim=d, scale=scale,
@@ -315,28 +392,33 @@ def _pallas_ragged_paged(q, k_pages, v_pages, page_table, seq_lens, scale,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
-        # sequences are independent; only the page axis carries scratch
+        # the ring of page DMAs runs on from one sequence to the next
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="ragged_paged_attention",
     )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32), *operands)
     return out.reshape(b, h, d)
 
 
-def paged_kernel_applies(head_dim: int, page_size: int) -> bool:
+def paged_kernel_applies(head_dim: int, page_size: int,
+                         num_heads: int = 0) -> bool:
     """THE shape rule that picks the Pallas kernel over the XLA gather
     path: head_dim and page_size multiples of 8 (whole sublane tiles
-    for W's head blocks and the scale-row transpose).  The TPU
-    lowering asks nothing more of the fused block: Mosaic takes a
-    (1, page, H·D) block — its last two dims ARE the array's — at any
-    width and in every pool dtype (compiled for a described v5e from
-    1 × 8 to 16 × 64 and 4 × 256 lanes, pages of 8 to 32, fp32 / bf16 /
-    int8).  Whether the pool is updated IN PLACE is a matter of its
-    layout, not of this rule: H·D a multiple of 128 lanes, on either
-    path.  Anything else — tiny CPU test shapes — is served by the
-    gather path."""
-    return head_dim % 8 == 0 and page_size % 8 == 0
+    for W's head blocks and the page buffers) and, ON THE CHIP, a fused
+    width H·D of whole 128-lane rows — the kernel asks Mosaic for a DMA
+    of pool page ``[page, H·D]`` into a VMEM buffer of that shape, and
+    Mosaic slices a DMA only out of whole lane tiles (compiled for a
+    described v5e at 8 × 64, 16 × 64 and 4 × 256 lanes, pages of 8 to
+    256, fp32 / bf16 / int8; refused at 1 × 8, 2 × 32 and 3 × 96).  It
+    is the width at which the pool is updated IN PLACE, too.  The
+    interpreter (every other backend) has no lanes and takes any width
+    — how the CPU tests run the kernel's own code at tiny shapes; asked
+    without ``num_heads`` the rule answers for the block alone.
+    Anything else is served by the gather path."""
+    if head_dim % 8 or page_size % 8:
+        return False
+    return jax.default_backend() != "tpu" or (num_heads * head_dim) % 128 == 0
 
 
 def ragged_paged_attention_quant(
@@ -350,7 +432,7 @@ def ragged_paged_attention_quant(
     kernel rule as the fp32 entry point."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    if paged_kernel_applies(q.shape[-1], k_pages.shape[1]):
+    if paged_kernel_applies(q.shape[-1], k_pages.shape[1], q.shape[1]):
         return _pallas_ragged_paged(
             q, k_pages, v_pages, page_table, seq_lens, float(scale),
             jax.default_backend() != "tpu", k_scale, v_scale)
@@ -373,7 +455,7 @@ def ragged_paged_attention(
     the tests that want it."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    if paged_kernel_applies(q.shape[-1], k_pages.shape[1]):
+    if paged_kernel_applies(q.shape[-1], k_pages.shape[1], q.shape[1]):
         return _pallas_ragged_paged(
             q, k_pages, v_pages, page_table, seq_lens, float(scale),
             jax.default_backend() != "tpu")

@@ -237,7 +237,8 @@ class DecodeAttentionOp(Operator):
 
         if (self.attrs["use_kernel"] and not multi_device
                 and paged_kernel_applies(self.head_dim,
-                                         self.attrs["page_size"])):
+                                         self.attrs["page_size"],
+                                         self.attrs["num_heads"])):
             return "pallas"
         return "xla"
 

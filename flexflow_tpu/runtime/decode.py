@@ -82,6 +82,11 @@ _EVICT = annotate.PHASE_PREFIX + "serve.evict"
 _FRAMES = METRICS.counter("decode.frames")
 _ACTIVE_SLOT_FRAMES = METRICS.counter("decode.active_slot_frames")
 _SLOT_FRAMES = METRICS.counter("decode.slot_frames")
+# pages the ragged kernel walks a frame (every row attends its fresh
+# token too; an idle row walks one page) against the page slots of the
+# frame's table: the share of the table that is live
+_LIVE_PAGES = METRICS.counter("decode.live_pages")
+_PAGE_SLOTS = METRICS.counter("decode.page_slots")
 _TOKENS_GENERATED = METRICS.counter("decode.tokens_generated")
 _PREFILL_CHUNKS = METRICS.counter("decode.prefill_chunks")
 _PREFILL_TOKENS = METRICS.counter("decode.prefill_tokens")
@@ -864,6 +869,8 @@ class ContinuousBatchingExecutor:
         _FRAMES.inc()
         _ACTIVE_SLOT_FRAMES.inc(len(active))
         _SLOT_FRAMES.inc(self.max_seqs)
+        _LIVE_PAGES.inc(int((lens // self.page_size + 1).sum()))
+        _PAGE_SLOTS.inc(self.max_seqs * self.pages_per_seq)
         _TOKENS_GENERATED.inc(generated)
         rec = {
             "frame": self.frame,

@@ -39,7 +39,6 @@ S = jax.ShapeDtypeStruct
 QKV = S((8, 1024, 12, 64), jnp.bfloat16)
 BQ, BK = 512, 1024
 B, H, D, PAGE, PPS = 32, 8, 64, 32, 128
-POOL = B * PPS
 SCALE = 0.125
 
 
@@ -62,21 +61,23 @@ def _flash_entries():
 
 
 def _paged_entries():
-    q = S((B, H, D), jnp.float32)
-    table, lens = S((B, PPS), jnp.int32), S((B,), jnp.int32)
-    scales = S((POOL, PAGE), jnp.float32)
+    """The ragged kernel at the smoke's frame and, ``*_cell``, at the
+    serving cell's (16 slots, 16 heads x 64, page 32 x 32 pages a
+    sequence), over fp32, bf16 and int8 pools."""
+    def call(q, k, v, t, n, *scales):
+        return rpa._pallas_ragged_paged(q, k, v, t, n, SCALE, False, *scales)
+
     out = {}
-    for name, dt in (("fp32", jnp.float32), ("bf16", jnp.bfloat16)):
-        pool = S((POOL, PAGE, H * D), dt)
-        out[f"paged_{name}"] = (
-            lambda q, k, v, t, n: rpa._pallas_ragged_paged(
-                q, k, v, t, n, SCALE, False),
-            (q, pool, pool, table, lens))
-    pool = S((POOL, PAGE, H * D), jnp.int8)
-    out["paged_int8"] = (
-        lambda q, k, v, ks, vs, t, n: rpa._pallas_ragged_paged(
-            q, k, v, t, n, SCALE, False, ks, vs),
-        (q, pool, pool, scales, scales, table, lens))
+    for tag, (b, h, pps) in (("", (B, H, PPS)), ("_cell", (16, 16, 32))):
+        q = S((b, h, D), jnp.float32)
+        table, lens = S((b, pps), jnp.int32), S((b,), jnp.int32)
+        scales = S((b * pps, PAGE), jnp.float32)
+        for name, dt in (("fp32", jnp.float32), ("bf16", jnp.bfloat16),
+                         ("int8", jnp.int8)):
+            pool = S((b * pps, PAGE, h * D), dt)
+            out[f"paged_{name}{tag}"] = (
+                call, (q, pool, pool, table, lens)
+                + ((scales, scales) if name == "int8" else ()))
     return out
 
 

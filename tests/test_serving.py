@@ -68,6 +68,15 @@ def _decode_views(graph, strategy):
         (4, 16, 64, 32, 4, (1, 37, 64, 128), "fp32"),
         (4, 16, 64, 32, 4, (1, 37, 64, 128), "bf16"),
         (4, 16, 64, 32, 4, (1, 37, 64, 128), "int8"),
+        # the kernel walks ceil(len / page) pages a row, nothing else:
+        # every row ONE page; a row that ends on a page boundary beside
+        # a full row (len = pages_per_seq x page) and a one-page row;
+        # the cell's 32 pages a sequence, 1 to 32 of them live
+        (4, 2, 16, 8, 3, (1, 1, 1, 1), "fp32"),
+        (3, 2, 16, 8, 4, (8, 32, 1), "fp32"),
+        (3, 2, 16, 8, 4, (8, 32, 1), "int8"),
+        (4, 16, 64, 32, 32, (1, 33, 236, 1024), "fp32"),
+        (4, 16, 64, 32, 32, (1, 33, 236, 1024), "int8"),
     ],
 )
 def test_ragged_kernel_matches_dense_reference(B, H, D, page_size,
@@ -127,6 +136,97 @@ def test_ragged_kernel_matches_dense_reference(B, H, D, page_size,
     pk = _pallas_ragged_paged(q, kp, vp, pt, sl, scale, True, *scales)
     np.testing.assert_allclose(np.asarray(pk), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("pool", ["fp32", "int8"])
+def test_dead_pages_are_never_read(pool):
+    """The kernel requests a sequence's LIVE pages and no other: every
+    pool page that no live sequence owns holds NaN, and every
+    ``page_table`` entry past a row's last live page points at one — a
+    dead page copied and weighed by 0 would still turn the output NaN.
+    (An int8 payload cannot hold a NaN: its scale rows do.)"""
+    import jax.numpy as jnp
+
+    from flexflow_tpu.kernels.ragged_paged_attention import (
+        _pallas_ragged_paged,
+        dense_decode_reference,
+        gather_kv_pages,
+    )
+    from flexflow_tpu.ops.decode_attention import _quantize_kv
+
+    B, H, D, page, pps = 4, 2, 16, 8, 6
+    lens = (1, 8, 19, 48)  # 1, 1, 3 and all 6 pages live
+    rng = np.random.default_rng(1)
+    P = B * pps + 3
+    kp = rng.normal(size=(P, page, H * D)).astype(np.float32)
+    vp = rng.normal(size=(P, page, H * D)).astype(np.float32)
+    q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
+    pt = rng.permutation(P)[:B * pps].reshape(B, pps).astype(np.int32)
+    owned = np.zeros(P, bool)
+    for b, n in enumerate(lens):
+        owned[pt[b, :-(-n // page)]] = True
+    dead = np.flatnonzero(~owned)
+    assert len(dead) >= 3
+    for b, n in enumerate(lens):
+        live = -(-n // page)
+        pt[b, live:] = dead[(b + np.arange(pps - live)) % len(dead)]
+    kp, vp = jnp.asarray(kp), jnp.asarray(vp)
+    scales = ()
+    if pool == "int8":
+        (kp, ks), (vp, vs) = _quantize_kv(kp), _quantize_kv(vp)
+        k_clean, v_clean = kp * ks[..., None], vp * vs[..., None]
+        scales = (ks.at[dead].set(jnp.nan), vs.at[dead].set(jnp.nan))
+    else:
+        k_clean, v_clean = kp, vp
+        kp, vp = kp.at[dead].set(jnp.nan), vp.at[dead].set(jnp.nan)
+    pt, sl = jnp.asarray(pt), jnp.asarray(lens, jnp.int32)
+    # the oracle multiplies a masked position by 0: it takes the pool
+    # as it was before the dead pages were poisoned
+    ref = dense_decode_reference(
+        q, gather_kv_pages(k_clean, pt, H), gather_kv_pages(v_clean, pt, H),
+        sl)
+    got = np.asarray(_pallas_ragged_paged(
+        q, kp, vp, pt, sl, 1.0 / math.sqrt(D), True, *scales))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, np.asarray(ref), rtol=1e-5, atol=1e-5)
+
+
+def test_kernel_grid_does_not_grow_with_pages_per_seq():
+    """One grid step a sequence: the ``pallas_call``'s grid is the same
+    at 32 and at 64 pages a sequence and has no axis of that length —
+    the pages are walked inside the kernel, live ones only."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.kernels.ragged_paged_attention import (
+        _pallas_ragged_paged,
+    )
+
+    B, H, D, page = 4, 2, 16, 8
+
+    def grid(pps):
+        S = jax.ShapeDtypeStruct
+        pool = S((B * pps, page, H * D), jnp.float32)
+        jaxpr = jax.make_jaxpr(
+            lambda *a: _pallas_ragged_paged(*a, scale=0.25, interpret=True))(
+            S((B, H, D), jnp.float32), pool, pool,
+            S((B, pps), jnp.int32), S((B,), jnp.int32))
+        calls = []
+
+        def walk(jp):
+            for eqn in jp.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    calls.append(eqn)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+        walk(jaxpr.jaxpr)
+        assert len(calls) == 1
+        return tuple(calls[0].params["grid_mapping"].grid)
+
+    g32, g64 = grid(32), grid(64)
+    assert g32 == g64 == (B,)
+    assert 32 not in g32 and 64 not in g64
 
 
 def test_decode_op_incremental_matches_dense():
@@ -618,6 +718,41 @@ def test_executor_exhausted_pool_never_corrupts_live_cache():
     assert run(3) == run(4)  # 4 = slot-aligned, trivially safe
 
 
+def test_executor_counts_live_pages_and_page_slots():
+    """``decode.live_pages`` adds, a frame, the pages the ragged kernel
+    walks — ceil((cached + 1) / page) of EVERY row, an idle row one —
+    and ``decode.page_slots`` the slots x pages a sequence that a grid
+    over page slots would step: both worked out by hand here."""
+    from flexflow_tpu.obs.metrics import METRICS
+    from flexflow_tpu.runtime.decode import (
+        ContinuousBatchingExecutor,
+        DecodeRequest,
+    )
+
+    seen = []
+    inner = _synthetic_step()
+
+    def step(ids, table, lens):
+        seen.append(np.asarray(lens).copy())
+        return inner(ids, table, lens)
+
+    live, slots = (METRICS.counter(f"decode.{n}")
+                   for n in ("live_pages", "page_slots"))
+    live0, slots0 = live.value, slots.value
+    ex = ContinuousBatchingExecutor(step, max_seqs=2, page_size=4,
+                                    pages_per_seq=4)
+    ex.run([DecodeRequest(rid="a", prompt=[5, 6, 7], max_new_tokens=4)],
+           max_frames=40)
+    # one request in slot 0, slot 1 idle: the frames cache 0..5 tokens
+    # (three prompt tokens, then a fresh token a frame)
+    assert [tuple(x) for x in seen] == [(c, 0) for c in range(6)]
+    # slot 0 walks 1, 1, 1, 1, 2, 2 pages (it attends its fresh token
+    # too: cached 3 fills page 0, cached 4 opens page 1); the idle row
+    # one page a frame
+    assert live.value - live0 == (1 + 1 + 1 + 1 + 2 + 2) + 6
+    assert slots.value - slots0 == 6 * 2 * 4
+
+
 def test_executor_page_accounting_and_caps():
     from flexflow_tpu.runtime.decode import (
         ContinuousBatchingExecutor,
@@ -786,6 +921,26 @@ def test_paged_kernel_rule_and_pool_shape():
             {"k_scale", "v_scale"} if kvd == "int8" else set())
         assert op.attention_path(multi_device=False) == "pallas"
         assert op.attention_path(multi_device=True) == "xla"
+
+
+def test_paged_kernel_rule_on_the_chip_wants_whole_lane_rows(monkeypatch):
+    """On the TPU the kernel DMAs ``[page, H·D]`` pages out of the pool
+    and Mosaic slices only whole 128-lane rows: the fused width decides
+    there; the interpreter takes any width (how the tests above run the
+    kernel at tiny shapes)."""
+    import jax
+
+    from flexflow_tpu.kernels.ragged_paged_attention import (
+        paged_kernel_applies,
+    )
+
+    assert paged_kernel_applies(32, 8, 2) and paged_kernel_applies(96, 32, 3)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert paged_kernel_applies(64, 32, 16) and paged_kernel_applies(64, 32, 8)
+    assert paged_kernel_applies(96, 32, 4)  # 384 lanes
+    assert not paged_kernel_applies(32, 8, 2)  # 64 lanes
+    assert not paged_kernel_applies(96, 32, 3)  # 288 lanes
+    assert not paged_kernel_applies(64, 4, 16)  # the block rule still holds
 
 
 def test_decode_graph_searched_strategy_executes():

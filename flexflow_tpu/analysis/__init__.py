@@ -36,6 +36,7 @@ from flexflow_tpu.analysis.findings import (
     Finding,
     emit_findings,
     errors_only,
+    raise_if_errors,
 )
 from flexflow_tpu.analysis.invariants import (
     CHECK_STATS,
@@ -68,6 +69,7 @@ __all__ = [
     "Finding",
     "emit_findings",
     "errors_only",
+    "raise_if_errors",
     "CHECK_STATS",
     "GraphInvariantError",
     "assert_graph_ok",

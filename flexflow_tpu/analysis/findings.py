@@ -56,6 +56,15 @@ def errors_only(findings: Iterable[Finding]) -> List[Finding]:
     return [f for f in findings if f.severity == "error"]
 
 
+def raise_if_errors(findings: Iterable[Finding], message: str) -> None:
+    """The end of every gate: publish the error findings and raise
+    ``AnalysisError(message)`` carrying them; warnings pass."""
+    bad = errors_only(findings)
+    if bad:
+        emit_findings(bad)
+        raise AnalysisError(message, bad)
+
+
 def emit_findings(findings: Iterable[Finding]) -> None:
     """Publish findings as ``analysis.finding`` events (no-op when the
     bus is disabled — same one-boolean-check discipline as every other

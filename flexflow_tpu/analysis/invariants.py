@@ -28,8 +28,7 @@ Hook points: ``search/substitution._finish_rewrite`` runs
 ``assert_graph_ok`` after every ``GraphXfer.apply`` when verification
 is on (``FLEXFLOW_TPU_VERIFY=1`` / ``FFConfig.verify`` / ``--verify``),
 and the substitution test suite runs it unconditionally.  Overhead is
-tracked in ``CHECK_STATS`` so ``bench_search.py --verify`` can report
-the measured cost of always-on checking.
+tracked in ``CHECK_STATS``.
 """
 
 from __future__ import annotations
@@ -46,7 +45,7 @@ from flexflow_tpu.obs.metrics import METRICS
 _CHECKS = METRICS.counter("analysis.graph_checks")
 _FINDINGS = METRICS.counter("analysis.graph_findings")
 
-# verifier overhead accounting (bench_search.py --verify reads this)
+# verifier overhead accounting
 CHECK_STATS: Dict[str, float] = {"checks": 0, "seconds": 0.0, "findings": 0}
 
 _VERIFY = os.environ.get("FLEXFLOW_TPU_VERIFY", "") not in ("", "0", "false")
@@ -58,8 +57,7 @@ def verification_enabled() -> bool:
 
 def set_verify(enabled: bool) -> None:
     """Arm/disarm post-rewrite verification process-wide (the env var
-    ``FLEXFLOW_TPU_VERIFY=1`` sets the initial state; ``bench_search.py
-    --verify`` routes here for a whole run)."""
+    ``FLEXFLOW_TPU_VERIFY=1`` sets the initial state)."""
     global _VERIFY
     _VERIFY = bool(enabled)
 

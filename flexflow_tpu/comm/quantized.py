@@ -1,8 +1,7 @@
 """EQuARX-style quantized allreduce (arXiv:2506.17615).
 
 The weight-gradient allreduce is the dominant term of sync-bound data
-parallelism (per-device batch 1, full widths — the regime
-bench_search.py's BERT exec tier targets).  EQuARX shows a
+parallelism (per-device batch 1, full widths).  EQuARX shows a
 block-scaled int8 allreduce inside XLA cuts that wire time ~2-4x; the
 cross-replica weight-update sharding paper (arXiv:2004.13336, our
 ZeRO-1 path) already treats sync cost as a first-class lever.  This

@@ -347,8 +347,7 @@ class FFConfig:
     # run the graph-invariant checker after EVERY GraphXfer.apply and
     # check the compile-time graph before lowering.  The strategy/
     # sharding legality lint in optimize_strategy is always on; this
-    # flag adds the per-rewrite structural proof (bench_search.py
-    # --verify measures its overhead).
+    # flag adds the per-rewrite structural proof.
     zero_dp_shard: bool = False  # ZeRO-1 / weight-update sharding
     # (arXiv:2004.13336): shard optimizer state (and the update
     # compute) of replicated weights over the mesh axes they are

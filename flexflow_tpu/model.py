@@ -17,6 +17,7 @@ Differences by design (TPU-native):
 from __future__ import annotations
 
 import contextlib
+import functools
 import math as _math
 import time
 from typing import Dict, List, Optional, Sequence, Tuple, Union
@@ -86,22 +87,16 @@ def _adopt_kv_dtype(graph, dtype) -> None:
 
 class FFModel:
     def __init__(self, config: Optional[FFConfig] = None):
+        from flexflow_tpu.search.plan import StrategyPlan
+
         self.config = config or FFConfig()
-        self.graph = Graph()
+        # the one record compile() decides and the lowering reads
+        # (search/plan.py); until then it holds the graph being built
+        self.plan = StrategyPlan(Graph(), None, "caller")
         self._producer: Dict[int, Tuple[Node, int]] = {}  # tensor.guid -> (node, out_idx)
         self._input_tensors: List[Tensor] = []
         self._name_counts: Dict[str, int] = {}
         self.compiled = None
-        self.strategy = None  # chosen parallelization, set by compile()
-        self.pipeline_proposal = None  # staged-pipeline candidate for
-        # graphs the stacked executor can't run (StagedPipelineProposal)
-        self.disaggregation = None  # prefill/decode disaggregation
-        # proposal, set by compile() under the serve objective
-        self.fleet = None  # serving-fleet proposal (search/fleet.py),
-        # set by compile() under serve_fleet="search"; the controller's
-        # elastic re-search hot-swaps it (research_fleet)
-        self.fleet_base_graph = None  # pre-rewrite graph the fleet
-        # re-search solves narrow blocks on (research_fleet)
         self.params = None
         self.opt_state = None
         self.state = None
@@ -552,6 +547,52 @@ class FFModel:
         return self._binary(OperatorType.EW_MIN, a, b, name)
 
     # ------------------------------------------------------------------
+    # the plan's dimensions, under the names tests and tools read
+    @property
+    def graph(self) -> Graph:
+        return self.plan.graph
+
+    @graph.setter
+    def graph(self, graph: Graph) -> None:
+        self.plan.graph = graph
+
+    @property
+    def strategy(self):
+        return self.plan.strategy
+
+    @property
+    def pipeline_proposal(self):
+        return self.plan.staged
+
+    @property
+    def sync_precision_map(self) -> Dict[str, str]:
+        return self.plan.sync_precision
+
+    @property
+    def sync_schedule(self):
+        return self.plan.sync_schedule
+
+    @property
+    def zero_groups(self) -> tuple:
+        return self.plan.zero_groups
+
+    @property
+    def disaggregation(self):
+        # the live searched proposal; an imported block is provenance
+        # the import re-linted, nothing the runtime can act on
+        p = self.plan.disaggregation
+        return p if hasattr(p, "adopted") else None
+
+    @property
+    def fleet(self):
+        p = self.plan.fleet
+        return p if hasattr(p, "adopted") else None
+
+    @property
+    def fleet_base_graph(self):
+        return self.plan.base_graph
+
+    # ------------------------------------------------------------------
     def compile(
         self,
         optimizer: Optional[Optimizer] = None,
@@ -568,8 +609,15 @@ class FFModel:
         flexflow_tpu.parallel.pipeline.PipelineConfig enables the
         S-stage microbatched pipeline over a ``pp`` mesh axis (a
         capability the reference only stubbed: OP_PIPELINE,
-        ffconst.h:148)."""
-        from flexflow_tpu.compiler.lowering import CompiledModel, data_parallel_strategy
+        ffconst.h:148).
+
+        A pipeline over one record (search/plan.py StrategyPlan): a
+        SOURCE yields the plan (caller-supplied / imported / data-
+        parallel / searched), the post-search proposals and the comm
+        plan EXTEND it, it is EXPORTED, the KV dtype it chose is
+        adopted, ``compiler.lower`` picks the executor, and what that
+        executor cannot run is dropped from the record."""
+        from flexflow_tpu.compiler.lower import lower
         from flexflow_tpu.obs.annotate import PHASE_PREFIX, phase_span
         from flexflow_tpu.runtime.compile_cache import watch_jax_compiles
 
@@ -578,1127 +626,456 @@ class FFModel:
             raise ValueError(
                 f"comp_mode must be 'training' or 'inference', got {comp_mode!r}"
             )
-        self.config.comp_mode = comp_mode
-        if self.config.verify:
+        cfg = self.config
+        cfg.comp_mode = comp_mode
+        if cfg.verify:
             # prove the frontend-built graph well-formed before anything
             # consumes it (flexflow_tpu/analysis).  The per-rewrite hook
-            # inside the search is armed by optimize_strategy's own
+            # inside the search is armed by search_plan's own
             # scoped_verify — config.verify never becomes a sticky
             # process-wide latch.
             from flexflow_tpu.analysis import assert_graph_ok
 
             assert_graph_ok(self.graph, context="at compile entry")
-        if self.config.obs_log_file:
+        from flexflow_tpu.obs.events import BUS as _obs_bus
+
+        if cfg.obs_log_file:
             # FFConfig-gated unified telemetry (flexflow_tpu/obs): the
             # search, compile, and fit paths below all emit through the
             # same bus once it is armed
-            from flexflow_tpu.obs.events import BUS as _obs_bus
-
-            _obs_bus.configure(self.config.obs_log_file)
-        self.pipeline_proposal = None  # a stale proposal from an earlier
-        # compile must not hijack this one's lowering
-        self.disaggregation = None  # prefill/decode disaggregation
-        # proposal (search/disaggregation.py DisaggregationProposal):
-        # searched under objective="serve" +
-        # serve_disaggregation="search", persisted when adopted
-        self.fleet = None  # serving-fleet proposal (search/fleet.py
-        # FleetProposal): searched under objective="serve" +
-        # serve_fleet="search", persisted when adopted
-        self.fleet_base_graph = None
+            _obs_bus.configure(cfg.obs_log_file)
         self.optimizer = optimizer or SGDOptimizer(
-            lr=self.config.learning_rate, weight_decay=self.config.weight_decay
+            lr=cfg.learning_rate, weight_decay=cfg.weight_decay
         )
         if pipeline is not None and (
             pipeline.num_stages < 1
-            or self.config.num_devices % pipeline.num_stages != 0
+            or cfg.num_devices % pipeline.num_stages != 0
         ):
             raise ValueError(
                 f"pipeline.num_stages={pipeline.num_stages} must divide "
-                f"num_devices={self.config.num_devices}"
+                f"num_devices={cfg.num_devices}"
             )
         if pipeline is not None and mesh is not None:
             raise ValueError(
                 "mesh= is not supported with pipeline= (the pipelined "
                 "lowering builds its own pp-leading mesh)"
             )
-        if pipeline is not None and self.config.zero_dp_shard:
+        if pipeline is not None and cfg.zero_dp_shard:
             raise NotImplementedError(
                 "zero_dp_shard is not supported with pipeline= yet — the "
                 "pipelined lowering manages its own per-stage placement; "
                 "silently ignoring the flag would leave optimizer state "
                 "replicated while the user expects 1/N memory"
             )
-        searched_strategy = False  # did the joint search pick it?
-        searched_strategy_obj = None  # the exact strategy the search
-        # returned (a placement proposal may replace `strategy` below)
-        imported_sync_schedule = None  # __meta__.sync_schedule of an
-        # imported strategy file (already behind the digest gate)
-        imported_zero_groups = None  # __meta__.zero_groups likewise
-        kv_adopt_dtype = None  # pool dtype the decode ops ADOPT right
-        # before lowering (searched __meta__.kv or an imported one,
-        # both SHD168/169-gated).  Adoption is deliberately deferred
-        # past the strategy export: exported digests stay keyed to the
-        # attr-free frontend graph, so the import-side digest gate
-        # still passes and the kv block re-lints there instead.
-        if strategy is None:
-            if pipeline is not None:
-                # dp over the devices left after the pp axis is carved off
-                strategy = data_parallel_strategy(
-                    self.graph, self.config.num_devices // pipeline.num_stages
+        # source: a fresh record each compile — a stale proposal from
+        # an earlier compile must not hijack this one's lowering
+        plan = self.plan = self._source_plan(strategy, pipeline)
+        # the strategy object the search's own gates ran against — a
+        # pipeline/placement proposal below may REPLACE plan.strategy,
+        # and the gated schedule and zero map must not follow it onto
+        # a strategy they were never linted for
+        searched = plan.source == "searched"
+        gated = plan.strategy if searched else None
+        if searched:
+            self._propose(plan, mesh)
+        self._plan_comm(plan, gated)
+        # predicted step breakdown + strategy-explanation telemetry —
+        # the predicted half of the DriftReport fit() completes.  Only
+        # computed when something will consume it (profiling, the obs
+        # bus, a strategy/trace export): one extra simulate per compile
+        # is cheap but not free.
+        self.predicted_breakdown = None
+        self.drift_report = None
+        self.lane_drift_report = None  # filled by fit's device-trace
+        # capture (config.device_trace_dir) via obs/trace_ingest.py
+        pred_cal = None  # the coherent table the prediction was priced
+        # under — the export digests THIS object (STR210) instead of
+        # re-parsing the file a second time
+        if (
+            plan.strategy
+            and plan.pipeline is None
+            and plan.staged is None
+            and (
+                cfg.profiling
+                or _obs_bus.enabled
+                or cfg.export_strategy_file
+                or cfg.obs_trace_file
+                # a calibrated compile must ALWAYS record its prediction:
+                # the drift/healthy-reset loop (fit tail, re-probe
+                # allowance) closes on it even when neither profiling nor
+                # the obs bus is armed — without this, the allowance
+                # reset rode the drift-report path only
+                or cfg.calibration_file
+            )
+        ):
+            pred_cal = self._predict(plan, full=True)
+        if cfg.export_strategy_file:
+            self._export_plan(plan, pred_cal, mesh)
+        if cfg.export_strategy_computation_graph_file:
+            self.graph.write_dot(
+                cfg.export_strategy_computation_graph_file, plan.strategy
+            )
+        if cfg.export_strategy_task_graph_file:
+            from flexflow_tpu.search.simulator import Simulator
+
+            # for_config: search_devices + comp_mode/zero flags match
+            # what the search itself costed
+            Simulator.for_config(cfg).export_task_graph_dot(
+                self.graph, plan.strategy, cfg.export_strategy_task_graph_file
+            )
+        # KV-lane adoption (searched or imported __meta__.kv, both
+        # SHD168/169-gated): the decode ops take the chosen pool dtype
+        # NOW — after every export computed its digests against the
+        # attr-free graph (so the import-side digest gate still passes
+        # and the kv block re-lints there), before any lowering builds
+        # state
+        _adopt_kv_dtype(self.graph, (plan.kv or {}).get("dtype"))
+        self._lower_args = dict(
+            loss=LossType.from_any(loss_type), metrics=list(metrics),
+            optimizer=self.optimizer, mesh=mesh, block_of=block_of)
+        with phase_span(PHASE_PREFIX + "setup.lower"):
+            self.compiled = lower(plan, cfg, **self._lower_args)
+        plan.drop_unexecutable(self.compiled)
+        with phase_span(PHASE_PREFIX + "setup.init_params"):
+            self.params, self.state = self.compiled.init_params(cfg.seed)
+            self._obs_seen = {}  # the device counters start at 0 again
+            self.opt_state = self.optimizer.init_state(self.params)
+            self.opt_state = self.compiled.shard_opt_state(self.opt_state)
+        return self.compiled
+
+    def _source_plan(self, strategy, pipeline):
+        """The plan as its source yields it: the caller's own
+        ``strategy=`` / ``pipeline=``, an imported strategy file,
+        forced data parallelism, or the search."""
+        from flexflow_tpu.compiler.lowering import data_parallel_strategy
+        from flexflow_tpu.search.plan import STRATEGY_DIMS, StrategyPlan
+
+        cfg = self.config
+        if strategy is not None:
+            return StrategyPlan(self.graph, strategy, "caller",
+                                pipeline=pipeline)
+        if pipeline is not None:
+            # dp over the devices left after the pp axis is carved off
+            return StrategyPlan(
+                self.graph,
+                data_parallel_strategy(
+                    self.graph, cfg.num_devices // pipeline.num_stages),
+                "data_parallel", pipeline=pipeline)
+        if cfg.import_strategy_file:
+            from flexflow_tpu.analysis import AnalysisError
+            from flexflow_tpu.search.strategy_io import (
+                import_strategy,
+                read_meta,
+            )
+
+            # an imported strategy bypasses the search's always-on
+            # gate — provenance is checked by import_strategy and every
+            # dimension the file carries is re-linted, so an illegal
+            # file fails at compile with a finding, not inside XLA
+            try:
+                strategy = import_strategy(
+                    cfg.import_strategy_file, self.graph,
+                    allow_partial=cfg.import_strategy_partial)
+            except AnalysisError as e:
+                err = AnalysisError(
+                    f"{e}\n(hint: a strategy exported after a "
+                    f"REWRITING search is keyed to the rewritten "
+                    f"graph and cannot re-apply to a fresh frontend "
+                    f"build — use the persistent cost cache "
+                    f"(--cost-cache-file) for cross-process reuse of "
+                    f"rewritten searches, or "
+                    f"--import-strategy-partial / "
+                    f"FFConfig.import_strategy_partial for a "
+                    f"best-effort partial apply)")
+                err.findings = list(e.findings)
+                raise err from e
+            plan = StrategyPlan.from_meta(
+                read_meta(cfg.import_strategy_file), self.graph, strategy,
+                cfg)
+            plan.relint(cfg, STRATEGY_DIMS)
+            return plan
+        if cfg.only_data_parallel:
+            return StrategyPlan(
+                self.graph,
+                data_parallel_strategy(self.graph, cfg.num_devices),
+                "data_parallel")
+        # the Unity joint search IS the default compile path
+        # (reference: FFModel::compile -> graph_optimize,
+        # model.cc:2587-2655): graph rewrites compete with view
+        # assignment and the best REWRITTEN graph gets lowered —
+        # the model's graph is replaced the same way the reference
+        # deserializes the optimized PCG into its operator list
+        # (convert_graph_to_operators, substitution.cc:3014)
+        from flexflow_tpu import native as _native
+        from flexflow_tpu.obs.annotate import PHASE_PREFIX, phase_span
+        from flexflow_tpu.search.driver import search_plan
+
+        # the search loads the native engine on first use, and
+        # builds it (make) in a fresh checkout: its own span
+        with phase_span(PHASE_PREFIX + "setup.native_build"):
+            _native.get_lib()
+        with phase_span(PHASE_PREFIX + "setup.search"):
+            plan = search_plan(self.graph, cfg)
+        if plan.graph is not self.graph:
+            plan.base_graph = self.graph
+        return plan
+
+    def _propose(self, plan, mesh) -> None:
+        """Extend a SEARCHED plan with the proposals the flat search
+        does not cost: a pipelined, a placed or a staged candidate
+        (training), a prefill/decode disaggregation and a serving
+        fleet (serve objective).  Each was legality-gated where it was
+        proposed."""
+        from flexflow_tpu.search.driver import coherent_calibration
+
+        cfg = self.config
+        # the search also costs pipelined candidates for stacked-block
+        # graphs (reference gap: OP_PIPELINE is an enum stub,
+        # ffconst.h:148) — a winning PipelineConfig is adopted exactly
+        # as if the user had passed it
+        if (
+            mesh is None
+            and (cfg.enable_pipeline_search or cfg.enable_placement_search)
+            and not cfg.zero_dp_shard
+            and cfg.comp_mode == "training"
+        ):
+            from flexflow_tpu.compiler.lowering import data_parallel_strategy
+            from flexflow_tpu.search.pipeline_search import (
+                propose_pipeline,
+                propose_pipeline_general,
+            )
+            from flexflow_tpu.search.simulator import Simulator
+
+            # same cost currency as the flat search that just ran:
+            # measured calibration included when coherent
+            sim = Simulator.for_config(
+                cfg, calibration=coherent_calibration(cfg))
+            baseline = sim.simulate(plan.graph, plan.strategy)
+            prop = (propose_pipeline(plan.graph, cfg, sim, baseline)
+                    if cfg.enable_pipeline_search else None)
+            if prop is not None and (
+                cfg.num_devices % prop.num_stages == 0
+                and cfg.batch_size % prop.num_microbatches == 0
+            ):
+                plan.pipeline = prop
+                plan.strategy = data_parallel_strategy(
+                    plan.graph, cfg.num_devices // prop.num_stages)
+            elif cfg.enable_placement_search:
+                # no pipeline won: cost 2-block inter-op placed
+                # candidates in the placed executor's schedule
+                # (reference: VERTICAL splits + mapper placement,
+                # graph.cc:161-295, mapper.cc:371-475); a
+                # margin-beating placeable winner replaces the flat
+                # strategy and lowers via the placed path
+                from flexflow_tpu.search.placement_search import (
+                    propose_placement,
                 )
-            elif self.config.import_strategy_file:
-                from flexflow_tpu.search.strategy_io import import_strategy
 
-                # an imported strategy bypasses the search's always-on
-                # gate — provenance is checked by import_strategy and
-                # the views are linted below, so an illegal file fails
-                # at compile with a finding, not inside XLA
-                from flexflow_tpu.analysis import (
-                    AnalysisError,
-                    emit_findings,
-                    errors_only,
-                    lint_strategy,
-                )
+                placed = propose_placement(
+                    plan.graph, cfg, baseline,
+                    calibration=coherent_calibration(cfg))
+                if placed is not None:
+                    plan.strategy = placed
+                elif not _math.isfinite(baseline):
+                    # nothing executable fits: cost the GENERAL
+                    # staged-pipeline shape (any graph cut, reference
+                    # graph.cc:161-295); a winning proposal lowers via
+                    # the heterogeneous staged executor
+                    # (compiler/staged_pipeline_lowering.py)
+                    plan.staged = propose_pipeline_general(
+                        plan.graph, cfg, sim, baseline)
+                    if plan.staged is not None:
+                        from flexflow_tpu.utils.logging import SEARCH_LOG
 
-                try:
-                    strategy = import_strategy(
-                        self.config.import_strategy_file, self.graph,
-                        allow_partial=self.config.import_strategy_partial)
-                except AnalysisError as e:
-                    err = AnalysisError(
-                        f"{e}\n(hint: a strategy exported after a "
-                        f"REWRITING search is keyed to the rewritten "
-                        f"graph and cannot re-apply to a fresh frontend "
-                        f"build — use the persistent cost cache "
-                        f"(--cost-cache-file) for cross-process reuse of "
-                        f"rewritten searches, or "
-                        f"--import-strategy-partial / "
-                        f"FFConfig.import_strategy_partial for a "
-                        f"best-effort partial apply)")
-                    err.findings = list(e.findings)
-                    raise err from e
-
-                bad = errors_only(lint_strategy(
-                    self.graph, strategy, self.config.num_devices))
-                if bad:
-                    emit_findings(bad)
-                    raise AnalysisError(
-                        f"imported strategy "
-                        f"{self.config.import_strategy_file!r} is illegal "
-                        f"for this graph/mesh", bad)
-                from flexflow_tpu.search.strategy_io import read_meta
-
-                _imeta = read_meta(self.config.import_strategy_file)
-                imported_sync_schedule = _imeta.get("sync_schedule")
-                imported_zero_groups = _imeta.get("zero_groups")
-                # pipeline/placement proposal provenance rides the same
-                # digest gate — re-lint against THIS graph/strategy so
-                # a hand-edited proposal block fails with a finding at
-                # import, not inside the placed/staged lowering
-                # (analysis/placement.py SHD150-155)
-                if _imeta.get("placement") is not None:
-                    from flexflow_tpu.analysis import (
-                        lint_placement,
-                        placement_meta,
-                    )
-
-                    bad = errors_only(lint_placement(
-                        self.graph, strategy, self.config))
-                    if not bad and placement_meta(
-                            self.graph, strategy, self.config
-                    ) != _imeta["placement"]:
-                        from flexflow_tpu.analysis import Finding
-
-                        bad = [Finding(
-                            code="SHD153", pass_name="placement",
-                            message=(
-                                "imported __meta__.placement block frame "
-                                "disagrees with the device blocks the "
-                                "strategy's start_part views actually "
-                                "form"))]
-                    if bad:
-                        emit_findings(bad)
-                        raise AnalysisError(
-                            "imported placement proposal is illegal for "
-                            "this graph/strategy", bad)
-                _ispec = None  # the imported ServingSpec, shared with
-                # the __meta__.kv re-lint below
-                if _imeta.get("serving") is not None:
-                    # imported serving provenance re-lints against THIS
-                    # graph/strategy (SHD16x): a hand-edited or
-                    # re-targeted serve artifact fails with findings,
-                    # not inside the executor
-                    from flexflow_tpu.analysis import lint_serving
-                    from flexflow_tpu.search.machine_model import (
-                        CostModel as _SCM,
-                    )
-                    from flexflow_tpu.search.serving import ServingSpec
-
-                    _sv = _imeta["serving"]
-                    try:
-                        _spec = ServingSpec(
-                            max_seqs=int(_sv["max_seqs"]),
-                            page_size=int(_sv["page_size"]),
-                            pages_per_seq=int(_sv["pages_per_seq"]),
-                            p99_budget_ms=float(
-                                _sv.get("p99_budget_ms", 0.0)),
-                            quantile=float(_sv.get("quantile", 0.99)),
-                            # residency was ranked under the kv block's
-                            # prefix sharing (when present): the SHD161
-                            # re-proof must price the same pool
-                            shared_prefix_pages=int(
-                                (_imeta.get("kv") or {}).get(
-                                    "shared_prefix_pages", 0) or 0),
+                        SEARCH_LOG.log(
+                            f"staged-pipeline candidate: S="
+                            f"{plan.staged.num_stages} M="
+                            f"{plan.staged.num_microbatches} modeled "
+                            f"{plan.staged.cost * 1e3:.3f} ms/iter "
+                            f"(flat is infeasible)"
                         )
-                    except (KeyError, TypeError, ValueError) as e:
-                        raise AnalysisError(
-                            f"imported strategy file carries a malformed "
-                            f"__meta__.serving block: {e}", []) from e
-                    # inference=... must MATCH the producing gate's cost
-                    # model (the search ran under comp_mode=inference):
-                    # a training-mode CostModel counts activations 2x
-                    # and would SHD161-reject legal near-capacity
-                    # artifacts the search-time gate passed; serving=
-                    # arms the same shared-residency discount
-                    bad = errors_only(lint_serving(
-                        self.graph, strategy, _spec,
-                        _SCM(self.config.machine_spec,
-                             num_devices=self.config.search_devices,
-                             inference=comp_mode == "inference",
-                             serving=_spec)))
-                    if bad:
-                        emit_findings(bad)
-                        raise AnalysisError(
-                            "imported serving provenance is illegal for "
-                            "this graph/strategy", bad)
-                    _ispec = _spec
-                if _imeta.get("kv") is not None:
-                    # imported KV-lane provenance re-lints against THIS
-                    # graph/strategy (SHD168/169) BEFORE the pool dtype
-                    # is adopted onto the decode ops: a hand-edited or
-                    # re-targeted __meta__.kv fails with findings at
-                    # import, never inside the lowering or the kernel
-                    from flexflow_tpu.analysis import lint_kv
-
-                    bad = errors_only(lint_kv(
-                        self.graph, strategy, _imeta["kv"],
-                        serving=_ispec))
-                    if bad:
-                        emit_findings(bad)
-                        raise AnalysisError(
-                            "imported __meta__.kv block is illegal for "
-                            "this graph/strategy", bad)
-                    kv_adopt_dtype = _imeta["kv"].get("dtype")
-                if _imeta.get("disaggregation") is not None:
-                    # imported disaggregation provenance re-lints
-                    # against THIS graph (SHD164/165): the persisted
-                    # pool geometry must agree with the target's decode
-                    # ops and the shared-parameter-set bridge must
-                    # still hold — a hand-edited or re-targeted
-                    # artifact fails with findings at import
-                    from flexflow_tpu.analysis import lint_disaggregation
-
-                    bad = errors_only(lint_disaggregation(
-                        self.graph, _imeta["disaggregation"],
-                        self.config))
-                    if bad:
-                        emit_findings(bad)
-                        raise AnalysisError(
-                            "imported disaggregation proposal is "
-                            "illegal for this graph", bad)
-                if _imeta.get("fleet") is not None:
-                    # imported fleet provenance re-lints against THIS
-                    # graph (SHD166/167): replica blocks must tile the
-                    # mesh disjointly, routing must cover every SLO
-                    # class, and the persisted pool geometry must agree
-                    # with the target's decode ops
-                    from flexflow_tpu.analysis import lint_fleet
-
-                    bad = errors_only(lint_fleet(
-                        self.graph, _imeta["fleet"], self.config))
-                    if bad:
-                        emit_findings(bad)
-                        raise AnalysisError(
-                            "imported fleet proposal is illegal for "
-                            "this graph", bad)
-                if _imeta.get("pipeline") is not None:
-                    from flexflow_tpu.analysis import (
-                        Finding,
-                        lint_pipeline_stages,
-                    )
-
-                    _pmeta = _imeta["pipeline"]
-                    bad = []
-                    stage_guids = None
-                    _ns = _nm = 0
-                    # a hand-edited meta block may carry ANY JSON type:
-                    # malformed shapes must become findings, never a
-                    # bare TypeError out of the gate itself
-                    if not isinstance(_pmeta, dict):
-                        bad = [Finding(
-                            code="SHD150", pass_name="placement",
-                            message="imported __meta__.pipeline is not "
-                                    "an object")]
-                    else:
-                        _ns = _pmeta.get("num_stages", 0)
-                        _nm = _pmeta.get("num_microbatches", 0)
-                        _stages = _pmeta.get("stages")
-                        if (not isinstance(_ns, int)
-                                or not isinstance(_nm, int)
-                                or isinstance(_ns, bool)
-                                or isinstance(_nm, bool)):
-                            bad = [Finding(
-                                code="SHD150", pass_name="placement",
-                                message=(
-                                    f"imported __meta__.pipeline has "
-                                    f"non-integer num_stages/"
-                                    f"num_microbatches ({_ns!r}, "
-                                    f"{_nm!r})"))]
-                        elif _stages is not None and not (
-                                isinstance(_stages, list)
-                                and all(isinstance(s, list)
-                                        and all(isinstance(op, str)
-                                                for op in s)
-                                        for s in _stages)):
-                            bad = [Finding(
-                                code="SHD150", pass_name="placement",
-                                message=(
-                                    "imported __meta__.pipeline stages "
-                                    "is not a list of op-name lists"))]
-                        elif _stages is not None:
-                            by_name = {n.op.name: n.guid
-                                       for n in self.graph.topo_order()}
-                            stage_guids = [
-                                [by_name.get(op, -1) for op in stage]
-                                for stage in _stages
-                            ]
-                    if not bad:
-                        bad = errors_only(lint_pipeline_stages(
-                            self.graph, stage_guids, _ns, _nm,
-                            self.config))
-                    if bad:
-                        emit_findings(bad)
-                        raise AnalysisError(
-                            "imported pipeline proposal is illegal for "
-                            "this graph/strategy", bad)
-                    # the validated proposal is ADOPTED, not just
-                    # checked: an export whose compile ran the staged
-                    # executor must round-trip to the staged executor
-                    # (an import that re-lints but silently lowers
-                    # flat would defeat the proposal it validated —
-                    # e.g. the HBM-infeasible regime staged pipelining
-                    # exists for)
-                    if stage_guids is not None:
-                        from flexflow_tpu.search.pipeline_search import (
-                            StagedPipelineProposal,
-                        )
-
-                        self.pipeline_proposal = StagedPipelineProposal(
-                            num_stages=_ns, num_microbatches=_nm,
-                            stage_guids=stage_guids,
-                            cost=float("nan"),  # not re-simulated here
-                            executable=False,
-                        )
-                    elif pipeline is None:
-                        # S x M without explicit stages = the
-                        # stacked-block shape; adopt it exactly as if
-                        # the user had passed compile(pipeline=...)
-                        from flexflow_tpu.parallel.pipeline import (
-                            PipelineConfig,
-                        )
-
-                        if self.config.zero_dp_shard:
-                            # the early compile(pipeline=) guard has
-                            # already run by this point — re-raise its
-                            # contract rather than silently leaving
-                            # optimizer state replicated
-                            raise NotImplementedError(
-                                "zero_dp_shard is not supported with "
-                                "an imported pipeline proposal")
-                        pipeline = PipelineConfig(
-                            num_stages=_ns, num_microbatches=_nm)
-            elif self.config.only_data_parallel:
-                strategy = data_parallel_strategy(self.graph, self.config.num_devices)
-            else:
-                # the Unity joint search IS the default compile path
-                # (reference: FFModel::compile -> graph_optimize,
-                # model.cc:2587-2655): graph rewrites compete with view
-                # assignment and the best REWRITTEN graph gets lowered —
-                # self.graph is replaced the same way the reference
-                # deserializes the optimized PCG into its operator list
-                # (convert_graph_to_operators, substitution.cc:3014)
-                from flexflow_tpu.search.driver import optimize_strategy
-
-                # the pre-search graph: the disaggregation proposal's
-                # narrow-block solves run on it (rewrites bake
-                # full-mesh repartition views narrow blocks can't host)
-                _disagg_base_graph = self.graph
-                from flexflow_tpu import native as _native
-
-                # the search loads the native engine on first use, and
-                # builds it (make) in a fresh checkout: its own span
-                with phase_span(PHASE_PREFIX + "setup.native_build"):
-                    _native.get_lib()
-                with phase_span(PHASE_PREFIX + "setup.search"):
-                    best_graph, strategy = optimize_strategy(
-                        self.graph, self.config, return_graph=True
-                    )
-                self.graph = best_graph
-                searched_strategy = True
-                from flexflow_tpu.search import driver as _kvdriver
-
-                if _kvdriver.LAST_KV_META:
-                    # the searched pool dtype (SHD168/169-gated inside
-                    # the driver); adopted onto the decode ops right
-                    # before lowering, AFTER the strategy export's
-                    # digest computation
-                    kv_adopt_dtype = _kvdriver.LAST_KV_META.get("dtype")
-                # the strategy object the driver's sync-schedule gate
-                # ran against — a pipeline/placement proposal below may
-                # REPLACE `strategy`, and the gated schedule must not
-                # follow it onto a strategy it was never linted for
-                searched_strategy_obj = strategy
-                # the search also costs pipelined candidates for
-                # stacked-block graphs (reference gap: OP_PIPELINE is an
-                # enum stub, ffconst.h:148) — a winning PipelineConfig
-                # is adopted exactly as if the user had passed it
-                if (
-                    pipeline is None
-                    and mesh is None
-                    and (self.config.enable_pipeline_search
-                         or self.config.enable_placement_search)
-                    and not self.config.zero_dp_shard
-                    and comp_mode == "training"
-                ):
-                    from flexflow_tpu.search.driver import (
-                        coherent_calibration,
-                    )
-                    from flexflow_tpu.search.pipeline_search import (
-                        propose_pipeline,
-                    )
-                    from flexflow_tpu.search.simulator import Simulator
-
-                    # same cost currency as the flat search that just
-                    # ran: measured calibration included when coherent
-                    sim = Simulator.for_config(
-                        self.config,
-                        calibration=coherent_calibration(self.config),
-                    )
-                    baseline = sim.simulate(self.graph, strategy)
-                    prop = (
-                        propose_pipeline(
-                            self.graph, self.config, sim, baseline
-                        )
-                        if self.config.enable_pipeline_search else None
-                    )
-                    if prop is not None and (
-                        self.config.num_devices % prop.num_stages == 0
-                        and self.config.batch_size % prop.num_microbatches
-                        == 0
-                    ):
-                        pipeline = prop
-                        strategy = data_parallel_strategy(
-                            self.graph,
-                            self.config.num_devices // pipeline.num_stages,
-                        )
-                    elif self.config.enable_placement_search:
-                        # no pipeline won: cost 2-block inter-op placed
-                        # candidates in the placed executor's schedule
-                        # (reference: VERTICAL splits + mapper placement,
-                        # graph.cc:161-295, mapper.cc:371-475); a
-                        # margin-beating placeable winner replaces the
-                        # flat strategy and lowers via the placed path
-                        from flexflow_tpu.search.placement_search import (
-                            propose_placement,
-                        )
-
-                        placed = propose_placement(
-                            self.graph, self.config, baseline,
-                            calibration=coherent_calibration(self.config),
-                        )
-                        if placed is not None:
-                            strategy = placed
-                        elif not _math.isfinite(baseline):
-                            # nothing executable fits: cost the GENERAL
-                            # staged-pipeline shape (any graph cut,
-                            # reference graph.cc:161-295); a winning
-                            # proposal lowers via the heterogeneous
-                            # staged executor
-                            # (compiler/staged_pipeline_lowering.py)
-                            from flexflow_tpu.search.pipeline_search import (
-                                propose_pipeline_general,
-                            )
-
-                            self.pipeline_proposal = (
-                                propose_pipeline_general(
-                                    self.graph, self.config, sim, baseline
-                                )
-                            )
-                            if self.pipeline_proposal is not None:
-                                from flexflow_tpu.utils.logging import (
-                                    SEARCH_LOG,
-                                )
-
-                                p = self.pipeline_proposal
-                                SEARCH_LOG.log(
-                                    f"staged-pipeline candidate: S="
-                                    f"{p.num_stages} M="
-                                    f"{p.num_microbatches} modeled "
-                                    f"{p.cost * 1e3:.3f} ms/iter "
-                                    f"(flat is infeasible)"
-                                )
-        # the chosen strategy is public state: tooling (bench_search,
-        # strategy introspection) reads it back after compile
-        self.strategy = strategy
+        if not (
+            plan.strategy
+            and plan.pipeline is None
+            and mesh is None
+            and cfg.comp_mode == "inference"
+            and getattr(cfg, "objective", "train") == "serve"
+        ):
+            return
         # prefill/decode disaggregation (search/disaggregation.py):
         # under the serve objective, also price placing the prompt
         # graph and this decode graph on disjoint submeshes — the
         # two-block placement with the KV handoff as a cross-block
         # transfer.  The proposal (adopted or honest zero) is public
         # state; adopted winners persist as __meta__.disaggregation.
-        if (
-            searched_strategy
-            and strategy
-            and pipeline is None
-            and mesh is None
-            and comp_mode == "inference"
-            and getattr(self.config, "objective", "train") == "serve"
-            and getattr(self.config, "serve_disaggregation", "off")
-            == "search"
-        ):
+        # Its narrow-block solves run on the PRE-search graph (rewrites
+        # bake full-mesh repartition views narrow blocks can't host).
+        if getattr(cfg, "serve_disaggregation", "off") == "search":
             from flexflow_tpu.search.disaggregation import (
                 propose_disaggregation,
             )
-            from flexflow_tpu.search.driver import coherent_calibration
 
-            self.disaggregation = propose_disaggregation(
-                self.graph, strategy, self.config,
-                calibration=coherent_calibration(self.config),
-                base_graph=(_disagg_base_graph
-                            if _disagg_base_graph is not self.graph
-                            else None),
-            )
-        # serving fleet (search/fleet.py): under the serve objective,
-        # also price partitioning the mesh into N replica blocks with
-        # per-replica strategies and per-SLO-class routing — the
-        # N-block generalization of the disaggregation pass.  Public
-        # state like the disaggregation proposal; adopted winners
+            plan.disaggregation = propose_disaggregation(
+                plan.graph, plan.strategy, cfg,
+                calibration=coherent_calibration(cfg),
+                base_graph=plan.base_graph)
+        # serving fleet (search/fleet.py): also price partitioning the
+        # mesh into N replica blocks with per-replica strategies and
+        # per-SLO-class routing — the N-block generalization of the
+        # disaggregation pass; the controller's elastic re-search
+        # solves on the same pre-rewrite graph.  Adopted winners
         # persist as __meta__.fleet.
-        if (
-            searched_strategy
-            and strategy
-            and pipeline is None
-            and mesh is None
-            and comp_mode == "inference"
-            and getattr(self.config, "objective", "train") == "serve"
-            and getattr(self.config, "serve_fleet", "off") == "search"
-        ):
-            from flexflow_tpu.search.driver import coherent_calibration
+        if getattr(cfg, "serve_fleet", "off") == "search":
             from flexflow_tpu.search.fleet import propose_fleet
 
-            # the controller's elastic re-search needs the SAME
-            # pre-rewrite graph for its narrow-block solves (rewrites
-            # bake full-mesh views narrow blocks can't host)
-            self.fleet_base_graph = (
-                _disagg_base_graph
-                if _disagg_base_graph is not self.graph else None)
-            self.fleet = propose_fleet(
-                self.graph, strategy, self.config,
-                calibration=coherent_calibration(self.config),
-                base_graph=self.fleet_base_graph,
-            )
-        # sync-precision dimension of the strategy (EQuARX compressed
-        # gradient collectives): build the per-weight-group wire map
-        # with the SAME cost model the search ranked with, so execution
-        # runs exactly what the simulation priced.  Public state like
-        # the strategy itself (bench_search reads it back).
-        self.sync_precision_map: Dict[str, str] = {}
-        _sync_sim = None  # shared by the precision map + schedule
-        # builders below: one Simulator.for_config per compile, not three
-        if (
-            comp_mode == "training"
-            and strategy
-            and getattr(self.config, "sync_precision", "fp32") != "fp32"
-        ):
-            from flexflow_tpu.search.driver import coherent_calibration
+            plan.fleet = propose_fleet(
+                plan.graph, plan.strategy, cfg,
+                calibration=coherent_calibration(cfg),
+                base_graph=plan.base_graph)
+
+    def _plan_comm(self, plan, gated, sim=None) -> None:
+        """The comm plan of the strategy actually being lowered.
+
+        Sync precision (EQuARX compressed gradient collectives): the
+        per-weight-group wire map, built with the SAME cost model the
+        search ranked with, so execution runs exactly what the
+        simulation priced.  Gradient-sync SCHEDULE
+        (search/sync_schedule.py): bucketed, issue-ordered collectives
+        the lowering executes inside the backward (comm/bucketed.py).
+        Zero map (search/comm_plan.py): per-group optimizer-state
+        sharding.  The joint search chose and legality-gated the last
+        two for ITS result (``gated``), an import re-lints its file's;
+        every other strategy — forced DP, caller-supplied, imported
+        without a schedule, a searched one later REPLACED by a
+        proposal — runs the same schedule choice + always-on gate here
+        and takes no zero map.  ``sim`` — a shared simulator factory
+        (one per compile or swap, not three)."""
+        from flexflow_tpu.search import driver as _driver
+        from flexflow_tpu.search.plan import COMM_DIMS
+
+        cfg = self.config
+        if sim is None:
             from flexflow_tpu.search.simulator import Simulator
+
+            sim = functools.cache(lambda: Simulator.for_config(
+                cfg, calibration=_driver.coherent_calibration(cfg)))
+        plan.sync_precision = {}
+        if (
+            cfg.comp_mode == "training"
+            and plan.strategy
+            and getattr(cfg, "sync_precision", "fp32") != "fp32"
+        ):
             from flexflow_tpu.search.sync_precision import (
                 choose_sync_precision,
             )
 
-            _sync_sim = Simulator.for_config(
-                self.config, calibration=coherent_calibration(self.config)
-            )
-            self.sync_precision_map = choose_sync_precision(
-                self.graph, strategy, _sync_sim.cost
-            )
-        # gradient-sync SCHEDULE (search/sync_schedule.py): bucketed,
-        # issue-ordered collectives the lowering executes inside the
-        # backward (comm/bucketed.py).  The joint search already chose
-        # and legality-gated one for ITS result (driver
-        # _build_sync_schedule); other strategy sources (forced DP,
-        # caller-supplied, imported without one) run the same choice +
-        # always-on gate here.  Public state like the strategy itself.
-        self.sync_schedule = None
-        if (
-            comp_mode == "training"
-            and strategy
-            and pipeline is None
-            and getattr(self.config, "sync_schedule", "off") == "search"
-        ):
-            if imported_sync_schedule is not None:
-                # a schedule persisted next to an imported strategy
-                # (digest gate already passed) — re-lint against THIS
-                # graph before adopting: a hand-edited file must fail
-                # with a finding, not inside XLA
-                from flexflow_tpu.analysis import (
-                    AnalysisError,
-                    emit_findings,
-                    errors_only,
-                    lint_sync_schedule,
-                )
-                from flexflow_tpu.search.sync_schedule import SyncSchedule
+            plan.sync_precision = choose_sync_precision(
+                plan.graph, plan.strategy, sim().cost)
+        sched_armed, zero_armed = plan.comm_plan_armed(cfg)
+        own = gated is not None and plan.strategy is gated
+        if plan.source == "imported":
+            plan.relint(cfg, COMM_DIMS)
+        elif not own:
+            plan.sync_schedule, plan.zero_groups = None, ()
+        if not sched_armed:
+            plan.sync_schedule = None
+        elif plan.sync_schedule is None and not own:
+            plan.sync_schedule, _ = _driver._build_sync_schedule(
+                plan.graph, plan.strategy, sim(), cfg)
+        if not zero_armed:
+            plan.zero_groups = ()
 
-                try:
-                    sched = SyncSchedule.from_jsonable(imported_sync_schedule)
-                except ValueError as e:
-                    raise AnalysisError(
-                        f"imported strategy file carries a malformed "
-                        f"sync_schedule: {e}", []) from e
-                from flexflow_tpu.analysis import lint_reduction_plan
-                from flexflow_tpu.search.machine_model import CostModel
-
-                _lint_cm = CostModel(
-                    self.config.machine_spec,
-                    num_devices=self.config.search_devices)
-                bad = errors_only(
-                    lint_sync_schedule(
-                        self.graph, strategy, sched,
-                        self.sync_precision_map)
-                    + lint_reduction_plan(
-                        self.graph, strategy, sched, _lint_cm))
-                if bad:
-                    emit_findings(bad)
-                    raise AnalysisError(
-                        "imported sync_schedule is illegal for this "
-                        "graph/strategy", bad)
-                self.sync_schedule = sched
-            elif searched_strategy and strategy is searched_strategy_obj:
-                from flexflow_tpu.search import driver as _driver
-
-                self.sync_schedule = _driver.LAST_SYNC_SCHEDULE
-            else:
-                # caller-supplied / forced-DP strategies, and searched
-                # strategies later REPLACED by a placement proposal:
-                # run the same choice + always-on gate against the
-                # strategy actually being lowered
-                from flexflow_tpu.search.driver import (
-                    _build_sync_schedule,
-                    coherent_calibration,
-                )
-                from flexflow_tpu.search.simulator import Simulator
-
-                if _sync_sim is None:
-                    _sync_sim = Simulator.for_config(
-                        self.config,
-                        calibration=coherent_calibration(self.config),
-                    )
-                self.sync_schedule = _build_sync_schedule(
-                    self.graph, strategy, _sync_sim, self.config
-                )
-        # per-group optimizer-state sharding (the co-searched ZeRO-1
-        # dimension, search/comm_plan.py): adopted from the search
-        # (LAST_ZERO_GROUPS — already gated by the driver's always-on
-        # SHD140/141 lint) or from an imported strategy file's
-        # __meta__.zero_groups (re-linted against THIS graph/strategy
-        # here).  The global config.zero_dp_shard flag is untouched and
-        # keeps arming every op; the per-group map is ignored under it.
-        self.zero_groups: tuple = ()
-        if (
-            comp_mode == "training"
-            and strategy
-            and pipeline is None
-            and not self.config.zero_dp_shard
-        ):
-            if imported_zero_groups is not None:
-                from flexflow_tpu.analysis import (
-                    AnalysisError,
-                    emit_findings,
-                    errors_only,
-                    lint_zero_map,
-                )
-                from flexflow_tpu.search.machine_model import CostModel
-
-                if (not isinstance(imported_zero_groups, list)
-                        or any(not isinstance(z, str)
-                               for z in imported_zero_groups)):
-                    raise AnalysisError(
-                        "imported strategy file carries a malformed "
-                        "zero_groups map (expected a list of op names)",
-                        [])
-                _zcm = CostModel(
-                    self.config.machine_spec,
-                    num_devices=self.config.search_devices)
-                bad = errors_only(lint_zero_map(
-                    self.graph, strategy, imported_zero_groups, _zcm))
-                if bad:
-                    emit_findings(bad)
-                    raise AnalysisError(
-                        "imported zero_groups map is illegal for this "
-                        "graph/strategy", bad)
-                self.zero_groups = tuple(imported_zero_groups)
-            elif searched_strategy and strategy is searched_strategy_obj:
-                from flexflow_tpu.search import driver as _driver
-
-                self.zero_groups = tuple(_driver.LAST_ZERO_GROUPS)
-        # predicted step breakdown + strategy-explanation telemetry —
-        # the predicted half of the DriftReport fit() completes.  Only
-        # computed when something will consume it (profiling, the obs
-        # bus, a strategy/trace export): one extra simulate per compile
-        # is cheap but not free.
+    def _predict(self, plan, sim=None, full: bool = False):
+        """Simulate the plan into ``self.predicted_breakdown``;
+        ``full`` also emits the strategy table and the predicted
+        timeline.  Telemetry must never fail a compile or a swap.
+        Returns the calibration table the prediction was priced under."""
         from flexflow_tpu.obs.events import BUS as _obs_bus
 
-        self.predicted_breakdown = None
-        self.drift_report = None
-        self.lane_drift_report = None  # filled by fit's device-trace
-        # capture (config.device_trace_dir) via obs/trace_ingest.py
-        _pred_cal = None  # the coherent table the prediction was priced
-        # under — the export block digests THIS object (STR210) instead
-        # of re-parsing the file a second time
-        if (
-            strategy
-            and pipeline is None
-            and self.pipeline_proposal is None
-            and (
-                self.config.profiling
-                or _obs_bus.enabled
-                or self.config.export_strategy_file
-                or self.config.obs_trace_file
-                # a calibrated compile must ALWAYS record its prediction:
-                # the drift/healthy-reset loop (fit tail, re-probe
-                # allowance) closes on it even when neither profiling nor
-                # the obs bus is armed — without this, the allowance
-                # reset rode the drift-report path only
-                or self.config.calibration_file
-            )
-        ):
-            from flexflow_tpu.search.driver import coherent_calibration
-            from flexflow_tpu.search.simulator import Simulator as _Sim
+        cfg = self.config
+        cal = None
+        try:
+            if sim is None:
+                from flexflow_tpu.search.driver import coherent_calibration
+                from flexflow_tpu.search.simulator import Simulator
 
-            try:
-                _pred_cal = coherent_calibration(self.config)
-                _psim = _Sim.for_config(
-                    self.config, calibration=_pred_cal
-                )
-                bd: Dict = {}
-                _sched: list = []
-                _comm: list = []
-                _psim.simulate(self.graph, strategy, breakdown=bd,
-                               schedule=_sched, comm_schedule=_comm,
-                               sync_schedule=self.sync_schedule)
-                bd["calibrated"] = _psim.cost.calibration is not None
-                bd["machine"] = self.config.machine_spec.name
-                self.predicted_breakdown = bd
-                if _obs_bus.enabled:
-                    _obs_bus.emit(
-                        "strategy.table",
-                        rows=_psim.strategy_table_rows(
-                            self.graph, strategy,
-                            self.sync_precision_map,
-                        ),
-                        predicted_s=bd.get("total_s"),
-                        devices=self.config.search_devices,
-                        comp_mode=comp_mode,
-                        # searched=False marks forced-DP / imported /
-                        # caller-supplied strategies so report tooling
-                        # can prefer the joint-search table when both
-                        # were compiled in one run
-                        searched=searched_strategy,
-                    )
-                if self.config.obs_trace_file:
-                    _psim.export_chrome_trace(
-                        self.graph, strategy, self.config.obs_trace_file,
-                        schedule=_sched, comm_schedule=_comm,
-                        total_s=bd.get("total_s"))
-            except Exception:  # telemetry must never fail a compile
-                self.predicted_breakdown = None
-        _placed_lint_cache: list = []
-
-        def _placed_lint_errors():
-            """Error findings of the placed-cut legality lint for the
-            strategy about to lower — computed ONCE per compile (the
-            per-segment sub-lints rebuild block subgraphs) and shared
-            by the export decision and the placed-lowering gate."""
-            if not _placed_lint_cache:
-                from flexflow_tpu.analysis import (
-                    errors_only,
-                    lint_placement,
-                )
-
-                _placed_lint_cache.append(errors_only(lint_placement(
-                    self.graph, strategy, self.config)))
-            return _placed_lint_cache[0]
-
-        if self.config.export_strategy_file:
-            from flexflow_tpu.search.strategy_io import export_strategy
-
-            _meta = {}
-            if self.predicted_breakdown:
-                _meta["predicted"] = self.predicted_breakdown
-            # the calibration signature the strategy was ranked under
-            # (content digest of the coherent measured table): fflint
-            # strategy compares it against the LIVE CALIBRATION.json
-            # (STR210) so a re-probed table flags every strategy file
-            # it orphans as stale.  The prediction block above already
-            # loaded the table; digest that exact object — it is BOTH
-            # the cheaper path and the honest one (the signature
-            # describes the table the predicted numbers were priced
-            # under).
-            from flexflow_tpu.search.cost_cache import calibration_digest
-
-            if _pred_cal is None and self.config.calibration_file:
-                from flexflow_tpu.search.driver import (
-                    coherent_calibration as _cc,
-                )
-
-                _pred_cal = _cc(self.config)
-            _cal_sig = calibration_digest(_pred_cal)
-            if _cal_sig is not None:
-                _meta["calibration_signature"] = _cal_sig
-            if self.sync_schedule is not None:
-                # the searched comm plan persists NEXT to the strategy,
-                # behind the same graph-digest gate import enforces
-                _meta["sync_schedule"] = self.sync_schedule.to_jsonable()
-            if self.zero_groups:
-                # the co-searched per-group optimizer-sharding map
-                # rides the same digest gate (fflint checks it, STR207)
-                _meta["zero_groups"] = sorted(self.zero_groups)
-            if (searched_strategy
-                    and getattr(self.config, "objective", "train")
-                    == "serve"):
-                # the serve objective's SHD16x-gated provenance
-                # (objective + SLO budget + frame geometry + predicted
-                # p99 + KV residency) persists behind the same digest
-                # gate; fflint strategy checks it stdlib-only (STR209)
-                from flexflow_tpu.search import driver as _sdriver
-
-                if _sdriver.LAST_SERVING_META:
-                    _meta["serving"] = dict(_sdriver.LAST_SERVING_META)
-                if _sdriver.LAST_KV_META:
-                    # the KV-lane provenance (pool dtype + scale layout
-                    # + prefix-sharing residency accounting, SHD168/169
-                    # gated in the driver; fflint checks the frame
-                    # stdlib-only, STR213).  Persisted BEFORE the dtype
-                    # is adopted onto the decode ops, so the exported
-                    # digests stay keyed to the attr-free frontend
-                    # graph and import's digest gate still passes.
-                    _meta["kv"] = dict(_sdriver.LAST_KV_META)
-                if (self.disaggregation is not None
-                        and self.disaggregation.adopted):
-                    # the ADOPTED two-block prefill/decode placement
-                    # (search/disaggregation.py — already SHD164/165
-                    # gated at proposal); import re-lints against the
-                    # target graph, fflint checks the frame stdlib-only
-                    # (STR211).  Honest zeros persist nothing.
-                    _meta["disaggregation"] = \
-                        self.disaggregation.to_meta()
-                if self.fleet is not None and self.fleet.adopted:
-                    # the ADOPTED N-replica fleet (search/fleet.py —
-                    # already SHD166/167 gated at proposal); import
-                    # re-lints against the target graph, fflint checks
-                    # the frame stdlib-only (STR212)
-                    _meta["fleet"] = self.fleet.to_meta()
-            # pipeline/placement proposals persist NEXT to the strategy
-            # behind the same digest gate (the lint already gated them
-            # at proposal time; fflint strategy re-checks the frame
-            # stdlib-only, STR208)
-            from flexflow_tpu.analysis import placement_meta as _pmeta_fn
-            from flexflow_tpu.compiler.placement_lowering import (
-                placeable as _placeable,
-            )
-
-            # only a cut the placed executor will actually run is a
-            # placement proposal: the lowering decision below requires
-            # pipeline/mesh unset AND placeable, and the frame must
-            # pass the same legality gate the placed branch enforces —
-            # a compile that will fail that gate (or run flat under
-            # mesh=) must not leave a placement artifact on disk.
-            # Inert multi-block strategies (the historical
-            # flat-lowering fallback) persist no meta either.
-            _pl = (
-                _pmeta_fn(self.graph, strategy, self.config)
-                if (strategy and pipeline is None and mesh is None
-                    and _placeable(self.graph, strategy, self.config)
-                    and not _placed_lint_errors())
-                else None
-            )
-            if _pl is not None:
-                _meta["placement"] = _pl
-            if self.pipeline_proposal is not None:
-                _pp = self.pipeline_proposal
-                _meta["pipeline"] = {
-                    "num_stages": _pp.num_stages,
-                    "num_microbatches": _pp.num_microbatches,
-                    "stages": [
-                        [self.graph.nodes[g].op.name for g in stage]
-                        for stage in _pp.stage_guids
-                    ],
-                }
-            elif pipeline is not None:
-                _meta["pipeline"] = {
-                    "num_stages": pipeline.num_stages,
-                    "num_microbatches": pipeline.num_microbatches,
-                }
-            export_strategy(
-                self.config.export_strategy_file, self.graph, strategy,
-                meta=_meta or None,
-            )
-        if self.config.export_strategy_computation_graph_file:
-            self.graph.write_dot(
-                self.config.export_strategy_computation_graph_file, strategy
-            )
-        if self.config.export_strategy_task_graph_file:
-            from flexflow_tpu.search.simulator import Simulator
-
-            # for_config: search_devices + comp_mode/zero flags match
-            # what the search itself costed
-            Simulator.for_config(self.config).export_task_graph_dot(
-                self.graph, strategy, self.config.export_strategy_task_graph_file
-            )
-
-        # KV-lane adoption (searched or imported __meta__.kv, both
-        # SHD168/169-gated above): the decode ops take the chosen pool
-        # dtype NOW — after every export computed its digests against
-        # the attr-free graph, before any lowering builds state
-        _adopt_kv_dtype(self.graph, kv_adopt_dtype)
-
-        from flexflow_tpu.compiler.placement_lowering import placeable
-
-        with phase_span(PHASE_PREFIX + "setup.lower"):
-            if pipeline is None and mesh is None and strategy and placeable(
-                    self.graph, strategy, self.config):
-                # mesh is None: a user-supplied mesh commits the whole graph
-                # to one submesh program, which a 2-block placed strategy
-                # cannot honor — fall through to the flat lowering (which
-                # respects mesh=) instead of silently ignoring it
-                # disjoint start_part device blocks that the placed lowering
-                # can express: EXECUTED inter-op placement (reference:
-                # mapper.cc:371-475 places ops on disjoint device sets and
-                # Legion runs them).  Multi-block strategies OUTSIDE its
-                # support (>2 blocks, multi-tensor cuts, grad accumulation)
-                # keep the historical behavior: offsets are inert and the
-                # single SPMD program replicates small-degree ops.
-                from flexflow_tpu.compiler.placement_lowering import (
-                    PlacedCompiledModel,
-                )
-
-                # always-on legality gate on the cut about to execute
-                # (search proposals were gated at proposal time; this also
-                # covers caller-supplied placed strategies with findings
-                # instead of opaque lowering errors).  Shares the export
-                # path's one-shot lint cache.
-                from flexflow_tpu.analysis import (
-                    AnalysisError,
-                    emit_findings,
-                )
-
-                _bad = _placed_lint_errors()
-                if _bad:
-                    emit_findings(_bad)
-                    raise AnalysisError(
-                        "placed strategy is illegal for this graph/mesh",
-                        _bad)
-                self.compiled = PlacedCompiledModel(
-                    self.graph,
-                    strategy,
-                    self.config,
-                    LossType.from_any(loss_type),
-                    list(metrics),
-                    self.optimizer,
-                )
-            elif pipeline is not None:
-                from flexflow_tpu.compiler.pipeline_lowering import PipelinedCompiledModel
-
-                self.compiled = PipelinedCompiledModel(
-                    self.graph,
-                    strategy,
-                    self.config,
-                    LossType.from_any(loss_type),
-                    list(metrics),
-                    self.optimizer,
-                    pipeline=pipeline,
-                    block_of=block_of,
-                )
-            elif (
-                self.pipeline_proposal is not None
-                and mesh is None
-                and comp_mode == "training"
-            ):
-                # (multi-process raises inside the constructor and falls
-                # back to flat via the except below)
-                # flat is infeasible and the general staged proposal won:
-                # lower it via the heterogeneous staged executor (GPipe over
-                # arbitrary graph cuts — compiler/staged_pipeline_lowering)
-                from flexflow_tpu.compiler.staged_pipeline_lowering import (
-                    StagedPipelinedModel,
-                )
-
-                try:
-                    self.compiled = StagedPipelinedModel(
-                        self.graph,
-                        self.pipeline_proposal.stage_guids,
-                        self.pipeline_proposal.num_microbatches,
-                        self.config,
-                        LossType.from_any(loss_type),
-                        list(metrics),
-                        self.optimizer,
-                    )
-                except (NotImplementedError, ValueError):
-                    # stateful stages etc.: keep the flat lowering (the
-                    # proposal stays surfaced on self.pipeline_proposal)
-                    self.compiled = None
-                if self.compiled is None:
-                    self.compiled = CompiledModel(
-                        self.graph, strategy, self.config,
-                        LossType.from_any(loss_type), list(metrics),
-                        self.optimizer, mesh=mesh,
-                        sync_precision=self.sync_precision_map,
-                        sync_schedule=self.sync_schedule,
-                        zero_groups=self.zero_groups,
-                    )
+                cal = coherent_calibration(cfg)
+                psim = Simulator.for_config(cfg, calibration=cal)
             else:
-                self.compiled = CompiledModel(
-                    self.graph,
-                    strategy,
-                    self.config,
-                    LossType.from_any(loss_type),
-                    list(metrics),
-                    self.optimizer,
-                    mesh=mesh,
-                    sync_precision=self.sync_precision_map,
-                    sync_schedule=self.sync_schedule,
-                    zero_groups=self.zero_groups,
+                psim = sim()
+            bd: Dict = {}
+            timeline = dict(schedule=[], comm_schedule=[]) if full else {}
+            psim.simulate(plan.graph, plan.strategy, breakdown=bd,
+                          sync_schedule=plan.sync_schedule, **timeline)
+            bd["calibrated"] = psim.cost.calibration is not None
+            bd["machine"] = cfg.machine_spec.name
+            self.predicted_breakdown = bd
+            if full and _obs_bus.enabled:
+                _obs_bus.emit(
+                    "strategy.table",
+                    rows=psim.strategy_table_rows(
+                        plan.graph, plan.strategy, plan.sync_precision),
+                    predicted_s=bd.get("total_s"),
+                    devices=cfg.search_devices,
+                    comp_mode=cfg.comp_mode,
+                    # searched=False marks forced-DP / imported /
+                    # caller-supplied strategies so report tooling can
+                    # prefer the joint-search table when both were
+                    # compiled in one run
+                    searched=plan.source == "searched",
                 )
-        from flexflow_tpu.compiler.staged_pipeline_lowering import (
-            StagedPipelinedModel as _Staged,
-        )
+            if full and cfg.obs_trace_file:
+                psim.export_chrome_trace(
+                    plan.graph, plan.strategy, cfg.obs_trace_file,
+                    total_s=bd.get("total_s"), **timeline)
+        except Exception:
+            self.predicted_breakdown = None
+        return cal
 
-        if self.sync_precision_map and not getattr(
-                self.compiled, "sync_precision", None):
-            # placed/pipelined lowerings manage their own grad paths and
-            # do not run _sync_grads yet — say so rather than silently
-            # training at fp32 while the user expects compression
-            from flexflow_tpu.utils.logging import SEARCH_LOG
+    def _export_plan(self, plan, pred_cal, mesh) -> None:
+        """Write the strategy file: the views, the prediction, and the
+        plan's blocks — all behind the graph-digest gate import
+        enforces."""
+        from flexflow_tpu.compiler.lower import placement_frame
+        from flexflow_tpu.search.cost_cache import calibration_digest
+        from flexflow_tpu.search.strategy_io import export_strategy
 
-            SEARCH_LOG.log(
-                f"sync_precision={self.config.sync_precision!r} chose "
-                f"{len(self.sync_precision_map)} compressed groups but "
-                f"this lowering ({type(self.compiled).__name__}) cannot "
-                f"execute them; gradients sync at fp32"
-            )
-            self.sync_precision_map = {}
-        if self.zero_groups and getattr(
-                self.compiled, "zero_groups", None) is None:
-            # same honesty rule for the per-group optimizer sharding:
-            # placed/pipelined lowerings manage their own placement and
-            # cannot execute the map — say so instead of silently
-            # leaving optimizer state replicated
-            from flexflow_tpu.utils.logging import SEARCH_LOG
+        cfg = self.config
+        meta = {}
+        if self.predicted_breakdown:
+            meta["predicted"] = self.predicted_breakdown
+        # the calibration signature the strategy was ranked under
+        # (content digest of the coherent measured table): fflint
+        # strategy compares it against the LIVE CALIBRATION.json
+        # (STR210) so a re-probed table flags every strategy file it
+        # orphans as stale.  The prediction already loaded the table;
+        # digest that exact object — it is BOTH the cheaper path and
+        # the honest one (the signature describes the table the
+        # predicted numbers were priced under).
+        if pred_cal is None and cfg.calibration_file:
+            from flexflow_tpu.search.driver import coherent_calibration
 
-            SEARCH_LOG.log(
-                f"co-search chose {len(self.zero_groups)} "
-                f"optimizer-sharded group(s) but this lowering "
-                f"({type(self.compiled).__name__}) cannot execute the "
-                f"per-group map; optimizer state stays replicated"
-            )
-            self.zero_groups = ()
-        if self.sync_schedule is not None and getattr(
-                self.compiled, "sync_schedule", None) is None:
-            # same honesty rule for the sync schedule: placed/pipelined
-            # lowerings do not run _sync_grads, so the searched comm
-            # plan cannot execute there — say so instead of silently
-            # falling back to the monolithic sync
-            from flexflow_tpu.utils.logging import SEARCH_LOG
-
-            SEARCH_LOG.log(
-                f"sync_schedule chose {len(self.sync_schedule.buckets)} "
-                f"buckets but this lowering "
-                f"({type(self.compiled).__name__}) cannot execute them; "
-                f"gradients sync monolithically"
-            )
-            self.sync_schedule = None
-
-        self._compile_ctx = dict(
-            strategy=strategy, loss_type=LossType.from_any(loss_type),
-            metrics=list(metrics), pipeline=pipeline, block_of=block_of,
-            mesh=mesh,
-            sync_precision=dict(self.sync_precision_map),
-            sync_schedule=self.sync_schedule,
-            zero_groups=self.zero_groups,
-            staged=(self.pipeline_proposal
-                    if isinstance(self.compiled, _Staged) else None),
-        )
-        with phase_span(PHASE_PREFIX + "setup.init_params"):
-            self.params, self.state = self.compiled.init_params(
-                self.config.seed)
-            self._obs_seen = {}  # the device counters start at 0 again
-            self.opt_state = self.optimizer.init_state(self.params)
-            self.opt_state = self.compiled.shard_opt_state(self.opt_state)
-        return self.compiled
+            pred_cal = coherent_calibration(cfg)
+        cal_sig = calibration_digest(pred_cal)
+        if cal_sig is not None:
+            meta["calibration_signature"] = cal_sig
+        if plan.placement is None:
+            plan.placement = placement_frame(plan, cfg, mesh)
+        meta.update(plan.to_meta())
+        export_strategy(cfg.export_strategy_file, plan.graph,
+                        plan.strategy, meta=meta or None)
 
     def recompile(self):
         """Re-lower the (possibly altered) graph into a fresh XLA
-        program, carrying params / optimizer state / model state over
-        (reference: dynamic re-optimization, recompile_state.cc — ops
-        altered in place; here the program is rebuilt instead)."""
-        from flexflow_tpu.compiler.lowering import CompiledModel
+        program under the SAME plan — a placed, pipelined or staged
+        model re-lowers as what it was (``compiler.lower`` chooses
+        from the record) — carrying params / optimizer state / model
+        state over (reference: dynamic re-optimization,
+        recompile_state.cc — ops altered in place; here the program is
+        rebuilt instead)."""
+        from flexflow_tpu.compiler.lower import lower
 
-        ctx = self._compile_ctx
-        if ctx["pipeline"] is not None:
-            from flexflow_tpu.compiler.pipeline_lowering import PipelinedCompiledModel
-
-            self.compiled = PipelinedCompiledModel(
-                self.graph, ctx["strategy"], self.config, ctx["loss_type"],
-                ctx["metrics"], self.optimizer,
-                pipeline=ctx["pipeline"], block_of=ctx["block_of"],
-            )
-        elif ctx.get("staged") is not None:
-            # a staged-pipelined model must RE-lower staged: the flat
-            # strategy it replaced was HBM-infeasible by construction
-            from flexflow_tpu.compiler.staged_pipeline_lowering import (
-                StagedPipelinedModel,
-            )
-
-            staged = ctx["staged"]
-            self.compiled = StagedPipelinedModel(
-                self.graph, staged.stage_guids, staged.num_microbatches,
-                self.config, ctx["loss_type"], ctx["metrics"],
-                self.optimizer,
-            )
-        else:
-            from flexflow_tpu.compiler.placement_lowering import (
-                PlacedCompiledModel,
-                placeable,
-            )
-
-            if ctx.get("mesh") is None and ctx["strategy"] and placeable(
-                    self.graph, ctx["strategy"], self.config):
-                # a placed model must RE-lower placed: flat re-lowering
-                # would silently drop the inter-op placement and carry
-                # submesh-committed params into a global-mesh program
-                self.compiled = PlacedCompiledModel(
-                    self.graph, ctx["strategy"], self.config,
-                    ctx["loss_type"], ctx["metrics"], self.optimizer,
-                )
-            else:
-                self.compiled = CompiledModel(
-                    self.graph, ctx["strategy"], self.config,
-                    ctx["loss_type"], ctx["metrics"], self.optimizer,
-                    mesh=ctx.get("mesh"),
-                    sync_precision=ctx.get("sync_precision"),
-                    sync_schedule=ctx.get("sync_schedule"),
-                    zero_groups=ctx.get("zero_groups"),
-                )
+        self.compiled = lower(self.plan, self.config, **self._lower_args)
         old_params, old_state, old_opt = self.params, self.state, self.opt_state
         self.params, self.state = self.compiled.init_params(self.config.seed)
         # shape-checked carry-over: an alter() that changes a weight's
@@ -1733,52 +1110,48 @@ class FFModel:
         monolithic fp32 sync path instead of failing the run.  Returns
         ``{"fallback", "fresh", "dropped", "swap_seconds"}``."""
         assert self.compiled is not None, "compile() before swap_strategy"
-        import time as _time
-
-        from flexflow_tpu.analysis import (
-            AnalysisError,
-            emit_findings,
-            errors_only,
-            lint_swap,
+        from flexflow_tpu.analysis import lint_swap, raise_if_errors
+        from flexflow_tpu.compiler.pipeline_lowering import (
+            PipelinedCompiledModel,
+        )
+        from flexflow_tpu.compiler.placement_lowering import (
+            PlacedCompiledModel,
+            placeable,
+        )
+        from flexflow_tpu.compiler.staged_pipeline_lowering import (
+            StagedPipelinedModel,
         )
         from flexflow_tpu.runtime.checkpoint import snapshot_in_memory
 
-        t0 = _time.perf_counter()
-        ctx = self._compile_ctx
-        from flexflow_tpu.compiler.placement_lowering import (
-            PlacedCompiledModel as _Placed,
-        )
-
-        if (ctx.get("pipeline") is not None or ctx.get("staged") is not None
-                or ctx.get("mesh") is not None
-                # a placed model's ctx has none of the three markers —
-                # gate on the lowering itself, or a live inter-op
-                # placement would silently re-lower FLAT mid-run
-                or isinstance(self.compiled, _Placed)):
+        t0 = time.perf_counter()
+        new_config = config if config is not None else self.config
+        new_graph = graph if graph is not None else self.graph
+        if (
+            # gate on the lowering itself: a live inter-op placement
+            # (or a pipelined/staged model) must never silently
+            # re-lower FLAT mid-run — nor a flat one into a placement
+            isinstance(self.compiled, (
+                PlacedCompiledModel, PipelinedCompiledModel,
+                StagedPipelinedModel))
+            or self._lower_args["mesh"] is not None
+            or placeable(new_graph, strategy, new_config)
+        ):
             raise NotImplementedError(
                 "swap_strategy supports the flat SPMD lowering only — "
                 "placed/pipelined/staged/user-mesh models manage their "
                 "own placement and cannot re-shard live state this way")
-        new_config = config if config is not None else self.config
-        new_graph = graph if graph is not None else self.graph
-        bad = errors_only(lint_swap(
-            self.graph, new_graph, strategy, new_config.num_devices))
-        if bad:
-            emit_findings(bad)
-            raise AnalysisError(
-                "hot-swap target is illegal for the live training state",
-                bad)
+        raise_if_errors(
+            lint_swap(self.graph, new_graph, strategy,
+                      new_config.num_devices),
+            "hot-swap target is illegal for the live training state")
         snap = snapshot_in_memory(self)
         rollback = dict(
-            config=self.config, graph=self.graph, strategy=self.strategy,
-            compiled=self.compiled, params=self.params,
-            opt_state=self.opt_state, state=self.state,
-            sync_precision_map=self.sync_precision_map,
-            sync_schedule=self.sync_schedule, zero_groups=self.zero_groups,
+            config=self.config, plan=self.plan, compiled=self.compiled,
+            params=self.params, opt_state=self.opt_state, state=self.state,
         )
         try:
             return self._swap_strategy_inner(
-                snap, new_config, new_graph, strategy, ctx, t0)
+                snap, new_config, new_graph, strategy, t0)
         except Exception:
             # a failed swap (e.g. an elastic GROW past the available
             # device count rejected by mesh construction, or a corrupt
@@ -1790,57 +1163,33 @@ class FFModel:
             raise
 
     def _swap_strategy_inner(self, snap, new_config, new_graph, strategy,
-                             ctx, t0) -> dict:
-        import time as _time
-
+                             t0) -> dict:
         from flexflow_tpu.analysis import AnalysisError, errors_only
-        from flexflow_tpu.compiler.lowering import CompiledModel
+        from flexflow_tpu.compiler.lower import lower
+        from flexflow_tpu.obs.events import BUS as _obs_bus
         from flexflow_tpu.runtime.checkpoint import restore_in_memory
         from flexflow_tpu.search.driver import coherent_calibration
+        from flexflow_tpu.search.plan import StrategyPlan
         from flexflow_tpu.search.simulator import Simulator
         from flexflow_tpu.utils.logging import SEARCH_LOG
 
+        old_zero = self.plan.zero_groups
         self.config = new_config
-        self.graph = new_graph
-        self.strategy = strategy
+        plan = self.plan = StrategyPlan(new_graph, strategy, "caller")
         # ONE calibration load + at most one Simulator per swap (the
         # compile-path discipline): swap latency is a headline number
-        _cal = coherent_calibration(self.config)
-        _sim = None
-
-        def sim():
-            nonlocal _sim
-            if _sim is None:
-                _sim = Simulator.for_config(self.config, calibration=_cal)
-            return _sim
-
+        sim = functools.cache(lambda: Simulator.for_config(
+            new_config, calibration=coherent_calibration(new_config)))
         # rebuild the comm plan for the new pair.  Every piece re-runs
         # its always-on legality gate against what is ACTUALLY being
         # lowered; a searched plan that fails post-swap costs the run
         # its overlap/compression win, never its life — graceful
         # fallback to the monolithic fp32 sync path.
         fallback = False
-        pmap: Dict[str, str] = {}
-        schedule = None
-        zero: tuple = ()
-        training = self.config.comp_mode == "training"
         try:
-            if training and getattr(
-                    self.config, "sync_precision", "fp32") != "fp32":
-                from flexflow_tpu.search.sync_precision import (
-                    choose_sync_precision,
-                )
-
-                pmap = choose_sync_precision(
-                    new_graph, strategy, sim().cost)
-            if training and getattr(
-                    self.config, "sync_schedule", "off") == "search":
-                from flexflow_tpu.search.driver import _build_sync_schedule
-
-                schedule = _build_sync_schedule(
-                    new_graph, strategy, sim(), self.config)
-            if (training and self.zero_groups
-                    and not self.config.zero_dp_shard):
+            self._plan_comm(plan, None, sim)
+            if (new_config.comp_mode == "training" and old_zero
+                    and not new_config.zero_dp_shard):
                 # the co-searched per-group optimizer-sharding map rides
                 # along only while it still lints for the new pair —
                 # remapping the per-group ZeRO shards is the restore's
@@ -1848,28 +1197,21 @@ class FFModel:
                 from flexflow_tpu.analysis import lint_zero_map
                 from flexflow_tpu.search.machine_model import CostModel
 
-                _zcm = CostModel(
-                    self.config.machine_spec,
-                    num_devices=self.config.search_devices)
                 if not errors_only(lint_zero_map(
-                        new_graph, strategy, sorted(self.zero_groups),
-                        _zcm)):
-                    zero = tuple(self.zero_groups)
+                        new_graph, strategy, sorted(old_zero),
+                        CostModel(new_config.machine_spec,
+                                  num_devices=new_config.search_devices))):
+                    plan.zero_groups = tuple(old_zero)
         except AnalysisError as e:
-            fallback, pmap, schedule, zero = True, {}, None, ()
+            fallback = True
+            plan.sync_precision, plan.sync_schedule = {}, None
+            plan.zero_groups = ()
             SEARCH_LOG.log(
                 f"hot swap: searched comm plan failed its legality gate "
                 f"post-swap ({e}); falling back to the monolithic fp32 "
                 f"sync path")
-        self.sync_precision_map = pmap
-        self.sync_schedule = schedule
-        self.zero_groups = zero
-        self.compiled = CompiledModel(
-            new_graph, strategy, self.config, ctx["loss_type"],
-            ctx["metrics"], self.optimizer,
-            sync_precision=pmap, sync_schedule=schedule, zero_groups=zero,
-        )
-        self.params, self.state = self.compiled.init_params(self.config.seed)
+        self.compiled = lower(plan, new_config, **self._lower_args)
+        self.params, self.state = self.compiled.init_params(new_config.seed)
         self.opt_state = self.optimizer.init_state(self.params)
         self.opt_state = self.compiled.shard_opt_state(self.opt_state)
         report = restore_in_memory(self, snap)
@@ -1878,27 +1220,13 @@ class FFModel:
                 f"hot swap: {len(report['dropped'])} state entr(ies) "
                 f"have no home under the new comm plan and were dropped "
                 f"(e.g. {report['dropped'][:3]})")
-        ctx.update(
-            strategy=strategy, sync_precision=dict(pmap),
-            sync_schedule=schedule, zero_groups=zero,
-        )
         # refresh the predicted side of the drift loop for the NEW
         # strategy (same consumers and same never-fail rule as compile)
-        from flexflow_tpu.obs.events import BUS as _obs_bus
-
-        if (self.config.profiling or _obs_bus.enabled
-                or self.config.calibration_file):
-            try:
-                bd: Dict = {}
-                sim().simulate(new_graph, strategy, breakdown=bd,
-                               sync_schedule=schedule)
-                bd["calibrated"] = sim().cost.calibration is not None
-                bd["machine"] = self.config.machine_spec.name
-                self.predicted_breakdown = bd
-            except Exception:  # telemetry must never fail a swap
-                self.predicted_breakdown = None
+        if (new_config.profiling or _obs_bus.enabled
+                or new_config.calibration_file):
+            self._predict(plan, sim)
         report["fallback"] = fallback
-        report["swap_seconds"] = _time.perf_counter() - t0
+        report["swap_seconds"] = time.perf_counter() - t0
         return report
 
     # ------------------------------------------------------------------

@@ -41,7 +41,7 @@ GPT_DECODE_KW = dict(vocab=2048, num_layers=2, hidden=256, num_heads=8,
 # (4 MB/layer of projections) is small enough that the train (mean
 # step) objective still prefers the pure batch split.  This is the
 # configuration where throughput and p99 provably part ways
-# (BENCH_SEARCH.md "Inference serving").
+# (tests/test_serving.py, simulated).
 GPT_DECODE_SERVE_KW = dict(vocab=4096, num_layers=2, hidden=512,
                            num_heads=8, ff_dim=1024, page_size=32,
                            pages_per_seq=128)

@@ -9,8 +9,8 @@ trunk whose persistent skip from the input bypasses every block
 multi-tower multibranch graph (two-tower rankers, multimodal trunks).
 Both scale linearly in their repeat count to 10k+ nodes, and both are
 built from ISOMORPHIC repeats so the structural segment cache stamps
-one solve across the family — the property ``bench_search.py
---sp-scale`` measures.
+one solve across the family (tests/test_decompose.py,
+tests/test_search_scale.py).
 """
 
 from __future__ import annotations

@@ -38,8 +38,8 @@ class DriftReport:
     # The executed step is one fused XLA program, so each bucket's
     # measured side stays None (honesty rule above) — the schedule's
     # overlap claim is verified by the measured STEP delta between the
-    # scheduled and monolithic programs (bench_search --sync-schedule),
-    # not by inventing per-bucket host timings.
+    # scheduled and monolithic programs, not by inventing per-bucket
+    # host timings.
     sync_buckets: list = field(default_factory=list)
 
     def to_dict(self) -> dict:

@@ -347,7 +347,7 @@ class TrainingController:
             print(f"[controller] fleet re-search at load x{scale:.2f}: "
                   f"{old_n} -> {new_n} replicas")
         if new is not None:
-            self.model.fleet = new
+            self.model.plan.fleet = new
         return new
 
     def maybe_refleet(self, step: Optional[int] = None):
@@ -425,9 +425,9 @@ class TrainingController:
         from flexflow_tpu.search import driver as _driver
 
         t0 = time.perf_counter()
-        new_graph, strategy = _driver.optimize_strategy(
-            self.model.graph, config, return_graph=True)
-        episodes = [dict(_driver.LAST_SEARCH_STATS)]
+        plan = _driver.search_plan(self.model.graph, config)
+        new_graph, strategy = plan.graph, plan.strategy
+        episodes = [plan.stats]
         dp_fallback = False
         if errors_only(lint_swap(self.model.graph, new_graph, strategy,
                                  config.num_devices)):
@@ -447,9 +447,10 @@ class TrainingController:
                     new_graph, config.num_devices)
                 dp_fallback = True
             else:
-                strategy = _driver.optimize_strategy(
+                plan = _driver.search_plan(
                     self.model.graph, config, return_graph=False)
-                episodes.append(dict(_driver.LAST_SEARCH_STATS))
+                strategy = plan.strategy
+                episodes.append(plan.stats)
         seconds = time.perf_counter() - t0
         # the episode may span TWO searches (rewritten graph rejected by
         # the swap gate → strategy-only fallback): sum the search/probe
@@ -535,7 +536,7 @@ class TrainingController:
         # the per-group optimizer-sharding map is part of the searched
         # comm plan too — swap_strategy carries a still-linting map
         # forward by design, so the fallback must drop it explicitly
-        self.model.zero_groups = ()
+        self.model.plan.zero_groups = ()
         self.stats["fallbacks"] += 1
         BUS.emit("controller.fallback", step=step, reason=reason)
         # a fallback is exactly the moment a post-mortem is worth its

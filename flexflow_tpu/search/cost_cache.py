@@ -266,8 +266,8 @@ class CostCache:
             self.stale = True
             print(
                 "flexflow_tpu cost cache: calibration flagged STALE by a "
-                "measured drift report — recalibrate (--calibrate / "
-                "bench_search.py --calibrate) or pass --no-cost-cache; "
+                "measured drift report — recalibrate (--calibrate) or "
+                "pass --no-cost-cache; "
                 "refusing to serve cached rows",
                 file=sys.stderr,
             )

@@ -60,6 +60,7 @@ from flexflow_tpu.search.dp import (
     decode_strategy_rows,
     encode_strategy_rows,
 )
+from flexflow_tpu.search.plan import StrategyPlan
 from flexflow_tpu.search.simulator import Simulator
 from flexflow_tpu.search.substitution import (
     _renamed,
@@ -1022,38 +1023,11 @@ def _merge_split(
     return g, strategy
 
 
-# perf observability of the LAST optimize_strategy call in this
-# process: bench_search splits its per-model timing into calibration
-# vs search and records the delta/cache hit rates from here
+# perf observability of the LAST search in this process — the same
+# numbers every search returns on ``StrategyPlan.stats``; kept as a
+# module dict (with LAST_DECOMPOSE) only for the tests that inspect
+# them after a bare ``optimize_strategy`` call (ROADMAP queue 3)
 LAST_SEARCH_STATS: Dict[str, object] = {}
-
-# the gradient-sync schedule the LAST optimize_strategy chose (and
-# gated) under config.sync_schedule="search" — compile() adopts it for
-# the strategy the search just returned instead of re-running the
-# choice; None when the mode is off or the monolithic baseline won
-LAST_SYNC_SCHEDULE = None
-
-# the per-group optimizer-state sharding map the LAST optimize_strategy
-# chose under config.co_search (search/comm_plan.py choose_zero_groups):
-# op names whose ZeRO-1 reduce-scatter/all-gather placement genuinely
-# shrinks the update term — compile() adopts it the way it adopts
-# LAST_SYNC_SCHEDULE; () when co-search is off or nothing qualifies
-LAST_ZERO_GROUPS: tuple = ()
-
-# the serving provenance of the LAST optimize_strategy run under
-# config.objective="serve" (search/serving.py): the SHD16x-gated
-# objective + SLO budget + frame geometry + predicted p99 + per-device
-# KV residency — compile() persists it as __meta__.serving behind the
-# digest gate (fflint strategy checks it stdlib-only, STR209); None
-# under the default train objective
-LAST_SERVING_META = None
-
-# KV-lane provenance of the last serve-objective search: chosen pool
-# dtype + scale layout + prefix-sharing residency accounting — compile()
-# persists it as __meta__.kv behind the digest gate (fflint strategy
-# checks it stdlib-only, STR213; SHD168/169 re-lint at import); None
-# when the lane is unarmed (kv_precision="off" and no declared sharing)
-LAST_KV_META = None
 
 
 def _kv_candidate_graph(graph, dtype: str):
@@ -1155,17 +1129,18 @@ def _build_sync_schedule(graph, strategy, sim, config, joint=None):
 
     Under co-search (``joint`` bound) the schedule is SERVED from the
     JointPricer's comm-plan memo — the plan the winning strategy was
-    actually priced with — instead of re-running the sweep, and the
-    memoized per-group optimizer-sharding choice lands in
-    ``LAST_ZERO_GROUPS``.  Served plans (memo or disk) still pass the
-    full SHD12x/SHD14x legality gates against THIS (graph, strategy):
-    a corrupt persisted plan costs one re-search, never an illegal
-    artifact."""
-    global LAST_SYNC_SCHEDULE, LAST_ZERO_GROUPS
-    LAST_SYNC_SCHEDULE = None
-    LAST_ZERO_GROUPS = ()
+    actually priced with — instead of re-running the sweep, together
+    with the memoized per-group optimizer-sharding choice (op names
+    whose ZeRO-1 reduce-scatter/all-gather placement genuinely shrinks
+    the update term).  Served plans (memo or disk) still pass the full
+    SHD12x/SHD14x legality gates against THIS (graph, strategy): a
+    corrupt persisted plan costs one re-search, never an illegal
+    artifact.
+
+    Returns ``(schedule, zero_groups)`` — ``(None, ())`` when the mode
+    is off or the monolithic baseline won."""
     if getattr(config, "sync_schedule", "off") != "search" or not strategy:
-        return None
+        return None, ()
     from flexflow_tpu.search.sync_precision import choose_sync_precision
     from flexflow_tpu.search.sync_schedule import (
         choose_sync_schedule,
@@ -1175,41 +1150,33 @@ def _build_sync_schedule(graph, strategy, sim, config, joint=None):
     if joint is not None:
         entry = joint.plan_for(graph, strategy, sim)
         schedule = None
+        zero_groups: tuple = ()
         if entry is not None and entry.adopted:
             schedule = entry.schedule
             lint_gate(graph, strategy, schedule, entry.pmap,
                       cost_model=sim.cost)
         if entry is not None and entry.zero:
-            from flexflow_tpu.analysis import (
-                AnalysisError,
-                emit_findings,
-                errors_only,
-                lint_zero_map,
-            )
+            from flexflow_tpu.analysis import lint_zero_map, raise_if_errors
 
-            bad = errors_only(lint_zero_map(
-                graph, strategy, entry.zero, sim.cost))
-            if bad:
-                # a served zero map that fails the always-on gate is a
-                # plan bug (or a corrupt persisted row): fail loudly
-                # like every other artifact this tree produces
-                emit_findings(bad)
-                raise AnalysisError(
-                    "co-search produced an illegal per-group "
-                    "optimizer-sharding map", bad)
-            LAST_ZERO_GROUPS = tuple(entry.zero)
+            # a served zero map that fails the always-on gate is a
+            # plan bug (or a corrupt persisted row): fail loudly like
+            # every other artifact this tree produces
+            raise_if_errors(
+                lint_zero_map(graph, strategy, entry.zero, sim.cost),
+                "co-search produced an illegal per-group "
+                "optimizer-sharding map")
+            zero_groups = tuple(entry.zero)
         LAST_SEARCH_STATS["sync_schedule"] = {
             "buckets": len(schedule.buckets) if schedule is not None else 0,
             "co_search": True,
-            "zero_groups": len(LAST_ZERO_GROUPS),
+            "zero_groups": len(zero_groups),
         }
         if BUS.enabled:
             BUS.emit(
-                "search.zero_groups", groups=list(LAST_ZERO_GROUPS),
+                "search.zero_groups", groups=list(zero_groups),
                 credit_s=entry.zero_credit if entry is not None else 0.0,
             )
-        LAST_SYNC_SCHEDULE = schedule
-        return schedule
+        return schedule, zero_groups
 
     pmap = {}
     if getattr(config, "sync_precision", "fp32") != "fp32":
@@ -1229,8 +1196,7 @@ def _build_sync_schedule(graph, strategy, sim, config, joint=None):
             f"({info['monolithic_s'] * 1e3:.4f} -> "
             f"{info['scheduled_s'] * 1e3:.4f} ms/iter simulated)"
         )
-    LAST_SYNC_SCHEDULE = schedule
-    return schedule
+    return schedule, ()
 
 
 def _lint_findings(graph, strategy, num_devices):
@@ -1323,14 +1289,19 @@ def coherent_calibration(config: FFConfig):
     return calibration
 
 
-def optimize_strategy(
-    graph: Graph, config: FFConfig, return_graph: bool = False
-) -> "Strategy | Tuple[Graph, Strategy]":
-    """Find a good (graph, strategy).  With ``return_graph=True`` — the
-    default compile path — the joint Unity search runs: graph rewrites
-    compete with view assignment and the best REWRITTEN graph is
-    returned for lowering.  With False only strategies on the original
-    graph are explored (strategy-only mode, e.g. for export).
+def search_plan(
+    graph: Graph, config: FFConfig, return_graph: bool = True
+) -> StrategyPlan:
+    """Find a good (graph, strategy) and everything else the search
+    decides with it — the gated sync schedule, the co-searched zero
+    map, the serve objective's provenance, the KV lane — as ONE record
+    (search/plan.py).  With ``return_graph=True`` — the default compile
+    path — the joint Unity search runs: graph rewrites compete with
+    view assignment and the best REWRITTEN graph is returned for
+    lowering.  With False only strategies on the original graph are
+    explored (strategy-only mode, e.g. for export).  A nested search
+    (a narrow block of the disaggregation or fleet pass) returns its
+    own record and cannot touch the caller's.
 
     ``config.verify`` arms the post-rewrite invariant checker for THIS
     search only (same checks as FLEXFLOW_TPU_VERIFY=1, scoped instead
@@ -1343,10 +1314,38 @@ def optimize_strategy(
     return _optimize_strategy(graph, config, return_graph)
 
 
-def _optimize_strategy(
+def optimize_strategy(
     graph: Graph, config: FFConfig, return_graph: bool = False
 ) -> "Strategy | Tuple[Graph, Strategy]":
-    global LAST_SERVING_META, LAST_KV_META
+    """``search_plan`` projected onto the strategy, or onto (graph,
+    strategy) with ``return_graph=True``."""
+    plan = search_plan(graph, config, return_graph)
+    return (plan.graph, plan.strategy) if return_graph else plan.strategy
+
+
+def _serving_meta(serving, graph, strategy, n, cost_s) -> dict:
+    """The serve objective's SHD16x-gated provenance: objective + SLO
+    budget + frame geometry + predicted p99 + per-device KV residency
+    (persisted as ``__meta__.serving``; fflint strategy checks it
+    stdlib-only, STR209)."""
+    from flexflow_tpu.search.serving import kv_residency_bytes
+
+    return {
+        "objective": "serve",
+        "p99_budget_ms": serving.p99_budget_ms,
+        "max_seqs": serving.max_seqs,
+        "page_size": serving.page_size,
+        "pages_per_seq": serving.pages_per_seq,
+        "quantile": serving.quantile,
+        "predicted_p99_step_ms": round(cost_s * 1e3, 6),
+        "kv_bytes_per_device": kv_residency_bytes(
+            graph, strategy, n, serving=serving),
+    }
+
+
+def _optimize_strategy(
+    graph: Graph, config: FFConfig, return_graph: bool = False
+) -> StrategyPlan:
     from flexflow_tpu.utils.logging import SEARCH_LOG as log
 
     t_start = time.monotonic()
@@ -1607,22 +1606,6 @@ def _optimize_strategy(
                 f"({best_cost * 1e3:.4f} ms/iter) for {graph.num_nodes}-"
                 f"node graph — skipping the search"
             )
-            LAST_SERVING_META = None
-            LAST_KV_META = _served_kv_meta
-            if serving is not None:
-                from flexflow_tpu.search.serving import kv_residency_bytes
-
-                LAST_SERVING_META = {
-                    "objective": "serve",
-                    "p99_budget_ms": serving.p99_budget_ms,
-                    "max_seqs": serving.max_seqs,
-                    "page_size": serving.page_size,
-                    "pages_per_seq": serving.pages_per_seq,
-                    "quantile": serving.quantile,
-                    "predicted_p99_step_ms": round(best_cost * 1e3, 6),
-                    "kv_bytes_per_device": kv_residency_bytes(
-                        best_graph, best_strategy, n, serving=serving),
-                }
             _emit_search_done(
                 floor_sim, best_graph, graph, best_strategy, best_cost,
                 kept_dp=False, helper=helper, t_start=t_start,
@@ -1631,9 +1614,15 @@ def _optimize_strategy(
             )
             # cache-served results pass the SAME schedule choice + gate
             # as fresh ones — the persisted artifact never skips it
-            _build_sync_schedule(best_graph, best_strategy, sim, config,
-                                 joint=joint)
-            return best_graph, best_strategy
+            schedule, zero_groups = _build_sync_schedule(
+                best_graph, best_strategy, sim, config, joint=joint)
+            return StrategyPlan(
+                best_graph, best_strategy, "searched",
+                serving=(_serving_meta(serving, best_graph,
+                                       best_strategy, n, best_cost)
+                         if serving is not None else None),
+                kv=_served_kv_meta, sync_schedule=schedule,
+                zero_groups=zero_groups, stats=dict(LAST_SEARCH_STATS))
     with log.enter(f"optimize_strategy: {graph.num_nodes} nodes, {n} devices"):
         if (return_graph and config.search_budget > 0
                 and graph.num_nodes > CHAIN_MIN_NODES):
@@ -1771,8 +1760,7 @@ def _optimize_strategy(
     # geometry coherent with the spec, KV residency within HBM, decode
     # views the executor's fixed frames can shard (SHD160-162; SHD163
     # warns on a blown SLO) — before it is returned or persisted.
-    LAST_SERVING_META = None
-    LAST_KV_META = None
+    serving_meta = kv_meta = None
     if serving is not None and best_strategy and math.isfinite(best_cost):
         from flexflow_tpu.analysis import (
             AnalysisError,
@@ -1781,7 +1769,6 @@ def _optimize_strategy(
             lint_kv,
             lint_serving,
         )
-        from flexflow_tpu.search.serving import kv_residency_bytes
 
         sfind = lint_serving(best_graph, best_strategy, serving,
                              floor_sim.cost, predicted_p99_s=best_cost)
@@ -1791,33 +1778,23 @@ def _optimize_strategy(
             raise AnalysisError(
                 "serve-objective search produced an illegal serving "
                 "artifact", sbad)
-        kv = kv_residency_bytes(best_graph, best_strategy, n,
-                                serving=serving)
-        LAST_SERVING_META = {
-            "objective": "serve",
-            "p99_budget_ms": serving.p99_budget_ms,
-            "max_seqs": serving.max_seqs,
-            "page_size": serving.page_size,
-            "pages_per_seq": serving.pages_per_seq,
-            "quantile": serving.quantile,
-            "predicted_p99_step_ms": round(best_cost * 1e3, 6),
-            "kv_bytes_per_device": kv,
-        }
+        serving_meta = _serving_meta(serving, best_graph, best_strategy,
+                                     n, best_cost)
         BUS.emit("search.serve", p99_s=best_cost,
                  budget_ms=serving.p99_budget_ms,
-                 kv_bytes_per_device=kv, kept_dp=kept_dp)
+                 kv_bytes_per_device=serving_meta["kv_bytes_per_device"],
+                 kept_dp=kept_dp)
         # KV lane (kv_precision / shared-prefix residency): choose the
         # pool dtype in the same p99 currency and gate the provenance
         # block on SHD168/169 — always-on, like the serving gate above
-        LAST_KV_META = _choose_kv_precision(
+        kv_meta = _choose_kv_precision(
             best_graph, best_strategy, config, serving, calibration)
-        if LAST_KV_META is not None:
-            kfind = lint_kv(best_graph, best_strategy, LAST_KV_META,
+        if kv_meta is not None:
+            kfind = lint_kv(best_graph, best_strategy, kv_meta,
                             serving=serving)
             emit_findings(kfind)
             kbad = errors_only(kfind)
             if kbad:
-                LAST_KV_META = None
                 raise AnalysisError(
                     "KV-precision lane produced an illegal __meta__.kv "
                     "artifact", kbad)
@@ -1844,17 +1821,14 @@ def _optimize_strategy(
         result_cache_hit=False, match_base=match_base,
     )
 
+    schedule, zero_groups = None, ()
     if best_strategy and math.isfinite(best_cost):
-        _build_sync_schedule(best_graph, best_strategy, floor_sim, config,
-                             joint=joint)
-    else:
-        global LAST_SYNC_SCHEDULE, LAST_ZERO_GROUPS
-        LAST_SYNC_SCHEDULE = None
-        LAST_ZERO_GROUPS = ()
-
-    if return_graph:
-        return best_graph, best_strategy
-    return best_strategy
+        schedule, zero_groups = _build_sync_schedule(
+            best_graph, best_strategy, floor_sim, config, joint=joint)
+    return StrategyPlan(
+        best_graph, best_strategy, "searched", serving=serving_meta,
+        kv=kv_meta, sync_schedule=schedule, zero_groups=zero_groups,
+        stats=dict(LAST_SEARCH_STATS))
 
 
 def _emit_search_done(
@@ -1864,7 +1838,7 @@ def _emit_search_done(
     """Search-completion telemetry: the final result/summary events
     plus the search-perf roll-up (delta-vs-full simulation counts,
     delta-matching rescan shrink, and persistent-cache hit rates) that
-    bench_search and ffobs report."""
+    ``StrategyPlan.stats`` carries and ffobs reports."""
     from flexflow_tpu.search import substitution as _subst
 
     sim = helper.sim

@@ -292,15 +292,15 @@ def propose_fleet(decode_graph, decode_strategy, config, *,
         key = (id(graph), devices, serving_armed)
         if key in _solve_memo:
             return _solve_memo[key]
-        from flexflow_tpu.search.driver import optimize_strategy
+        from flexflow_tpu.search.driver import search_plan
 
         cfg_blk = dataclasses.replace(
             cfg, num_devices=devices, search_num_devices=0,
             export_strategy_file=None, import_strategy_file=None,
             serve_disaggregation="off", serve_fleet="off")
         try:
-            g_blk, s_blk = optimize_strategy(graph, cfg_blk,
-                                             return_graph=True)
+            blk = search_plan(graph, cfg_blk)
+            g_blk, s_blk = blk.graph, blk.strategy
         except Exception:
             _solve_memo[key] = (math.inf, None, None)
             return _solve_memo[key]

@@ -99,7 +99,7 @@ def test_measure_and_calibrate_graph_smoke():
 def test_calibrate_graph_fills_caller_table_in_place():
     """Regression: an EMPTY CalibrationTable is falsy (__len__ == 0), so a
     `table or CalibrationTable()` default silently discarded the caller's
-    table — bench_search passed a fresh table, calibrate_graph filled a
+    table — a caller passed a fresh table, calibrate_graph filled a
     private one, and the artifact reported 'calibrated 0 records'."""
     m = mlp_model(batch=512, in_dim=512, hidden=1024, classes=64)
     mine = CalibrationTable()

@@ -71,15 +71,15 @@ def test_co_search_off_never_constructs_pricer(name, monkeypatch):
     the path, so the pre-PR trajectory cannot be perturbed.  (The
     value-level half — zoo strategies + sim costs bit-identical to the
     pre-PR tree — was verified against the seed source at PR time.)"""
-    import bench_search
     from flexflow_tpu.search import comm_plan
+    from zoo import model_specs
 
     def _poisoned(*a, **k):
         raise AssertionError(
             "JointPricer constructed on a co_search=False run")
 
     monkeypatch.setattr(comm_plan, "JointPricer", _poisoned)
-    spec = bench_search._model_specs()[name]
+    spec = model_specs()[name]
     cfg = ff.FFConfig(batch_size=spec["batch"], num_devices=8,
                       search_budget=4, cost_cache_file="")
     assert cfg.co_search is False
@@ -573,20 +573,27 @@ def test_search_perf_reports_index_skips():
 
 def test_co_search_result_wires_zero_groups_into_compile():
     """An end-to-end co-searched strategy lands its per-group
-    optimizer-sharding map on the compiled model (LAST_ZERO_GROUPS →
-    model.zero_groups), linted on the way."""
-    from flexflow_tpu.search import driver as drv
+    optimizer-sharding map on the compiled model (the search's record
+    → model.zero_groups), linted on the way."""
+    from flexflow_tpu.search.driver import search_plan
 
+    # placement search off: a placed proposal would REPLACE the
+    # co-searched strategy, and the map gated for it must not follow
+    # (on the parent of PR 30 exactly that happened here, and the
+    # assert compared () with a global the compile had just reset)
     cfg = ff.FFConfig(batch_size=64, num_devices=8, search_budget=6,
                       cost_cache_file="", sync_precision="search",
-                      sync_schedule="search", co_search=True)
+                      sync_schedule="search", co_search=True,
+                      enable_placement_search=False)
     m = ff.FFModel(cfg)
     x = m.create_tensor([64, 128], name="ew_x")
     t = m.dense(x, 512, activation="relu", name="ew_fc1")
     t = m.dense(t, 512, activation="relu", name="ew_fc2")
     m.dense(t, 16, name="ew_head")
+    searched = search_plan(m.graph, cfg)  # the same deterministic search
     m.compile(optimizer=ff.SGDOptimizer(),
               loss_type="sparse_categorical_crossentropy", metrics=[])
-    assert m.zero_groups == tuple(drv.LAST_ZERO_GROUPS)
-    if m.zero_groups:  # the search chose to shard at least one group
-        assert getattr(m.compiled, "zero_groups", ()) == m.zero_groups
+    assert m.plan.source == "searched"
+    assert searched.zero_groups  # the search shards at least one group
+    assert m.zero_groups == searched.zero_groups
+    assert m.compiled.zero_groups == m.zero_groups

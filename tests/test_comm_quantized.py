@@ -8,7 +8,7 @@ Three contracts:
   compressed paths obey ``allreduce_error_bound``; ZeRO-1 composes.
 * pricing — the cost model prices int8 sync below fp32 for big groups,
   and the simulated sync-bound BERT allreduce term drops >= 1.5x under
-  int8 (the BENCH_SEARCH acceptance number).
+  int8 (the acceptance number, simulated).
 * search — the per-weight-group choice compresses in the sync-bound
   regime and keeps fp32 in the compute-bound regime (same model,
   large per-device batch: sync hides behind compute).
@@ -219,7 +219,7 @@ def test_int8_sync_composes_with_zero1(mesh8):
 # ---------------------------------------------------------------------------
 # cost model + search integration
 def _sync_bound_bert(batch, n_devices=8, sync_precision="search"):
-    from bench_search import SYNC_BOUND_BERT_KW
+    from zoo import SYNC_BOUND_BERT_KW
     from flexflow_tpu.models import build_transformer
 
     cfg = ff.FFConfig(batch_size=batch, num_devices=n_devices,
@@ -245,7 +245,7 @@ def test_int8_sync_priced_below_fp32():
 
 
 def test_sync_bound_bert_allreduce_term_drops_1p5x():
-    """The BENCH_SEARCH acceptance number: the simulated DP weight-sync
+    """The acceptance number: the simulated DP weight-sync
     term of the sync-bound BERT config drops >= 1.5x under int8."""
     from flexflow_tpu.compiler.lowering import data_parallel_strategy
     from flexflow_tpu.search.simulator import Simulator

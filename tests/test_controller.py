@@ -410,7 +410,7 @@ def test_monolithic_fallback_drops_zero_groups():
     m.fit(X, Y, batch_size=8, epochs=1, verbose=False)
     # stand in for a co-searched map (any lint-passing content would
     # otherwise be carried forward by swap_strategy BY DESIGN)
-    m.zero_groups = ("d0",)
+    m.plan.zero_groups = ("d0",)
     plan = FaultPlan.parse("collective_failure@1:99", seed=3)
     ctl = TrainingController(m, faults=plan, max_retries=1)
     out = ctl.run(X, Y, steps=3)
@@ -496,8 +496,8 @@ def test_failed_swap_rolls_back_to_old_program(monkeypatch):
 
 def test_swap_refuses_placed_lowering():
     """Review fix: a live inter-op-placed model must be REFUSED by
-    swap_strategy (its _compile_ctx carries none of the pipeline/
-    staged/mesh markers) — never silently re-lowered flat mid-run."""
+    swap_strategy (its plan carries none of the pipeline/staged
+    markers) — never silently re-lowered flat mid-run."""
     from flexflow_tpu.compiler.placement_lowering import (
         PlacedCompiledModel,
     )

@@ -42,7 +42,7 @@ from flexflow_tpu.runtime.fleet import FleetExecutor
 N_DEV = 8
 
 # name:priority:deadline_frames:quantile:weight — the mixed-SLO table
-# the bench fleet sweep records (bench_search.py FLEET_SLO)
+# the fleet tests search under
 FLEET_SLO = ("interactive:2:64:0.99:1,standard:1:0:0.99:2,"
              "batch:0:0:0.9:5")
 
@@ -82,8 +82,7 @@ def host_fleet_search():
 # the fleet search: adoption, margin gate, elastic load response
 # ---------------------------------------------------------------------------
 def test_fleet_search_adopts_heterogeneous_blocks(host_fleet_search):
-    """THE acceptance scenario (recorded in BENCH_SEARCH "Serving
-    fleet"): on the host machine model with the replica bound at 3,
+    """THE acceptance scenario (serving fleet, simulated): on the host machine model with the replica bound at 3,
     the search picks a HETEROGENEOUS replica partition whose priced
     per-class p99 beats the single-replica baseline past the margin."""
     cfg, base, g, s, prop = host_fleet_search
@@ -446,11 +445,26 @@ def test_controller_elastic_refleet(tmp_path):
     from flexflow_tpu.obs.events import BUS
     from flexflow_tpu.runtime.controller import TrainingController
 
+    path = str(tmp_path / "fleet_strategy.json")
     cfg = _fleet_cfg(serve_fleet="search",
-                     serve_fleet_offered_load=0.3)
+                     serve_fleet_offered_load=0.3,
+                     export_strategy_file=path)
     m = build_gpt_decode(cfg, **FLEET_KW)
     m.compile(loss_type="sparse_categorical_crossentropy", metrics=[],
               comp_mode="inference")
+    # the file's serving provenance is the record of the search whose
+    # strategy the file holds — the full-mesh one — not of the last
+    # narrow replica block the fleet pass solved after it (which is
+    # what the module globals of the parent of PR 30 exported:
+    # 0.678207 ms / 524,288 B against 0.233693 ms / 65,536 B here)
+    from flexflow_tpu.search.driver import search_plan
+    from flexflow_tpu.search.strategy_io import read_meta
+
+    full_mesh = search_plan(build_gpt_decode(cfg, **FLEET_KW).graph, cfg)
+    meta = read_meta(path)
+    assert meta["serving"] == full_mesh.serving == m.plan.serving
+    assert meta.get("kv") == full_mesh.kv
+    assert meta["fleet"] == m.fleet.to_meta()
     old = m.fleet
     assert old is not None and old.adopted
     assert len(old.replicas) == 2  # the light-load optimum

@@ -501,27 +501,3 @@ def test_str213_kv_meta_lint(tmp_path):
         found = [f for f in lint_strategy_file(write(meta))
                  if f[1] == "STR213" and f[0] == "error"]
         assert found, f"corruption {label!r} not caught by STR213"
-
-
-def test_benchdiff_learns_kv_directions():
-    """The bench guard judges kv metrics in the right direction —
-    notably kv_shared_bytes, whose "_s" substring the latency
-    heuristic would otherwise read as lower-is-better."""
-    sys.path.insert(0, "tools")
-    try:
-        from benchdiff import compare, direction
-    finally:
-        sys.path.pop(0)
-
-    assert direction("kv_sweep.measured_sharing.kv_shared_bytes") == "up"
-    assert direction("a.max_concurrent") == "up"
-    assert direction("a.prefix_hits") == "up"
-    assert direction("a.shared_pages") == "up"
-    assert direction("kv_sweep.kv_pool_bytes") == "down"
-    assert direction("a.kv_bytes_per_device") == "down"
-    assert direction("a.cow_copies") == "down"
-    assert direction("a.private_pages") == "down"
-    # less sharing past tolerance IS a regression now
-    regs, compared = compare({"x.kv_shared_bytes": 10.0},
-                             {"x.kv_shared_bytes": 100.0}, 0.25)
-    assert compared == 1 and regs and regs[0][4] == "lower"

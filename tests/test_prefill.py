@@ -37,7 +37,7 @@ from flexflow_tpu.runtime.decode import (
 N_DEV = 8
 
 # the short-prompt interactive regime where disaggregation genuinely
-# wins on the stock machine model (bench_search.py GPT_DECODE_CHAT_KW)
+# wins on the stock machine model
 CHAT_KW = dict(vocab=4096, num_layers=2, hidden=2048, num_heads=16,
                ff_dim=4096, page_size=16, pages_per_seq=32)
 CHAT_ARRIVAL = dict(serve_prompt_tokens_mean=128,
@@ -387,8 +387,8 @@ def chat_search():
 
 
 def test_disaggregation_adopts_where_handoff_is_cheap(chat_search):
-    """THE acceptance scenario (recorded in BENCH_SEARCH
-    "Prefill/decode disaggregation"): on the short-prompt interactive
+    """THE acceptance scenario (prefill/decode disaggregation,
+    simulated): on the short-prompt interactive
     config — the weight-streaming-bound prefill regime, where a
     prompt's KV handoff is cheap relative to the phase interference
     colocation pays — the search PICKS disaggregation."""
@@ -496,6 +496,16 @@ def test_disaggregation_meta_round_trip(tmp_path):
                                                       "batch"]
     # geometry agrees with the sibling serving block (STR211's rule)
     assert dm["page_size"] == meta["serving"]["page_size"]
+    # ... and that sibling is the record of the search whose strategy
+    # the file holds — the full-mesh one — not of the last narrow block
+    # the disaggregation pass solved after it (the parent of PR 30
+    # exported 0.228317 ms / 134,217,728 B against 0.134191 ms /
+    # 33,554,432 B)
+    from flexflow_tpu.search.driver import search_plan
+
+    full_mesh = search_plan(build_gpt_decode(cfg, **kw).graph, cfg)
+    assert meta["serving"] == full_mesh.serving == m.plan.serving
+    assert meta.get("kv") == full_mesh.kv
 
     # clean re-import
     cfg2 = ff.FFConfig(batch_size=32, num_devices=N_DEV,

@@ -29,7 +29,7 @@ import numpy as np
 import pytest
 
 import flexflow_tpu as ff
-from bench_search import SYNC_BOUND_BERT_KW
+from zoo import SYNC_BOUND_BERT_KW, assert_int8_weights_close
 from flexflow_tpu.compiler.lowering import data_parallel_strategy
 from flexflow_tpu.core.machine import MachineSpec
 from flexflow_tpu.search.machine_model import CostModel
@@ -428,11 +428,7 @@ def test_staged_int8_close_and_composes_with_zero1(mesh8):
     m32, l32 = _train_mlp()
     m8, l8 = _train_mlp(_sched("int8", plan), zero=True)
     assert np.isfinite(l8) and np.isclose(l32, l8, rtol=5e-3)
-    for op, ws in m32.params.items():
-        for w, a in ws.items():
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(m8.params[op][w]),
-                rtol=5e-2, atol=5e-3)
+    assert_int8_weights_close(m32.params, m8.params)
     v = m8.opt_state["v"]["fc1"]["kernel"]
     assert v.addressable_shards[0].data.size * 8 == v.size
 

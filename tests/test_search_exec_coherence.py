@@ -18,8 +18,7 @@ Documented bound: executed_ratio >= NOISE_FLOOR (0.85) for every
 model; single-core hosts jitter 8-18% between timing blocks, the
 median-of-blocks measurement keeps residual noise within ~10%.
 The magnitude of big wins is NOT asserted (a host-bound CPU mesh
-cannot reproduce a 74x simulated ratio — see BENCH_SEARCH.md honesty
-notes); the sign is what the search's decisions ride on.
+cannot reproduce a 74x simulated ratio); the sign is what the search's decisions ride on.
 
 Reference: scripts/osdi22ae/*.sh runs the same two-program comparison
 on real hardware.
@@ -37,8 +36,8 @@ from flexflow_tpu.search.simulator import Simulator
 
 N_DEV = 8
 # round-4 verdict weak #5: 0.85 tolerated a 15% executed loss.  Every
-# genuinely-different program pair currently wins >=1.8x executed
-# (BENCH_SEARCH.md), so the floor now only absorbs single-core timing
+# genuinely-different program pair won >=1.8x executed on the CPU
+# mesh when this was set, so the floor now only absorbs single-core timing
 # jitter, not modeling error.
 NOISE_FLOOR = 0.92
 BIG_WIN = 1.5
@@ -68,9 +67,9 @@ def _sync_bound_bert(cfg):
     compute-parallel (TP) strategy must win at EXECUTION, not just in
     the simulator (round-4 verdict: no configuration had shown a
     compute-parallel searched strategy beating DP when executed).
-    The spec is SHARED with bench_search.py's bert exec tier — the CI
-    gate and the benchmark must measure the same program pair."""
-    from bench_search import SYNC_BOUND_BERT_KW
+    The spec is SHARED with the comm-plan and sync-schedule tests
+    (tests/zoo.py) — they must measure the same program pair."""
+    from zoo import SYNC_BOUND_BERT_KW
 
     from flexflow_tpu.models import build_transformer
 

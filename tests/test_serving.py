@@ -12,7 +12,7 @@ Contract highlights:
   search, never at OOM;
 * on the serving-regime decode config the serve objective selects a
   DIFFERENT strategy than the throughput objective and wins on
-  simulated p99 (the acceptance scenario BENCH_SEARCH records);
+  simulated p99 (the acceptance scenario);
 * with objective="train" (the default) the serving machinery is
   structurally inert — a poisoned spec builder proves the default
   path never touches it, and cache signatures only extend under serve;
@@ -355,37 +355,37 @@ def test_capacity_edge_rejected_inside_search():
 # serve objective: divergence + inertness
 # ---------------------------------------------------------------------------
 def _search(objective, batch, kw):
+    """(config, the search's whole record)."""
     from flexflow_tpu.models import build_gpt_decode
-    from flexflow_tpu.search.driver import optimize_strategy
+    from flexflow_tpu.search.driver import search_plan
 
     cfg = ff.FFConfig(batch_size=batch, num_devices=N_DEV,
                       search_budget=8, search_timeout_s=45.0,
                       objective=objective, comp_mode="inference",
                       cost_cache_file="")
     m = build_gpt_decode(cfg, **kw)
-    g, s = optimize_strategy(m.graph, cfg, return_graph=True)
-    return cfg, g, s
+    return cfg, search_plan(m.graph, cfg)
 
 
 def test_serve_objective_diverges_and_wins_p99():
-    """THE acceptance scenario (also recorded in BENCH_SEARCH.md
-    "Inference serving"): on the serving-regime decode config the serve
+    """THE acceptance scenario (inference serving, simulated): on the serving-regime decode config the serve
     objective picks a different strategy than throughput and wins on
     simulated p99 under the same arrival-model currency."""
     from flexflow_tpu.models import GPT_DECODE_SERVE_KW, SERVE_FRAME_SLOTS
-    from flexflow_tpu.search import driver
     from flexflow_tpu.search.serving import serve_latency_quantiles
 
-    cfg_t, g_t, s_t = _search("train", SERVE_FRAME_SLOTS,
-                              GPT_DECODE_SERVE_KW)
-    assert driver.LAST_SERVING_META is None  # train run leaves no meta
-    cfg_s, g_s, s_s = _search("serve", SERVE_FRAME_SLOTS,
-                              GPT_DECODE_SERVE_KW)
+    cfg_t, plan_t = _search("train", SERVE_FRAME_SLOTS,
+                            GPT_DECODE_SERVE_KW)
+    assert plan_t.serving is None  # train run leaves no meta
+    cfg_s, plan_s = _search("serve", SERVE_FRAME_SLOTS,
+                            GPT_DECODE_SERVE_KW)
+    g_t, s_t, g_s, s_s = (plan_t.graph, plan_t.strategy,
+                          plan_s.graph, plan_s.strategy)
     assert _decode_views(g_t, s_t) != _decode_views(g_s, s_s)
     p99_t = serve_latency_quantiles(g_t, s_t, cfg_s)["p99"]
     p99_s = serve_latency_quantiles(g_s, s_s, cfg_s)["p99"]
     assert p99_s < p99_t, (p99_s, p99_t)
-    meta = driver.LAST_SERVING_META
+    meta = plan_s.serving
     assert meta is not None and meta["objective"] == "serve"
     assert meta["predicted_p99_step_ms"] > 0
     assert meta["kv_bytes_per_device"] > 0
@@ -442,15 +442,14 @@ def test_train_objective_is_structurally_inert(monkeypatch):
 
 def test_serve_objective_without_decode_ops_degenerates():
     from flexflow_tpu.models import build_mlp_unify
-    from flexflow_tpu.search import driver
-    from flexflow_tpu.search.driver import optimize_strategy
+    from flexflow_tpu.search.driver import search_plan
 
     cfg = ff.FFConfig(batch_size=16, num_devices=N_DEV, search_budget=4,
                       search_timeout_s=20.0, cost_cache_file="",
                       objective="serve", comp_mode="inference")
     m = build_mlp_unify(cfg, in_dim=64, hidden=(64, 64))
-    g, s = optimize_strategy(m.graph, cfg, return_graph=True)
-    assert s and driver.LAST_SERVING_META is None
+    plan = search_plan(m.graph, cfg)
+    assert plan.strategy and plan.serving is None
 
 
 def test_serve_objective_requires_inference_mode():
@@ -523,8 +522,7 @@ def test_lint_serving_codes():
     # no serving meta is minted for the infeasible artifact
     import dataclasses as _dc
 
-    from flexflow_tpu.search import driver
-    from flexflow_tpu.search.driver import optimize_strategy
+    from flexflow_tpu.search.driver import search_plan
     from flexflow_tpu.search.simulator import Simulator
 
     floor_bytes = sum(
@@ -536,8 +534,9 @@ def test_lint_serving_codes():
         batch_size=16, num_devices=N_DEV, comp_mode="inference",
         machine_spec=hopeless, cost_cache_file="", search_budget=4,
         search_timeout_s=20.0, objective="serve")
-    g_bad, s_bad = optimize_strategy(m.graph, cfg_bad, return_graph=True)
-    assert driver.LAST_SERVING_META is None
+    bad = search_plan(m.graph, cfg_bad)
+    g_bad, s_bad = bad.graph, bad.strategy
+    assert bad.serving is None
     assert Simulator(hopeless, num_devices=N_DEV,
                      inference=True).simulate(g_bad, s_bad) == math.inf
 

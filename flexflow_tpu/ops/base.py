@@ -205,6 +205,19 @@ class Operator:
     ) -> List[jax.Array]:
         raise NotImplementedError(type(self).__name__)
 
+    def serving_weights(
+        self, weights: Dict[str, jax.Array], compute_dtype
+    ) -> Dict[str, jax.Array]:
+        """The op's own weights in the dtype and layout ``forward``
+        computes with, for a program that is called many times on the
+        same weights (a decode frame, a prefill chunk): derived ONCE
+        (runtime/decode.py ``compiled_decode_step``) instead of inside
+        every call.  ``forward`` takes either tree — an op that
+        overrides this calls it from ``forward`` too, where it is a
+        no-op on leaves already served, so the two cannot drift.
+        Default: the weights as they are."""
+        return weights
+
     def forward_sharded(
         self,
         ctx: LoweringContext,

@@ -34,6 +34,7 @@ from __future__ import annotations
 import math
 from typing import Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from flexflow_tpu.core.machine import MachineView
@@ -242,6 +243,31 @@ class DecodeAttentionOp(Operator):
             return "pallas"
         return "xla"
 
+    def serving_weights(self, weights, compute_dtype):
+        """The projections as the matmuls read them: the compute dtype,
+        heads and head_dim FUSED — wq/wk/wv [E, H·D], wo [H·D, E] — as
+        the pool is (``state_specs``): a bf16 [E, H, 64] leaf would pad
+        its 64-wide minor axis to 128 lanes and give the halved bytes
+        back, so the cast and the layout are one change.  On a leaf
+        already served both steps are no-ops."""
+        hd = self.attrs["num_heads"] * self.head_dim
+        out = {n: weights[n].astype(compute_dtype).reshape(-1, hd)
+               for n in ("wq", "wk", "wv")}
+        out["wo"] = weights["wo"].astype(compute_dtype).reshape(hd, -1)
+        return out
+
+    @staticmethod
+    def _project(x, w):
+        """q, k, v of x [..., E] as fp32 [..., H·D], each rounded to
+        the compute dtype its matmul made it in.  The barrier holds the
+        products as they are: without it XLA folds q's reshape to heads
+        into the matmul (a windowed convolution that re-lays-out wq on
+        every call) and may skip the rounding of the K/V rows the pool
+        keeps (XLA:CPU does, ``xla_allow_excess_precision``)."""
+        return tuple(p.astype(jnp.float32) for p in
+                     jax.lax.optimization_barrier(
+                         tuple(jnp.dot(x, w[n]) for n in ("wq", "wk", "wv"))))
+
     def forward(self, ctx: LoweringContext, inputs, weights):
         from flexflow_tpu.kernels.ragged_paged_attention import (
             _xla_ragged_paged,
@@ -256,14 +282,10 @@ class DecodeAttentionOp(Operator):
         seq_lens = seq_lens.astype(jnp.int32)
         cd = ctx.compute_dtype
         x = hidden[:, 0, :].astype(cd)  # [B, E]
-        wq, wk, wv, wo = (weights[n].astype(cd)
-                          for n in ("wq", "wk", "wv", "wo"))
-        q = jnp.einsum("be,ehd->bhd", x, wq)
+        w = self.serving_weights(weights, cd)
         # fresh K/V rows as the pool holds them: heads fused, [B, H·D]
-        k_new = jnp.einsum("be,ehd->bhd", x, wk).astype(
-            jnp.float32).reshape(x.shape[0], -1)
-        v_new = jnp.einsum("be,ehd->bhd", x, wv).astype(
-            jnp.float32).reshape(x.shape[0], -1)
+        q, k_new, v_new = self._project(x, w)
+        qf = q.reshape(x.shape[0], a["num_heads"], self.head_dim)
 
         ps = a["page_size"]
         k_cache = ctx.state_in[f"{self.name}/k_cache"]
@@ -310,7 +332,6 @@ class DecodeAttentionOp(Operator):
 
         scale = 1.0 / math.sqrt(self.head_dim)
         lens = seq_lens + 1  # the fresh token attends to itself too
-        qf = q.astype(jnp.float32)
         if self.attention_path(ctx.mesh is not None) == "pallas":
             if kvd == "int8":
                 out = ragged_paged_attention_quant(
@@ -326,8 +347,8 @@ class DecodeAttentionOp(Operator):
         else:
             out = _xla_ragged_paged(
                 qf, k_cache, v_cache, page_table, lens, scale)
-        y = jnp.einsum("bhd,hde->be", out.astype(cd), wo,
-                       preferred_element_type=jnp.float32)
+        y = jnp.dot(out.astype(cd).reshape(x.shape[0], -1), w["wo"],
+                    preferred_element_type=jnp.float32)
         return [y[:, None, :].astype(hidden.dtype)]
 
     # ---- chunked prefill lowering ---------------------------------------
@@ -348,8 +369,6 @@ class DecodeAttentionOp(Operator):
         dtype, cache and softmax in fp32), so the populated cache is
         numerically the one the token-by-token path writes
         (runtime/prefill.py proves token identity end-to-end)."""
-        import jax
-
         from flexflow_tpu.kernels.ragged_paged_attention import (
             NEG_INF,
             gather_kv_pages,
@@ -362,14 +381,10 @@ class DecodeAttentionOp(Operator):
         positions = positions.astype(jnp.int32)
         cd = ctx.compute_dtype
         x = hidden.astype(cd)  # [B, C, E]
-        wq, wk, wv, wo = (weights[n].astype(cd)
-                          for n in ("wq", "wk", "wv", "wo"))
-        q = jnp.einsum("bce,ehd->bchd", x, wq)
+        w = self.serving_weights(weights, cd)
         # fresh K/V rows as the pool holds them: [B, C, H·D]
-        k_new = jnp.einsum("bce,ehd->bchd", x, wk).astype(
-            jnp.float32).reshape(*x.shape[:2], -1)
-        v_new = jnp.einsum("bce,ehd->bchd", x, wv).astype(
-            jnp.float32).reshape(*x.shape[:2], -1)
+        q, k_new, v_new = self._project(x, w)
+        qf = q.reshape(*x.shape[:2], a["num_heads"], self.head_dim)
 
         ps = a["page_size"]
         k_cache = ctx.state_in[f"{self.name}/k_cache"]
@@ -412,15 +427,14 @@ class DecodeAttentionOp(Operator):
         else:
             k_dense = gather_kv_pages(k_cache, page_table, h)  # [B, S, H, D]
             v_dense = gather_kv_pages(v_cache, page_table, h)
-        qf = q.astype(jnp.float32)
         s = jnp.einsum("bchd,bshd->bchs", qf, k_dense) * scale
         pos_k = jnp.arange(k_dense.shape[1], dtype=jnp.int32)
         mask = pos_k[None, None, :] <= positions[:, :, None]  # [B, C, S]
         s = jnp.where(mask[:, :, None, :], s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
         out = jnp.einsum("bchs,bshd->bchd", p, v_dense)
-        y = jnp.einsum("bchd,hde->bce", out.astype(cd), wo,
-                       preferred_element_type=jnp.float32)
+        y = jnp.dot(out.astype(cd).reshape(*x.shape[:2], -1), w["wo"],
+                    preferred_element_type=jnp.float32)
         return [y.astype(hidden.dtype)]
 
     # ---- degree propagation ---------------------------------------------

@@ -110,9 +110,15 @@ class LinearOp(Operator):
         return specs
 
     # ---- lowering --------------------------------------------------------
+    def serving_weights(self, weights, compute_dtype):
+        """The kernel in the dtype the matmul reads; the bias stays as
+        it is (it is added to the fp32 accumulator)."""
+        return {**weights,
+                "kernel": weights["kernel"].astype(compute_dtype)}
+
     def forward(self, ctx: LoweringContext, inputs, weights):
         x = inputs[0].astype(ctx.compute_dtype)
-        k = weights["kernel"].astype(ctx.compute_dtype)
+        k = self.serving_weights(weights, ctx.compute_dtype)["kernel"]
         y = jnp.dot(x, k, preferred_element_type=jnp.float32)
         if self.attrs["use_bias"]:
             y = y + weights["bias"].astype(jnp.float32)

@@ -51,7 +51,9 @@ The executor is deliberately decoupled from FFModel: it drives any
 ``step_fn(token_ids [B,1] i32, page_table [B,P] i32, seq_lens [B] i32)
 -> logits [B, 1, V]``; ``compiled_decode_step`` builds that function
 from a compiled decode model (threading the KV-cache state dict
-across calls).
+across calls, and handing the frame and the prefill chunk the SERVED
+weight tree — ``model.params`` cast to the compute dtype and laid out
+as the matmuls read it, once, not inside every call).
 """
 
 from __future__ import annotations
@@ -93,6 +95,11 @@ _PREFILL_TOKENS = METRICS.counter("decode.prefill_tokens")
 _PROMPT_TOKENS = METRICS.counter("decode.prompt_tokens")
 _PREFIX_HIT_TOKENS = METRICS.counter("decode.prefix_hit_tokens")
 _FRAME_S = METRICS.histogram("decode.frame_s")
+# the weight tree the two serving programs take (compiled_decode_step):
+# how often it was derived, its bytes and the bytes of ``model.params``
+_WEIGHT_PREPARES = METRICS.counter("decode.weight_prepares")
+_WEIGHT_BYTES = METRICS.gauge("decode.weight_bytes")
+_WEIGHT_BYTES_MASTER = METRICS.gauge("decode.weight_bytes_master")
 
 
 @dataclass
@@ -1094,6 +1101,12 @@ class _LiveState:
 _KV_LEAVES = ("k_cache", "v_cache", "k_scale", "v_scale")
 
 
+def _tree_bytes(tree) -> int:
+    import jax
+
+    return sum(leaf.nbytes for leaf in jax.tree.leaves(tree))
+
+
 def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
     """A ``step_fn`` over a COMPILED decode model: one jitted forward
     per frame over the model's state dict (the caches are model state —
@@ -1115,10 +1128,27 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
     ``step.prefill(ids [1,C], positions [1,C], page_table [1,P])`` for
     the executor's ``prefill_fn``.
 
-    ``step.frame_fn`` is the jitted frame itself (``(params, state,
+    The weights are NOT ``model.params``: ``step.weights`` is the
+    served tree — every op's ``Operator.serving_weights`` of its own
+    leaves, keyed as ``model.params`` is: the matmuls' kernels in the
+    compute dtype, the attention projections fused [E, H·D] / [H·D, E];
+    embedding tables, norms and biases the master's own arrays —
+    derived by ONE jitted program when the step is built (each leaf
+    laid out over the mesh as its master is), and again on the first
+    call after ``model.params`` became another tree (a restored
+    checkpoint, a weight swap; compared by identity).  ``model.params``
+    stays what checkpoints, ``fit`` and a float32 reference read.
+    Counter ``decode.weight_prepares`` counts the derivations, gauges
+    ``decode.weight_bytes`` / ``decode.weight_bytes_master`` the two
+    trees' bytes.
+
+    ``step.frame_fn`` is the jitted frame itself (``(weights, state,
     [ids, page_table, seq_lens])``), ``step.chunk_fn`` the jitted
-    prefill chunk (``(params, state, ids, positions, page_table)``),
-    and ``step.attention_path`` names
+    prefill chunk (``(weights, state, ids, positions, page_table)``);
+    either takes ``step.weights`` — the program ``step()`` and
+    ``step.prefill()`` run — or ``model.params``, whose fp32 [E, H, D]
+    leaves it then converts and fuses inside the call (the same
+    arithmetic, bit for bit).  ``step.attention_path`` names
     what its decode attention lowered to — ``"pallas"`` or ``"xla"``,
     by ``DecodeAttentionOp.attention_path``'s rule."""
     import jax
@@ -1129,6 +1159,46 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
     fn = jax.jit(
         lambda p, s, ins: compiled.apply(p, s, ins, None, False),
         donate_argnums=(1,))
+    owners = {n.op.name: n.op for n in model.graph.topo_order()}
+
+    def serve_all(params):
+        return {name: owners[name].serving_weights(
+                    ws, compiled.compute_dtype)
+                for name, ws in params.items()}
+
+    # the leaves serving changes (a matmul's operands); every other —
+    # embedding tables, norms, biases — is served as the master's own
+    # array.  One program derives them all, each laid out over the mesh
+    # as the compiler carries its master's sharding through
+    avals = jax.eval_shape(serve_all, model.params)
+    moved = [(name, w) for name, ws in model.params.items() for w in ws
+             if (avals[name][w].shape, avals[name][w].dtype)
+             != (ws[w].shape, ws[w].dtype)]
+
+    @jax.jit
+    def derive(params):
+        served = serve_all(params)
+        return {(name, w): served[name][w] for name, w in moved}
+
+    held = {"master": None}  # the tree ``step.weights`` was derived from
+
+    def weights():
+        """The served tree of ``model.params`` as it is NOW: derived
+        again when the model was given another tree (a restored
+        checkpoint, a weight swap)."""
+        master = model.params
+        if held["master"] is not master:
+            made = derive(master)
+            step.weights = {
+                name: {w: made.get((name, w), leaf)
+                       for w, leaf in ws.items()}
+                for name, ws in master.items()}
+            held["master"] = master
+            _WEIGHT_PREPARES.inc()
+            _WEIGHT_BYTES.set(_tree_bytes(step.weights))
+            _WEIGHT_BYTES_MASTER.set(_tree_bytes(master))
+        return step.weights
+
     paths = {
         n.op.attention_path(compiled._multi_device)
         for n in model.graph.topo_order()
@@ -1149,9 +1219,10 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
     def step(ids, page_table, seq_lens):
         logits, model.state = call(
             "decode_frame", fn,
-            model.params, model.state, [ids, page_table, seq_lens])
+            weights(), model.state, [ids, page_table, seq_lens])
         return logits
 
+    weights()
     step.state = _LiveState(model)  # tests inspect the live cache
     step.frame_fn = fn
     step.attention_path = "+".join(sorted(paths)) or None
@@ -1181,7 +1252,7 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
                      donate_argnums=(1,))
 
         def prefill(ids, positions, page_table):
-            model.state = call("prefill_chunk", pf, model.params,
+            model.state = call("prefill_chunk", pf, weights(),
                                model.state, ids, positions, page_table)
 
         step.prefill = prefill

@@ -228,7 +228,7 @@ def test_decode_frame_and_chunk_update_the_pool_in_place(
     def ints(*shape):
         return S(shape, jnp.int32, sharding=sh)
 
-    params, state = described(model.params), described(model.state)
+    state = described(model.state)
     pools = sorted(k for k in state if k.endswith(("/k_cache", "/v_cache")))
     assert len(pools) == 2 * layers
     leaf = state[pools[0]]
@@ -236,16 +236,24 @@ def test_decode_frame_and_chunk_update_the_pool_in_place(
     # the kernel picks interpreter mode off-TPU by the default backend;
     # this compile is FOR the chip
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    programs = {
-        "frame": step.frame_fn.lower(
-            params, state, [ints(slots, 1), ints(slots, 32), ints(slots)]),
-        "chunk": step.chunk_fn.lower(
-            params, state, ints(1, chunk), ints(1, chunk), ints(1, 32)),
-    }
+    # the programs the server runs take ``step.weights``; the same two
+    # over ``model.params`` (the fp32 tree, converted in the call) are
+    # what the harness and the smoke lower for their Mosaic count
+    programs = {}
+    for tag, tree in (("", step.weights), (" over model.params",
+                                           model.params)):
+        params = described(tree)
+        programs["frame" + tag] = step.frame_fn.lower(
+            params, state, [ints(slots, 1), ints(slots, 32), ints(slots)])
+        programs["chunk" + tag] = step.chunk_fn.lower(
+            params, state, ints(1, chunk), ints(1, chunk), ints(1, 32))
     pool_elems = int(np.prod(leaf.shape))
     for name, lowered in programs.items():
         compiled = lowered.compile()
         hlo = compiled.as_text()
+        # an fp32 matmul weight is read (and converted) only where the
+        # program was handed the fp32 tree
+        assert ("f32[1024,16,64]" in hlo) == name.endswith("model.params")
         # the compiled program numbers only the arguments it kept (the
         # chunk reads no lm_head): find the pool leaves by their type
         entry = hlo[hlo.index("ENTRY "):]
@@ -266,7 +274,7 @@ def test_decode_frame_and_chunk_update_the_pool_in_place(
                    for op, jax_op in made), (name, made)
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < pool_elems * leaf.dtype.itemsize, (name, temp)
-        if name == "frame":
+        if name.startswith("frame"):
             assert hlo.count(f'custom_call_target="{MOSAIC}"') == layers
 
 

@@ -55,13 +55,14 @@ def _trivial_strategy(graph):
     }
 
 
-def _compiled_small(num_devices=1, batch=4, **overrides):
+def _compiled_small(num_devices=1, batch=4, compute_dtype="bfloat16",
+                    **overrides):
     from flexflow_tpu.models import build_gpt_decode
 
     kw = dict(SMALL_KW)
     kw.update(overrides)
     cfg = ff.FFConfig(batch_size=batch, num_devices=num_devices,
-                      cost_cache_file="")
+                      cost_cache_file="", compute_dtype=compute_dtype)
     m = build_gpt_decode(cfg, **kw)
     m.compile(loss_type="sparse_categorical_crossentropy", metrics=[],
               comp_mode="inference",
@@ -152,6 +153,42 @@ def test_chunked_prefill_on_searched_multidevice_strategy():
                        for i, p in enumerate(prompts)], max_frames=200)
 
     assert run(0) == run(4)
+
+
+@pytest.mark.parametrize("compute_dtype", ["bfloat16", "float32"])
+def test_chunk_state_is_the_same_over_the_served_tree(compute_dtype):
+    """The prefill chunk handed ``step.weights`` (what ``step.prefill``
+    hands it) and handed ``model.params`` leaves every state leaf bit
+    for bit the same — over two chunks, the second attending to the
+    first's rows — and ``step.prefill`` is the first of the two."""
+    import jax
+    import jax.numpy as jnp
+
+    m = _compiled_small(compute_dtype=compute_dtype)
+    step = compiled_decode_step(m, prefill_chunk=8)
+    rng = np.random.default_rng(5)
+    ids = rng.integers(1, 255, size=(2, 1, 8)).astype(np.int32)
+    table = np.arange(8, dtype=np.int32)[None, :]
+
+    def write(run):
+        m.state = jax.tree.map(jnp.zeros_like, m.state)
+        for c in range(2):
+            run(ids[c], (8 * c + np.arange(8, dtype=np.int32))[None, :],
+                table)
+        return {k: np.asarray(v) for k, v in m.state.items()}
+
+    def over(tree):
+        def run(ids, positions, page_table):
+            m.state = step.chunk_fn(tree, m.state, ids, positions,
+                                    page_table)
+        return run
+
+    served = write(over(step.weights))
+    for other in (write(over(m.params)), write(step.prefill)):
+        assert served.keys() == other.keys()
+        for key, val in served.items():
+            np.testing.assert_array_equal(val, other[key], err_msg=key)
+    assert all(np.any(v[:2] != 0) for v in served.values())
 
 
 def test_chunk_forward_rejects_non_decode_graph():

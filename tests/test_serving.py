@@ -968,3 +968,344 @@ def test_decode_graph_searched_strategy_executes():
     out = ex.run([DecodeRequest(rid="a", prompt=[5, 6, 7],
                                 max_new_tokens=4)], max_frames=60)
     assert len(out["a"]) == 4
+
+
+# ---------------------------------------------------------------------------
+# the served weight tree: what the frame and the prefill chunk are handed
+# ---------------------------------------------------------------------------
+SERVED_KW = dict(vocab=97, num_layers=2, hidden=32, num_heads=4, ff_dim=64,
+                 page_size=8, pages_per_seq=4)  # heads of 8: the kernel runs
+
+
+def _served_model(compute_dtype="bfloat16", seed=3, kw=SERVED_KW,
+                  **ffconfig):
+    from flexflow_tpu.models import build_gpt_decode
+
+    cfg = ff.FFConfig(batch_size=4, num_devices=1, cost_cache_file="",
+                      compute_dtype=compute_dtype, seed=seed, **ffconfig)
+    m = build_gpt_decode(cfg, **kw)
+    m.compile(loss_type="sparse_categorical_crossentropy", metrics=[],
+              comp_mode="inference")
+    return m
+
+
+def _over_master(model, step):
+    """``step``'s two programs handed ``model.params`` itself — the
+    fp32 [E, H, D] tree, converted inside every call — behind the
+    interface of ``step``."""
+    def frame(ids, page_table, seq_lens):
+        logits, model.state = step.frame_fn(
+            model.params, model.state, [ids, page_table, seq_lens])
+        return logits
+
+    def prefill(ids, positions, page_table):
+        model.state = step.chunk_fn(model.params, model.state, ids,
+                                    positions, page_table)
+
+    frame.prefill, frame.copy_page = prefill, step.copy_page
+    frame.attention_path = step.attention_path
+    return frame
+
+
+def _serve_recording(model, step, prefix_sharing):
+    """Six requests behind one shared prefix through chunked prefill and
+    the executor, over a zeroed pool: (every frame's logits, the tokens
+    handed back, the pool as it was left)."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.runtime.decode import (
+        ContinuousBatchingExecutor,
+        DecodeRequest,
+    )
+
+    model.state = jax.tree.map(jnp.zeros_like, model.state)
+    logits = []
+
+    def recording(ids, page_table, seq_lens):
+        out = step(ids, page_table, seq_lens)
+        logits.append(np.asarray(out, np.float32))
+        return out
+
+    extra = (dict(prefix_sharing=True, copy_page_fn=step.copy_page)
+             if prefix_sharing else {})
+    ex = ContinuousBatchingExecutor(
+        recording, max_seqs=4, page_size=SERVED_KW["page_size"],
+        pages_per_seq=SERVED_KW["pages_per_seq"], prefill_fn=step.prefill,
+        prefill_chunk=4, **extra)
+    rng = np.random.default_rng(0)
+    shared = rng.integers(1, 97, size=11).tolist()
+    tokens = ex.run([
+        DecodeRequest(rid=f"r{i}", max_new_tokens=5,
+                      prompt=shared + rng.integers(1, 97, size=2 + i).tolist())
+        for i in range(6)], max_frames=200)
+    return (np.stack(logits), tokens,
+            {k: np.asarray(v) for k, v in model.state.items()})
+
+
+@pytest.mark.parametrize("variant", ["fp32_pool", "int8_pool",
+                                     "prefix_sharing"])
+@pytest.mark.parametrize("compute_dtype", ["bfloat16", "float32"])
+def test_served_tree_and_master_tree_serve_bit_identically(compute_dtype,
+                                                           variant):
+    """THE numerics contract of the served tree: rounding a matmul's
+    weights to the compute dtype once, at build, and storing the
+    projections fused is the SAME arithmetic as doing both inside every
+    call — every frame's logits, the pool the chunks and frames left
+    and every token are bit-identical, also under an int8 pool and
+    under prefix sharing."""
+    from flexflow_tpu.runtime.decode import compiled_decode_step
+
+    lane = (dict(objective="serve", kv_precision="int8")
+            if variant == "int8_pool" else {})
+    m = _served_model(compute_dtype, **lane)
+    step = compiled_decode_step(m, prefill_chunk=4)
+    assert step.attention_path == "pallas"
+    sharing = variant == "prefix_sharing"
+    logits, tokens, pool = _serve_recording(m, step, sharing)
+    logits0, tokens0, pool0 = _serve_recording(
+        m, _over_master(m, step), sharing)
+    assert tokens == tokens0 and all(len(t) == 5 for t in tokens.values())
+    np.testing.assert_array_equal(logits, logits0)
+    assert pool.keys() == pool0.keys()
+    if variant == "int8_pool":
+        assert {v.dtype for v in pool.values()} == {np.dtype(np.int8),
+                                                    np.dtype(np.float32)}
+    for key, val in pool.items():
+        np.testing.assert_array_equal(val, pool0[key], err_msg=key)
+    assert any(np.any(v != 0) for v in pool.values())
+
+
+@pytest.mark.parametrize("compute_dtype", ["bfloat16", "float32"])
+def test_served_tree_holds_matmul_leaves_as_the_matmuls_read_them(
+        compute_dtype):
+    """``step.weights``: every matmul's operand in the compute dtype,
+    2-D, lane-dense (the attention projections fused to [E, H·D] /
+    [H·D, E]); embedding tables, norms and biases THE master's arrays;
+    ``model.params`` untouched; the registry says what was built."""
+    import jax.numpy as jnp
+
+    from flexflow_tpu.obs.exposition import render_prometheus
+    from flexflow_tpu.obs.metrics import METRICS
+    from flexflow_tpu.runtime.decode import compiled_decode_step
+
+    m = _served_model(compute_dtype)
+    master = m.params
+    prepares = METRICS.counter("decode.weight_prepares").value
+    step = compiled_decode_step(m, prefill_chunk=4)
+    assert METRICS.counter("decode.weight_prepares").value == prepares + 1
+    assert m.params is master
+    cd, hd = jnp.dtype(compute_dtype), SERVED_KW["hidden"]
+    matmul = {"wq", "wk", "wv", "wo", "kernel"}
+    assert step.weights.keys() == master.keys()
+    for name, ws in master.items():
+        assert step.weights[name].keys() == ws.keys()
+        for w, leaf in ws.items():
+            served = step.weights[name][w]
+            if w in matmul:
+                assert served.dtype == cd and served.ndim == 2, (name, w)
+                assert served.shape[-1] >= hd, (name, w, served.shape)
+                assert int(np.prod(served.shape)) == int(np.prod(leaf.shape))
+                # a kernel already in the compute dtype is served as it is
+                assert (served is leaf) == (
+                    leaf.dtype == cd and leaf.ndim == 2), (name, w)
+            else:
+                assert served is leaf, (name, w)
+            assert leaf.dtype == jnp.float32
+    attn = master["layer0_mha"]
+    assert attn["wq"].shape == (hd, 4, 8) and attn["wo"].shape == (4, 8, hd)
+    assert step.weights["layer0_mha"]["wo"].shape == (hd, hd)
+
+    def nbytes(tree):
+        return sum(x.nbytes for ws in tree.values() for x in ws.values())
+
+    gauges = METRICS.snapshot()["gauges"]
+    assert gauges["decode.weight_bytes"] == nbytes(step.weights)
+    assert gauges["decode.weight_bytes_master"] == nbytes(master)
+    halved = sum(x.nbytes for ws in master.values()
+                 for w, x in ws.items() if w in matmul) // 2
+    assert nbytes(master) - nbytes(step.weights) == (
+        halved if compute_dtype == "bfloat16" else 0)
+    text = render_prometheus(METRICS.snapshot())
+    for name in ("decode_weight_prepares", "decode_weight_bytes",
+                 "decode_weight_bytes_master"):
+        assert name in text, name
+
+
+def _weight_converts(lowered_text: str):
+    """(shape, from, to) of every ``convert`` in ``main`` applied
+    straight to an argument of rank 2 or more."""
+    import re
+
+    main = lowered_text[lowered_text.index("func.func public @main"):]
+    main = main.split("func.func private")[0]
+    return [m.groups() for m in re.finditer(
+        r"stablehlo\.convert %arg\d+ : \(tensor<((?:\d+x){2,})(\w+)>\) -> "
+        r"tensor<(?:\d+x)+(\w+)>", main)]
+
+
+def test_frame_over_the_served_tree_converts_no_weight(monkeypatch):
+    """The program the server runs reads its matmul weights as they
+    are: lowered over ``step.weights`` the frame and the chunk hold NO
+    convert of a weight argument, where over ``model.params`` they hold
+    one for every matmul leaf it reads (six a layer and the head; the
+    chunk ends at the last layer's cache write, so that layer's wo, its
+    FFN and the head are pruned).  Both still lower, with the same
+    Mosaic calls."""
+    import jax
+
+    from flexflow_tpu.runtime.decode import compiled_decode_step
+
+    # two heads of 64: on the chip the kernel wants whole 128-lane rows
+    m = _served_model("bfloat16", kw=dict(SERVED_KW, hidden=128,
+                                          num_heads=2))
+    step = compiled_decode_step(m, prefill_chunk=4)
+    layers = SERVED_KW["num_layers"]
+    ints = lambda *shape: np.zeros(shape, np.int32)  # noqa: E731
+    frame_ins = [ints(4, 1), ints(4, 4), ints(4)]
+    chunk_ins = (ints(1, 4), ints(1, 4), ints(1, 4))
+    for tree, per_layer in ((step.weights, 0), (m.params, 6)):
+        frame = step.frame_fn.lower(tree, m.state, frame_ins).as_text()
+        chunk = step.chunk_fn.lower(tree, m.state, *chunk_ins).as_text()
+        got = _weight_converts(frame)
+        assert len(got) == (per_layer * layers + 1 if per_layer else 0), got
+        assert all(src == "f32" and dst == "bf16" for _, src, dst in got)
+        assert len(_weight_converts(chunk)) == (
+            per_layer * layers - 3 if per_layer else 0)
+    # lowered FOR the chip (the kernel picks the interpreter off-TPU by
+    # the default backend, when it is traced: a step of its own), either
+    # tree holds the kernel
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    chip = compiled_decode_step(m)
+    calls = {chip.frame_fn.trace(tree, m.state, frame_ins).lower(
+                 lowering_platforms=("tpu",)).as_text().count(
+                     "tpu_custom_call")
+             for tree in (chip.weights, m.params)}
+    # (StableHLO keeps one function for the layers' identical kernels)
+    assert calls == {1}, calls
+
+
+def test_restored_params_are_served_anew(tmp_path):
+    """``model.params`` replaced (a checkpoint of other values restored
+    into the serving model): the next call derives the served tree
+    again — ``decode.weight_prepares`` 1 → 2 — and the logits are the
+    new weights', bit for bit; a call on the same tree derives
+    nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.obs.metrics import METRICS
+    from flexflow_tpu.runtime.checkpoint import CheckpointManager
+    from flexflow_tpu.runtime.decode import compiled_decode_step
+
+    m = _served_model("bfloat16", seed=3)
+    prepares = METRICS.counter("decode.weight_prepares")
+    n0 = prepares.value
+    step = compiled_decode_step(m)
+    ids = np.arange(1, 5, dtype=np.int32)[:, None]
+    table = np.arange(16, dtype=np.int32).reshape(4, 4)
+    lens = np.zeros((4,), np.int32)
+
+    def frame_over(params):
+        return np.asarray(step.frame_fn(  # the state is donated: a copy
+            params, jax.tree.map(jnp.copy, m.state), [ids, table, lens])[0])
+
+    want_old = frame_over(m.params)
+    old = np.asarray(step(ids, table, lens))
+    np.asarray(step(ids, table, lens))
+    assert prepares.value == n0 + 1
+    served_old = step.weights
+
+    donor = _served_model("bfloat16", seed=11)
+    with CheckpointManager(str(tmp_path)) as mgr:
+        mgr.save(0, donor)
+        mgr.wait()
+        mgr.restore(m)
+    new = np.asarray(step(ids, table, lens))
+    assert prepares.value == n0 + 2
+    assert step.weights is not served_old
+    np.asarray(step(ids, table, lens))
+    assert prepares.value == n0 + 2
+    np.testing.assert_array_equal(old, want_old)
+    np.testing.assert_array_equal(new, frame_over(m.params))
+    np.testing.assert_array_equal(new, frame_over(donor.params))
+    assert np.abs(new - old).max() > 1e-2
+
+
+def test_fit_builds_no_served_tree():
+    """Training never serves: a ``fit`` of the tiny training model
+    leaves ``decode.weight_prepares`` where it was."""
+    from flexflow_tpu.models import build_gpt
+    from flexflow_tpu.obs.metrics import METRICS
+
+    prepares = METRICS.counter("decode.weight_prepares")
+    n0 = prepares.value
+    cfg = ff.FFConfig(batch_size=2, num_devices=1, cost_cache_file="",
+                      compute_dtype="bfloat16", epochs=1)
+    m = build_gpt(cfg, vocab=64, seq_len=16, num_layers=1, hidden=32,
+                  num_heads=2, ff_dim=32)
+    m.compile(loss_type="sparse_categorical_crossentropy", metrics=[])
+    rng = np.random.default_rng(0)
+    x = rng.integers(0, 64, size=(4, 16)).astype(np.int32)
+    m.fit(x, np.roll(x, -1, axis=1), epochs=1)
+    assert prepares.value == n0
+
+
+def test_served_tree_keeps_the_masters_shardings():
+    """Sharded weights (heads over four of the CPU's virtual devices,
+    the first FFN's columns too): each served leaf is laid out as its
+    master is — a head split of [E, H, D] is the same split of the
+    fused [E, H·D] — and the frame over it equals the frame over the
+    sharded fp32 tree bit for bit."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec
+
+    from flexflow_tpu.models import build_gpt_decode
+    from flexflow_tpu.runtime.decode import compiled_decode_step
+
+    kw = dict(vocab=256, num_layers=1, hidden=64, num_heads=4, ff_dim=64,
+              page_size=4, pages_per_seq=4)
+    cfg = ff.FFConfig(batch_size=8, num_devices=N_DEV, cost_cache_file="",
+                      compute_dtype="bfloat16",
+                      machine_spec=MachineSpec.host_cpu(N_DEV))
+    m = build_gpt_decode(cfg, **kw)
+    strategy = _trivial_strategy(m.graph)
+    for n in m.graph.topo_order():
+        if n.op.op_type == OperatorType.DECODE_ATTENTION:
+            strategy[n.guid] = MachineView(dim_degrees=(1, 1, 1),
+                                           replica_degree=4)
+        elif n.op.name.endswith("ff1"):
+            strategy[n.guid] = MachineView(dim_degrees=(1, 1, 4))
+    m.compile(loss_type="sparse_categorical_crossentropy", metrics=[],
+              comp_mode="inference", strategy=strategy)
+    step = compiled_decode_step(m)
+    assert step.attention_path == "xla"
+    split = 0
+    for name, ws in m.params.items():
+        for w, leaf in ws.items():
+            served = step.weights[name][w]
+            spec = tuple(leaf.sharding.spec)
+            if spec and any(spec):
+                split += 1
+                axes = next(a for a in spec if a)
+                # wq/wk/wv split dim 1 of 3, wo dim 0 of 3, the FFN
+                # kernel dim 1 of 2: the same dim of the fused leaf
+                want = PartitionSpec(*spec[:2]) if leaf.ndim == 3 else \
+                    PartitionSpec(*spec)
+                assert served.sharding.is_equivalent_to(
+                    jax.sharding.NamedSharding(leaf.sharding.mesh, want),
+                    served.ndim), (name, w, served.sharding)
+                assert len(served.sharding.device_set) == N_DEV
+                shard = served.addressable_shards[0].data
+                assert shard.size * 4 == served.size, (name, w, axes)
+            else:
+                assert served.sharding.is_fully_replicated, (name, w)
+    assert split == 6  # wq, wk, wv, wo, ff1's kernel and bias
+    ids = np.ones((8, 1), np.int32)
+    table = np.arange(32, dtype=np.int32).reshape(8, 4)
+    lens = np.zeros((8,), np.int32)
+    got = np.asarray(step(ids, table, lens))
+    m.state = jax.tree.map(jnp.zeros_like, m.state)
+    want, _ = step.frame_fn(m.params, m.state, [ids, table, lens])
+    np.testing.assert_array_equal(got, np.asarray(want))

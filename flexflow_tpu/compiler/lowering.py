@@ -157,6 +157,7 @@ class CompiledModel:
             self._slot_axes[node.guid] = view_slot_axes(mv, axis_pool)
 
         self._multi_device = int(np.prod(list(self.mesh.shape.values()))) > 1
+        self._remat_blocks = self._find_remat_blocks() if config.remat else {}
         self._train_step_fn = None
         self._eval_step_fn = None
 
@@ -224,7 +225,11 @@ class CompiledModel:
         values: Dict[Tuple[int, int], jax.Array] = {}
         input_pos = {n.guid: i for i, n in enumerate(self._input_nodes)}
         for node in self._topo:
-            self._run_node(node, ctx, values, params, inputs, input_pos)
+            block = self._remat_blocks.get(node.guid)
+            if block is None:
+                self._run_node(node, ctx, values, params, inputs, input_pos)
+            elif node is block[-1]:
+                self._run_remat_block(block, ctx, values, params, outputs)
         new_state = dict(state)
         new_state.update(ctx.state_out)
         return tuple(values[key] for key in outputs), new_state
@@ -236,9 +241,66 @@ class CompiledModel:
         spec = annot_partition_spec(annot, self._slot_axes[guid])
         return jax.sharding.NamedSharding(self.mesh, spec)
 
-    def _run_node(self, node, ctx, values, params, inputs, input_pos):
+    @staticmethod
+    def _pure(op) -> bool:
+        """``forward`` writes no state, so it may be recomputed."""
+        return not op.writes_state and getattr(op, "state_specs", None) is None
+
+    def _find_remat_blocks(self) -> Dict[int, List[Node]]:
+        """guid -> the ops of its ``FFModel.remat_block`` in topo order,
+        for every block that can run as ONE checkpoint where its last op
+        stands: all of its ops pure, and nothing outside reads one of
+        its values before then.  An op of a block that cannot falls back
+        to the per-op rule of ``_lower_op``."""
+        pos = {n.guid: i for i, n in enumerate(self._topo)}
+        blocks: Dict[int, List[Node]] = {}
+        for node in self._topo:
+            block_id = node.op.remat_block
+            if block_id is not None:
+                blocks.setdefault(block_id, []).append(node)
+        found = {}
+        for nodes in blocks.values():
+            inside = {n.guid for n in nodes}
+            last = pos[nodes[-1].guid]
+            early_reader = any(
+                e.dst not in inside and pos[e.dst] < last
+                for n in nodes for e in self.graph.out_edges[n.guid])
+            if (len(nodes) > 1 and not early_reader
+                    and all(self._pure(n.op) and not isinstance(n.op, InputOp)
+                            for n in nodes)):
+                found.update({g: nodes for g in inside})
+        return found
+
+    def _run_remat_block(self, nodes, ctx, values, params, wanted):
+        """The block's ops under one ``jax.checkpoint``: what enters the
+        block is saved, nothing inside it.  ``wanted``: the values the
+        caller asked ``apply_multi`` for."""
+        inside = {n.guid for n in nodes}
+        entering = sorted({
+            (e.src, e.src_idx) for n in nodes
+            for e in self.graph.in_edges[n.guid] if e.src not in inside})
+        leaving = sorted({
+            (e.src, e.src_idx) for n in nodes
+            for e in self.graph.out_edges[n.guid] if e.dst not in inside}
+            | {k for k in wanted if k[0] in inside})
+        keys = {n.op.weights_key for n in nodes}
+
+        def block(entered, ws):
+            local = dict(zip(entering, entered))
+            for n in nodes:
+                self._run_node(n, ctx, local, ws, (), {}, in_block=True)
+            return [local[k] for k in leaving]
+
+        left = jax.checkpoint(block)(
+            [values[k] for k in entering],
+            {k: params[k] for k in keys if k in params})
+        values.update(zip(leaving, left))
+
+    def _run_node(self, node, ctx, values, params, inputs, input_pos,
+                  in_block: bool = False):
         """Lower one PCG node into ``values`` (shared by the pipelined
-        subclass's apply)."""
+        subclass's apply).  ``in_block``: inside a block that is
+        checkpointed as a whole, so not once more by itself."""
         osh = self._shardings[node.guid]
         axes = self._slot_axes[node.guid]
         if node.guid in input_pos:
@@ -259,9 +321,10 @@ class CompiledModel:
         # the program it always did
         scope = "/".join(s for s in (node.op.block_scope, node.op.scope) if s)
         with jax.named_scope(scope) if scope else contextlib.nullcontext():
-            self._lower_op(node, ctx, ins, ws, osh, axes, values)
+            self._lower_op(node, ctx, ins, ws, osh, axes, values,
+                           remat=self.config.remat and not in_block)
 
-    def _lower_op(self, node, ctx, ins, ws, osh, axes, values):
+    def _lower_op(self, node, ctx, ins, ws, osh, axes, values, remat):
         if self._multi_device:
             # ops with an explicit-SPMD lowering (shard_map +
             # collectives) take it when the sharding calls for it —
@@ -273,12 +336,9 @@ class CompiledModel:
                 for i, y in enumerate(outs):
                     values[(node.guid, i)] = y
                 return
-        if (
-            self.config.remat
-            and getattr(node.op, "state_specs", None) is None
-            and node.op._weight_specs
-        ):
-            # rematerialize weighted stateless ops in backward: their
+        if remat and ws and self._pure(node.op):
+            # rematerialize stateless ops that read weights — their own
+            # or, ``weights_of``, another op's — in backward: their
             # activations are recomputed instead of saved (state-mutating
             # ops can't be checkpointed — forward must be pure)
             outs = jax.checkpoint(
@@ -614,7 +674,12 @@ class CompiledModel:
         return updates
 
     def _loss_from(self, logits, labels, new_state):
-        loss = compute_loss(self.loss_type, logits, labels)
+        """The loss of ``logits`` — or, where an op IS the objective and
+        says so in ``{op}/loss`` (ops/exit_loss.py), that — plus every
+        ``{op}/aux_loss``."""
+        own = [v for k, v in new_state.items() if k.endswith("/loss")]
+        loss = sum(own) if own else compute_loss(self.loss_type, logits,
+                                                 labels)
         for k, v in new_state.items():
             if k.endswith("/aux_loss"):
                 loss = loss + v

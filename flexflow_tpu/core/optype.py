@@ -88,6 +88,8 @@ class OperatorType(enum.Enum):
     # multi-token prediction (ops/mtp.py): shifted ids, a second loss
     SHIFT = "shift"
     NEXT_TOKEN_LOSS = "next_token_loss"
+    # the objective over one exit a loop step (ops/exit_loss.py)
+    EXIT_LOSS = "exit_loss"
 
     # ---- fused -----------------------------------------------------------
     FUSED = "fused"

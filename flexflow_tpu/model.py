@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import itertools
 import math as _math
 import time
 from typing import Dict, List, Optional, Sequence, Tuple, Union
@@ -103,6 +104,8 @@ class FFModel:
         self.optimizer: Optional[Optimizer] = None
         self._rng_counter = 0
         self._block_scope: Optional[str] = None  # see block_scope()
+        self._remat_block: Optional[int] = None  # see remat_block()
+        self._remat_ids = itertools.count()
         # device counters' totals already published (obs/device_counters.py)
         self._obs_seen: Dict[str, int] = {}
 
@@ -128,9 +131,26 @@ class FFModel:
         finally:
             self._block_scope = outer
 
+    @contextlib.contextmanager
+    def remat_block(self):
+        """Ops added inside are recomputed TOGETHER under
+        ``FFConfig.remat``: the lowering saves what enters the block and
+        nothing inside it (one layer of a deep or looped stack), where
+        it would otherwise save every weighted op's inputs.  Without
+        ``remat`` it says nothing."""
+        outer, self._remat_block = self._remat_block, next(self._remat_ids)
+        try:
+            yield
+        finally:
+            self._remat_block = outer
+
     def _add_op(self, op: O.Operator, inputs: Sequence[Tensor]) -> List[Tensor]:
         if self._block_scope:
             op.block_scope = self._block_scope
+        if self._remat_block is not None:
+            op.remat_block = self._remat_block
+        if op.weights_key != op.name:
+            self._check_sharer(op)
         node = self.graph.new_node(op)
         for i, t in enumerate(inputs):
             src_node, src_idx = self._producer[t.guid]
@@ -142,6 +162,25 @@ class FFModel:
             self._producer[t.guid] = (node, i)
             outs.append(t)
         return outs
+
+    def _check_sharer(self, op: O.Operator) -> None:
+        """A ``weights_of`` op reads leaves its owner declared: refuse
+        at graph construction one that would declare other names,
+        shapes or dtypes."""
+        def leaves(specs):
+            return [(w.name, w.shape, w.dtype) for w in specs]
+
+        owner = next((n.op for n in self.graph.nodes.values()
+                      if n.op.name == op.weights_key), None)
+        if owner is None or not owner._weight_specs:
+            raise ValueError(
+                f"{op.name}: weights_of={op.weights_key!r} names no op "
+                f"added before it that owns weights")
+        if leaves(op.weight_specs()) != leaves(owner._weight_specs):
+            raise ValueError(
+                f"{op.name} cannot read the weights of {owner.name}: it "
+                f"would declare {leaves(op.weight_specs())}, the owner "
+                f"holds {leaves(owner._weight_specs)}")
 
     # ------------------------------------------------------------------
     def create_tensor(self, dims: Sequence[int], dtype="float32", name=None) -> Tensor:
@@ -218,9 +257,11 @@ class FFModel:
                            axes=tuple(axes), elementwise_affine=elementwise_affine, eps=eps)
         return self._add_op(op, [input])[0]
 
-    def rms_norm(self, input: Tensor, eps: float = 1e-6, name=None) -> Tensor:
+    def rms_norm(self, input: Tensor, eps: float = 1e-6, name=None,
+                 weights_of: Optional[str] = None) -> Tensor:
         op = O.RMSNormOp(self._fresh_name("rmsnorm", name),
-                         [self._shape_of(input)], eps=eps)
+                         [self._shape_of(input)], eps=eps,
+                         weights_of=weights_of)
         return self._add_op(op, [input])[0]
 
     def embedding(self, input: Tensor, num_entries: int, out_dim: int,
@@ -253,13 +294,18 @@ class FFModel:
                             vdim: int = 0, dropout: float = 0.0, bias: bool = False,
                             causal: bool = False, sp_mode: str = "ring",
                             kernel_initializer=None,
-                            name=None) -> Tensor:
+                            name=None, rope_theta: Optional[float] = None,
+                            weights_of: Optional[str] = None) -> Tensor:
+        """``rope_theta`` turns the q and k heads by their positions
+        (half-split rotary) before attention; ``weights_of`` names
+        another attention op whose projections this one reads."""
         op = O.MultiHeadAttentionOp(
             self._fresh_name("attention", name),
             [self._shape_of(query), self._shape_of(key), self._shape_of(value)],
             embed_dim=embed_dim, num_heads=num_heads, kdim=kdim, vdim=vdim,
             dropout=dropout, use_bias=bias, causal=causal, sp_mode=sp_mode,
-            kernel_initializer=kernel_initializer)
+            kernel_initializer=kernel_initializer, rope_theta=rope_theta,
+            weights_of=weights_of)
         return self._add_op(op, [query, key, value])[0]
 
     def decode_attention(self, hidden: Tensor, page_table: Tensor,
@@ -428,6 +474,16 @@ class FFModel:
             [self._shape_of(t) for t in (logits, ahead_logits, ids)],
             shift=shift, weight=weight)
         return self._add_op(op, [logits, ahead_logits, ids])[0]
+
+    def exit_loss(self, logits: Sequence[Tensor], gates: Sequence[Tensor],
+                  ids: Tensor, beta: float = 0.1, name=None) -> Tensor:
+        """The objective of a model with one exit a loop step
+        (ops/exit_loss.py): the graph's sink, which hands the last
+        exit's logits through."""
+        op = O.ExitLossOp(
+            self._fresh_name("exit_loss", name),
+            [self._shape_of(t) for t in (*logits, *gates, ids)], beta=beta)
+        return self._add_op(op, [*logits, *gates, ids])[0]
 
     def cache(self, input: Tensor, use_cached: bool = False, name=None) -> Tensor:
         op = O.CacheOp(self._fresh_name("cache", name), [self._shape_of(input)],
@@ -678,6 +734,7 @@ class FFModel:
         gated = plan.strategy if searched else None
         if searched:
             self._propose(plan, mesh)
+        plan.tie_views()
         self._plan_comm(plan, gated)
         # predicted step breakdown + strategy-explanation telemetry —
         # the predicted half of the DriftReport fit() completes.  Only

@@ -30,13 +30,20 @@ from flexflow_tpu.config import FFConfig
 from flexflow_tpu.model import FFModel
 
 
-def gated_ffn(model, x, width: int, hidden: int, name: str):
-    """W_down(silu(W_gate x) * W_up x), no bias."""
+def gated_ffn(model, x, width: int, hidden: int, name: str,
+              weights_of: str | None = None):
+    """W_down(silu(W_gate x) * W_up x), no bias; ``weights_of`` names
+    another gated feed-forward whose three kernels this one reads."""
+    def of(part):
+        return f"{weights_of}_{part}" if weights_of else None
+
     gate = model.dense(x, width, activation="silu", use_bias=False,
-                       name=f"{name}_gate")
-    up = model.dense(x, width, use_bias=False, name=f"{name}_up")
+                       name=f"{name}_gate", weights_of=of("gate"))
+    up = model.dense(x, width, use_bias=False, name=f"{name}_up",
+                     weights_of=of("up"))
     return model.dense(model.multiply(gate, up, name=f"{name}_act"), hidden,
-                       use_bias=False, name=f"{name}_down")
+                       use_bias=False, name=f"{name}_down",
+                       weights_of=of("down"))
 
 
 def held_experts_ffn(model, x, name: str, *, hidden, expert_ff_dim,

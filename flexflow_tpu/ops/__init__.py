@@ -45,6 +45,7 @@ from flexflow_tpu.ops.moe import (
     GroupByOp,
     MoERouterOp,
 )
+from flexflow_tpu.ops.exit_loss import ExitLossOp
 from flexflow_tpu.ops.mtp import NextTokenLossOp, ShiftOp
 
 __all__ = [
@@ -79,6 +80,7 @@ __all__ = [
     "ExpertCombineOp",
     "ShiftOp",
     "NextTokenLossOp",
+    "ExitLossOp",
     "SoftmaxOp",
     "Conv2DOp",
     "Pool2DOp",

@@ -21,6 +21,7 @@ from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from flexflow_tpu.core.machine import MachineView
 from flexflow_tpu.core.optype import OperatorType
@@ -37,6 +38,23 @@ from flexflow_tpu.ops.base import (
 )
 
 
+def half_split_rotary(x, theta: float):
+    """Rotary embedding of ``x`` [B, S, H, D] at positions 0..S-1 in the
+    half-split convention: for i < D/2 the pair (x_i, x_{i+D/2}) turns
+    by position * theta^(-2i/D); in float32.  The angles are made in
+    the program (an iota times D/2 frequencies), not baked in as
+    [S, D] constants an op."""
+    s, d = x.shape[1], x.shape[-1]
+    inv_freq = (theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+                ).astype(np.float32)
+    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    angle = jnp.concatenate([angle, angle], axis=-1)[None, :, None, :]
+    x = x.astype(jnp.float32)
+    # lane i < D/2 gets -x[i + D/2], lane i + D/2 gets x[i]
+    partner = jnp.concatenate([-x[..., d // 2:], x[..., :d // 2]], axis=-1)
+    return x * jnp.cos(angle) + partner * jnp.sin(angle)
+
+
 @register_op
 class MultiHeadAttentionOp(Operator):
     """query [B, Sq, E], key [B, Sk, E], value [B, Sk, E] -> [B, Sq, E].
@@ -49,7 +67,9 @@ class MultiHeadAttentionOp(Operator):
     the seq degree; "auto" — ulysses for non-causal divisible shapes
     where its single exchange moves strictly fewer bytes than the
     ring's n-1 K/V hops, ring otherwise incl. causal, whose zigzag
-    schedule overlaps comm with compute).
+    schedule overlaps comm with compute), rope_theta (absent = no
+    rotary; set, the q and k heads turn by their positions —
+    ``half_split_rotary`` — before attention; self-attention shapes).
     """
 
     op_type = OperatorType.MULTIHEAD_ATTENTION
@@ -71,12 +91,22 @@ class MultiHeadAttentionOp(Operator):
         use_flash: bool = True,
         sp_mode: str = "ring",
         kernel_initializer: Initializer | None = None,
+        rope_theta: float | None = None,
+        weights_of: str | None = None,
     ):
         kdim = kdim or embed_dim
         vdim = vdim or embed_dim
         assert embed_dim % num_heads == 0
         assert sp_mode in ("ring", "ulysses", "auto"), sp_mode
         self._kernel_init = kernel_initializer or DEFAULT_WEIGHT_INIT
+        rope = {}
+        if rope_theta:
+            # absent unless set: an op built before the key existed
+            # keeps its signature (cost cache, calibration)
+            assert input_shapes[0].sizes[1] == input_shapes[1].sizes[1], (
+                "rotary: self-attention shapes only")
+            assert (embed_dim // num_heads) % 2 == 0, "rotary pairs"
+            rope["rope_theta"] = float(rope_theta)
         super().__init__(
             name,
             input_shapes,
@@ -89,6 +119,8 @@ class MultiHeadAttentionOp(Operator):
             causal=causal,
             use_flash=use_flash,
             sp_mode=sp_mode,
+            weights_of=weights_of,
+            **rope,
         )
 
     def _use_ulysses(self, n: int) -> bool:
@@ -183,6 +215,16 @@ class MultiHeadAttentionOp(Operator):
             qh = qh + weights["bq"].astype(cd)
             kh = kh + weights["bk"].astype(cd)
             vh = vh + weights["bv"].astype(cd)
+        theta = a.get("rope_theta")
+        if theta:
+            if (ctx.slot_axes or {}).get(1, ()):
+                # a shard of the sequence would turn by its LOCAL
+                # positions; the ring and ulysses paths carry none
+                raise NotImplementedError(
+                    f"{self.name}: rotary attention under a "
+                    f"sequence-sharded view is not supported")
+            qh = half_split_rotary(qh, theta).astype(cd)
+            kh = half_split_rotary(kh, theta).astype(cd)
 
         out = self._attention(ctx, qh, kh, vh)  # [b, sq, h, d]
         y = jnp.einsum("bshd,hde->bse", out, wo, preferred_element_type=jnp.float32)
@@ -297,6 +339,8 @@ class MultiHeadAttentionOp(Operator):
         return OpSharding(inputs=(q_annot, kv_annot, kv_annot), weights=tuple(ws), outputs=(out,))
 
     def splittable_output_dims(self) -> Tuple[int, ...]:
+        if self.attrs.get("rope_theta"):
+            return (0,)  # a sequence shard would turn by local positions
         return (0, 1)  # batch and (new capability) sequence
 
     def max_replica_degree(self) -> int:

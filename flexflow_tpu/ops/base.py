@@ -165,6 +165,7 @@ class Operator:
         self,
         name: str,
         input_shapes: Sequence[ParallelTensorShape],
+        weights_of: Optional[str] = None,
         **attrs,
     ):
         self.name = name
@@ -172,8 +173,14 @@ class Operator:
             s.drop_parallelism() for s in input_shapes
         )
         self.attrs: Dict[str, Any] = dict(attrs)
+        if weights_of:
+            # absent unless set, so every op built before the key
+            # existed keeps its signature (cost cache, calibration)
+            self.attrs["weights_of"] = weights_of
         self.output_shapes: Tuple[ParallelTensorShape, ...] = tuple(self.infer())
-        self._weight_specs: Tuple[WeightSpec, ...] = tuple(self.weight_specs())
+        # an op that reads another's weights declares none of its own
+        self._weight_specs: Tuple[WeightSpec, ...] = (
+            () if weights_of else tuple(self.weight_specs()))
 
     # ``jax.named_scope`` of the op's lowering (``ff.mla``,
     # ``ff.moe.route`` ...): device time can be charged to it in a trace
@@ -181,13 +188,18 @@ class Operator:
     # ... and of the block of the model the builder put it in
     # (``FFModel.block_scope``: ``ff.mtp``), outside ``scope``
     block_scope: Optional[str] = None
+    # ... and the ``FFModel.remat_block`` it was added in, whose ops
+    # the lowering recomputes together under ``FFConfig.remat``
+    remat_block: Optional[int] = None
 
     @property
     def weights_key(self) -> str:
         """The entry of the parameter tree this op reads: its own name,
-        or — ``weights_of`` — the op whose weights it shares (it then
-        declares none of its own: one copy is initialised, optimised and
-        counted, and both readers' gradients add up in it)."""
+        or — ``weights_of``, which every weighted op takes — the op
+        whose weights it shares (it then declares none of its own: one
+        copy is initialised, optimised and counted, and every reader's
+        gradient adds up in it; ``FFModel`` refuses a sharer whose
+        ``weight_specs()`` differ from its owner's)."""
         return self.attrs.get("weights_of") or self.name
 
     # ---- hooks -----------------------------------------------------------

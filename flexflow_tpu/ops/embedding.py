@@ -60,9 +60,7 @@ class EmbeddingOp(Operator):
             out_dim=out_dim,
             aggr=aggr,
             param_dtype=param_dtype,
-            # absent unless set: ops built before the key existed keep
-            # their signature (cost cache, calibration)
-            **({"weights_of": weights_of} if weights_of else {}),
+            weights_of=weights_of,
         )
 
     def infer(self) -> Sequence[ParallelTensorShape]:
@@ -76,8 +74,6 @@ class EmbeddingOp(Operator):
 
     def weight_specs(self) -> Sequence[WeightSpec]:
         a = self.attrs
-        if a.get("weights_of"):
-            return ()  # another op's table (MTP re-embeds the next ids)
         return (
             WeightSpec(
                 "table",

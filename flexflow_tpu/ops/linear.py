@@ -80,9 +80,7 @@ class LinearOp(Operator):
             activation=activation,
             use_bias=use_bias,
             param_dtype=param_dtype,
-            # absent unless set, so every op built before the key
-            # existed keeps its signature (cost cache, calibration)
-            **({"weights_of": weights_of} if weights_of else {}),
+            weights_of=weights_of,
         )
 
     # ---- shapes ----------------------------------------------------------
@@ -99,8 +97,6 @@ class LinearOp(Operator):
         return self.input_shapes[0].sizes[-1]
 
     def weight_specs(self) -> Sequence[WeightSpec]:
-        if self.attrs.get("weights_of"):
-            return ()  # another op's kernel (an output head read twice)
         pd = DataType.from_any(self.attrs["param_dtype"])
         specs = [
             WeightSpec("kernel", (self.in_dim, self.attrs["out_dim"]), pd, self._kernel_init)

@@ -25,7 +25,7 @@ from flexflow_tpu.ops.base import (
 )
 
 
-def _batch_only(op: Operator, mv: MachineView) -> OpSharding:
+def batch_only(op: Operator, mv: MachineView) -> OpSharding:
     """Every input and output split over the batch dim alone."""
     b = mv.dim_degrees[0]
 
@@ -56,7 +56,7 @@ class ShiftOp(Operator):
         return [jnp.concatenate([x[:, by:], pad], axis=1)]
 
     def propagate(self, mv: MachineView) -> OpSharding:
-        return _batch_only(self, mv)
+        return batch_only(self, mv)
 
 
 @register_op
@@ -98,7 +98,7 @@ class NextTokenLossOp(Operator):
         return [logits]
 
     def propagate(self, mv: MachineView) -> OpSharding:
-        return _batch_only(self, mv)
+        return batch_only(self, mv)
 
     def flops(self) -> float:
         return 5.0 * self.input_shapes[1].num_elements

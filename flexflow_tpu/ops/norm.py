@@ -129,8 +129,10 @@ class RMSNormOp(Operator):
 
     op_type = OperatorType.RMSNORM
 
-    def __init__(self, name, input_shapes, eps: float = 1e-6):
-        super().__init__(name, input_shapes, eps=float(eps))
+    def __init__(self, name, input_shapes, eps: float = 1e-6,
+                 weights_of: str | None = None):
+        super().__init__(name, input_shapes, eps=float(eps),
+                         weights_of=weights_of)
 
     def infer(self) -> Sequence[ParallelTensorShape]:
         return (self.input_shapes[0],)

@@ -225,6 +225,32 @@ class StrategyPlan:
                 _RELINT[dim](self, config)
 
     # -- what the lowering could not take -------------------------------
+    def tie_views(self) -> None:
+        """One view for the ops of one ``weights_key``: an op that
+        reads another's weights (``weights_of``) takes its owner's
+        ``MachineView`` — same op type on the same shapes, so always
+        legal — instead of having the weights re-sharded to its own in
+        every step, unpriced (a sharer declares no weights, so the
+        search saw none).  Only within one device block: a placed
+        strategy's blocks stay as they were proposed.
+        ``stats["tied_views_moved"]`` counts them."""
+        if not self.strategy:
+            return
+        owners = {n.op.name: n for n in self.graph.nodes.values()}
+        moved = 0
+        for node in self.graph.nodes.values():
+            owner = owners.get(node.op.weights_key)
+            if (owner is None or owner is node
+                    or type(owner.op) is not type(node.op)
+                    or owner.op.input_shapes != node.op.input_shapes):
+                continue
+            view, own = (self.strategy.get(n.guid) for n in (owner, node))
+            if (view is not None and own != view
+                    and (own is None or own.start_part == view.start_part)):
+                self.strategy[node.guid] = view
+                moved += 1
+        self.stats["tied_views_moved"] = moved
+
     def drop_unexecutable(self, compiled) -> None:
         """Placed/pipelined lowerings manage their own grad paths and
         placement and do not run ``_sync_grads``: say so rather than

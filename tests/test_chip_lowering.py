@@ -182,6 +182,47 @@ def test_grouped_expert_products_compile_as_kernels_at_the_cells_widths(
     assert hlo.count(f'custom_call_target="{MOSAIC}"') == 9 + 2
 
 
+def test_remat_by_block_shrinks_a_looped_models_temporaries(v5e_device):
+    """A looped model (one stack run four times over tied weights)
+    compiled for the described v5e: recomputation by block
+    (``FFModel.remat_block``) holds little more than the residual stream
+    at each block's edge.  Recomputation per op does NOT shrink this
+    model (every large activation of a gated feed-forward is some
+    weighted op's input, which a per-op checkpoint saves): at the
+    cell's size it read 17.15 GB against 17.05 GB without and 12.70 GB
+    by block (PERF.md section 4) — that it covers the tied ops at all
+    is held by tests/test_ouro.py."""
+    import flexflow_tpu as ff
+    from flexflow_tpu.models import build_ouro
+
+    kw = dict(vocab=256, num_layers=2, hidden=256, num_heads=2, head_dim=128,
+              ff_dim=512, loop_steps=4, seq_len=256)
+    sh = jax.sharding.SingleDeviceSharding(v5e_device)
+    temps = {}
+    for name, remat in (("none", False), ("op", True), ("block", True)):
+        cfg = ff.FFConfig(batch_size=4, compute_dtype="bfloat16",
+                          num_devices=1, cost_cache_file="", remat=remat)
+        model = build_ouro(cfg, **kw)
+        if name == "op":
+            for node in model.graph.nodes.values():
+                node.op.remat_block = None
+        model.compile(optimizer=ff.AdamOptimizer(alpha=1e-3),
+                      loss_type="sparse_categorical_crossentropy", metrics=[])
+        compiled = model.compiled
+
+        def loss(params, ids):
+            logits, state = compiled.apply(params, model.state, [ids], None,
+                                           train=True)
+            return compiled._loss_from(logits, ids, state)
+
+        params = jax.tree.map(
+            lambda a: S(a.shape, a.dtype, sharding=sh), model.params)
+        program = jax.jit(jax.grad(loss)).lower(
+            params, S((4, 256), jnp.int32, sharding=sh)).compile()
+        temps[name] = program.memory_analysis().temp_size_in_bytes
+    assert temps["block"] < 0.5 * min(temps["op"], temps["none"]), temps
+
+
 def _pool_sized_producers(hlo: str, pool_elems: int):
     """HLO instructions of a compiled program whose result is an array
     of at least a pool leaf's element count, as (opcode, jax op name)."""

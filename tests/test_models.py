@@ -14,6 +14,7 @@ from flexflow_tpu.models import (
     build_inception_v3,
     build_mlp_unify,
     build_moe,
+    build_ouro,
     build_resnext50,
     build_transformer,
 )
@@ -127,6 +128,19 @@ def test_moe_tiny():
     # every expert has weights of its own
     fc1 = model.get_weight("expert_fc1", "kernel")
     assert fc1.shape == (4, 32, 16) and not np.allclose(fc1[0], fc1[1])
+
+
+def test_ouro_tiny():
+    """The looped language model under data parallelism on 8 devices:
+    two layers run three times hold two layers' weights."""
+    model = build_ouro(tiny_cfg(), vocab=64, num_layers=2, hidden=32,
+                       num_heads=2, head_dim=16, ff_dim=48, loop_steps=3,
+                       seq_len=16)
+    x = np.random.default_rng(8).integers(0, 64, (16, 16)).astype(np.int32)
+    fit_one(model, x, np.roll(x, -1, axis=1), metrics=())
+    assert "loop2_layer0_attn" not in model.params
+    assert model.get_weight("layer1_attn", "wq").shape == (32, 2, 16)
+    assert model.get_weight("exit_gate", "kernel").shape == (32, 1)
 
 
 def test_mlp_unify_tiny():

@@ -37,7 +37,15 @@ one jitted program serves an arbitrary request stream:
   paths START at the first token past the claimed cached prefix
   (prefill skips pages the trie already holds);
 * each ``step`` fills every live slot's next token through ONE decode
-  graph call, until ``max_new_tokens`` or EOS;
+  graph call, until ``max_new_tokens`` or EOS — and where the step
+  function's output CARRIES the frame's tokens (``FrameOutput``: the
+  greedy choice made on the device), ONE FRAME STAYS IN FLIGHT: frame
+  n+1 is composed from frame n's device tokens and dispatched before
+  frame n's [B] tokens are pulled and harvested, so the device works
+  under the host's harvest, eviction, admission and composition.  A
+  plain array of logits is harvested as it always was — synchronously,
+  ``argmax`` on the host.  The output decides, frame by frame; the
+  token streams are the same (tests/test_decode_ahead.py);
 * every frame emits a ``decode.frame`` obs event (admissions,
   evictions, live slots, pages in use, measured latency, predicted
   latency when the caller supplies the search's number) and the run
@@ -49,8 +57,26 @@ one jitted program serves an arbitrary request stream:
 
 The executor is deliberately decoupled from FFModel: it drives any
 ``step_fn(token_ids [B,1] i32, page_table [B,P] i32, seq_lens [B] i32)
--> logits [B, 1, V]``; ``compiled_decode_step`` builds that function
-from a compiled decode model (threading the KV-cache state dict
+-> output``, three NumPy arrays in.  **The ``step_fn`` contract.**  The
+output is the frame's logits [B, 1, V] (anything ``np.asarray`` takes;
+the token is their ``argmax``), or the frame's tokens ([B] or [B, 1]
+ids), or an object that carries ``tokens`` [B] beside its logits and
+pulls either only when asked (``FrameOutput``) — ``_host_tokens`` is
+the one rule.  Only a function whose outputs carry ``tokens`` is run
+ahead, and only it is ever handed an id of -1: "this row's id is the
+token YOUR LAST CALL chose for it".  The host supplies an id wherever
+it knows one — a prompt token, the last token of a newly admitted
+sequence.  Running ahead, an end by ``max_new_tokens`` is known a frame
+early (the host counts), so that sequence is not sent again and its
+slot is refilled for the very next frame; an end the host learns from
+the token (EOS) or decides after the dispatch (SLO preemption) finds
+one row of that sequence already in flight: its token is DROPPED
+(``decode.rows_dropped``), its K/V write lands in pages nothing
+dispatched earlier can read, and the EOS costs that sequence's slot one
+frame.  An output whose logits someone has already pulled (a tap that
+reads every frame) is harvested at once: that frame has run.
+``compiled_decode_step`` builds such a function from a compiled decode
+model (threading the KV-cache state dict and the last frame's tokens
 across calls, and handing the frame and the prefill chunk the SERVED
 weight tree — ``model.params`` cast to the compute dtype and laid out
 as the matmuls read it, once, not inside every call).
@@ -95,6 +121,13 @@ _PREFILL_TOKENS = METRICS.counter("decode.prefill_tokens")
 _PROMPT_TOKENS = METRICS.counter("decode.prompt_tokens")
 _PREFIX_HIT_TOKENS = METRICS.counter("decode.prefix_hit_tokens")
 _FRAME_S = METRICS.histogram("decode.frame_s")
+# one frame in flight: frames dispatched while the frame before them was
+# still unharvested (over ``decode.frames``: how often the executor runs
+# ahead — 0 for a step function whose output carries no tokens), and
+# rows of such frames whose token was thrown away because the sequence
+# had ended (EOS) or lost its slot (preemption) in the meantime
+_FRAMES_AHEAD = METRICS.counter("decode.frames_ahead")
+_ROWS_DROPPED = METRICS.counter("decode.rows_dropped")
 # the weight tree the two serving programs take (compiled_decode_step):
 # how often it was derived, its bytes and the bytes of ``model.params``
 _WEIGHT_PREPARES = METRICS.counter("decode.weight_prepares")
@@ -184,6 +217,62 @@ class _Live:
     admit_t: Optional[float] = None
     prefill_done_t: Optional[float] = None
     first_token_t: Optional[float] = None
+    # one frame in flight: frames dispatched for this sequence and not
+    # yet harvested (its next position is ``cached + ahead``); ``retired``
+    # once its slot and pages went back while its LAST frame was still in
+    # flight; ``closed`` once it finished or was preempted — a frame still
+    # in flight for it then carries a token nobody takes
+    ahead: int = 0
+    retired: bool = False
+    closed: bool = False
+
+
+@dataclass
+class _Frame:
+    """One composed frame: the arrays the step function is given, the
+    (slot, sequence) of every row that carries one and, once
+    dispatched, the step function's output and the instant it went."""
+
+    ids: np.ndarray
+    table: np.ndarray
+    lens: np.ndarray
+    rows: List[tuple]
+    out: object = None
+    t0: float = 0.0
+
+
+class FrameOutput:
+    """A frame's output with the token already chosen on the device:
+    ``tokens`` [B] int32 (greedy: argmax over the vocabulary, the first
+    index on a tie, as ``np.argmax`` has it) beside the ``logits``
+    [B, 1, V] they were taken from.  Both stay on the device until
+    someone asks: the executor pulls only ``tokens``; ``np.asarray(out)``
+    and ``out[...]`` pull the logits (a probe, a test), once —
+    ``pulled`` says that this has happened, so that frame has run."""
+
+    __slots__ = ("logits", "tokens", "_host")
+
+    def __init__(self, logits, tokens):
+        self.logits = logits
+        self.tokens = tokens
+        self._host = None
+
+    @property
+    def shape(self):
+        return self.logits.shape
+
+    @property
+    def pulled(self) -> bool:
+        return self._host is not None
+
+    def __array__(self, dtype=None, copy=None):
+        if self._host is None:
+            self._host = np.asarray(self.logits)
+        return (self._host if dtype is None
+                else self._host.astype(dtype, copy=False))
+
+    def __getitem__(self, index):
+        return self.__array__()[index]
 
 
 class PageAllocator:
@@ -356,6 +445,28 @@ class PageAllocator:
                 del self._children[parent]
 
 
+def _host_tokens(out) -> np.ndarray:
+    """The [B] tokens of a frame's output, on the host (this blocks
+    until the frame has run).  ONE rule: the output is the frame's
+    tokens ([B] or [B, 1] ids) — those it carries as ``tokens`` where it
+    has them beside its logits, and then only they are pulled — or it IS
+    the logits [B, 1, V], whose argmax over the vocabulary is the
+    token."""
+    tokens = np.asarray(getattr(out, "tokens", out))
+    if tokens.ndim == 3:
+        tokens = tokens.argmax(axis=-1)
+    return tokens.reshape(-1).astype(np.int32)
+
+
+def _in_flight(out) -> bool:
+    """The output's tokens are still on the device: it carries them,
+    and nobody has pulled the frame to the host yet (a tap that reads
+    every frame's logits has: that frame is over, and the executor
+    harvests it at once)."""
+    return (getattr(out, "tokens", None) is not None
+            and not getattr(out, "pulled", False))
+
+
 class ContinuousBatchingExecutor:
     """Admit ragged requests into fixed decode frames and drive the
     step function until every request completes."""
@@ -441,6 +552,13 @@ class ContinuousBatchingExecutor:
         self.expired: Dict[str, List[int]] = {}  # deadline-missed rids
         self.frame = 0
         self.frame_seconds: List[float] = []
+        # one frame in flight (see ``step``): the frame dispatched and
+        # not yet harvested, the sequences whose slot went back while
+        # their last frame was in it, and when the last frame's tokens
+        # reached the host
+        self._flight: Optional[_Frame] = None
+        self._retired: List[_Live] = []
+        self._harvested_t = 0.0
         self.total_admitted = 0
         self.total_evicted = 0
         self.total_expired = 0
@@ -542,6 +660,7 @@ class ContinuousBatchingExecutor:
         live = self.slots[i]
         self.allocator.free(live.pages)
         self.slots[i] = None
+        live.closed = True  # a frame in flight for it is dropped
         self.total_preempted += 1
         back = _Pending(
             req=live.req, seq=live.seq, priority=live.priority,
@@ -607,7 +726,11 @@ class ContinuousBatchingExecutor:
         order while the allocator can reserve a FULL per-sequence
         allotment; expired requests are refused first, and a
         strictly-higher-priority arrival may preempt the
-        lowest-priority live sequence when no allotment is free."""
+        lowest-priority live sequence when no allotment is free.  With
+        a frame in flight, a sequence whose LAST frame is in it gives
+        its slot and pages back first (``_retire_sent``)."""
+        if self._flight is not None:
+            self._retire_sent()
         self._expire(obs, tr)
         admitted = 0
         while self.queue:
@@ -723,33 +846,72 @@ class ContinuousBatchingExecutor:
         self.total_admitted += admitted
         return admitted
 
-    def _evict(self, obs: bool, tr: bool, now: float) -> int:
-        """Free finished sequences' pages and reopen their slots;
-        ``now`` is when the frame's tokens reached the host."""
-        evicted = 0
+    @staticmethod
+    def _sent(live: _Live) -> bool:
+        """The sequence's LAST frame is in flight: the host counts, so
+        an end by ``max_new_tokens`` is known a frame before its token
+        is."""
+        return bool(live.ahead) and (
+            live.cached + live.ahead
+            >= len(live.req.prompt) + live.req.max_new_tokens - 1)
+
+    def _retire_sent(self) -> None:
+        """Give back the slot and the pages of every sequence whose last
+        frame is the one in flight, so the coming admission can fill the
+        slot in the very next frame.  Sound because whatever is
+        dispatched from now on — a prefill chunk into those pages, an
+        idle row's scatter — runs AFTER the frame in flight (every
+        program takes the donated state the one before it returned).
+        The sequence waits in ``_retired`` for its last token."""
         for i, live in enumerate(self.slots):
-            if live is None:
-                continue
-            done_gen = live.generated >= live.req.max_new_tokens
-            eos = (live.req.eos_id is not None and live.generated > 0
-                   and live.tokens[-1] == live.req.eos_id)
-            if done_gen or eos:
-                self.finished[live.req.rid] = live.tokens[len(live.req.prompt):]
+            if live is not None and self._sent(live):
                 self.allocator.free(live.pages)
                 self.slots[i] = None
+                live.retired = True
+                self._retired.append(live)
+
+    def _evict(self, obs: bool, tr: bool, now: float) -> int:
+        """Close every sequence that ended in the frame just harvested
+        (``max_new_tokens`` or EOS): its tokens go to ``finished``, its
+        pages back and its slot reopens — unless ``_retire_sent`` did
+        that a frame ago; ``now`` is when the frame's tokens reached the
+        host."""
+        evicted = 0
+        for i, live in enumerate(self.slots):
+            if live is not None and self._ended(live):
+                self._finish(live, obs, tr, now, slot=i)
                 evicted += 1
-                self._record_request(live, now, obs)
-                if tr:
-                    tid = TRACER.trace_of(live.req.rid)
-                    if tid is not None:
-                        TRACER.end(tid, "decode", eos=eos,
-                                   tokens=live.generated)
-                        TRACER.finish_request(
-                            live.req.rid, outcome="finish",
-                            tokens=live.generated,
-                            preempted=live.preempted)
+        for live in self._retired:  # their last token has just come
+            assert self._ended(live), live.req.rid
+            self._finish(live, obs, tr, now)
+            evicted += 1
+        self._retired = []
         self.total_evicted += evicted
         return evicted
+
+    @staticmethod
+    def _ended(live: _Live) -> bool:
+        return (live.generated >= live.req.max_new_tokens
+                or (live.req.eos_id is not None and live.generated > 0
+                    and live.tokens[-1] == live.req.eos_id))
+
+    def _finish(self, live: _Live, obs: bool, tr: bool, now: float,
+                slot: Optional[int] = None) -> None:
+        self.finished[live.req.rid] = live.tokens[len(live.req.prompt):]
+        live.closed = True  # a frame in flight for it is dropped
+        if slot is not None:
+            self.allocator.free(live.pages)
+            self.slots[slot] = None
+        self._record_request(live, now, obs)
+        if tr:
+            tid = TRACER.trace_of(live.req.rid)
+            if tid is not None:
+                eos = (live.req.eos_id is not None and live.generated > 0
+                       and live.tokens[-1] == live.req.eos_id)
+                TRACER.end(tid, "decode", eos=eos, tokens=live.generated)
+                TRACER.finish_request(
+                    live.req.rid, outcome="finish",
+                    tokens=live.generated, preempted=live.preempted)
 
     def _record_request(self, live: _Live, now: float, obs: bool) -> None:
         """Close a finished request's lifecycle: queue wait
@@ -816,19 +978,23 @@ class ContinuousBatchingExecutor:
         BUS.emit("decode.request", **rec)
 
     # ------------------------------------------------------------------
-    def _compose_frame(self):
-        """The fixed-shape frame arrays for the CURRENT step: every
-        live slot contributes its next uncached token (a prompt token
-        still being prefilled, or the last generated token); idle slots
+    def _compose_frame(self) -> _Frame:
+        """The fixed-shape arrays of the NEXT frame to dispatch: every
+        live slot with a frame still to send contributes the token at
+        its next position, ``cached + ahead`` — a prompt token still
+        being prefilled or the last generated token where the host has
+        it, else -1: the token the frame in flight is choosing, which
+        the step function takes on the device (only a step function
+        whose output carries tokens is ever handed one).  Idle slots
         carry token 0 at length 0 — page_table rows of idle slots point
         at page 0 of live-anywhere pages, masked off by seq_lens=0."""
         b = self.max_seqs
         ids = np.zeros((b, 1), np.int32)
         table = np.zeros((b, self.pages_per_seq), np.int32)
         lens = np.zeros((b,), np.int32)
-        active = []
+        rows = []
         for i, live in enumerate(self.slots):
-            if live is None:
+            if live is None or self._sent(live):
                 # idle row: its scatter must land where no live
                 # sequence reads (see __init__ — own slot range when
                 # slot-aligned, the reserved scratch page otherwise)
@@ -839,49 +1005,102 @@ class ContinuousBatchingExecutor:
                 else:
                     table[i, :] = self._scratch_page
                 continue
-            active.append(i)
-            ids[i, 0] = live.tokens[live.cached]
+            rows.append((i, live))
+            pos = live.cached + live.ahead
+            ids[i, 0] = live.tokens[pos] if pos < len(live.tokens) else -1
             table[i, :len(live.pages)] = live.pages
-            lens[i] = live.cached
-        return ids, table, lens, active
+            lens[i] = pos
+        return _Frame(ids, table, lens, rows)
+
+    def _dispatch(self, frame: _Frame) -> _Frame:
+        frame.t0 = time.perf_counter()
+        frame.out = self.step_fn(frame.ids, frame.table, frame.lens)
+        for _, live in frame.rows:
+            live.ahead += 1
+        return frame
+
+    def has_work(self) -> bool:
+        """A request queued, a sequence live, or a frame in flight whose
+        tokens are not on the host yet."""
+        return bool(self.queue or self._flight is not None
+                    or any(s is not None for s in self.slots))
 
     def step(self) -> dict:
-        """One decode frame: admit, compose, dispatch, wait, harvest,
-        evict — each a ``ff.phase/serve.*`` child of the frame's
-        ``ff.phase/decode_frame`` span.  Returns the frame record (also
-        emitted as ``decode.frame``).  ``frame_seconds`` is dispatch +
-        wait.  Events and the request span tree cost exactly one
-        ``BUS.enabled`` and one ``TRACER.enabled`` read per frame when
-        they are off (test-enforced)."""
+        """One decode frame HARVESTED: admit, compose, dispatch, wait,
+        harvest, evict — each a ``ff.phase/serve.*`` child of the
+        frame's ``ff.phase/decode_frame`` span.  Returns the harvested
+        frame's record (also emitted as ``decode.frame``).
+
+        What is dispatched depends on what the step function's output
+        carries.  A plain array of logits: the frame composed here is
+        dispatched, waited for and harvested by this call, the token
+        chosen on the host — the synchronous loop.  An output that
+        carries ``tokens`` (``FrameOutput``): ONE FRAME STAYS IN FLIGHT
+        between calls.  This call composes the frame AFTER it — a row
+        that continues takes id -1, its token on the device — and
+        dispatches that BEFORE it blocks on the [B] tokens of the frame
+        in flight, so the device runs the next frame under harvest,
+        evict, the caller's bookkeeping and the next admit and compose.
+        (A call that finds nothing in flight dispatches two frames, the
+        second composed inside the dispatch span.)  A token still exists
+        only once it is on the host: ``generated``, ``tokens`` and
+        ``finished`` advance in the harvest of the frame that made it.
+        An end by ``max_new_tokens`` is known a frame ahead, so that
+        sequence is not sent again and its slot is refilled at once
+        (``_retire_sent``); an end by EOS or a preemption finds one row
+        already in flight, whose token is dropped (``decode.rows_dropped``)
+        and whose K/V lands where no live sequence reads (its own freed
+        pages, before anything dispatched later).  No frame is
+        dispatched ahead with no row to carry.
+
+        ``frame_seconds`` is the time the harvested frame had the
+        pipeline to itself: from its dispatch — or from the harvest of
+        the frame before it, where that came later — to its tokens on
+        the host; dispatch + wait in the synchronous loop, the step's
+        period with a frame in flight.  Events and the request span tree
+        cost exactly one ``BUS.enabled`` and one ``TRACER.enabled`` read
+        per frame when they are off (test-enforced)."""
         obs = BUS.enabled  # ONE check per frame gates every event
         tr = TRACER.enabled  # ditto for the request span tree
         with phase_span(annotate.DECODE_PHASE):
             with phase_span(_ADMIT):
                 admitted = self._admit(obs, tr)
             with phase_span(_COMPOSE):
-                ids, table, lens, active = self._compose_frame()
-            t0 = time.perf_counter()
+                frame = self._compose_frame()
             with phase_span(_DISPATCH):
-                out = self.step_fn(ids, table, lens)
-            with phase_span(_WAIT):
-                logits = np.asarray(out)
+                flight, self._flight = self._flight, None
+                if flight is None:
+                    flight = self._dispatch(frame)
+                    frame = None
+                    if _in_flight(flight.out):
+                        frame = self._compose_frame()
+                if frame is not None and frame.rows:
+                    self._flight = self._dispatch(frame)
+                    _FRAMES_AHEAD.inc()
+            with phase_span(_WAIT):  # blocks until the frame has run
+                out = flight.out
+                if getattr(out, "tokens", None) is None:
+                    out = np.asarray(out)  # the logits come to the host
+                else:
+                    np.asarray(out.tokens)  # only the [B] tokens do
             now = time.perf_counter()  # the frame's tokens are on the host
-            dt = now - t0
+            dt = now - max(flight.t0, self._harvested_t)
+            self._harvested_t = now
             self.frame_seconds.append(dt)
             _FRAME_S.observe(dt)
             with phase_span(_HARVEST):
-                generated = self._harvest(logits, active, now, tr)
+                generated = self._harvest(out, flight.rows, now, tr)
             with phase_span(_EVICT):
                 evicted = self._evict(obs, tr, now)
         _FRAMES.inc()
-        _ACTIVE_SLOT_FRAMES.inc(len(active))
+        _ACTIVE_SLOT_FRAMES.inc(len(flight.rows))
         _SLOT_FRAMES.inc(self.max_seqs)
-        _LIVE_PAGES.inc(int((lens // self.page_size + 1).sum()))
+        _LIVE_PAGES.inc(int((flight.lens // self.page_size + 1).sum()))
         _PAGE_SLOTS.inc(self.max_seqs * self.pages_per_seq)
         _TOKENS_GENERATED.inc(generated)
         rec = {
             "frame": self.frame,
-            "active": len(active),
+            "active": len(flight.rows),
             "admitted": admitted,
             "evicted": evicted,
             "pages_in_use": self.allocator.pages_in_use,
@@ -894,16 +1113,20 @@ class ContinuousBatchingExecutor:
         self.frame += 1
         return rec
 
-    def _harvest(self, logits, active, now: float, tr: bool) -> int:
-        """Greedy-sample the frame's logits on the host and advance
-        every active slot by one token; returns the tokens generated."""
-        next_tokens = logits[:, 0].argmax(axis=-1).astype(np.int32) \
-            if logits.ndim == 3 else logits[:, 0].astype(np.int32)
+    def _harvest(self, out, rows, now: float, tr: bool) -> int:
+        """Advance every row — (slot, sequence) — of the harvested
+        frame by the token its output holds for it (``_host_tokens``);
+        returns the tokens generated.  A row whose sequence was closed
+        after the frame went (EOS, preemption) is dropped."""
+        next_tokens = _host_tokens(out)
         generated = 0
-        for i in active:
-            live = self.slots[i]
+        for i, live in rows:
+            live.ahead -= 1
+            if live.closed:
+                _ROWS_DROPPED.inc()
+                continue
             live.cached += 1
-            if (self.prefix_sharing
+            if (self.prefix_sharing and not live.retired
                     and live.cached % self.page_size == 0):
                 # a page just filled — publish it so later admissions
                 # can claim it (generated tokens included: the stream
@@ -935,12 +1158,13 @@ class ContinuousBatchingExecutor:
 
     def run(self, requests: Sequence[DecodeRequest] = (),
             max_frames: int = 10_000) -> Dict[str, List[int]]:
-        """Drive frames until every submitted request finished (or the
-        frame cap trips — a stuck executor must fail loud, not spin).
+        """Drive frames until every submitted request finished and no
+        frame is in flight — every token on the host — (or the frame
+        cap trips — a stuck executor must fail loud, not spin).
         Returns rid -> generated token ids."""
         if requests:
             self.submit(requests)
-        while (self.queue or any(s is not None for s in self.slots)):
+        while self.has_work():
             if self.frame >= max_frames:
                 raise RuntimeError(
                     f"decode executor exceeded {max_frames} frames with "
@@ -1086,19 +1310,23 @@ class ContinuousBatchingExecutor:
 
 
 class _LiveState:
-    """``step.state``: a read-only window on the model's live state
-    dict under the historical key (``step.state["state"]``)."""
+    """``step.state``: a read-only window on the live state the frame
+    takes — the model's state dict and the step's last tokens — under
+    the historical key (``step.state["state"]``)."""
 
-    def __init__(self, model):
-        self._model = model
+    def __init__(self, live_state):
+        self._live_state = live_state
 
     def __getitem__(self, key):
         if key != "state":
             raise KeyError(key)
-        return self._model.state
+        return self._live_state()
 
 
 _KV_LEAVES = ("k_cache", "v_cache", "k_scale", "v_scale")
+# the state leaf that carries a frame's chosen tokens [B] into the next
+# frame (``compiled_decode_step``): not an op's state, so no "/" in it
+_LAST_TOKENS = "last_tokens"
 
 
 def _tree_bytes(tree) -> int:
@@ -1112,6 +1340,20 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
     per frame over the model's state dict (the caches are model state —
     compiler/lowering.py init_params placed them under the strategy's
     view).
+
+    THE TOKEN IS CHOSEN ON THE DEVICE.  ``step(ids, page_table,
+    seq_lens)`` returns a ``FrameOutput``: the frame's float32 logits
+    and their ``argmax`` over the vocabulary as [B] int32 (the first
+    index on a tie, as ``np.argmax``), both still on the device, the 64
+    bytes of tokens already on their way to the host.  The tokens also
+    stay with the step, as one more leaf of the state the next frame
+    takes (``last_tokens``; the model's own state dict never holds it):
+    a row whose id is -1 is fed the token the step's LAST call chose
+    for it, inside the frame program — no program is added, and a
+    continuing row's id never visits the host.  That is what lets
+    ``ContinuousBatchingExecutor`` keep one frame in flight (its
+    docstring has when a row's token is dropped, and that an end by EOS
+    costs a slot one frame).
 
     The state is DONATED into every program here, as the train step
     donates its own: the scatter writes the KV pool where it sits and
@@ -1143,7 +1385,10 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
     trees' bytes.
 
     ``step.frame_fn`` is the jitted frame itself (``(weights, state,
-    [ids, page_table, seq_lens])``), ``step.chunk_fn`` the jitted
+    [ids, page_table, seq_lens]) -> ((logits, tokens), state)``;
+    ``step.state["state"]`` is the state the step hands it: the
+    model's, and ``last_tokens``; over a state without that leaf every
+    id is taken as given), ``step.chunk_fn`` the jitted
     prefill chunk (``(weights, state, ids, positions, page_table)``);
     either takes ``step.weights`` — the program ``step()`` and
     ``step.prefill()`` run — or ``model.params``, whose fp32 [E, H, D]
@@ -1152,13 +1397,37 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
     what its decode attention lowered to — ``"pallas"`` or ``"xla"``,
     by ``DecodeAttentionOp.attention_path``'s rule."""
     import jax
+    import jax.numpy as jnp
 
     from flexflow_tpu.core.optype import OperatorType
 
     compiled = model.compiled
-    fn = jax.jit(
-        lambda p, s, ins: compiled.apply(p, s, ins, None, False),
-        donate_argnums=(1,))
+    # the chosen tokens, whole on every device of the mesh: a frame
+    # takes them back laid out as it handed them out (one program)
+    whole = jax.sharding.NamedSharding(compiled.mesh,
+                                       jax.sharding.PartitionSpec())
+
+    def frame(p, s, ins):
+        """``(logits, tokens), state``: the frame's logits and the
+        greedy token of each row.  Where the state carries the tokens
+        of the frame before (``_LAST_TOKENS``), a row whose id is -1 is
+        fed that token — it never left the device — and the state
+        handed back carries this frame's."""
+        ids, page_table, seq_lens = ins
+        s = dict(s)
+        last = s.pop(_LAST_TOKENS, None)
+        if last is not None:
+            ids = jnp.where(ids < 0, last[:, None], ids)
+        logits, s = compiled.apply(p, s, [ids, page_table, seq_lens],
+                                   None, False)
+        tokens = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+        if compiled._multi_device:
+            tokens = jax.lax.with_sharding_constraint(tokens, whole)
+        if last is not None:
+            s[_LAST_TOKENS] = tokens
+        return (logits, tokens), s
+
+    fn = jax.jit(frame, donate_argnums=(1,))
     owners = {n.op.name: n.op for n in model.graph.topo_order()}
 
     def serve_all(params):
@@ -1216,14 +1485,25 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
         with phase_span(annotate.FIRST_CALL_PHASE + program):
             return jitted(*args)
 
+    # the tokens the last frame chose, as the next frame's state carries
+    # them (donated with it: ``FrameOutput.tokens`` is another buffer)
+    slots = compiled._input_nodes[0].op.output_shapes[0].sizes[0]
+    last = {"tokens": jax.device_put(np.zeros((slots,), np.int32), whole)}
+
+    def live_state():
+        return {**model.state, _LAST_TOKENS: last["tokens"]}
+
     def step(ids, page_table, seq_lens):
-        logits, model.state = call(
+        (logits, tokens), state = call(
             "decode_frame", fn,
-            weights(), model.state, [ids, page_table, seq_lens])
-        return logits
+            weights(), live_state(), [ids, page_table, seq_lens])
+        last["tokens"] = state.pop(_LAST_TOKENS)
+        model.state = state
+        tokens.copy_to_host_async()  # 64 bytes, on their way at once
+        return FrameOutput(logits, tokens)
 
     weights()
-    step.state = _LiveState(model)  # tests inspect the live cache
+    step.state = _LiveState(live_state)  # tests inspect the live cache
     step.frame_fn = fn
     step.attention_path = "+".join(sorted(paths)) or None
 

@@ -136,12 +136,12 @@ class FleetExecutor:
 
     # ------------------------------------------------------------------
     def step(self) -> int:
-        """One frame on every replica that has live or queued work —
-        the interleaved mode the controller's elastic loop drives.
-        Returns how many replicas stepped."""
+        """One frame on every replica that has work — queued, live or
+        in flight — the interleaved mode the controller's elastic loop
+        drives.  Returns how many replicas stepped."""
         stepped = 0
         for ex in self.replicas:
-            if ex.queue or any(s is not None for s in ex.slots):
+            if ex.has_work():
                 ex.step()
                 stepped += 1
         return stepped

@@ -863,8 +863,13 @@ def test_donated_state_has_one_live_owner():
                       max_frames=120)
 
     def live():
-        assert chunked.state["state"] is m.state
-        assert plain.state["state"] is m.state
+        # what a step's frame takes: the model's live state and the
+        # step's own last tokens
+        for step in (chunked, plain):
+            state = dict(step.state["state"])
+            assert state.pop("last_tokens").shape == (4,)
+            assert state.keys() == m.state.keys()
+            assert all(state[k] is m.state[k] for k in state)
         assert not any(v.is_deleted() for v in m.state.values())
         return {k: np.asarray(v) for k, v in m.state.items()}
 
@@ -994,7 +999,7 @@ def _over_master(model, step):
     fp32 [E, H, D] tree, converted inside every call — behind the
     interface of ``step``."""
     def frame(ids, page_table, seq_lens):
-        logits, model.state = step.frame_fn(
+        (logits, _), model.state = step.frame_fn(
             model.params, model.state, [ids, page_table, seq_lens])
         return logits
 
@@ -1208,7 +1213,8 @@ def test_restored_params_are_served_anew(tmp_path):
 
     def frame_over(params):
         return np.asarray(step.frame_fn(  # the state is donated: a copy
-            params, jax.tree.map(jnp.copy, m.state), [ids, table, lens])[0])
+            params, jax.tree.map(jnp.copy, m.state),
+            [ids, table, lens])[0][0])
 
     want_old = frame_over(m.params)
     old = np.asarray(step(ids, table, lens))
@@ -1307,5 +1313,5 @@ def test_served_tree_keeps_the_masters_shardings():
     lens = np.zeros((8,), np.int32)
     got = np.asarray(step(ids, table, lens))
     m.state = jax.tree.map(jnp.zeros_like, m.state)
-    want, _ = step.frame_fn(m.params, m.state, [ids, table, lens])
+    (want, _), _ = step.frame_fn(m.params, m.state, [ids, table, lens])
     np.testing.assert_array_equal(got, np.asarray(want))

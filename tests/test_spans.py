@@ -18,6 +18,7 @@ from flexflow_tpu.obs.metrics import METRICS
 from flexflow_tpu.runtime.decode import (
     ContinuousBatchingExecutor,
     DecodeRequest,
+    FrameOutput,
 )
 
 SERVE_PHASES = ("admit", "compose", "dispatch", "wait", "harvest", "evict")
@@ -45,11 +46,28 @@ def synthetic_step(vocab=97):
     return step
 
 
-def run_executor(n_requests=5, prompt_len=9, new_tokens=3, **kw):
+def carrying_step(vocab=97):
+    """``synthetic_step`` with the token chosen where the logits are:
+    the output carries it, and an id of -1 is the token of the call
+    before — the executor then keeps one frame in flight."""
+    plain, last = synthetic_step(vocab), {}
+
+    def step(ids, table, lens):
+        ids = np.asarray(ids)
+        if (ids < 0).any():
+            ids = np.where(ids < 0, last["tokens"], ids)
+        logits = plain(ids, table, lens)
+        last["tokens"] = logits.argmax(-1).astype(np.int32)  # [B, 1]
+        return FrameOutput(logits, last["tokens"][:, 0])
+
+    return step
+
+
+def run_executor(n_requests=5, prompt_len=9, new_tokens=3, step=None, **kw):
     """The tiny size: 2 slots, 4-token pages, a 4-token chunk lane."""
     written = []
     ex = ContinuousBatchingExecutor(
-        synthetic_step(), max_seqs=2, page_size=4, pages_per_seq=4,
+        step or synthetic_step(), max_seqs=2, page_size=4, pages_per_seq=4,
         prefill_fn=lambda ids, pos, table: written.append(ids.shape),
         prefill_chunk=4, **kw)
     out = ex.run([DecodeRequest(rid=f"r{i}",
@@ -153,6 +171,29 @@ def test_every_serve_phase_has_one_sample_a_frame_inside_the_frame_span():
     # the chunk lane's dispatches are spans of their own, inside admit
     assert hist("serve.prefill_chunk_s").count == ex.prefill_chunks > 0
     assert hist("serve.prefill_chunk_s").sum <= hist("serve.admit_s").sum
+
+
+def test_with_a_frame_in_flight_every_phase_still_has_one_sample_a_frame():
+    """The output carries its tokens, so frame n+1 is dispatched before
+    frame n is harvested: every ``step`` still harvests ONE frame and
+    yields one sample of each phase under the frame's span — a step
+    that finds nothing in flight dispatches two frames inside its one
+    dispatch span, the last steps of a run none."""
+    ex = run_executor(n_requests=6, new_tokens=5, step=carrying_step())
+    assert ex.finished == run_executor(n_requests=6, new_tokens=5).finished
+    frames = ex.frame
+    assert METRICS.counter("decode.frames").value == 2 * frames  # two runs
+    ahead = METRICS.counter("decode.frames_ahead").value
+    assert 0.8 * frames <= ahead < frames
+    assert len(ex.frame_seconds) == frames
+    assert hist("serve.step_s").count == hist("decode.frame_s").count \
+        == 2 * frames
+    for phase in SERVE_PHASES:
+        assert hist(f"serve.{phase}_s").count == 2 * frames, phase
+    # frame_seconds: the time a frame had the pipeline to itself — the
+    # step's period with a frame in flight, so the sum is the run's time
+    assert hist("serve.wait_s").sum <= sum(ex.frame_seconds)
+    assert all(dt > 0 for dt in ex.frame_seconds)
 
 
 def test_counters_agree_with_the_executors_summary():

@@ -3,16 +3,54 @@
 from __future__ import annotations
 
 import contextlib
+import re
 import shutil
 import tempfile
 
+from benchmarks.harness import trace_reduce
+from benchmarks.harness.trace_reduce import instruction_name, op_family
+
 MOSAIC_TARGET = 'custom_call_target="tpu_custom_call"'
+# one instruction of a compiled program's text, and its metadata's op_name
+INSTRUCTION = re.compile(r"^\s*(?:ROOT )?(%?[\w.\-]+ = .*)$", re.MULTILINE)
+OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
 
 
 def mosaic_calls(compiled) -> int:
     """Mosaic (Pallas TPU) custom calls in a compiled program — what
     proves a kernel ran compiled, not interpreted or replaced."""
     return compiled.as_text().count(MOSAIC_TARGET)
+
+
+def scopes_of(compiled) -> dict:
+    """{instruction name: op_name} of every instruction in a compiled
+    program's text.  The ``op_name`` of an instruction's metadata holds
+    the ``jax.named_scope``s it was traced under
+    (``jit(_raw_step)/transpose(jvp(ff.exit))/.../reduce_sum``); an
+    instruction the compiler made without metadata maps to ``""``.  A
+    profiler's device events carry instruction names only, so this is
+    what charges device time to a scope (``readers.scope_time_share``)."""
+    scopes = {}
+    for text in INSTRUCTION.findall(compiled.as_text()):
+        found = OP_NAME.search(text)
+        scopes[instruction_name(text)] = found.group(1) if found else ""
+    return scopes
+
+
+def families_of(compiled) -> dict:
+    """{instruction name: ``trace_reduce.op_family``} of the same text:
+    a traced instruction whose family differs from the compiled one of
+    its name came from ANOTHER program (``fusion.7`` is some fusion in
+    every program), and ``readers.scope_time_share`` does not place it."""
+    return {instruction_name(text): op_family(text)
+            for text in INSTRUCTION.findall(compiled.as_text())}
+
+
+def scope_facts(compiled) -> dict:
+    """What a traced run's driver hands the scope readers among its
+    facts, of the program it compiled after the window."""
+    return {"scopes": scopes_of(compiled),
+            "scope_families": families_of(compiled)}
 
 
 def memory_analysis_bytes(compiled) -> dict:
@@ -56,8 +94,6 @@ class Tracer:
 
     def reduce(self):
         """The trace as ``trace_reduce.load`` gives it; the files go."""
-        from benchmarks.harness import trace_reduce
-
         try:
             return trace_reduce.load_file(trace_reduce.find_xplane(self.dir))
         finally:

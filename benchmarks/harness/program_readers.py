@@ -71,3 +71,16 @@ def one_minus_ratio(ctx, num: str, den: str, scale: float = 1.0):
     ``num`` does not cover."""
     r = ratio(ctx, num, den)
     return None if r is None else (1.0 - r) * scale
+
+
+def mean_outside(ctx, whole: str, part: str, scale: float = 1.0):
+    """(sum of histogram ``whole`` − sum of histogram ``part``) ÷ the
+    samples of ``whole``, times ``scale``: the mean of a span less the
+    child span inside it — a serving step's host work where ``part`` is
+    the wait for the device.  None where either has no sample."""
+    histograms = _snapshot(ctx).get("histograms", {})
+    outer, inner = histograms.get(whole), histograms.get(part)
+    if not outer or not inner or not outer.get("count") \
+            or not inner.get("count"):
+        return None
+    return (outer["sum"] - inner["sum"]) / outer["count"] * scale

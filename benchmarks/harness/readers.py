@@ -79,6 +79,61 @@ def flash_roofline_share(ctx):
     return need / flash / peak * 100.0
 
 
+UNPLACED_LIMIT = 0.10  # of busy time; past it a scope's share is not given
+
+
+def scope_seconds(ctx) -> dict:
+    """The traced device seconds by the ``op_name`` of the compiled
+    program's instruction of that name (``facts["scopes"]``, from
+    ``device.scopes_of``), worked out once a run: ``{"by_scope":
+    {op_name: seconds}, "unplaced_s": seconds}``.  An instruction is NOT
+    placed where the compiled text has no such name, gives it another
+    family (``facts["scope_families"]``: the event came from another
+    program) or no ``op_name``.  None where the driver handed no
+    scopes."""
+    if "scope_seconds" not in ctx:
+        scopes = ctx["facts"].get("scopes")
+        if not scopes:
+            return None
+        families = ctx["facts"]["scope_families"]
+        by_scope, unplaced = {}, 0.0
+        for name, sec in trace_reduce.seconds_by_name(ctx["trace"]).items():
+            scope = scopes.get(name)
+            if not scope or families[name] != ctx["trace"]["families"][name]:
+                unplaced += sec
+            else:
+                by_scope[scope] = by_scope.get(scope, 0.0) + sec
+        ctx["scope_seconds"] = {"by_scope": by_scope, "unplaced_s": unplaced}
+    return ctx["scope_seconds"]
+
+
+def scope_unplaced_share(ctx):
+    """Per cent of busy time the join could not place; None without
+    scopes."""
+    joined = scope_seconds(ctx)
+    if joined is None:
+        return None
+    return joined["unplaced_s"] / busy(ctx)["busy_s"] * 100.0
+
+
+def scope_time_share(ctx, prefixes):
+    """Device seconds of the traced instructions whose ``op_name`` holds
+    one of ``prefixes`` (a ``jax.named_scope``: ``ff.exit``, ``ff.moe.``)
+    over busy seconds, per cent.  None where the driver handed no
+    scopes, where no instruction is under the scope, or where more than
+    ``UNPLACED_LIMIT`` of busy time belongs to instructions the join
+    cannot place: a share of a part of the step is not reported."""
+    joined = scope_seconds(ctx)
+    if joined is None:
+        return None
+    inside = sum(sec for scope, sec in joined["by_scope"].items()
+                 if any(p in scope for p in prefixes))
+    busy_s = busy(ctx)["busy_s"]
+    if inside == 0.0 or joined["unplaced_s"] > UNPLACED_LIMIT * busy_s:
+        return None
+    return inside / busy_s * 100.0
+
+
 def frame_ms_p50(ctx):
     frames = ctx["facts"].get("window_frame_seconds")
     if not frames:

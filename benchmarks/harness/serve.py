@@ -351,8 +351,9 @@ def run(cell, seed: int, seconds: float, trace: bool, t_proc0: float,
             f"{np.median(run_['late']) * 1e3:.3f} ms, max "
             f"{max(run_['late']) * 1e3:.3f} ms over {len(run_['late'])}")
     ids, table, lens = idle_frame(config["slots"], harness["pages_per_seq"])
-    # the second compile of a program this process ran is a cache hit
-    frame = step.frame_fn.lower(model.params, step.state["state"],
+    # the frame as the window ran it, over the served weight tree: the
+    # second compile of a program this process ran is a cache hit
+    frame = step.frame_fn.lower(step.weights, step.state["state"],
                                 [ids, table, lens]).compile()
     calls = device.mosaic_calls(frame)
     expect_calls = harness["mosaic_calls"]["decode_frame"]
@@ -417,6 +418,9 @@ def run(cell, seed: int, seconds: float, trace: bool, t_proc0: float,
             "pool_itemsize": pool_itemsizes.pop(),
             "window_flops": window_flops, "window_s": window_s,
             "latency_ms": latency, "checks": checks,
+            # the FRAME's instructions alone: the traced tail also holds
+            # the prefill chunk's, under names of its own numbering
+            **(device.scope_facts(frame) if trace else {}),
         },
         "trace": tracer.reduce() if tracer is not None else None,
     }

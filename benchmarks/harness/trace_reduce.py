@@ -158,6 +158,18 @@ def op_seconds(trace: dict, match) -> float:
     return sum(per_dev) / len(per_dev)
 
 
+def seconds_by_name(trace: dict) -> Dict[str, float]:
+    """Device seconds of every instruction name, summed over its events,
+    mean over devices: what a join with the compiled program's text
+    (``device.scopes_of``) starts from."""
+    total: Dict[str, float] = {}
+    for dev in trace["devices"].values():
+        for name, _, dur in dev["ops"]:
+            total[name] = total.get(name, 0.0) + dur
+    k = len(trace["devices"])
+    return {name: sec / k for name, sec in total.items()}
+
+
 def ops_inside_modules(trace: dict, match) -> float:
     """Device BUSY seconds (mean over devices) of ops that start inside a
     module event ``match(name)`` accepts: the device time of one jitted

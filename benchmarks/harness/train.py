@@ -222,6 +222,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_proc0: float,
                   "steps_per_epoch": nb, "batch": batch, "seq_len": seq_len,
                   "window_flops": window_flops,
                   "window_s": window_s, "checks": checks,
-                  "traced_steps": nb if trace else 0},
+                  "traced_steps": nb if trace else 0,
+                  **(device.scope_facts(program) if trace else {})},
         "trace": tracer.reduce() if tracer is not None else None,
     }

@@ -94,6 +94,12 @@ def main(argv=None) -> int:
             if value is not None:
                 values[metric["name"]] = value
                 units[metric["name"]] = metric["unit"]
+        unplaced = readers.scope_unplaced_share(ctx)
+        if unplaced is not None:
+            print(f"[layer] name scopes: {unplaced:.3f} % of device busy "
+                  f"time is of instructions the compiled program's text "
+                  f"does not place (a scope's share is left out past "
+                  f"{readers.UNPLACED_LIMIT:.0%})")
         device = result.device_facts(readers.busy(ctx))
         breakdown = trace_reduce.breakdown(trace)
     else:

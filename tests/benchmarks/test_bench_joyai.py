@@ -156,9 +156,33 @@ def tiny_out():
                                             "tiny-joyai-train.json")),
         end_to_end=[], per_layer=[], run_seconds=1)
     lines = []
-    out = train.run(cell, SEED, 0.3, False, time.perf_counter(),
+    # traced, as a ``--trace 1`` run is: one more epoch under the
+    # profiler, then the compiled step's name scopes among the facts
+    out = train.run(cell, SEED, 0.3, True, time.perf_counter(),
                     log=lines.append)
     return cell, out, lines
+
+
+def test_the_traced_tiny_run_hands_the_expert_scopes_to_the_reader(tiny_out):
+    """``train.moe_time_share`` charges device time to the scopes its
+    file names; the compiled step's text has instructions under each of
+    the four, forward and — but for the router, which takes no gradient
+    in a share — transposed.  (No device plane on a CPU: the share itself
+    is a chip's to read.)"""
+    _, out, _ = tiny_out
+    metric = spec.load_json(os.path.join(
+        BDIR, "layer_metrics", "train.moe_time_share.json"))
+    assert metric["reader"] == "benchmarks.harness.readers:scope_time_share"
+    # XLA:TPU's grouped-product kernels lose the scope (their op_name is
+    # ``ragged-dot-none``): the file names them beside it
+    assert metric["args"] == {"prefixes": ["ff.moe.", "ragged-dot"]}
+    facts = out["facts"]
+    assert set(facts["scopes"]) == set(facts["scope_families"])
+    for part in ("route", "dispatch", "experts", "combine"):
+        under = [s for s in facts["scopes"].values() if f"ff.moe.{part}" in s]
+        assert under, part
+        assert any("transpose(" in s for s in under) == (part != "route"), part
+    assert out["trace"]["devices"] == {}
 
 
 def test_the_tiny_preset_is_correct_against_the_plain_reference(tiny_out):

@@ -6,12 +6,10 @@ through the training driver on the CPU — ``correct`` against the plain
 reference (benchmarks/reference/ouro.py) included, and every per-layer
 metric of the cell that a CPU can give.
 
-The two looped-stack ratios (``LOOP_RATIOS``) are NOT per-layer metrics
-of the benchmark yet: a program PR appends to ``per_layer``, and the
-benchmark's own test_bench_live_pages.py wants ``serve.live_page_share``
-last, so the entries wait for a ``benchmark`` PR (PERF.md section 7).
-The ``loop.*`` counters they would read are held here through the
-reader such a metric has to use."""
+The two looped-stack ratios (``train.loop_expected_exit_step``,
+``train.loop_exit_entropy``; ``LOOP_RATIOS`` reads their files) are
+per-layer metrics of the cell since PR 34, over the ``loop.*`` device
+counters the exit objective publishes."""
 
 import copy
 import os
@@ -26,14 +24,20 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 BDIR = os.path.join(ROOT, "benchmarks")
 CELL = "ouro-2.6b.train-seq4096"
-# what ``train.loop_expected_exit_step`` (steps) and
-# ``train.loop_exit_entropy`` (nats) would hand ``program_readers:ratio``
+LOOP_METRICS = ("train.loop_expected_exit_step", "train.loop_exit_entropy")
+# what each of the two metrics' files hands ``program_readers:ratio``
 LOOP_RATIOS = {
-    "expected_exit_step": {"num": "loop.exit_step_milli",
-                           "den": "loop.gated_tokens", "scale": 0.001},
-    "exit_entropy": {"num": "loop.exit_entropy_milli",
-                     "den": "loop.gated_tokens", "scale": 0.001},
-}
+    name: spec.load_json(os.path.join(BDIR, "layer_metrics",
+                                      name + ".json"))["args"]
+    for name in LOOP_METRICS}
+# the lists the cell joined in PR 32; later PRs add to them, none leaves
+JOINED_IN_PR32 = {
+    "train_tokens_per_s", "train.step_ms", "train.device_idle_share",
+    "train.flash_time_share", "train.flash_roofline_share",
+    "train.data_ms_per_step", "train.dispatch_ms_per_step", "train.mfu",
+    "setup.phase_s.native_build", "setup.phase_s.search",
+    "setup.phase_s.lower", "setup.jax_compile_requests",
+    "setup.jax_compile_s", "setup.jax_cache_misses"}
 SEED = 2 ** 31 + 13  # the driver's seeds are large
 
 
@@ -100,28 +104,45 @@ def test_the_cell_resolves_with_the_metrics_it_joined(config):
     assert not any(n.startswith(("serve.", "train.moe_")) for n in names)
 
 
-def test_the_cell_joins_the_training_lists_and_nothing_the_benchmark_had_moves():
-    bench = spec.load_benchmark(ROOT)
-    assert bench["configs"][-1]["name"] == "ouro-2.6b-train"
-    assert bench["workloads"][-1] == {
-        "name": CELL, "config": "ouro-2.6b-train", "traffic": "train-seq4096",
-        "chips": 1, "why": bench["workloads"][-1]["why"]}
-    # the benchmark's own test wants this one last, so no entry is added
-    assert bench["per_layer"][-1]["name"] == "serve.live_page_share"
-    assert not any("loop" in m["name"] for m in bench["per_layer"])
-    assert not any(name.startswith("train.loop") for name in os.listdir(
-        os.path.join(BDIR, "layer_metrics")))
-    # every list that held both training cells now holds three
-    joined = []
+def test_the_cell_joins_the_training_lists_and_nothing_the_benchmark_had_moves(
+        bench_root, named):
+    """Every entry is found by its name, wherever it stands: on the real
+    file, and on a copy to which a later PR appended a configuration, a
+    cell and a metric of its own (conftest.py)."""
+    bench = spec.load_benchmark(bench_root)
+    held = named(bench["configs"], "ouro-2.6b-train")
+    assert held == {
+        "name": "ouro-2.6b-train", "why": held["why"],
+        "source": "https://huggingface.co/ByteDance/Ouro-2.6B/blob/main/"
+                  "config.json",
+        "file": "benchmarks/configs/ouro-2.6b-train.json",
+        "reduced": ["num_hidden_layers", "layer_types"]}
+    entry = named(bench["workloads"], CELL)
+    assert entry == {"name": CELL, "config": "ouro-2.6b-train",
+                     "traffic": "train-seq4096", "chips": 1,
+                     "why": entry["why"]}
+    # the two looped-stack metrics are the cell's alone, each by a file
+    for name in LOOP_METRICS:
+        metric = named(bench["per_layer"], name)
+        assert metric["workloads"] == [CELL]
+        assert (metric["layer"], metric["moves"], metric["source"]) == (
+            "looped stack", "train_tokens_per_s", "program_counter")
+        assert os.path.isfile(os.path.join(bench_root, "benchmarks",
+                                           "layer_metrics", name + ".json"))
+    # the cell is in every list that holds both older training cells —
+    # how many there are is the file's to say — and in no serving list
+    serving = {w["name"] for w in bench["workloads"]
+               if w["config"] == "opt-350m-serve"}
+    joined = set()
     for m in bench["end_to_end"] + bench["per_layer"]:
         cells = m.get("workloads", [])
-        if "joyai-llm-flash.train-seq4096" in cells and (
-                "opt-350m.train-seq2048" in cells):
-            assert cells[-1] == CELL, m["name"]
-            joined.append(m["name"])
-        else:
-            assert CELL not in cells, m["name"]
-    assert len(joined) == 14 and "train_tokens_per_s" in joined
+        if {"joyai-llm-flash.train-seq4096",
+                "opt-350m.train-seq2048"} <= set(cells):
+            assert CELL in cells, m["name"]
+            joined.add(m["name"])
+        elif CELL in cells:
+            assert not serving & set(cells), m["name"]
+    assert JOINED_IN_PR32 <= joined
 
 
 def test_the_work_module_by_hand(config):
@@ -161,8 +182,9 @@ def test_the_loop_ratios_on_hand_made_counters():
     reg.counter("loop.gated_tokens").inc(3)
     reg.counter("loop.exit_step_milli").inc(1500 + 2250 + 4000)
     reg.counter("loop.exit_entropy_milli").inc(693 + 1386 + 0)
-    assert read("expected_exit_step", reg) == pytest.approx(7.75 / 3)
-    assert read("exit_entropy", reg) == pytest.approx(2.079 / 3)
+    assert read("train.loop_expected_exit_step", reg) == pytest.approx(
+        7.75 / 3)
+    assert read("train.loop_exit_entropy", reg) == pytest.approx(2.079 / 3)
     # a program without the counters (the parent) reads nothing
     assert all(read(name, MetricsRegistry()) is None for name in LOOP_RATIOS)
 
@@ -178,9 +200,29 @@ def tiny_out():
                                             "tiny-train.json")),
         end_to_end=[], per_layer=[], run_seconds=1)
     lines = []
-    out = train.run(cell, SEED, 0.3, False, time.perf_counter(),
+    # traced, as a ``--trace 1`` run is: one more epoch under the
+    # profiler, then the compiled step's name scopes among the facts
+    out = train.run(cell, SEED, 0.3, True, time.perf_counter(),
                     log=lines.append)
     return cell, out, lines
+
+
+def test_the_traced_tiny_run_hands_the_exit_scope_to_the_reader(tiny_out):
+    """``train.exit_chain_time_share`` charges device time to the scope
+    its file names; the compiled step's text has instructions under it
+    and under every loop step, forward and transposed.  (No device plane
+    on a CPU: the share itself is a chip's to read.)"""
+    _, out, _ = tiny_out
+    metric = spec.load_json(os.path.join(
+        BDIR, "layer_metrics", "train.exit_chain_time_share.json"))
+    assert metric["reader"] == "benchmarks.harness.readers:scope_time_share"
+    assert metric["args"] == {"prefixes": ["ff.exit"]}
+    facts = out["facts"]
+    assert set(facts["scopes"]) == set(facts["scope_families"])
+    for scope in ("ff.exit", "ff.loop1", "ff.loop2", "ff.loop3", "ff.loop4"):
+        under = [s for s in facts["scopes"].values() if scope in s]
+        assert under and any("transpose(" in s for s in under), scope
+    assert out["trace"]["devices"] == {}
 
 
 def test_the_tiny_preset_is_correct_against_the_plain_reference(tiny_out):
@@ -214,11 +256,11 @@ def test_the_tiny_preset_counts_epochs_and_prices_them_by_its_work_module(
 
 def test_the_tiny_run_gives_every_metric_of_the_cell_a_cpu_can(tiny_out):
     """The readers of the cell's program-side metrics find their spans
-    and counters in the run's registry; the two loop ratios, read as a
-    metric of them would be, equal a hand count over the counters ``fit``
-    published."""
+    and counters in the run's registry; the two loop metrics among them
+    equal a hand count over the counters ``fit`` published."""
     _, out, _ = tiny_out
-    steps = 1 + (1 + len(out["facts"]["epoch_seconds"])) * 3
+    # step 0, the warm-up epoch, the window's epochs and the traced one
+    steps = 1 + (1 + len(out["facts"]["epoch_seconds"]) + 1) * 3
     counters = METRICS.snapshot()["counters"]
     assert counters["loop.gated_tokens"] == steps * 2 * 127
     cell = spec.resolve_cell(ROOT, CELL)
@@ -229,9 +271,7 @@ def test_the_tiny_run_gives_every_metric_of_the_cell_a_cpu_can(tiny_out):
                 ctx, **metric.get("args", {}))
     assert {"train.data_ms_per_step", "train.dispatch_ms_per_step",
             "setup.phase_s.search", "setup.phase_s.lower",
-            "setup.jax_compile_requests"} <= set(values)
-    for name, args in LOOP_RATIOS.items():
-        values["train.loop_" + name] = program_readers.ratio(ctx, **args)
+            "setup.jax_compile_requests", *LOOP_METRICS} <= set(values)
     # native_build and the jax_* cache metrics depend on the process
     # (a library already loaded, a cache that is off in tests)
     for name, value in values.items():

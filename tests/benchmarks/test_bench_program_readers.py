@@ -54,6 +54,16 @@ def test_ratio_and_its_complement(filled):
     assert pr.ratio(filled, "decode.prefill_tokens", "decode.frames") == 0.0
 
 
+def test_mean_outside_is_a_spans_mean_less_its_childs(filled):
+    # three steps of 40 ms of which the host waited 30, 34 and 32
+    assert pr.mean_outside(filled, "serve.step_s", "serve.wait_s",
+                           1e3) == pytest.approx((120 - 96) / 3)
+    assert pr.mean_outside(filled, "serve.step_s", "serve.evict_s") is None
+    assert pr.mean_outside(filled, "no.such_s", "serve.wait_s") is None
+    assert pr.mean_outside(ctx_of(MetricsRegistry()), "serve.step_s",
+                           "serve.wait_s") is None
+
+
 def test_total_adds_counters_and_histogram_sums(filled):
     assert pr.total(filled, ["setup.lower_s", "setup.init_params_s"]) \
         == pytest.approx(1.75)
@@ -141,8 +151,8 @@ def test_a_program_metric_resolves_for_its_cells_and_reads(metric):
     # number; on an empty one, None
     reg = MetricsRegistry()
     args = metric["args"]
-    names = args.get("names") or [args[k] for k in ("name", "num", "den")
-                                  if k in args]
+    names = args.get("names") or [
+        args[k] for k in ("name", "num", "den", "whole", "part") if k in args]
     for name in names:
         if name.endswith("_s"):
             reg.histogram(name).observe(0.5)

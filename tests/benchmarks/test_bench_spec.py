@@ -1,12 +1,12 @@
 """BENCHMARK.json against the contract's shape, and the harness as data:
 a cell, a configuration, a traffic mix and a per-layer metric are each
 added by new files plus one new entry, with no edit to a file that is
-there."""
+there.  The contract tests run twice (``bench_root``, conftest.py): on
+the real checkout, and on a copy in which such entries were appended."""
 
 import json
 import os
 import re
-import shutil
 
 import pytest
 
@@ -19,9 +19,18 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
+TRAIN_CELL = "opt-350m.train-seq2048"
+
+
 @pytest.fixture(scope="module")
 def bench():
     return spec.load_benchmark(ROOT)
+
+
+@pytest.fixture
+def contract(bench_root):
+    """(checkout, its ``BENCHMARK.json``): real, then real + appended."""
+    return bench_root, spec.load_benchmark(bench_root)
 
 
 def cells_of(metric, bench):
@@ -29,11 +38,12 @@ def cells_of(metric, bench):
                or [w["name"] for w in bench["workloads"]])
 
 
-def test_top_level_keys_and_limits(bench):
+def test_top_level_keys_and_limits(contract):
+    root, bench = contract
     assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
     assert 1 <= bench["run_seconds"] <= 51
-    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 64 * 1024
+    assert os.path.getsize(os.path.join(root, "BENCHMARK.json")) < 64 * 1024
     # a full check with 24 cells must fit into 43200 s
     n, rs = 24, bench["run_seconds"]
     assert (2 + 14 * n) * (rs + 60) + n * 2 * 90 + 1200 <= 43200
@@ -41,7 +51,9 @@ def test_top_level_keys_and_limits(bench):
     assert four <= max(1, len(bench["workloads"]) // 4)
 
 
-def test_entries_have_just_the_contract_keys(bench):
+def test_entries_have_just_the_contract_keys(contract):
+    root, bench = contract
+
     def one_line(text):
         return 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text
 
@@ -51,7 +63,7 @@ def test_entries_have_just_the_contract_keys(bench):
         assert one_line(c["why"]) and one_line(c["source"])
         assert len(c["reduced"]) <= 16
         assert c["file"].startswith(tuple(p + "/" for p in bench["paths"]))
-        assert os.path.isfile(os.path.join(ROOT, c["file"]))
+        assert os.path.isfile(os.path.join(root, c["file"]))
     for w in bench["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         assert w["chips"] in (1, 4) and one_line(w["why"])
@@ -66,7 +78,8 @@ def test_entries_have_just_the_contract_keys(bench):
         assert m["source"] in SOURCES and one_line(m["layer"])
 
 
-def test_names_and_units_use_the_allowed_characters(bench):
+def test_names_and_units_use_the_allowed_characters(contract):
+    _, bench = contract
     names = []
     for group in ("configs", "workloads", "end_to_end", "per_layer"):
         names += [e["name"] for e in bench[group]]
@@ -85,14 +98,16 @@ def test_names_and_units_use_the_allowed_characters(bench):
     assert len(set(pairs)) == len(pairs)
 
 
-def test_every_moves_names_a_metric_its_cells_report(bench):
+def test_every_moves_names_a_metric_its_cells_report(contract):
+    _, bench = contract
     e2e = {m["name"]: m for m in bench["end_to_end"]}
     for m in bench["per_layer"]:
         assert m["moves"] in e2e, m
         assert cells_of(m, bench) <= cells_of(e2e[m["moves"]], bench), m
 
 
-def test_every_cell_reports_setup_and_more(bench):
+def test_every_cell_reports_setup_and_more(contract):
+    _, bench = contract
     used_configs = set()
     for w in bench["workloads"]:
         reported = [m["name"] for m in bench["end_to_end"]
@@ -104,15 +119,16 @@ def test_every_cell_reports_setup_and_more(bench):
     assert used_configs == {c["name"] for c in bench["configs"]}
 
 
-def test_every_cell_resolves_and_its_files_agree(bench):
+def test_every_cell_resolves_and_its_files_agree(contract):
+    root, bench = contract
     for w in bench["workloads"]:
-        cell = spec.resolve_cell(ROOT, w["name"])
+        cell = spec.resolve_cell(root, w["name"])
         assert cell.traffic["kind"] in spec.DRIVERS
         assert cell.per_layer and cell.end_to_end
         for metric in cell.per_layer:
             assert callable(spec.resolve_dotted(metric["reader"]))
     for c in bench["configs"]:
-        with open(os.path.join(ROOT, c["file"])) as f:
+        with open(os.path.join(root, c["file"])) as f:
             config = json.load(f)
         assert config["source"] == c["source"]
         assert sorted(config["reduced"]) == sorted(c["reduced"])
@@ -165,67 +181,34 @@ def test_unknown_names_fail(bench):
 
 
 def test_a_cell_config_traffic_and_metric_are_added_by_files_alone(
-        tmp_path, bench):
-    """A later PR's move, in a temporary copy: new files under
-    benchmarks/ and new entries in BENCHMARK.json; no existing file of
-    benchmarks/ is edited, and the harness finds each by name."""
-    root = str(tmp_path)
-    shutil.copytree(os.path.join(ROOT, "benchmarks"),
-                    os.path.join(root, "benchmarks"),
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    before = {p: open(p, "rb").read() for p in
-              (os.path.join(d, f) for d, _, fs in
-               os.walk(os.path.join(root, "benchmarks")) for f in fs)}
-
-    def put(rel, obj):
-        with open(os.path.join(root, "benchmarks", rel), "w") as f:
-            json.dump(obj, f)
-
-    config = json.load(open(os.path.join(ROOT, bench["configs"][0]["file"])))
-    config["name"] = "dummy-model"
-    put("configs/dummy-model.json", config)
-    put("traffic/dummy-traffic.json",
-        {"kind": "open_loop", "rate_per_s": 3, "arrival": "poisson",
-         "warmup_s": 1, "prompt_tokens": {"dist": "const", "value": 8},
-         "max_new_tokens": {"dist": "const", "value": 4}, "why": "dummy"})
-    put("layer_metrics/dummy.metric.json",
-        {"name": "dummy.metric", "unit": "s", "layer": "search + lowering",
-         "moves": "setup_s",
-         "reader": "benchmarks.harness.readers:compile_s"})
-    new = json.loads(json.dumps(bench))
-    new["configs"].append({"name": "dummy-model", "source": config["source"],
-                           "file": "benchmarks/configs/dummy-model.json",
-                           "reduced": list(config["reduced"]), "why": "x"})
-    new["workloads"].append({"name": "dummy-model.dummy-traffic",
-                             "config": "dummy-model",
-                             "traffic": "dummy-traffic", "chips": 1,
-                             "why": "x"})
-    new["per_layer"].append({"name": "dummy.metric", "unit": "s",
-                             "better": "lower", "source": "host_clock",
-                             "layer": "search + lowering",
-                             "moves": "setup_s",
-                             "workloads": ["dummy-model.dummy-traffic"]})
-    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
-        json.dump(new, f)
-
+        appended_checkout):
+    """A later PR's move, in a temporary copy (conftest.py): new files
+    under benchmarks/ and new entries in BENCHMARK.json; no existing file
+    of benchmarks/ is edited, and the harness finds each by name."""
+    root, before = appended_checkout
     cell = spec.resolve_cell(root, "dummy-model.dummy-traffic")
     assert cell.config["name"] == "dummy-model"
     assert cell.traffic["rate_per_s"] == 3
-    assert [m["name"] for m in cell.per_layer] == ["search.compile_s",
-                                                   "dummy.metric"]
-    assert [m["name"] for m in cell.end_to_end] == ["setup_s"]
+    names = [m["name"] for m in cell.per_layer]
+    assert names[0] == "search.compile_s" and names[-1] == "dummy.metric"
+    assert sum(n.startswith("setup.") for n in names) == 6
+    assert [m["name"] for m in cell.end_to_end] == ["serve_tokens_per_s",
+                                                    "setup_s"]
     for path, content in before.items():
-        assert open(path, "rb").read() == content, path
+        with open(path, "rb") as f:
+            assert f.read() == content, path
     # the cells that were there resolve as before
-    old = spec.resolve_cell(root, bench["workloads"][0]["name"])
-    assert "dummy.metric" not in [m["name"] for m in old.per_layer]
+    old, real = (spec.resolve_cell(r, TRAIN_CELL) for r in (root, ROOT))
+    assert [m["name"] for m in old.per_layer] == [
+        m["name"] for m in real.per_layer]
+    assert old.end_to_end == real.end_to_end
 
 
-def test_run_without_a_tpu_exits_nonzero_and_prints_no_result(capsys, bench):
+def test_run_without_a_tpu_exits_nonzero_and_prints_no_result(capsys):
     from benchmarks import run
 
-    rc = run.main(["--workload", bench["workloads"][0]["name"], "--seed",
-                   "1", "--seconds", "1", "--trace", "0"])
+    rc = run.main(["--workload", TRAIN_CELL, "--seed", "1", "--seconds", "1",
+                   "--trace", "0"])
     out = capsys.readouterr()
     assert rc != 0
     assert out.out == ""          # no result line, nothing else either

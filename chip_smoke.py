@@ -229,7 +229,8 @@ class Server:
 
     def frame_mosaic_calls(self) -> int:
         ids, table, lens = self.idle_frame()
-        return mosaic_calls(self.step.frame_fn, self.model.params,
+        # the program the server RUNS takes the served tree
+        return mosaic_calls(self.step.frame_fn, self.step.weights,
                             self.step.state["state"], [ids, table, lens])
 
     def fixed_frame_logits(self, prompt_lens=FIXED_FRAME_PROMPTS):

@@ -78,15 +78,25 @@ NEG_INF = -1e30
 # ---------------------------------------------------------------------------
 # dense masked reference (the oracle)
 # ---------------------------------------------------------------------------
-def dense_decode_reference(q, k_dense, v_dense, seq_lens, scale=None):
+def dense_decode_reference(q, k_dense, v_dense, seq_lens, scale=None,
+                           window: int = 0, starts=None):
     """Single-token decode attention against dense per-sequence KV.
 
     q [B, H, D], k_dense/v_dense [B, S_max, H, D], seq_lens [B] int32
     -> [B, H, D].  Positions >= seq_lens[b] are masked out.  Pure XLA,
     numerically the plain (not online) softmax — the reference both
-    the paged kernel and the gather fallback must match."""
+    the paged kernel and the gather fallback must match.
+
+    GROUPED heads: k_dense/v_dense may hold Hkv < H heads, query head h
+    reading key/value head ``h // (H // Hkv)``.  ``window`` W > 0: the
+    query (position ``seq_lens - 1``) sees the last W positions only.
+    ``starts`` [B]: row 0 of ``k_dense[b]`` is position ``starts[b]``
+    (a dense copy of the pages a window walk names), not 0."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    if window or starts is not None or q.shape[1] != k_dense.shape[2]:
+        return _dense_grouped_reference(q, k_dense, v_dense, seq_lens, scale,
+                                        window, starts)
     # HIGHEST: the TPU's default fp32 einsum rounds its operands to
     # bf16; an oracle for an fp32 kernel has to multiply in fp32
     hi = jax.lax.Precision.HIGHEST
@@ -99,6 +109,27 @@ def dense_decode_reference(q, k_dense, v_dense, seq_lens, scale=None):
     out = jnp.einsum("bhs,bshd->bhd", p, v_dense.astype(jnp.float32),
                      precision=hi)
     return out.astype(q.dtype)
+
+
+def _dense_grouped_reference(q, k_dense, v_dense, seq_lens, scale, window,
+                             starts):
+    b, hq, d = q.shape
+    hkv = k_dense.shape[2]
+    hi = jax.lax.Precision.HIGHEST
+    qg = q.astype(jnp.float32).reshape(b, hkv, hq // hkv, d)
+    s = jnp.einsum("bhgd,bshd->bhgs", qg, k_dense.astype(jnp.float32),
+                   precision=hi) * scale
+    pos = jnp.arange(k_dense.shape[1], dtype=jnp.int32)[None, :]
+    if starts is not None:
+        pos = pos + starts.astype(jnp.int32)[:, None]
+    mask = pos < seq_lens[:, None]
+    if window:
+        mask = mask & (pos >= seq_lens[:, None] - window)
+    s = jnp.where(mask[:, None, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bhgs,bshd->bhgd", p, v_dense.astype(jnp.float32),
+                     precision=hi)
+    return out.reshape(b, hq, d).astype(q.dtype)
 
 
 def gather_kv_pages(pages, page_table, num_heads: int):
@@ -461,3 +492,219 @@ def ragged_paged_attention(
             jax.default_backend() != "tpu")
     return _xla_ragged_paged(q, k_pages, v_pages, page_table, seq_lens,
                              float(scale))
+
+
+# ---------------------------------------------------------------------------
+# grouped heads, a window: the walk is handed in
+# ---------------------------------------------------------------------------
+# A layer whose query heads share key/value heads (Hq = G x Hkv) and, in
+# a WINDOW layer, see the last W positions only.  The caller names the
+# pages to walk: ``walk_table[b, i]`` is the pool page that holds
+# positions ``(starts[b] + i) * page_size ...`` of sequence b, for the
+# ``ceil(seq_lens[b] / page_size) - starts[b]`` pages from the window's
+# first to the tail — so one kernel serves a pool addressed by the
+# sequence's table (a global layer: ``starts`` 0, the table itself) and a
+# pool used as a ring (ops/decode_attention.py names the ring's pages),
+# and a page below the window is never requested.
+
+def _xla_grouped_paged(q, k_pages, v_pages, walk_table, seq_lens, starts,
+                       window, scale):
+    hkv = k_pages.shape[-1] // q.shape[-1]
+    k_dense = gather_kv_pages(k_pages, walk_table, hkv)
+    v_dense = gather_kv_pages(v_pages, walk_table, hkv)
+    return dense_decode_reference(q, k_dense, v_dense, seq_lens, scale,
+                                  window=window,
+                                  starts=starts * k_pages.shape[1])
+
+
+def _grouped_kernel(
+    table_ref, lens_ref, starts_ref,  # scalar-prefetch operands
+    q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, ring, *,
+    page_size: int, groups: int, window: int, scale: float,
+):
+    """Grid (B,), the pools in HBM, a ring of hand-issued page DMAs kept
+    full across sequences — ``_rpa_kernel``'s walk, over the pages
+    ``table_ref[b, 0 .. ceil(n / page) - starts[b])``.
+
+    q_ref [1, Hkv·Gp, D]: the query heads of key/value head h in rows
+    ``h·Gp ..`` (a group padded to Gp rows, whole sublane tiles).  A
+    page is [page, Hkv·D] as the pool holds it.  The heads meet on the
+    MXU: q is spread BLOCK-DIAGONALLY over the fused axis — row r keeps
+    its D lanes in the lanes of its own key/value head, zeros elsewhere
+    — so ``q_bd · pageᵀ`` is every head's scores [Hkv·Gp, page] in ONE
+    product and ``p · page`` every head's weighted values [Hkv·Gp,
+    Hkv·D], of which row r's own head's D lanes are read at the end; no
+    reshape of the fused axis, no per-head slicing inside the loop.  The
+    products run in the POOL's dtype (bf16: q and p rounded to it, fp32
+    accumulation; an fp32 pool multiplies at HIGHEST).  Rows below
+    ``n - window`` of the first page and at or past ``n`` of the tail
+    are masked at NEG_INF."""
+    b = pl.program_id(0)
+    nb = pl.num_programs(0)
+    depth = k_buf.shape[0]
+    n = lens_ref[b]
+    first = starts_ref[b]
+    bufs = (k_buf, v_buf)
+
+    def walk_pages(bi):
+        return pl.cdiv(lens_ref[bi], page_size) - starts_ref[bi]
+
+    def next_live(bi):
+        return jax.lax.while_loop(
+            lambda x: jnp.logical_and(
+                x < nb, walk_pages(jnp.minimum(x, nb - 1)) <= 0),
+            lambda x: x + 1, bi)
+
+    def copies(bi, j, slot):
+        page = table_ref[bi, j]
+        return [pltpu.make_async_copy(src.at[page], buf.at[slot],
+                                      sem.at[i, slot])
+                for i, (src, buf) in enumerate(zip((k_hbm, v_hbm), bufs))]
+
+    def request():
+        rb, rj = ring[0], ring[1]
+
+        @pl.when(rb < nb)
+        def _():
+            for c in copies(rb, rj, ring[2] % depth):
+                c.start()
+            ring[2] = ring[2] + 1
+            last = rj + 1 == walk_pages(rb)
+            ring[0] = jnp.where(last, next_live(rb + 1), rb)
+            ring[1] = jnp.where(last, 0, rj + 1)
+
+    @pl.when(b == 0)
+    def _fill():
+        ring[0] = next_live(0)
+        ring[1] = 0
+        ring[2] = 0
+        ring[3] = 0
+        for _ in range(depth - 1):
+            request()
+
+    q = q_ref[0].astype(jnp.float32)  # [Hkv·Gp, D]
+    rows, d = q.shape
+    hkv = rows // groups
+    width = hkv * d
+    exact = k_buf.dtype == jnp.float32
+    prec = jax.lax.Precision.HIGHEST if exact else None
+    row_head = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0) // groups
+    lane_head = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1) // d
+    q_bd = jnp.where(row_head == lane_head,
+                     jnp.concatenate([q] * hkv, axis=1), 0.0
+                     ).astype(k_buf.dtype)
+
+    def page_step(j, carry):
+        m_prev, l_prev, acc_prev = carry
+        request()
+        slot = ring[3] % depth
+        for c in copies(b, j, slot):
+            c.wait()
+        ring[3] = ring[3] + 1
+        k = k_buf[slot]  # [page, Hkv·D]
+        v = v_buf[slot]
+        s = jax.lax.dot_general(
+            q_bd, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=prec) * scale
+        pos = (first + j) * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        seen = pos < n
+        if window:
+            seen = jnp.logical_and(seen, pos >= n - window)
+        s = jnp.where(seen, s, NEG_INF)  # [Hkv·Gp, page]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_new = acc_prev * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32,
+            precision=prec)
+        return m_new, l_new, acc_new
+
+    _, l, acc = jax.lax.fori_loop(
+        0, walk_pages(b), page_step,
+        (jnp.full((rows, 1), NEG_INF, jnp.float32),
+         jnp.zeros((rows, 1), jnp.float32),
+         jnp.zeros((rows, width), jnp.float32)))
+    acc = acc / jnp.maximum(l, 1e-30)
+    # row r's own head: its D lanes of the fused axis
+    o_ref[0] = jnp.concatenate(
+        [acc[h * groups:(h + 1) * groups, h * d:(h + 1) * d]
+         for h in range(hkv)], axis=0).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("window", "scale", "interpret"))
+def _pallas_grouped_paged(q, k_pages, v_pages, walk_table, seq_lens, starts,
+                          window: int, scale: float, interpret: bool):
+    b, hq, d = q.shape
+    _, page_size, width = k_pages.shape
+    hkv = width // d
+    assert hkv * d == width and hq % hkv == 0, (k_pages.shape, q.shape)
+    g = hq // hkv
+    gp = -(-g // 8) * 8  # a group's rows: whole sublane tiles
+    qp = jnp.pad(q.reshape(b, hkv, g, d), ((0, 0), (0, 0), (0, gp - g), (0, 0))
+                 ).reshape(b, hkv * gp, d)
+    depth = _ring_depth(page_size * width * k_pages.dtype.itemsize)
+
+    def q_map(bi, *_):
+        return (bi, 0, 0)
+
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, hkv * gp, d), q_map), in_hbm, in_hbm],
+        out_specs=pl.BlockSpec((1, hkv * gp, d), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((depth, page_size, width), k_pages.dtype),
+            pltpu.VMEM((depth, page_size, width), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, depth)),
+            pltpu.SMEM((4,), jnp.int32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_grouped_kernel, page_size=page_size, groups=gp,
+                          window=window, scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv * gp, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="grouped_paged_attention",
+    )(walk_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
+      starts.astype(jnp.int32), qp, k_pages, v_pages)
+    return out.reshape(b, hkv, gp, d)[:, :, :g].reshape(b, hq, d)
+
+
+def grouped_kernel_applies(head_dim: int, page_size: int) -> bool:
+    """The shape rule of the grouped kernel: ON THE CHIP a head is whole
+    128-lane tiles (the block-diagonal q and the read of a head's own
+    lanes slice the fused axis at head boundaries) and a page whole
+    16-row tiles (a bf16 page buffer's sublane packing); the interpreter
+    takes any multiple of 8.  Anything else is served by the gather
+    path."""
+    if head_dim % 8 or page_size % 8:
+        return False
+    return jax.default_backend() != "tpu" or (
+        head_dim % 128 == 0 and page_size % 16 == 0)
+
+
+def grouped_paged_attention(q, k_pages, v_pages, walk_table, seq_lens,
+                            starts, window: int = 0, scale=None,
+                            use_kernel: bool = True):
+    """Paged-KV decode attention for grouped heads with a window:
+    q [B, Hq, D], k_pages/v_pages [P, page_size, Hkv·D], ``walk_table``
+    [B, n] int32 the pool pages to walk from logical page ``starts[b]``
+    on, ``seq_lens`` [B] live token counts -> [B, Hq, D].  ``window``
+    W > 0: positions below ``seq_lens - W`` are not seen (the caller
+    starts the walk at the window's first page).  The Pallas kernel
+    where ``grouped_kernel_applies`` and ``use_kernel``, the XLA gather
+    path otherwise — by shape alone."""
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    if use_kernel and grouped_kernel_applies(q.shape[-1], k_pages.shape[1]):
+        return _pallas_grouped_paged(
+            q, k_pages, v_pages, walk_table, seq_lens, starts, int(window),
+            float(scale), jax.default_backend() != "tpu")
+    return _xla_grouped_paged(q, k_pages, v_pages, walk_table, seq_lens,
+                              starts, int(window), float(scale))

@@ -221,6 +221,7 @@ class FFModel:
                         out_dim=out_dim, activation=activation, use_bias=use_bias,
                         kernel_initializer=kernel_initializer,
                         bias_initializer=bias_initializer,
+                        param_dtype=self.config.param_dtype,
                         weights_of=weights_of)
         return self._add_op(op, [input])[0]
 
@@ -272,6 +273,7 @@ class FFModel:
         op = O.EmbeddingOp(self._fresh_name("embedding", name), [self._shape_of(input)],
                            num_entries=num_entries, out_dim=out_dim, aggr=aggr,
                            kernel_initializer=kernel_initializer,
+                           param_dtype=self.config.param_dtype,
                            weights_of=weights_of)
         return self._add_op(op, [input])[0]
 
@@ -323,6 +325,38 @@ class FFModel:
             embed_dim=embed_dim, num_heads=num_heads, page_size=page_size,
             pages_per_seq=pages_per_seq, num_pages=num_pages,
             use_kernel=use_kernel, kernel_initializer=kernel_initializer)
+        return self._add_op(op, [hidden, page_table, seq_lens])[0]
+
+    def grouped_decode_attention(self, hidden: Tensor, page_table: Tensor,
+                                 seq_lens: Tensor, num_heads: int,
+                                 num_kv_heads: int, head_dim: int,
+                                 page_size: int = 16, pages_per_seq: int = 8,
+                                 num_pages: int = 0, window: int = 0,
+                                 ring_pages: int = 0,
+                                 rope_theta: Optional[float] = None,
+                                 qk_norm_eps: Optional[float] = None,
+                                 gated: bool = False, use_kernel: bool = True,
+                                 kv_dtype: str = "fp32",
+                                 kernel_initializer=None,
+                                 qk_norm_initializer=None,
+                                 name=None) -> Tensor:
+        """Decode attention with grouped query heads, per-head q/k norms,
+        rotary, a sliding window over a ring of pages and an output gate,
+        each by argument (ops/decode_attention.py
+        ``GroupedDecodeAttentionOp``); projections in
+        ``config.param_dtype``."""
+        op = O.GroupedDecodeAttentionOp(
+            self._fresh_name("decode_attention", name),
+            [self._shape_of(hidden), self._shape_of(page_table),
+             self._shape_of(seq_lens)],
+            num_heads=num_heads, num_kv_heads=num_kv_heads,
+            head_dim=head_dim, page_size=page_size,
+            pages_per_seq=pages_per_seq, num_pages=num_pages, window=window,
+            ring_pages=ring_pages, rope_theta=rope_theta,
+            qk_norm_eps=qk_norm_eps, gated=gated, use_kernel=use_kernel,
+            kv_dtype=kv_dtype, param_dtype=self.config.param_dtype,
+            kernel_initializer=kernel_initializer,
+            qk_norm_initializer=qk_norm_initializer)
         return self._add_op(op, [hidden, page_table, seq_lens])[0]
 
     def batch_matmul(self, A: Tensor, B: Tensor, a_seq_length_dim: int = -1,
@@ -450,7 +484,8 @@ class FFModel:
                               [self._shape_of(t) for t in inputs],
                               out_dim=out_dim, activation=activation,
                               use_bias=use_bias,
-                              kernel_initializer=kernel_initializer)
+                              kernel_initializer=kernel_initializer,
+                              param_dtype=self.config.param_dtype)
         return self._add_op(op, inputs)[0]
 
     def expert_combine(self, weights: Tensor, source: Tensor,

@@ -24,6 +24,7 @@ from flexflow_tpu.models.candle_uno import build_candle_uno
 from flexflow_tpu.models.moe import build_moe
 from flexflow_tpu.models.joyai_flash import build_joyai_flash
 from flexflow_tpu.models.ouro import build_ouro
+from flexflow_tpu.models.afmoe import build_afmoe_decode
 from flexflow_tpu.models.mlp import build_mlp_unify
 from flexflow_tpu.models.synthetic import build_moe_trunk, build_multibranch
 
@@ -49,6 +50,7 @@ __all__ = [
     "build_moe",
     "build_joyai_flash",
     "build_ouro",
+    "build_afmoe_decode",
     "build_moe_trunk",
     "build_multibranch",
     "build_mlp_unify",

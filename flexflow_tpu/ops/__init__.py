@@ -32,7 +32,10 @@ from flexflow_tpu.ops.norm import (
 from flexflow_tpu.ops.conv import Conv2DOp, Pool2DOp
 from flexflow_tpu.ops.embedding import EmbeddingOp
 from flexflow_tpu.ops.attention import BatchMatmulOp, MultiHeadAttentionOp
-from flexflow_tpu.ops.decode_attention import DecodeAttentionOp
+from flexflow_tpu.ops.decode_attention import (
+    DecodeAttentionOp,
+    GroupedDecodeAttentionOp,
+)
 from flexflow_tpu.ops.reductions import GatherOp, MeanOp, TopKOp
 from flexflow_tpu.ops.latent_attention import LatentAttentionOp
 from flexflow_tpu.ops.moe import (
@@ -87,6 +90,7 @@ __all__ = [
     "EmbeddingOp",
     "BatchMatmulOp",
     "DecodeAttentionOp",
+    "GroupedDecodeAttentionOp",
     "MultiHeadAttentionOp",
     "GatherOp",
     "MeanOp",

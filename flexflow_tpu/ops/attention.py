@@ -38,17 +38,22 @@ from flexflow_tpu.ops.base import (
 )
 
 
-def half_split_rotary(x, theta: float):
-    """Rotary embedding of ``x`` [B, S, H, D] at positions 0..S-1 in the
-    half-split convention: for i < D/2 the pair (x_i, x_{i+D/2}) turns
-    by position * theta^(-2i/D); in float32.  The angles are made in
-    the program (an iota times D/2 frequencies), not baked in as
+def half_split_rotary(x, theta: float, positions=None):
+    """Rotary embedding of ``x`` [B, S, H, D] at positions 0..S-1 — or
+    at ``positions`` [B, S], a decode frame's or a prefill chunk's — in
+    the half-split convention: for i < D/2 the pair (x_i, x_{i+D/2})
+    turns by position * theta^(-2i/D); in float32.  The angles are made
+    in the program (an iota times D/2 frequencies), not baked in as
     [S, D] constants an op."""
     s, d = x.shape[1], x.shape[-1]
     inv_freq = (theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
                 ).astype(np.float32)
-    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    angle = jnp.concatenate([angle, angle], axis=-1)[None, :, None, :]
+    if positions is None:
+        angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+        angle = jnp.concatenate([angle, angle], axis=-1)[None, :, None, :]
+    else:
+        angle = positions.astype(jnp.float32)[..., None] * inv_freq
+        angle = jnp.concatenate([angle, angle], axis=-1)[:, :, None, :]
     x = x.astype(jnp.float32)
     # lane i < D/2 gets -x[i + D/2], lane i + D/2 gets x[i]
     partner = jnp.concatenate([-x[..., d // 2:], x[..., :d // 2]], axis=-1)

@@ -40,7 +40,11 @@ import jax.numpy as jnp
 from flexflow_tpu.core.machine import MachineView
 from flexflow_tpu.core.optype import OperatorType
 from flexflow_tpu.core.ptensor import DataType, ParallelTensorShape
-from flexflow_tpu.initializers import DEFAULT_WEIGHT_INIT, Initializer
+from flexflow_tpu.initializers import (
+    DEFAULT_WEIGHT_INIT,
+    ConstantInitializer,
+    Initializer,
+)
 from flexflow_tpu.ops.base import (
     REPLICA_SLOT,
     LoweringContext,
@@ -150,6 +154,11 @@ class DecodeAttentionOp(Operator):
     @property
     def max_seq_len(self) -> int:
         return self.attrs["page_size"] * self.attrs["pages_per_seq"]
+
+    @property
+    def attended_len(self) -> int:
+        """Cached positions a query reads at most (the cost hooks')."""
+        return self.max_seq_len
 
     @property
     def kv_dtype(self) -> str:
@@ -470,7 +479,7 @@ class DecodeAttentionOp(Operator):
         bsz = self.max_seqs
         e, h, dk = a["embed_dim"], a["num_heads"], self.head_dim
         proj = 2.0 * bsz * e * h * dk * 4  # q, k, v, o projections
-        attn = 2.0 * bsz * h * self.max_seq_len * dk * 2
+        attn = 2.0 * bsz * h * self.attended_len * dk * 2
         return proj + attn
 
     # KV quantize-overhead pricing (the EQuARX discipline the cost
@@ -526,7 +535,7 @@ class DecodeAttentionOp(Operator):
         # activations + weights + the full-occupancy cache read (the
         # decode-dominant term: attention streams every live KV byte)
         base = super().bytes_accessed()
-        return base + (self.max_seqs * self.max_seq_len
+        return base + (self.max_seqs * self.attended_len
                        * self.kv_bytes_per_token())
 
     def sharded_bytes_accessed(self, mv: MachineView,
@@ -552,7 +561,7 @@ class DecodeAttentionOp(Operator):
             for d in ws.shape:
                 n *= d
             wbytes += n * ws.dtype.itemsize
-        live = self.max_seqs * self.max_seq_len
+        live = self.max_seqs * self.attended_len
         # attention streams each sequence's OWN pages (a prefix shared
         # in residency is still read once per attending sequence), so
         # the stream term never takes the shared-residency discount —
@@ -571,3 +580,370 @@ class DecodeAttentionOp(Operator):
                         * self.head_dim * 4.0)
             quant = self.KV_QUANT_PASSES * tok_fp32 / (b * r)
         return act / b + wbytes / r + kv + quant
+
+
+# keys a block of the chunk's attention (``forward_chunk``): the scores
+# of one block are [B, Hq, C, _CHUNK_KEY_BLOCK] float32
+_CHUNK_KEY_BLOCK = 512
+
+
+class GroupedDecodeAttentionOp(DecodeAttentionOp):
+    """Decode attention with GROUPED query heads (``num_heads`` query
+    heads share ``num_kv_heads`` key/value heads, six to one, say), an
+    RMS norm on q and on k per head, half-split rotary, a sliding WINDOW
+    and a gated output — each present or absent by attr, so one op
+    serves a model's window layers and its global layers:
+
+        q = Nq(x Wq) [Hq, D];  k = Nk(x Wk), v = x Wv [Hkv, D]
+        q, k turn by their positions              (``rope_theta``)
+        o = softmax(q·k / sqrt(D)) v over the last ``window`` positions
+            (``window`` 0: over all of them)
+        y = (sigmoid(x Wg) * o) Wo                (``gated``)
+
+    hidden [B, 1, E], page_table [B, pages_per_seq], seq_lens [B] ->
+    [B, 1, E], as ``DecodeAttentionOp`` (same ``op_type``: the runtime
+    finds decode ops by it).  The projections are declared FUSED —
+    ``wq``/``wg`` [E, Hq·D], ``wk``/``wv`` [E, Hkv·D], ``wo`` [Hq·D, E] —
+    and in ``param_dtype``, so where that is the compute dtype
+    ``serving_weights`` changes nothing and a server holds ONE tree.
+
+    TWO KINDS OF PAGE under one table row.  A pool is
+    [pages, page_size, Hkv·D].  A global layer (``window`` 0,
+    ``ring_pages`` 0): the pool has ``num_pages`` pages and logical page
+    j of a sequence is ``page_table[b, j]``.  A window layer
+    (``ring_pages`` R > 0): the pool has ``max_seqs · R`` pages,
+    sequence slot s owns pages [s·R, (s+1)·R) and logical page j lives
+    in ring page ``j mod R``; the slot is read off the row itself,
+    ``page_table[b, 0] // pages_per_seq`` — which holds for SLOT-ALIGNED
+    tables only (slot i owns pages [i·pps, (i+1)·pps): what
+    ``ContinuousBatchingExecutor`` composes where its pool covers every
+    slot and prefixes are not shared; it refuses to be built otherwise).
+    R must cover the window, a prefill chunk and one page:
+    a write at position p lands on the page of position p − R·page_size,
+    which then lies below every window that a query at or after
+    p − chunk can see.
+
+    A row that must not write — a full sequence's clamped position, a
+    prefill chunk's pad rows clamped to ``cap − 1``, which in a ring
+    would alias a LIVE page of the window — is kept out of the scatter
+    (its page index points past the pool, ``mode="drop"``)."""
+
+    def __init__(
+        self,
+        name,
+        input_shapes,
+        num_heads: int,
+        num_kv_heads: int,
+        head_dim: int,
+        page_size: int = 16,
+        pages_per_seq: int = 8,
+        num_pages: int = 0,
+        window: int = 0,
+        ring_pages: int = 0,
+        rope_theta: float | None = None,
+        qk_norm_eps: float | None = None,
+        gated: bool = False,
+        use_kernel: bool = True,
+        kv_dtype: str = "fp32",
+        param_dtype: str = "float32",
+        kernel_initializer: Initializer | None = None,
+        qk_norm_initializer: Initializer | None = None,
+    ):
+        assert num_heads % num_kv_heads == 0, (num_heads, num_kv_heads)
+        assert kv_dtype in ("fp32", "bf16"), kv_dtype
+        assert bool(window) == bool(ring_pages), (
+            "a window layer's pool is a ring, a global layer's the table's")
+        assert not ring_pages or (ring_pages <= pages_per_seq
+                                  and ring_pages * page_size
+                                  >= window + page_size), (
+            f"a ring of {ring_pages} pages of {page_size} cannot hold a "
+            f"window of {window}")
+        b = input_shapes[0].sizes[0]
+        num_pages = num_pages or b * pages_per_seq
+        assert num_pages >= b
+        self._kernel_init = kernel_initializer or DEFAULT_WEIGHT_INIT
+        self._qk_norm_init = qk_norm_initializer or ConstantInitializer(1.0)
+        self.scope = "ff.attn.window" if window else "ff.attn.global"
+        Operator.__init__(
+            self, name, input_shapes,
+            embed_dim=input_shapes[0].sizes[-1], num_heads=num_heads,
+            num_kv_heads=num_kv_heads, head_dim=head_dim,
+            page_size=page_size, pages_per_seq=pages_per_seq,
+            num_pages=num_pages, window=int(window),
+            ring_pages=int(ring_pages), rope_theta=rope_theta,
+            qk_norm_eps=qk_norm_eps, gated=bool(gated),
+            use_kernel=use_kernel, kv_dtype=kv_dtype,
+            param_dtype=param_dtype)
+
+    @property
+    def head_dim(self) -> int:
+        return self.attrs["head_dim"]
+
+    @property
+    def kv_heads(self) -> int:
+        return self.attrs["num_kv_heads"]
+
+    @property
+    def pool_pages(self) -> int:
+        """Pages of THIS layer's pool: a ring a sequence slot, or the
+        table's."""
+        r = self.attrs["ring_pages"]
+        return self.max_seqs * r if r else self.attrs["num_pages"]
+
+    @property
+    def attended_len(self) -> int:
+        return min(self.max_seq_len, self.attrs["window"] or self.max_seq_len)
+
+    def weight_specs(self) -> Sequence[WeightSpec]:
+        a = self.attrs
+        e, d = a["embed_dim"], self.head_dim
+        hq, hkv = a["num_heads"] * d, self.kv_heads * d
+        pd = DataType.from_any(a["param_dtype"])
+        specs = [WeightSpec("wq", (e, hq), pd, self._kernel_init),
+                 WeightSpec("wk", (e, hkv), pd, self._kernel_init),
+                 WeightSpec("wv", (e, hkv), pd, self._kernel_init),
+                 WeightSpec("wo", (hq, e), pd, self._kernel_init)]
+        if a["gated"]:
+            specs.append(WeightSpec("wg", (e, hq), pd, self._kernel_init))
+        if a["qk_norm_eps"] is not None:
+            specs += [WeightSpec(n, (d,), DataType.FLOAT32, self._qk_norm_init)
+                      for n in ("q_norm", "k_norm")]
+        return specs
+
+    def state_specs(self):
+        shape = (self.pool_pages, self.attrs["page_size"],
+                 self.kv_heads * self.head_dim)
+        return [("k_cache", shape, self.pool_dtype, 0),
+                ("v_cache", shape, self.pool_dtype, 0)]
+
+    def attention_path(self, multi_device: bool) -> str:
+        from flexflow_tpu.kernels.ragged_paged_attention import (
+            grouped_kernel_applies,
+        )
+
+        if (self.attrs["use_kernel"] and not multi_device
+                and grouped_kernel_applies(self.head_dim,
+                                           self.attrs["page_size"])):
+            return "pallas"
+        return "xla"
+
+    def serving_weights(self, weights, compute_dtype):
+        """The projections in the compute dtype — their own arrays where
+        ``param_dtype`` already is; the norms' gains as they are."""
+        return {n: (w if n.endswith("_norm") else w.astype(compute_dtype))
+                for n, w in weights.items()}
+
+    # ---- lowering --------------------------------------------------------
+    def _project(self, x, positions, weights, cd):
+        """x [B, S, E] (compute dtype) at ``positions`` [B, S] -> q
+        [B, S, Hq, D] float32 (normed, turned), the K and V rows as the
+        pool holds them [B, S, Hkv·D] (K normed and turned: stored so)
+        and the output gate [B, S, Hq·D] or None."""
+        from flexflow_tpu.ops.attention import half_split_rotary
+        from flexflow_tpu.ops.norm import rms_norm
+
+        a = self.attrs
+        w = self.serving_weights(weights, cd)
+        names = ("wq", "wk", "wv") + (("wg",) if a["gated"] else ())
+        # the barrier as in ``DecodeAttentionOp._project``: the products
+        # stay plain matmuls, each rounded to the compute dtype
+        prods = jax.lax.optimization_barrier(
+            tuple(jnp.dot(x, w[n]) for n in names))
+        q, k, v = (p.astype(jnp.float32) for p in prods[:3])
+        lead, d = x.shape[:2], self.head_dim
+        q = q.reshape(*lead, a["num_heads"], d)
+        k = k.reshape(*lead, self.kv_heads, d)
+        if a["qk_norm_eps"] is not None:
+            q = rms_norm(q, w["q_norm"], a["qk_norm_eps"])
+            k = rms_norm(k, w["k_norm"], a["qk_norm_eps"])
+        if a["rope_theta"] is not None:
+            q = half_split_rotary(q, a["rope_theta"], positions)
+            k = half_split_rotary(k, a["rope_theta"], positions)
+        gate = (jax.nn.sigmoid(prods[3].astype(jnp.float32))
+                if a["gated"] else None)
+        return q, k.reshape(*lead, -1), v, gate
+
+    def _pages_of(self, page_table, logical):
+        """Pool pages of the logical pages ``logical`` [B, n] of each
+        row's sequence (class docstring: the table's, or the ring's)."""
+        a = self.attrs
+        r = a["ring_pages"]
+        if r:
+            slot = page_table[:, :1] // a["pages_per_seq"]
+            return slot * r + logical % r
+        return jnp.take_along_axis(
+            page_table, jnp.minimum(logical, a["pages_per_seq"] - 1), axis=1)
+
+    def _scatter(self, ctx, page_table, positions, k_rows, v_rows, writes):
+        """Put the rows [B, S, Hkv·D] at ``positions`` [B, S] into the
+        pool, but for the rows ``writes`` [B, S] excludes."""
+        ps = self.attrs["page_size"]
+        page = jnp.where(writes, self._pages_of(page_table, positions // ps),
+                         self.pool_pages)  # past the pool: dropped
+        slot = positions % ps
+        out = []
+        for leaf, rows in (("k_cache", k_rows), ("v_cache", v_rows)):
+            key = f"{self.name}/{leaf}"
+            pool = ctx.state_in[key]
+            pool = pool.at[page, slot].set(rows.astype(pool.dtype),
+                                           mode="drop")
+            ctx.state_out[key] = pool
+            out.append(pool)
+        return out
+
+    def _finish(self, out, gate, weights, cd, dtype):
+        """[..., Hq·D] attention output -> gate, output projection."""
+        if gate is not None:
+            out = out * gate
+        y = jnp.dot(out.astype(cd), self.serving_weights(weights, cd)["wo"],
+                    preferred_element_type=jnp.float32)
+        return y.astype(dtype)
+
+    def forward(self, ctx: LoweringContext, inputs, weights):
+        from flexflow_tpu.kernels.ragged_paged_attention import (
+            grouped_paged_attention,
+        )
+
+        a = self.attrs
+        hidden, page_table, seq_lens = inputs
+        page_table = page_table.astype(jnp.int32)
+        seq_lens = seq_lens.astype(jnp.int32)
+        cd = ctx.compute_dtype
+        b = hidden.shape[0]
+        positions = seq_lens[:, None]  # the fresh token's
+        q, k_rows, v_rows, gate = self._project(
+            hidden.astype(cd), positions, weights, cd)
+        # a full sequence (seq_lens == max_seq_len) has nowhere to write
+        k_cache, v_cache = self._scatter(
+            ctx, page_table, positions, k_rows, v_rows,
+            positions < self.max_seq_len)
+
+        ps, w = a["page_size"], a["window"]
+        lens = seq_lens + 1  # the fresh token attends to itself too
+        if w:
+            starts = jnp.maximum(lens - w, 0) // ps
+            n_walk = min(a["pages_per_seq"], -(-w // ps) + 1)
+        else:
+            starts = jnp.zeros_like(lens)
+            n_walk = a["pages_per_seq"]
+        walk = self._pages_of(
+            page_table, starts[:, None] + jnp.arange(n_walk, dtype=jnp.int32))
+        out = grouped_paged_attention(
+            q[:, 0], k_cache, v_cache, walk, lens, starts, w,
+            1.0 / math.sqrt(self.head_dim),
+            use_kernel=self.attention_path(ctx.mesh is not None) == "pallas")
+        return [self._finish(out.reshape(b, 1, -1), gate, weights, cd,
+                             hidden.dtype)]
+
+    def _chunk_key_blocks(self, positions, writes):
+        """The key blocks a chunk at ``positions`` [B, C] attends to:
+        each row's first logical page [B] and how many blocks of
+        ``_CHUNK_KEY_BLOCK`` keys follow it.  A global layer walks from
+        page 0 to the last position that WRITES — a pad row's clamped
+        position (``cap − 1``) is no key anyone needs; a window layer a
+        fixed count from the window's first page."""
+        a = self.attrs
+        ps, w = a["page_size"], a["window"]
+        block = max(1, _CHUNK_KEY_BLOCK // ps) * ps
+        if w:
+            lo = jnp.maximum(jnp.min(positions, axis=1) - w + 1, 0) // ps
+            return lo, -(-(w + positions.shape[1] + ps) // block) + 1
+        last = jnp.max(jnp.where(writes, positions, 0))
+        return jnp.zeros(positions.shape[:1], jnp.int32), last // block + 1
+
+    def forward_chunk(self, ctx: LoweringContext, inputs, weights):
+        """C prompt tokens a sequence in one pass (``DecodeAttentionOp.
+        forward_chunk``'s contract).  The chunk's K/V are scattered
+        first; attention then runs in KEY BLOCKS with an online softmax
+        — from the window's first page (page 0 in a global layer) to the
+        chunk's last position — so no [C, H, context] score tensor
+        exists.  A position of ``cap − 1`` is always a pad
+        (``run_chunked_prefill`` clamps there; a prompt's prefilled
+        tokens end at ``cap − 2``) and is kept out of the scatter."""
+        from flexflow_tpu.kernels.ragged_paged_attention import NEG_INF
+
+        a = self.attrs
+        hidden, page_table, positions = inputs
+        page_table = page_table.astype(jnp.int32)
+        positions = positions.astype(jnp.int32)
+        cd = ctx.compute_dtype
+        b, c = hidden.shape[:2]
+        q, k_rows, v_rows, gate = self._project(
+            hidden.astype(cd), positions, weights, cd)
+        writes = positions < self.max_seq_len - 1
+        k_cache, v_cache = self._scatter(
+            ctx, page_table, positions, k_rows, v_rows, writes)
+
+        ps, w, d = a["page_size"], a["window"], self.head_dim
+        hkv = self.kv_heads
+        g = a["num_heads"] // hkv
+        bp = max(1, _CHUNK_KEY_BLOCK // ps)  # pages a key block
+        scale = 1.0 / math.sqrt(d)
+        qg = q.reshape(b, c, hkv, g, d).astype(cd)
+        lo, blocks = self._chunk_key_blocks(positions, writes)
+
+        def block(i, carry):
+            m, l, acc = carry
+            logical = (lo[:, None] + i * bp
+                       + jnp.arange(bp, dtype=jnp.int32)[None, :])  # [B, bp]
+            pages = self._pages_of(page_table, logical)
+            kb = k_cache[pages].reshape(b, bp * ps, hkv, d).astype(cd)
+            vb = v_cache[pages].reshape(b, bp * ps, hkv, d).astype(cd)
+            key_pos = (logical[:, :, None] * ps
+                       + jnp.arange(ps, dtype=jnp.int32)).reshape(b, bp * ps)
+            s = jnp.einsum("bchgd,bkhd->bhgck", qg, kb,
+                           preferred_element_type=jnp.float32) * scale
+            seen = key_pos[:, None, :] <= positions[:, :, None]  # [B, C, K]
+            if w:
+                seen &= key_pos[:, None, :] > positions[:, :, None] - w
+            seen = seen[:, None, None]
+            m_new = jnp.maximum(
+                m, jnp.max(jnp.where(seen, s, NEG_INF), axis=-1))
+            p = jnp.where(seen, jnp.exp(s - m_new[..., None]), 0.0)
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + jnp.sum(p, axis=-1)
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "bhgck,bkhd->bhgcd", p.astype(cd), vb,
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        stat = jnp.zeros((b, hkv, g, c), jnp.float32)
+        _, l, acc = jax.lax.fori_loop(
+            0, blocks, block,
+            (stat + NEG_INF, stat, jnp.zeros((b, hkv, g, c, d), jnp.float32)))
+        out = acc / jnp.maximum(l, 1e-30)[..., None]
+        out = out.transpose(0, 3, 1, 2, 4).reshape(b, c, -1)
+        return [self._finish(out, gate, weights, cd, hidden.dtype)]
+
+    # ---- degree propagation ---------------------------------------------
+    def propagate(self, mv: MachineView) -> OpSharding:
+        b, s, e_deg = mv.dim_degrees
+        assert s == 1 and e_deg == 1 and mv.replica_degree == 1, (
+            "grouped decode attention splits over sequence slots only")
+        ws = tuple(ShardAnnot((1,) * len(spec.shape), replica=b)
+                   for spec in self._weight_specs)
+        return OpSharding(
+            inputs=(ShardAnnot((b, 1, 1)), ShardAnnot((b, 1)),
+                    ShardAnnot((b,))),
+            weights=ws, outputs=(ShardAnnot(mv.dim_degrees),))
+
+    def max_replica_degree(self) -> int:
+        return 1  # no head split: the kernel spans a row's heads
+
+    # ---- cost hooks ------------------------------------------------------
+    def flops(self) -> float:
+        a = self.attrs
+        e, d = a["embed_dim"], self.head_dim
+        hq, hkv = a["num_heads"] * d, self.kv_heads * d
+        proj = 2.0 * self.max_seqs * e * (
+            (3 if a["gated"] else 2) * hq + 2 * hkv)
+        return proj + 4.0 * self.max_seqs * hq * self.attended_len
+
+    def _kv_payload_bytes_per_token(self) -> float:
+        return (2.0 * self.kv_heads * self.head_dim
+                * jnp.dtype(self.pool_dtype).itemsize)
+
+    def kv_cache_bytes(self, mv: MachineView, serving=None) -> float:
+        b = max(mv.dim_degrees[0], 1) if mv.dim_degrees else 1
+        return (self.pool_pages * self.attrs["page_size"]
+                * self._kv_payload_bytes_per_token() / b)

@@ -240,7 +240,8 @@ class CacheOp(Operator):
 # device counters: state keys are ``{op}/obs/{metric}`` (obs/device_counters.py)
 _DISPATCH_COUNTERS = ("moe.assignments", "moe.assignments_dropped",
                       "moe.rows_filled", "moe.row_slots",
-                      "moe.expert_load_max", "moe.rows_at_fullest_load")
+                      "moe.expert_load_max", "moe.rows_at_fullest_load",
+                      "moe.experts_touched", "moe.experts_held")
 
 
 @register_op
@@ -326,10 +327,13 @@ class ExpertDispatchOp(Operator):
 
     attrs: ``n_experts`` (the router's width), ``experts_held`` and
     ``expert_offset`` (which of them live here), ``rows`` (the chip's
-    row bound).  ``sorted`` holds the token row of every assignment to a
-    held expert, the experts' rows one after the other; ``sizes`` counts
-    each expert's; ``source`` names the assignment (token * K + choice)
-    in each row, or T * K where the row is empty (a zero row)."""
+    row bound; never more rows than there are assignments, so one graph
+    serves a decode frame's [B, 1, D] and a prefill chunk's [1, C, D]
+    under one bound).  ``sorted`` holds the token row of every
+    assignment to a held expert, the experts' rows one after the other;
+    ``sizes`` counts each expert's; ``source`` names the assignment
+    (token * K + choice) in each row, or T * K where the row is empty (a
+    zero row)."""
 
     op_type = OperatorType.EXPERT_DISPATCH
     scope = "ff.moe.dispatch"
@@ -343,8 +347,9 @@ class ExpertDispatchOp(Operator):
                          expert_offset=int(expert_offset), rows=int(rows))
 
     def infer(self) -> Sequence[ParallelTensorShape]:
-        x = self.input_shapes[0]
-        held, rows = self.attrs["experts_held"], self.attrs["rows"]
+        x, experts = self.input_shapes
+        held = self.attrs["experts_held"]
+        rows = min(self.attrs["rows"], experts.num_elements)
         return (ParallelTensorShape.make((rows, x.sizes[-1]), x.dtype),
                 ParallelTensorShape.make((rows,), DataType.INT32),
                 ParallelTensorShape.make((held,), DataType.INT32))
@@ -358,19 +363,24 @@ class ExpertDispatchOp(Operator):
         x, experts = inputs
         a = self.attrs
         k = experts.shape[-1]
+        rows = min(a["rows"], experts.size)
         source, sizes, load = held_rows(experts.reshape(-1), a["experts_held"],
-                                        a["expert_offset"], a["rows"])
+                                        a["expert_offset"], rows)
         tokens = x.reshape(-1, x.shape[-1])
         sorted_rows = tokens.at[source // k].get(mode="fill", fill_value=0)
         counted = {
             "moe.assignments": jnp.sum(load),
             "moe.assignments_dropped": jnp.sum(load - sizes),
             "moe.rows_filled": jnp.sum(sizes),
-            "moe.row_slots": jnp.int32(a["rows"]),
+            "moe.row_slots": jnp.int32(rows),
             "moe.expert_load_max": jnp.max(load),
             # what padding every held expert to the fullest would take:
             # over ``moe.assignments`` it reads the fullest against the mean
             "moe.rows_at_fullest_load": jnp.max(load) * a["experts_held"],
+            # held experts with a row this step, of the experts held: what
+            # of the experts' weights a weight-bound step has to read
+            "moe.experts_touched": jnp.sum(sizes > 0),
+            "moe.experts_held": jnp.int32(a["experts_held"]),
         }
         for name in _DISPATCH_COUNTERS:
             key = f"{self.name}/obs/{name}"
@@ -413,16 +423,19 @@ class ExpertLinearOp(Operator):
 
     def __init__(self, name, input_shapes, out_dim: int,
                  activation: str | None = None, use_bias: bool = False,
-                 kernel_initializer=None):
+                 kernel_initializer=None, param_dtype: str = "float32"):
         if activation not in _ACTIVATIONS:
             raise NotImplementedError(
                 f"ExpertLinearOp activation {activation!r} not supported")
+        # extension-only: float32 kernels add NO attr (signatures stay)
+        extra = {} if param_dtype == "float32" else {"param_dtype": param_dtype}
         # Glorot over the STACKED kernel [E, D, out]: the expert dim counts
         # as receptive field, so every expert starts at 1/sqrt(E) of a lone
         # Linear's scale — E experts' outputs are summed into one stream
         self._kernel_init = kernel_initializer or DEFAULT_WEIGHT_INIT
         super().__init__(name, input_shapes, out_dim=int(out_dim),
-                         activation=activation, use_bias=bool(use_bias))
+                         activation=activation, use_bias=bool(use_bias),
+                         **extra)
         assert not (self.grouped and use_bias), (
             "a bias on sorted rows needs each row's expert: no caller has one")
 
@@ -445,16 +458,21 @@ class ExpertLinearOp(Operator):
     def weight_specs(self):
         e, d = self.n_experts, self.input_shapes[0].sizes[-1]
         out = self.attrs["out_dim"]
-        specs = [WeightSpec("kernel", (e, d, out), DataType.FLOAT32,
-                            self._kernel_init)]
+        pd = DataType.from_any(self.attrs.get("param_dtype", "float32"))
+        specs = [WeightSpec("kernel", (e, d, out), pd, self._kernel_init)]
         if self.attrs["use_bias"]:
-            specs.append(WeightSpec("bias", (e, out), DataType.FLOAT32,
-                                    DEFAULT_BIAS_INIT))
+            specs.append(WeightSpec("bias", (e, out), pd, DEFAULT_BIAS_INIT))
         return specs
+
+    def serving_weights(self, weights, compute_dtype):
+        """The stacked kernel in the dtype the grouped product reads."""
+        return {**weights,
+                "kernel": weights["kernel"].astype(compute_dtype)}
 
     def forward(self, ctx: LoweringContext, inputs, weights):
         cd = ctx.compute_dtype
-        x, kernel = inputs[0].astype(cd), weights["kernel"].astype(cd)
+        x = inputs[0].astype(cd)
+        kernel = self.serving_weights(weights, cd)["kernel"]
         act = _ACTIVATIONS[self.attrs["activation"]]
         if self.grouped:
             sizes = inputs[1]

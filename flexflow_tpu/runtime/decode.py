@@ -84,14 +84,16 @@ as the matmuls read it, once, not inside every call).
 
 from __future__ import annotations
 
+import collections
 import functools
+import itertools
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from flexflow_tpu.obs import annotate
+from flexflow_tpu.obs import annotate, device_counters
 from flexflow_tpu.obs.annotate import phase_span
 from flexflow_tpu.obs.events import BUS
 from flexflow_tpu.obs.metrics import METRICS
@@ -133,6 +135,17 @@ _ROWS_DROPPED = METRICS.counter("decode.rows_dropped")
 _WEIGHT_PREPARES = METRICS.counter("decode.weight_prepares")
 _WEIGHT_BYTES = METRICS.gauge("decode.weight_bytes")
 _WEIGHT_BYTES_MASTER = METRICS.gauge("decode.weight_bytes_master")
+# two kinds of KV page (compiled_decode_step): the bytes of the pools the
+# page table addresses and of the window layers' rings, and — a frame, a
+# layer, a row — the pages the kernel walks (a window layer's capped at
+# its window) against the live pages a walk with no window would take
+_KV_BYTES_GLOBAL = METRICS.gauge("decode.kv_bytes_global")
+_KV_BYTES_WINDOW = METRICS.gauge("decode.kv_bytes_window")
+_KV_PAGES_WALKED = METRICS.counter("decode.kv_pages_walked")
+_KV_PAGES_LIVE = METRICS.counter("decode.kv_pages_live")
+# a frame's device counters are published every this many frames, from a
+# copy that left the device with an earlier frame's tokens
+_OBS_EVERY = 16
 
 
 @dataclass
@@ -528,6 +541,20 @@ class ContinuousBatchingExecutor:
         self.slot_aligned = (
             not self.prefix_sharing
             and self.allocator.num_pages >= max_seqs * pages_per_seq)
+        # a model whose window layers keep a RING of pages a sequence
+        # slot reads the slot off the table row: free-list pages (an
+        # oversubscribed pool, prefix sharing) would name another
+        # sequence's ring
+        if not self.slot_aligned and any(
+                getattr(f, "needs_slot_aligned", False)
+                for f in (step_fn, prefill_fn)):
+            raise ValueError(
+                "the step function's window layers hold a ring of KV pages "
+                "a sequence SLOT (the op's ring_pages; slot i owns pages "
+                "[i*pps, (i+1)*pps) of the table); this executor hands out "
+                "pages from the free list (prefix sharing, or a pool "
+                "smaller than max_seqs x pages_per_seq), which would name "
+                "another sequence's ring")
         # idle frame rows still scatter one garbage k/v (static-shape
         # scatter — the op cannot skip rows), so they must point at a
         # page no LIVE sequence can own.  Slot-aligned pools use the
@@ -1327,6 +1354,10 @@ _KV_LEAVES = ("k_cache", "v_cache", "k_scale", "v_scale")
 # the state leaf that carries a frame's chosen tokens [B] into the next
 # frame (``compiled_decode_step``): not an op's state, so no "/" in it
 _LAST_TOKENS = "last_tokens"
+# the device counters' totals after a frame, [n] int32: handed out beside
+# the state (never taken back in), so the host's copy outlives the next
+# call's donation
+_OBS_TOTALS = "obs_totals"
 
 
 def _tree_bytes(tree) -> int:
@@ -1407,12 +1438,21 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
     whole = jax.sharding.NamedSharding(compiled.mesh,
                                        jax.sharding.PartitionSpec())
 
+    # the ops' device counters (``*/obs/*`` integers of the state,
+    # obs/device_counters.py): the frame hands their totals out as ONE
+    # fresh vector beside the state it is donated, so the host reads a
+    # copy that the next call does not consume
+    obs_keys = sorted(
+        k for k, v in model.state.items()
+        if device_counters.MARK in k and jnp.issubdtype(v.dtype, jnp.integer))
+
     def frame(p, s, ins):
         """``(logits, tokens), state``: the frame's logits and the
         greedy token of each row.  Where the state carries the tokens
         of the frame before (``_LAST_TOKENS``), a row whose id is -1 is
         fed that token — it never left the device — and the state
-        handed back carries this frame's."""
+        handed back carries this frame's, and (``_OBS_TOTALS``) the
+        device counters' totals after this frame."""
         ids, page_table, seq_lens = ins
         s = dict(s)
         last = s.pop(_LAST_TOKENS, None)
@@ -1425,6 +1465,9 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
             tokens = jax.lax.with_sharding_constraint(tokens, whole)
         if last is not None:
             s[_LAST_TOKENS] = tokens
+            if obs_keys:
+                s[_OBS_TOTALS] = jnp.stack(
+                    [s[k].astype(jnp.int32) for k in obs_keys])
         return (logits, tokens), s
 
     fn = jax.jit(frame, donate_argnums=(1,))
@@ -1468,10 +1511,45 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
             _WEIGHT_BYTES_MASTER.set(_tree_bytes(master))
         return step.weights
 
-    paths = {
-        n.op.attention_path(compiled._multi_device)
-        for n in model.graph.topo_order()
-        if n.op.op_type == OperatorType.DECODE_ATTENTION}
+    decode_ops = [n.op for n in model.graph.topo_order()
+                  if n.op.op_type == OperatorType.DECODE_ATTENTION]
+    paths = {op.attention_path(compiled._multi_device) for op in decode_ops}
+    # window layers: (window, ring pages a slot) of each; a ring is
+    # addressed by the SLOT a table row belongs to, so the tables must be
+    # slot-aligned (``ContinuousBatchingExecutor.slot_aligned``, which
+    # refuses to be built over this step otherwise)
+    windows = [(op.attrs["window"], op.attrs["ring_pages"])
+               for op in decode_ops if op.attrs.get("window")]
+    page_size = decode_ops[0].attrs["page_size"] if decode_ops else 1
+    for w, r in windows:
+        if r * page_size < w + prefill_chunk + page_size:
+            raise ValueError(
+                f"a window layer's ring of {r} pages of {page_size} holds "
+                f"a window of {w} and a chunk of "
+                f"{r * page_size - w - page_size} tokens; prefill_chunk "
+                f"{prefill_chunk} would overwrite pages its own queries "
+                f"still see — build the model for this chunk")
+    def pool_bytes(ops):
+        return sum(v.nbytes for op in ops for k, v in model.state.items()
+                   if k.startswith(op.name + "/")
+                   and k.rsplit("/", 1)[-1] in _KV_LEAVES)
+
+    _KV_BYTES_WINDOW.set(pool_bytes(
+        op for op in decode_ops if op.attrs.get("ring_pages")))
+    _KV_BYTES_GLOBAL.set(pool_bytes(
+        op for op in decode_ops if not op.attrs.get("ring_pages")))
+
+    def count_walk(seq_lens):
+        """``decode.kv_pages_walked`` / ``_live`` of one frame, from its
+        lengths on the host: every row attends its fresh token too, an
+        idle row walks one page (as ``decode.live_pages`` counts)."""
+        lens = np.asarray(seq_lens, np.int64) + 1
+        live = -(-lens // page_size)
+        walked = (len(decode_ops) - len(windows)) * int(live.sum())
+        for w, _ in windows:
+            walked += int((live - np.maximum(lens - w, 0) // page_size).sum())
+        _KV_PAGES_WALKED.inc(walked)
+        _KV_PAGES_LIVE.inc(len(decode_ops) * int(live.sum()))
 
     cold = {"decode_frame", "prefill_chunk"}  # programs never called yet
 
@@ -1493,19 +1571,49 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
     def live_state():
         return {**model.state, _LAST_TOKENS: last["tokens"]}
 
+    # the device counters' totals of the last three frames, each on its
+    # way to the host with its frame's tokens; the oldest has arrived (a
+    # frame's tokens were waited for before the call after next is made),
+    # so reading it costs no frame a sync
+    sent = collections.deque(maxlen=3)
+    calls = itertools.count(1)
+
+    def publish_obs(block: bool = False) -> None:
+        """Put the device counters into ``METRICS``: from the oldest of
+        the last three totals vectors, or — ``block`` — from the live
+        state itself, after everything dispatched has run (the end of a
+        run, a test)."""
+        if block:
+            values = {k: model.state[k] for k in obs_keys}
+        elif len(sent) == sent.maxlen:
+            values = dict(zip(obs_keys, np.asarray(sent[0])))
+        else:
+            return
+        device_counters.publish(values, model._obs_seen)
+
     def step(ids, page_table, seq_lens):
         (logits, tokens), state = call(
             "decode_frame", fn,
             weights(), live_state(), [ids, page_table, seq_lens])
         last["tokens"] = state.pop(_LAST_TOKENS)
+        obs = state.pop(_OBS_TOTALS, None)
         model.state = state
         tokens.copy_to_host_async()  # 64 bytes, on their way at once
+        if windows:
+            count_walk(seq_lens)
+        if obs is not None:
+            obs.copy_to_host_async()
+            sent.append(obs)
+            if next(calls) % _OBS_EVERY == 0:
+                publish_obs()
         return FrameOutput(logits, tokens)
 
     weights()
     step.state = _LiveState(live_state)  # tests inspect the live cache
     step.frame_fn = fn
     step.attention_path = "+".join(sorted(paths)) or None
+    step.publish_obs = publish_obs
+    step.needs_slot_aligned = bool(windows)
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def copy_kv_page(state, src, dst):
@@ -1535,6 +1643,7 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
             model.state = call("prefill_chunk", pf, weights(),
                                model.state, ids, positions, page_table)
 
+        prefill.needs_slot_aligned = bool(windows)
         step.prefill = prefill
         step.chunk_fn = pf
     return step

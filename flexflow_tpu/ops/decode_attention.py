@@ -31,11 +31,13 @@ TP-vs-batch Pareto real instead of asserted.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from flexflow_tpu.core.machine import MachineView
 from flexflow_tpu.core.optype import OperatorType
@@ -67,6 +69,151 @@ def _quantize_kv(x):
     s = jnp.maximum(amax, 1e-30) / 127.0
     q = jnp.clip(jnp.round(x / s[..., None]), -127, 127).astype(jnp.int8)
     return q, s.astype(jnp.float32)
+
+
+def _write_chunk_pages(pools, rows, page_table, positions):
+    """Put a chunk's fresh rows — ``rows`` {state leaf: [B, C, ...]} at
+    ``positions`` [B, C] — into ``pools`` {state leaf: [P, page, ...]};
+    returns the pools, updated in place.
+
+    A CONTIGUOUS run (``DecodeAttentionOp.forward_chunk``'s contract)
+    covers at most ``C / page_size + 1`` pages a sequence: each is read,
+    the run's rows are laid over it and it is written back WHOLE — one
+    page-sized window a touched page instead of one row-sized window a
+    token.  A page the run does not reach is not written (its index
+    points past the pool: dropped).  The pool holds afterwards what the
+    row scatter leaves: every row at its position, the rows the
+    ``cap - 1`` clamp folds onto one position resolved as a scatter
+    applied in order resolves them (the last row stays), every other row
+    of a touched page as it was.  A run that is NOT contiguous takes the
+    row scatter itself — the branch is chosen on the device from
+    ``positions``; nothing sends such a run."""
+    num_pages, ps = next(iter(pools.values())).shape[:2]
+    pps = page_table.shape[1]
+    cap = ps * pps
+    b, c = positions.shape
+    rows = {leaf: r.astype(pools[leaf].dtype) for leaf, r in rows.items()}
+    steps = jnp.arange(c, dtype=jnp.int32)
+    c0 = positions[:, :1]  # [B, 1]
+    contiguous = jnp.all(positions == jnp.minimum(c0 + steps, cap - 1))
+
+    def by_row(pools):
+        page = jnp.take_along_axis(
+            page_table, jnp.minimum(positions // ps, pps - 1), axis=1)
+        slot = positions % ps
+        return {leaf: pool.at[page, slot].set(rows[leaf])
+                for leaf, pool in pools.items()}
+
+    def by_page(pools):
+        n = -(-c // ps) + 1  # pages a run of C positions can touch
+        off = c0 % ps  # the run's first slot in its first page
+        # slot r of touched page j holds chunk row j * ps + r - off
+        row = jnp.arange(n * ps, dtype=jnp.int32) - off  # [B, n * ps]
+        held = ((row >= 0) & (row < c) & (c0 + row < cap)).reshape(b, n, ps)
+        logical = c0 // ps + jnp.arange(n, dtype=jnp.int32)  # [B, n]
+        page = jnp.take_along_axis(
+            page_table, jnp.minimum(logical, pps - 1), axis=1)
+        target = jnp.where(jnp.any(held, axis=-1), page,
+                           num_pages)  # past the pool: dropped
+        # the rows clamped onto ``cap - 1``: the last one stays
+        folded = (c0 + steps == cap - 1)  # [B, C]
+        out = {}
+        for leaf, pool in pools.items():
+            r = rows[leaf]
+            tail = (1,) * (r.ndim - 2)
+            r = jnp.where(folded.reshape(b, c, *tail), r[:, -1:], r)
+            laid = jnp.zeros((n * ps,) + r.shape[2:], r.dtype)
+            laid = jnp.stack([
+                jax.lax.dynamic_update_slice(
+                    laid, r[i], (off[i, 0],) + (0,) * len(tail))
+                for i in range(b)])
+            merged = jnp.where(
+                held.reshape(b, n, ps, *tail),
+                laid.reshape((b, n, ps) + r.shape[2:]), pool[page])
+            out[leaf] = pool.at[target].set(merged, mode="drop")
+        return out
+
+    return jax.lax.cond(contiguous, by_page, by_row, pools)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "num_heads", "block_pages", "compute_dtype"))
+def _chunk_write_and_attend(pools, rows, q, page_table, positions, blocks,
+                            *, num_heads, block_pages, compute_dtype):
+    """The pool's side of ``DecodeAttentionOp.forward_chunk``: the
+    chunk's rows written (``_write_chunk_pages``), then its queries ``q``
+    [B, C, H·D] against ``blocks`` key blocks of ``block_pages`` pages
+    from page 0, online softmax in fp32; returns the pools and the
+    attention output [B, C, H·D] float32.
+
+    Jitted on its own so that the layers of a model, whose shapes are
+    one, share ONE trace and one lowered function inside the chunk
+    program (a chunk's set-up is traced on every start); XLA inlines the
+    calls.  The products take the operands the whole-table form's
+    compiled program had — q, K and V in the compute dtype, the weights
+    p in fp32 — and only the order of the softmax's sums differs."""
+    from flexflow_tpu.kernels.ragged_paged_attention import (
+        NEG_INF,
+        gather_kv_pages,
+        gather_kv_pages_quant,
+    )
+
+    cd = compute_dtype
+    pools = _write_chunk_pages(pools, rows, page_table, positions)
+    b, c = positions.shape
+    h, bp = num_heads, block_pages
+    ps = pools["k_cache"].shape[1]
+    d = q.shape[-1] // h
+    qc = q.reshape(b, c, h, d).astype(cd)
+    scale = 1.0 / math.sqrt(d)
+
+    def keys_of(leaf, pages):
+        """Block ``pages`` [B, bp] of a pool as the products take it:
+        [B, keys, H, D] in the compute dtype."""
+        if f"{leaf}_scale" in pools:
+            dense = gather_kv_pages_quant(
+                pools[f"{leaf}_cache"], pools[f"{leaf}_scale"], pages, h)
+        else:
+            dense = gather_kv_pages(pools[f"{leaf}_cache"], pages, h)
+        if dense.dtype == jnp.float32 and cd == jnp.bfloat16:
+            # bf16's rounding, made ON THE BLOCK by an op XLA does not
+            # move: a bare convert it lifts above the gather, retypes
+            # the loop's pool operand to bf16 and converts the WHOLE
+            # fp32 pool before the loop, every layer of every chunk
+            # (tests/test_chip_lowering.py holds that no pool-sized
+            # convert exists)
+            dense = jax.lax.reduce_precision(dense, 8, 7)
+        return dense.astype(cd)
+
+    # the table in whole blocks (a last block past the table repeats its
+    # last page, at positions no query sees)
+    table = jnp.pad(page_table, ((0, 0), (0, -page_table.shape[1] % bp)),
+                    mode="edge")
+
+    def block(i, carry):
+        m, l, acc = carry
+        pages = jax.lax.dynamic_slice_in_dim(table, i * bp, bp, axis=1)
+        s = jnp.einsum("bchd,bshd->bchs", qc, keys_of("k", pages),
+                       preferred_element_type=jnp.float32) * scale
+        # key j of the block sits at position i * keys + j
+        seen = (jnp.arange(bp * ps, dtype=jnp.int32)[None, None, :]
+                <= (positions - i * bp * ps)[:, :, None])  # [B, C, S]
+        s = jnp.where(seen[:, :, None, :], s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        # block 0 holds position 0, which every query sees: m_new is a
+        # real score from there on and an unseen key weighs 0
+        p = jnp.exp(s - m_new[..., None])
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(p, axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bchs,bshd->bchd", p, keys_of("v", pages))
+        return m_new, l, acc
+
+    stat = jnp.zeros((b, c, h), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(
+        0, blocks, block,
+        (stat + NEG_INF, stat, jnp.zeros((b, c, h, d), jnp.float32)))
+    return pools, (acc / l[..., None]).reshape(b, c, h * d)
 
 
 @register_op
@@ -361,29 +508,60 @@ class DecodeAttentionOp(Operator):
         return [y[:, None, :].astype(hidden.dtype)]
 
     # ---- chunked prefill lowering ---------------------------------------
+    # keys a block of the chunk's attention: the scores of one block are
+    # [B, C, H, CHUNK_KEY_BLOCK] float32
+    CHUNK_KEY_BLOCK = 128
+
+    @property
+    def chunk_block_pages(self) -> int:
+        """Pages a key block of the chunk's attention spans."""
+        return max(1, self.CHUNK_KEY_BLOCK // self.attrs["page_size"])
+
+    def chunk_walk(self, positions, xp=jnp):
+        """The key blocks (of ``chunk_block_pages`` pages) a chunk at
+        ``positions`` [B, C] attends to: each row's first logical page
+        [B] and how many blocks follow it — here from page 0 to the
+        chunk's last position.  ``xp`` is the array module: ``jnp`` gives
+        ``forward_chunk`` its trip count, ``numpy`` the host its
+        ``decode.prefill_keys_walked`` (``chunk_keys_walked``) from the
+        very positions it sends."""
+        block = self.chunk_block_pages * self.attrs["page_size"]
+        return (xp.zeros(positions.shape[:1], xp.int32),
+                xp.max(positions) // block + 1)
+
+    def chunk_keys_walked(self, positions) -> int:
+        """Keys the blocks of a chunk at ``positions`` (numpy, [B, C])
+        cover in this layer, all rows together."""
+        _, blocks = self.chunk_walk(positions, np)
+        return (int(blocks) * self.chunk_block_pages
+                * self.attrs["page_size"] * positions.shape[0])
+
     def forward_chunk(self, ctx: LoweringContext, inputs, weights):
         """The CHUNKED-PREFILL twin of ``forward``: C prompt tokens per
         sequence in ONE pass instead of one decode frame each.  Inputs:
 
         * hidden    [B, C, E] — the chunk's token embeddings
         * page_table [B, pages_per_seq]
-        * positions [B, C] int32 — each token's absolute cache position
-          (the caller clamps pad positions into the sequence's own
-          allotment; a pad write is overwritten by the decode loop
-          before any frame reads it, so no masking is needed)
+        * positions [B, C] int32 — each token's absolute cache position.
+          THE CONTRACT (``run_chunked_prefill`` is its one sender): a
+          row's positions are ONE CONTIGUOUS RUN ``c0 … c0 + C − 1``,
+          clamped at ``cap − 1`` (cap = page_size · pages_per_seq); the
+          prompt's tokens come first and the pad tail after them, at
+          FUTURE positions of the sequence's own allotment — a pad
+          write is overwritten by the decode loop before any frame
+          reads it, so no masking is needed.  ``cap − 1`` itself is
+          always a pad.
 
-        Scatters all C tokens' K/V into the page pool and attends each
-        query against cache prefix + intra-chunk causal — the same
-        dtype discipline as ``forward`` (projections in the compute
-        dtype, cache and softmax in fp32), so the populated cache is
-        numerically the one the token-by-token path writes
-        (runtime/prefill.py proves token identity end-to-end)."""
-        from flexflow_tpu.kernels.ragged_paged_attention import (
-            NEG_INF,
-            gather_kv_pages,
-            gather_kv_pages_quant,
-        )
-
+        Writes all C tokens' K/V into the page pool page by page
+        (``_write_chunk_pages``) and attends each query against cache prefix +
+        intra-chunk causal in KEY BLOCKS from page 0 to the chunk's last
+        position, with an online softmax: a chunk at position 0 walks
+        one block, the last chunk of a full table all of them
+        (``chunk_walk``), and no [C, H, table] score tensor
+        exists.  The same dtype discipline as ``forward`` (projections
+        in the compute dtype, cache and softmax in fp32), so the
+        populated cache is numerically the one the token-by-token path
+        writes (runtime/prefill.py proves token identity end-to-end)."""
         a = self.attrs
         hidden, page_table, positions = inputs
         page_table = page_table.astype(jnp.int32)
@@ -393,56 +571,21 @@ class DecodeAttentionOp(Operator):
         w = self.serving_weights(weights, cd)
         # fresh K/V rows as the pool holds them: [B, C, H·D]
         q, k_new, v_new = self._project(x, w)
-        qf = q.reshape(*x.shape[:2], a["num_heads"], self.head_dim)
 
-        ps = a["page_size"]
-        k_cache = ctx.state_in[f"{self.name}/k_cache"]
-        v_cache = ctx.state_in[f"{self.name}/v_cache"]
-        slot = positions % ps  # [B, C]
-        page_idx = jnp.minimum(positions // ps, a["pages_per_seq"] - 1)
-        page = jnp.take_along_axis(page_table, page_idx, axis=1)  # [B, C]
-        kvd = self.kv_dtype
-        if kvd == "int8":
-            # batched quantize-on-scatter, same per-token scheme as the
+        rows = {"k_cache": k_new, "v_cache": v_new}
+        if self.kv_dtype == "int8":
+            # batched quantize-on-write, same per-token scheme as the
             # decode step — the chunked path populates the SAME pool
-            k_q, k_s = _quantize_kv(k_new)  # [B, C, H·D] / [B, C]
-            v_q, v_s = _quantize_kv(v_new)
-            k_scale = ctx.state_in[f"{self.name}/k_scale"]
-            v_scale = ctx.state_in[f"{self.name}/v_scale"]
-            k_cache = k_cache.at[page, slot].set(k_q)
-            v_cache = v_cache.at[page, slot].set(v_q)
-            k_scale = k_scale.at[page, slot].set(k_s)
-            v_scale = v_scale.at[page, slot].set(v_s)
-            ctx.state_out[f"{self.name}/k_scale"] = k_scale
-            ctx.state_out[f"{self.name}/v_scale"] = v_scale
-        else:
-            k_cache = k_cache.at[page, slot].set(
-                k_new.astype(k_cache.dtype))
-            v_cache = v_cache.at[page, slot].set(
-                v_new.astype(v_cache.dtype))
-        ctx.state_out[f"{self.name}/k_cache"] = k_cache
-        ctx.state_out[f"{self.name}/v_cache"] = v_cache
-
-        # each chunk query attends to every cached position <= its own:
-        # the prefix written by earlier chunks plus the intra-chunk
-        # causal triangle (this chunk's K/V are already in the pool)
-        scale = 1.0 / math.sqrt(self.head_dim)
-        h = a["num_heads"]
-        if kvd == "int8":
-            k_dense = gather_kv_pages_quant(k_cache, k_scale,
-                                            page_table, h)  # [B, S, H, D]
-            v_dense = gather_kv_pages_quant(v_cache, v_scale,
-                                            page_table, h)
-        else:
-            k_dense = gather_kv_pages(k_cache, page_table, h)  # [B, S, H, D]
-            v_dense = gather_kv_pages(v_cache, page_table, h)
-        s = jnp.einsum("bchd,bshd->bchs", qf, k_dense) * scale
-        pos_k = jnp.arange(k_dense.shape[1], dtype=jnp.int32)
-        mask = pos_k[None, None, :] <= positions[:, :, None]  # [B, C, S]
-        s = jnp.where(mask[:, :, None, :], s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bchs,bshd->bchd", p, v_dense)
-        y = jnp.dot(out.astype(cd).reshape(*x.shape[:2], -1), w["wo"],
+            rows["k_cache"], rows["k_scale"] = _quantize_kv(k_new)
+            rows["v_cache"], rows["v_scale"] = _quantize_kv(v_new)
+        pools = {leaf: ctx.state_in[f"{self.name}/{leaf}"] for leaf in rows}
+        pools, out = _chunk_write_and_attend(
+            pools, rows, q, page_table, positions,
+            self.chunk_walk(positions)[1], num_heads=a["num_heads"],
+            block_pages=self.chunk_block_pages, compute_dtype=jnp.dtype(cd))
+        for leaf, pool in pools.items():
+            ctx.state_out[f"{self.name}/{leaf}"] = pool
+        y = jnp.dot(out.astype(cd), w["wo"],
                     preferred_element_type=jnp.float32)
         return [y.astype(hidden.dtype)]
 
@@ -580,11 +723,6 @@ class DecodeAttentionOp(Operator):
                         * self.head_dim * 4.0)
             quant = self.KV_QUANT_PASSES * tok_fp32 / (b * r)
         return act / b + wbytes / r + kv + quant
-
-
-# keys a block of the chunk's attention (``forward_chunk``): the scores
-# of one block are [B, Hq, C, _CHUNK_KEY_BLOCK] float32
-_CHUNK_KEY_BLOCK = 512
 
 
 class GroupedDecodeAttentionOp(DecodeAttentionOp):
@@ -835,21 +973,23 @@ class GroupedDecodeAttentionOp(DecodeAttentionOp):
         return [self._finish(out.reshape(b, 1, -1), gate, weights, cd,
                              hidden.dtype)]
 
-    def _chunk_key_blocks(self, positions, writes):
-        """The key blocks a chunk at ``positions`` [B, C] attends to:
-        each row's first logical page [B] and how many blocks of
-        ``_CHUNK_KEY_BLOCK`` keys follow it.  A global layer walks from
-        page 0 to the last position that WRITES — a pad row's clamped
-        position (``cap − 1``) is no key anyone needs; a window layer a
-        fixed count from the window's first page."""
+    # the scores of one block are [B, Hq, C, CHUNK_KEY_BLOCK] float32
+    CHUNK_KEY_BLOCK = 512
+
+    def chunk_walk(self, positions, xp=jnp):
+        """``DecodeAttentionOp.chunk_walk``, by layer type.  A global
+        layer walks from page 0 to the last position that WRITES — a pad
+        row's clamped position (``cap − 1``) is no key anyone needs; a
+        window layer a fixed count from the window's first page."""
         a = self.attrs
         ps, w = a["page_size"], a["window"]
-        block = max(1, _CHUNK_KEY_BLOCK // ps) * ps
+        block = self.chunk_block_pages * ps
         if w:
-            lo = jnp.maximum(jnp.min(positions, axis=1) - w + 1, 0) // ps
+            lo = xp.maximum(xp.min(positions, axis=1) - w + 1, 0) // ps
             return lo, -(-(w + positions.shape[1] + ps) // block) + 1
-        last = jnp.max(jnp.where(writes, positions, 0))
-        return jnp.zeros(positions.shape[:1], jnp.int32), last // block + 1
+        writes = positions < self.max_seq_len - 1
+        last = xp.max(xp.where(writes, positions, 0))
+        return xp.zeros(positions.shape[:1], xp.int32), last // block + 1
 
     def forward_chunk(self, ctx: LoweringContext, inputs, weights):
         """C prompt tokens a sequence in one pass (``DecodeAttentionOp.
@@ -877,10 +1017,10 @@ class GroupedDecodeAttentionOp(DecodeAttentionOp):
         ps, w, d = a["page_size"], a["window"], self.head_dim
         hkv = self.kv_heads
         g = a["num_heads"] // hkv
-        bp = max(1, _CHUNK_KEY_BLOCK // ps)  # pages a key block
+        bp = self.chunk_block_pages  # pages a key block
         scale = 1.0 / math.sqrt(d)
         qg = q.reshape(b, c, hkv, g, d).astype(cd)
-        lo, blocks = self._chunk_key_blocks(positions, writes)
+        lo, blocks = self.chunk_walk(positions)
 
         def block(i, carry):
             m, l, acc = carry

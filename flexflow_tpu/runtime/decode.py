@@ -143,6 +143,11 @@ _KV_BYTES_GLOBAL = METRICS.gauge("decode.kv_bytes_global")
 _KV_BYTES_WINDOW = METRICS.gauge("decode.kv_bytes_window")
 _KV_PAGES_WALKED = METRICS.counter("decode.kv_pages_walked")
 _KV_PAGES_LIVE = METRICS.counter("decode.kv_pages_live")
+# the prefill chunk's attention (compiled_decode_step): the keys its
+# blocks cover — a chunk, a layer, from the positions sent and by the
+# op's own ``chunk_walk`` — against the keys of the page table
+_PREFILL_KEYS_WALKED = METRICS.counter("decode.prefill_keys_walked")
+_PREFILL_KEYS_TABLE = METRICS.counter("decode.prefill_keys_table")
 # a frame's device counters are published every this many frames, from a
 # copy that left the device with an earlier frame's tokens
 _OBS_EVERY = 16
@@ -1639,9 +1644,22 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
                                          compiled.compute_dtype),
                      donate_argnums=(1,))
 
+        # the layers whose chunks walk alike, as {kind: (one of them, how
+        # many)}: the host prices a chunk's walk once a kind, not a layer
+        walks = {}
+        for op in decode_ops:
+            kind = (type(op), op.chunk_block_pages, op.attrs.get("window", 0))
+            walks[kind] = (op, walks.get(kind, (op, 0))[1] + 1)
+        table_keys = sum(op.max_seq_len for op in decode_ops)
+
         def prefill(ids, positions, page_table):
             model.state = call("prefill_chunk", pf, weights(),
                                model.state, ids, positions, page_table)
+            positions = np.asarray(positions)
+            _PREFILL_KEYS_WALKED.inc(sum(
+                n * op.chunk_keys_walked(positions)
+                for op, n in walks.values()))
+            _PREFILL_KEYS_TABLE.inc(table_keys * len(positions))
 
         prefill.needs_slot_aligned = bool(windows)
         step.prefill = prefill

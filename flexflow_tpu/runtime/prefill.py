@@ -63,10 +63,12 @@ def run_chunked_prefill(prefill_fn: Callable, tokens: Sequence[int],
     shift, positions stay absolute, and the already-cached pages are
     never touched.
 
-    Pad positions past the prompt clamp into the sequence's own
-    allotment (``cap - 1``): a pad write lands at a FUTURE position the
-    decode loop rewrites before any frame reads it (see module
-    docstring) — no masking, no dynamic shapes.
+    Every pass sends the positions ``DecodeAttentionOp.forward_chunk``
+    states its contract on — the one place it is written: ONE
+    contiguous run ``c0 … c0 + chunk − 1`` clamped at ``cap - 1``, the
+    prompt's tokens first, the pad tail after them — which is what
+    lets the op write the run page by page and walk its keys only to
+    the run's last position.
 
     When ``trace_id`` names a live request trace, each pass closes as
     one ``prefill.chunk`` child span under the open ``prefill`` span —

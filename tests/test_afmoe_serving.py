@@ -363,14 +363,16 @@ def test_a_padded_chunk_walks_the_key_blocks_of_its_real_rows(
     assert cap == 16384
     positions = jnp.asarray(
         [list(range(7680, 8191)) + [cap - 1]], jnp.int32)
-    lo, blocks = op._chunk_key_blocks(positions, positions < cap - 1)
+    lo, blocks = op.chunk_walk(positions)
     # global: ceil(8191 / 512) = 16 blocks, not the 32 of the pad's
     # position; window: from the page of 7680 - 4095, a fixed count
     assert (int(lo[0]), int(blocks)) == (want_lo, want_blocks)
     # no pad: the same walk
     whole = jnp.arange(7680, 8192, dtype=jnp.int32)[None, :]
-    lo2, blocks2 = op._chunk_key_blocks(whole, whole < cap - 1)
+    lo2, blocks2 = op.chunk_walk(whole)
     assert (int(lo2[0]), int(blocks2)) == (want_lo, want_blocks)
+    # the host counts the same walk from the same positions
+    assert op.chunk_keys_walked(np.asarray(positions)) == want_blocks * 512
 
 
 # ---- (f) counters -----------------------------------------------------------

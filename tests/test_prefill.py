@@ -191,6 +191,320 @@ def test_chunk_state_is_the_same_over_the_served_tree(compute_dtype):
     assert all(np.any(v[:2] != 0) for v in served.values())
 
 
+# ---------------------------------------------------------------------------
+# the chunk's own write and read of the pool (DecodeAttentionOp.forward_chunk)
+# ---------------------------------------------------------------------------
+CHUNK_OP = dict(embed_dim=64, num_heads=4, page_size=8, pages_per_seq=8,
+                num_pages=24)
+
+
+def _chunk_op(kv_dtype="fp32", **overrides):
+    from flexflow_tpu.core.ptensor import DataType, ParallelTensorShape
+    from flexflow_tpu.ops import DecodeAttentionOp
+
+    kw = dict(CHUNK_OP, **overrides)
+    shapes = [ParallelTensorShape.make(s, d) for s, d in (
+        ((2, 1, kw["embed_dim"]), DataType.FLOAT32),
+        ((2, kw["pages_per_seq"]), DataType.INT32), ((2,), DataType.INT32))]
+    return DecodeAttentionOp("attn", shapes, kv_dtype=kv_dtype, **kw)
+
+
+def _chunk_fixture(op, c, seed=0):
+    """Seeded weights, a pool with no zero in it, two rows over pages
+    dealt out of order, and a [2, c, E] chunk."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    a = op.attrs
+    e, hd = a["embed_dim"], a["num_heads"] * op.head_dim
+    weights = {n: jnp.asarray(rng.normal(0, e ** -0.5, shape), jnp.float32)
+               for n, shape in (("wq", (e, hd)), ("wk", (e, hd)),
+                                ("wv", (e, hd)), ("wo", (hd, e)))}
+    state = {}
+    for leaf, shape, dtype, _ in op.state_specs():
+        if dtype == jnp.int8:
+            val = rng.integers(-127, 128, shape)
+        elif leaf.endswith("_scale"):  # int8 rows of about unit size
+            val = rng.uniform(0.004, 0.012, shape)
+        else:  # rows the compute dtype could have made
+            val = np.asarray(jnp.asarray(rng.normal(0, 1, shape),
+                                         jnp.bfloat16))
+        state[f"{op.name}/{leaf}"] = jnp.asarray(val, dtype)
+    table = rng.permutation(a["num_pages"])[:2 * a["pages_per_seq"]]
+    table = jnp.asarray(table.reshape(2, -1), jnp.int32)
+    hidden = jnp.asarray(rng.normal(0, 1, (2, c, e)), jnp.float32)
+    return weights, state, table, hidden
+
+
+def _dense_chunk(op, ctx, inputs, weights):
+    """The whole-table formulation ``forward_chunk`` had: one row-sized
+    scatter a token, then every query against the sequence's WHOLE page
+    table, densified, with one softmax — the reference of the page-wise
+    write and the blocked walk."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.kernels.ragged_paged_attention import (
+        NEG_INF,
+        gather_kv_pages,
+        gather_kv_pages_quant,
+    )
+    from flexflow_tpu.ops.decode_attention import _quantize_kv
+
+    a = op.attrs
+    hidden, page_table, positions = inputs
+    cd = ctx.compute_dtype
+    x = hidden.astype(cd)
+    w = op.serving_weights(weights, cd)
+    q, k_new, v_new = op._project(x, w)
+    h, ps = a["num_heads"], a["page_size"]
+    qf = q.reshape(*x.shape[:2], h, op.head_dim)
+    slot = positions % ps
+    page = jnp.take_along_axis(
+        page_table, jnp.minimum(positions // ps, a["pages_per_seq"] - 1),
+        axis=1)
+    rows = {"k_cache": k_new, "v_cache": v_new}
+    if op.kv_dtype == "int8":
+        rows["k_cache"], rows["k_scale"] = _quantize_kv(k_new)
+        rows["v_cache"], rows["v_scale"] = _quantize_kv(v_new)
+    pools = {}
+    for leaf, r in rows.items():
+        pool = ctx.state_in[f"{op.name}/{leaf}"]
+        pools[leaf] = pool.at[page, slot].set(r.astype(pool.dtype))
+        ctx.state_out[f"{op.name}/{leaf}"] = pools[leaf]
+    if op.kv_dtype == "int8":
+        k_dense = gather_kv_pages_quant(pools["k_cache"], pools["k_scale"],
+                                        page_table, h)
+        v_dense = gather_kv_pages_quant(pools["v_cache"], pools["v_scale"],
+                                        page_table, h)
+    else:
+        k_dense = gather_kv_pages(pools["k_cache"], page_table, h)
+        v_dense = gather_kv_pages(pools["v_cache"], page_table, h)
+    s = jnp.einsum("bchd,bshd->bchs", qf, k_dense) / math.sqrt(op.head_dim)
+    seen = (jnp.arange(k_dense.shape[1])[None, None, :]
+            <= positions[:, :, None])
+    p = jax.nn.softmax(jnp.where(seen[:, :, None, :], s, NEG_INF), axis=-1)
+    out = jnp.einsum("bchs,bshd->bchd", p, v_dense)
+    y = jnp.dot(out.astype(cd).reshape(*x.shape[:2], -1), w["wo"],
+                preferred_element_type=jnp.float32)
+    return [y.astype(hidden.dtype)]
+
+
+def _run_chunk(fn, op, compute_dtype, weights, state, inputs):
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.ops.base import LoweringContext
+
+    def call(state, *inputs):
+        ctx = LoweringContext(compute_dtype=jnp.dtype(compute_dtype),
+                              train=False, state_in=state)
+        (y,) = fn(ctx, list(inputs), weights)
+        return y, {**state, **ctx.state_out}
+
+    return jax.jit(call)(state, *inputs)
+
+
+# two rows a case, (c0 of row 0, c0 of row 1); chunk 16, page 8, cap 64
+CHUNK_STARTS = {
+    "first": (0, 0),
+    "second": (16, 32),
+    "inside_a_page": (21, 3),
+    "pad_tail": (40, 45),
+    "clamped_at_cap": (56, 50),
+}
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
+@pytest.mark.parametrize("compute_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", CHUNK_STARTS)
+def test_chunk_matches_the_dense_whole_table_reference(
+        case, compute_dtype, kv_dtype):
+    """The chunk — its K/V written page by page, its queries walking key
+    blocks to the chunk's last position — against the whole-table
+    formulation: the outputs agree, and EVERY state leaf is the row
+    scatter's, bit for bit (the rows the chunk wrote, the rows of a
+    touched page it did not, every other page), wherever the run
+    starts: at 0, at a later chunk, inside a page, with a pad tail
+    (zero embeddings), folded onto ``cap - 1`` by the clamp."""
+    import jax.numpy as jnp
+
+    c = 16
+    op = _chunk_op(kv_dtype)
+    weights, state, table, hidden = _chunk_fixture(op, c, seed=len(case))
+    cap = op.max_seq_len
+    starts = np.asarray(CHUNK_STARTS[case])[:, None]
+    positions = jnp.asarray(
+        np.minimum(starts + np.arange(c), cap - 1), jnp.int32)
+    if case == "pad_tail":
+        hidden = hidden.at[:, 5:].set(0.0)
+    inputs = (hidden, table, positions)
+    want_y, want_state = _run_chunk(
+        lambda *a: _dense_chunk(op, *a), op, compute_dtype, weights, state,
+        inputs)
+    got_y, got_state = _run_chunk(
+        op.forward_chunk, op, compute_dtype, weights, state, inputs)
+    tol = 2e-2 if compute_dtype == "bfloat16" else 2e-5
+    np.testing.assert_allclose(np.asarray(got_y), np.asarray(want_y),
+                               atol=tol, rtol=tol)
+    assert got_state.keys() == want_state.keys()
+    for key, want in want_state.items():
+        np.testing.assert_array_equal(
+            np.asarray(got_state[key]), np.asarray(want), err_msg=key)
+        # the pages the run does not touch are the ones it was handed
+        touched = np.unique(np.asarray(jnp.take_along_axis(
+            table, positions // op.attrs["page_size"], axis=1)))
+        rest = np.setdiff1d(np.arange(op.attrs["num_pages"]), touched)
+        np.testing.assert_array_equal(
+            np.asarray(got_state[key])[rest], np.asarray(state[key])[rest],
+            err_msg=key)
+
+
+def test_chunk_takes_the_row_scatter_for_a_run_that_is_not_contiguous():
+    """Nothing sends one — ``run_chunked_prefill`` sends one contiguous
+    run — but positions that are not (here: reversed) still land row by
+    row, as the reference's do."""
+    import jax.numpy as jnp
+
+    c = 16
+    op = _chunk_op()
+    weights, state, table, hidden = _chunk_fixture(op, c)
+    positions = jnp.asarray(
+        np.stack([np.arange(c)[::-1], 2 * np.arange(c) + 7]), jnp.int32)
+    inputs = (hidden, table, positions)
+    want_y, want_state = _run_chunk(
+        lambda *a: _dense_chunk(op, *a), op, "float32", weights, state,
+        inputs)
+    got_y, got_state = _run_chunk(
+        op.forward_chunk, op, "float32", weights, state, inputs)
+    np.testing.assert_allclose(np.asarray(got_y), np.asarray(want_y),
+                               atol=2e-5, rtol=2e-5)
+    for key, want in want_state.items():
+        np.testing.assert_array_equal(
+            np.asarray(got_state[key]), np.asarray(want), err_msg=key)
+
+
+def _pool_updates(jaxpr, fallback=False):
+    """(primitive, update shape, inside the non-contiguous fallback) of
+    every scatter and dynamic_update_slice of a jaxpr, sub-jaxprs
+    included; a ``cond``'s branch 0 is what runs when its predicate is
+    False."""
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name.startswith("scatter"):
+            found.append((name, eqn.invars[2].aval.shape, fallback))
+        elif name == "dynamic_update_slice":
+            found.append((name, eqn.invars[1].aval.shape, fallback))
+        if name == "cond":
+            for i, branch in enumerate(eqn.params["branches"]):
+                found += _pool_updates(branch.jaxpr, fallback or i == 0)
+            continue
+        for sub in eqn.params.values():
+            for j in (sub if isinstance(sub, (tuple, list)) else (sub,)):
+                inner = getattr(j, "jaxpr", j)
+                if hasattr(inner, "eqns"):
+                    found += _pool_updates(inner, fallback)
+    return found
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
+def test_chunk_program_holds_no_row_sized_update_of_a_pool(kv_dtype):
+    """The chunk's jaxpr at the serving cell's geometry (page 32, chunk
+    64): every update of a pool on the path a contiguous run takes has
+    a PAGE-sized window, 64 / 32 + 1 = 3 of them a sequence; the one
+    scatter with 64 row-sized updates a pool sits in the branch that a
+    run which is not contiguous takes, and nowhere else."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.ops.base import LoweringContext
+
+    c, ps = 64, 32
+    op = _chunk_op(kv_dtype, embed_dim=128, num_heads=2, page_size=ps,
+                   pages_per_seq=8)
+    weights, state, table, hidden = _chunk_fixture(op, c)
+    positions = jnp.asarray(
+        np.stack([np.arange(c), 70 + np.arange(c)]), jnp.int32)
+
+    def call(state, hidden, table, positions):
+        ctx = LoweringContext(compute_dtype=jnp.bfloat16, train=False,
+                              state_in=state)
+        (y,) = op.forward_chunk(ctx, [hidden, table, positions], weights)
+        return y, ctx.state_out
+
+    jaxpr = jax.make_jaxpr(call)(state, hidden, table, positions).jaxpr
+    updates = _pool_updates(jaxpr)
+    hd = 128
+    pools = 4 if kv_dtype == "int8" else 2
+    taken = [(n, shape) for n, shape, fallback in updates if not fallback]
+    by_page = [shape for n, shape in taken if n.startswith("scatter")]
+    # [B, pages a run touches, page, (H·D)]: page-sized windows
+    assert sorted(by_page) == sorted(
+        [(2, 3, ps, hd)] * 2 + [(2, 3, ps)] * (pools - 2)), updates
+    # what else the taken path updates is a row's worth of the CHUNK, laid
+    # at its offset into the pages it covers: never a pool
+    assert all(shape[0] == c for n, shape in taken
+               if n == "dynamic_update_slice"), updates
+    by_row = [shape for n, shape, fallback in updates if fallback]
+    assert sorted(by_row) == sorted(
+        [(2, c, hd)] * 2 + [(2, c)] * (pools - 2)), updates
+
+
+def test_prefill_keys_counters_count_what_the_chunk_walks():
+    """``decode.prefill_keys_walked`` / ``_table`` at the serving cell's
+    geometry (page 32 x 32, chunk 64): a prompt of 65 tokens is one
+    chunk that walks one key block, one of 897 fourteen chunks, the
+    last of which walks the whole table — counted on the host from the
+    positions sent, by the function the op takes its trip count from."""
+    import jax.numpy as jnp
+
+    from flexflow_tpu.obs.metrics import METRICS
+    from flexflow_tpu.runtime.prefill import run_chunked_prefill
+
+    chunk, cap = 64, 1024
+    m = _compiled_small(batch=2, page_size=32, pages_per_seq=32, hidden=32,
+                        ff_dim=32, vocab=64)
+    step = compiled_decode_step(m, prefill_chunk=chunk)
+    ops = [n.op for n in m.graph.topo_order()
+           if n.op.op_type.name == "DECODE_ATTENTION"]
+    assert len(ops) == 2
+    block = ops[0].chunk_block_pages * 32
+    assert block == ops[0].CHUNK_KEY_BLOCK and cap % block == 0
+
+    def counters():
+        c = METRICS.snapshot()["counters"]
+        return (c.get("decode.prefill_keys_walked", 0),
+                c.get("decode.prefill_keys_table", 0))
+
+    sent = []
+
+    def prefill(ids, positions, table):
+        sent.append(positions)
+        step.prefill(ids, positions, table)
+
+    rng = np.random.default_rng(0)
+    for length, chunks in ((65, 1), (897, 14)):
+        del sent[:]
+        before = counters()
+        tokens = list(map(int, rng.integers(1, 63, size=length)))
+        assert run_chunked_prefill(prefill, tokens, list(range(32)),
+                                   chunk=chunk, cap=cap) == chunks
+        walked, table = (a - b for a, b in zip(counters(), before))
+        assert table == len(ops) * chunks * cap
+        # by hand: a chunk at c0 walks the blocks up to position c0 + 63
+        by_hand = sum((c0 + chunk - 1) // block + 1
+                      for c0 in range(0, length - 1, chunk)) * block
+        assert walked == len(ops) * by_hand
+        # and by the op: the trip count ``forward_chunk`` loops to
+        trips = sum(int(op.chunk_walk(jnp.asarray(p))[1])
+                    for op in ops for p in sent)
+        assert walked == trips * block
+    assert walked < table  # the last prompt: 14 chunks, 2 to 4 blocks of 4
+
+
 def test_chunk_forward_rejects_non_decode_graph():
     from flexflow_tpu.models import build_mlp_unify
     from flexflow_tpu.runtime.prefill import build_chunk_forward

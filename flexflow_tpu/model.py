@@ -1504,7 +1504,8 @@ class FFModel:
                 # the host is held in it — and, inside a
                 # device_trace_dir capture, the window trace_ingest
                 # assigns lane markers to
-                with annotate.phase_span(annotate.STEP_PHASE):
+                with annotate.phase_span(annotate.STEP_PHASE,
+                                         key=self._rng_counter):
                     if profiler is not None:
                         profiler.start_step()
                         profiler.start_phase("dispatch")

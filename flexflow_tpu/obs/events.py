@@ -154,6 +154,11 @@ EVENT_KINDS = {
     # file; flight.dump is emitted on the bus when a post-mortem was
     # written (fault injection, controller fallback, atexit/SIGTERM)
     "flight.meta": {"reason", "events", "dropped"},
+    # the program's timeline (obs/annotate.py): the last closed
+    # ``phase_span``s, in flight-recorder dumps only, after the events;
+    # ``start_s`` is on time.perf_counter's clock, ``ts`` the close on
+    # the wall clock, ``parent`` a ``seq`` (0: a root)
+    "phase.span": {"tag", "start_s", "dur_s", "seq", "parent"},
     "flight.dump": {"path", "events", "open_spans", "reason"},
     # event-volume guard roll-up: per-kind counts the sampler
     # suppressed (emitted at close so totals stay exactly recoverable)

@@ -10,8 +10,9 @@ events are always in memory (a ``collections.deque`` append of an
 already-built payload — no JSON encoding, no I/O), and a dump site
 (fault injector, controller fallback, atexit/SIGTERM when armed with a
 dump dir, or an explicit ``FLIGHT.dump``) writes them out together
-with the tracer's still-open spans — the in-flight requests at the
-moment of death.
+with the last closed spans of the program's own timeline
+(``obs/annotate.py``: what the host was doing) and the tracer's
+still-open spans — the in-flight requests at the moment of death.
 
 Overhead discipline mirrors the bus: ``FLIGHT.enabled`` is a plain
 attribute checked once per emit; ``FLEXFLOW_TPU_FLIGHT=0`` turns the
@@ -20,8 +21,10 @@ recorder off entirely, ``FLEXFLOW_TPU_FLIGHT_RING`` resizes the ring
 the atexit/SIGTERM hook) into that directory.
 
 Dump format: JSONL, first line a ``flight.meta`` record (reason,
-counts), then the ring's events verbatim (oldest first), then one
-``trace.open`` line per still-open span.  ``ffobs trace`` renders it.
+counts), then the ring's events verbatim (oldest first), then the last
+``PHASE_SPANS`` closed ``phase_span``s as ``phase.span`` lines (tag,
+start, duration, seq, parent, key), then one ``trace.open`` line per
+still-open span.  ``ffobs trace`` renders it.
 """
 
 from __future__ import annotations
@@ -35,6 +38,7 @@ import time
 from typing import Deque, List, Optional, Tuple
 
 _DEF_CAPACITY = 512
+PHASE_SPANS = 512  # of the timeline's ring, the newest: a dump stays small
 
 
 class FlightRecorder:
@@ -122,20 +126,31 @@ class FlightRecorder:
                 self.dump_dir,
                 f"flight-{os.getpid()}-{self.dumps:03d}.jsonl")
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+        from flexflow_tpu.obs.annotate import timeline
         from flexflow_tpu.obs.events import BUS, _jsonable
         from flexflow_tpu.obs.tracing import TRACER
 
         events = list(self.ring)
+        phase_spans = timeline()[-PHASE_SPANS:]
+        # what moves a perf_counter stamp onto the events' wall clock
+        wall = time.time() - time.perf_counter_ns() * 1e-9
         open_spans = TRACER.open_spans()
         with open(path, "w") as f:
             meta = {"ts": time.time(), "kind": "flight.meta",
                     "reason": reason, "events": len(events),
-                    "dropped": max(self.recorded - len(events), 0)}
+                    "dropped": max(self.recorded - len(events), 0),
+                    "phase_spans": len(phase_spans)}
             f.write(json.dumps(meta, default=_jsonable) + "\n")
             for t, kind, payload in events:
                 evt = {"ts": t, "kind": kind}
                 evt.update(payload)
                 f.write(json.dumps(evt, default=_jsonable) + "\n")
+            for seq, parent, tag, t0, t1, key in phase_spans:
+                f.write(json.dumps(
+                    {"ts": wall + t1 * 1e-9, "kind": "phase.span",
+                     "tag": tag, "start_s": t0 * 1e-9,
+                     "dur_s": (t1 - t0) * 1e-9, "seq": seq,
+                     "parent": parent, "key": key}) + "\n")
             for span in open_spans:
                 evt = {"ts": time.time(), "kind": "trace.open",
                        "trace_id": span.trace_id, "span": span.name,

@@ -11,8 +11,9 @@ spans open at every lifecycle edge:
 * ``route``   — the router decision (replica tag), zero-duration;
 * ``queue``   — enqueue → admission (re-opened on preemption re-queue,
   so a preempted request's timeline partitions into residency windows);
-* ``prefill`` — admission → prompt cached (with one ``prefill.chunk``
-  child per batched chunk pass, runtime/prefill.py);
+* ``prefill`` — admission → prompt cached (its chunk passes are the
+  ``ff.phase/prefill_chunk`` spans of the program's timeline,
+  obs/annotate.py);
 * ``decode``  — decode-loop residency (prompt cached → EOS/evict/
   preempt);
 * the root ``request`` span closes at eviction/EOS/expiry with the
@@ -50,9 +51,9 @@ import os
 import time
 from typing import Dict, Iterable, List, Optional, Tuple
 
-# the phase children that PARTITION a request's lifetime (route and
-# prefill.chunk nest inside them; their durations must not be double
-# counted by the sum-to-e2e validation)
+# the phase children that PARTITION a request's lifetime (route nests
+# inside them; its duration must not be double counted by the
+# sum-to-e2e validation)
 REQUEST_PHASES = ("queue", "prefill", "decode")
 REQUEST_ROOT = "request"
 EPISODE_ROOT = "controller.episode"

@@ -722,8 +722,7 @@ class ContinuousBatchingExecutor:
                              requeue=True)
         return True
 
-    def _run_prefill(self, live: _Live, obs: bool,
-                     tr: bool = False) -> None:
+    def _run_prefill(self, live: _Live, obs: bool) -> None:
         """The chunked prefill lane: write the sequence's first
         ``len(tokens) - 1`` cached-to-be tokens through the batched
         chunk writer (``run_chunked_prefill``, runtime/prefill.py), so
@@ -741,8 +740,7 @@ class ContinuousBatchingExecutor:
             self.prefill_fn, live.tokens, live.pages,
             chunk=self.prefill_chunk,
             cap=self.page_size * self.pages_per_seq,
-            start=start,
-            trace_id=TRACER.trace_of(live.req.rid) if tr else None)
+            start=start)
         live.cached = n_pre
         self.prefill_chunks += chunks
         self.prefill_tokens += n_pre - start
@@ -850,7 +848,7 @@ class ContinuousBatchingExecutor:
                 TRACER.begin(tid, "prefill", parent="request",
                              slot=i, pages=len(pages),
                              cached_prefix=matched)
-            self._run_prefill(live, obs, tr)
+            self._run_prefill(live, obs)
             if self.prefix_sharing and live.cached:
                 # publish this sequence's fully-cached pages (claimed
                 # ones are already in the trie and skip out)
@@ -1094,7 +1092,7 @@ class ContinuousBatchingExecutor:
         per frame when they are off (test-enforced)."""
         obs = BUS.enabled  # ONE check per frame gates every event
         tr = TRACER.enabled  # ditto for the request span tree
-        with phase_span(annotate.DECODE_PHASE):
+        with phase_span(annotate.DECODE_PHASE, key=self.frame):
             with phase_span(_ADMIT):
                 admitted = self._admit(obs, tr)
             with phase_span(_COMPOSE):
@@ -1559,13 +1557,18 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
     cold = {"decode_frame", "prefill_chunk"}  # programs never called yet
 
     def call(program, jitted, *args):
-        """``jitted(*args)``; its FIRST call — trace, lower and compile
-        or load from the cache — shows on the timeline as
-        ``ff.phase/setup.first_call.<program>``."""
-        if program not in cold:
-            return jitted(*args)
-        cold.discard(program)
-        with phase_span(annotate.FIRST_CALL_PHASE + program):
+        """``jitted(*args)`` and nothing else under a span of its own:
+        ``ff.phase/call.<program>`` — flattening the arguments, the
+        enqueue, any wait for room in the device's queue; what the span
+        around it holds besides is the Python of ``step`` / ``prefill``.
+        The FIRST call — trace, lower and compile or load from the
+        cache — is ``ff.phase/setup.first_call.<program>`` instead."""
+        if program in cold:
+            cold.discard(program)
+            tag = annotate.FIRST_CALL_PHASE + program
+        else:
+            tag = annotate.CALL_PHASE + program
+        with phase_span(tag):
             return jitted(*args)
 
     # the tokens the last frame chose, as the next frame's state carries

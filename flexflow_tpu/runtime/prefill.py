@@ -37,7 +37,7 @@ masking, no dynamic shapes, one compiled program per chunk size.
 
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Sequence, Tuple
 
 import numpy as np
 
@@ -49,8 +49,7 @@ from flexflow_tpu.ops.inout import InputOp
 
 def run_chunked_prefill(prefill_fn: Callable, tokens: Sequence[int],
                         pages: Sequence[int], *, chunk: int, cap: int,
-                        start: int = 0,
-                        trace_id: Optional[str] = None) -> int:
+                        start: int = 0) -> int:
     """Drive the chunk writer over a prompt: write ``tokens[start:-1]``
     into the sequence's pages in ``ceil((len-1-start)/chunk)``
     fixed-shape passes (the decode loop then starts at the LAST
@@ -70,29 +69,21 @@ def run_chunked_prefill(prefill_fn: Callable, tokens: Sequence[int],
     lets the op write the run page by page and walk its keys only to
     the run's last position.
 
-    When ``trace_id`` names a live request trace, each pass closes as
-    one ``prefill.chunk`` child span under the open ``prefill`` span —
-    the per-chunk attribution the request span tree renders."""
+    Each pass is one ``ff.phase/prefill_chunk`` span of the program's
+    timeline (``obs/annotate.py``), under the ``ff.phase/serve.admit``
+    of the frame that admitted the request."""
     n_pre = len(tokens) - 1
     if n_pre - start <= 0:
         return 0
-    tracer = None
-    if trace_id is not None:
-        from flexflow_tpu.obs.tracing import TRACER as tracer
     table = np.asarray(pages, np.int32)[None, :]  # [1, P]
     chunks = 0
     for c0 in range(start, n_pre, chunk):
-        if tracer is not None:
-            tracer.begin(trace_id, "prefill.chunk", parent="prefill",
-                         c0=c0)
         ids = np.zeros((1, chunk), np.int32)
         valid = min(chunk, n_pre - c0)
         ids[0, :valid] = tokens[c0:c0 + valid]
         pos = np.minimum(c0 + np.arange(chunk), cap - 1)
         with phase_span(PREFILL_PHASE):  # the chunk's dispatch
             prefill_fn(ids, pos[None, :].astype(np.int32), table)
-        if tracer is not None:
-            tracer.end(trace_id, "prefill.chunk", tokens=valid)
         chunks += 1
     return chunks
 

@@ -56,8 +56,14 @@ def test_decode_request_spans_one_bus_check_per_frame(monkeypatch):
     """The off-by-default contract on the decode hot path: with
     FLEXFLOW_TPU_OBS unset, request-span instrumentation must cost
     exactly one ``BUS.enabled`` read per frame (plus one per submit
-    batch and one at run end) — no per-slot stamps, no histogram
-    traffic, no lifecycle records."""
+    batch and one at run end) and one ``TRACER.enabled`` read per frame
+    (plus one per submit batch) — no per-slot stamps, no histogram
+    traffic, no lifecycle records.  The program's span timeline
+    (obs/annotate.py) is always on and asks neither: the run leaves one
+    ``decode_frame`` tree a frame in the ring all the same."""
+    import time
+
+    from flexflow_tpu.obs import annotate
     from flexflow_tpu.runtime import decode as decode_mod
     from flexflow_tpu.runtime.decode import (
         ContinuousBatchingExecutor,
@@ -76,8 +82,22 @@ def test_decode_request_spans_one_bus_check_per_frame(monkeypatch):
         def emit(self, *a, **k):  # pragma: no cover — enabled is False
             raise AssertionError("emit while disabled")
 
-    bus = CountingBus()
+    class CountingTracer:
+        def __init__(self):
+            self.reads = 0
+
+        @property
+        def enabled(self):
+            self.reads += 1
+            return False
+
+        def __getattr__(self, name):  # pragma: no cover — enabled is False
+            raise AssertionError(f"TRACER.{name} while disabled")
+
+    bus, tracer = CountingBus(), CountingTracer()
     monkeypatch.setattr(decode_mod, "BUS", bus)
+    monkeypatch.setattr(decode_mod, "TRACER", tracer)
+    since = time.perf_counter_ns()
 
     def step(ids, table, lens):
         b = np.asarray(ids).shape[0]
@@ -92,6 +112,10 @@ def test_decode_request_spans_one_bus_check_per_frame(monkeypatch):
     frames = ex.frame
     # one read per frame + one per submit batch + one at run end
     assert bus.reads <= frames + 2, (bus.reads, frames)
+    assert tracer.reads <= frames + 1, (tracer.reads, frames)
+    roots = [s for s in annotate.timeline(since)
+             if s[2] == annotate.DECODE_PHASE]
+    assert [s[5] for s in roots] == list(range(frames))
     # and none of the span machinery ran
     assert ex.request_records == []
     assert ex.queue == []

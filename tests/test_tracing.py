@@ -272,6 +272,46 @@ def test_flight_ring_records_disabled_bus_and_bounds(tmp_path):
         FLIGHT.configure(capacity=512)
 
 
+def test_flight_dump_carries_the_last_phase_spans_after_the_events(tmp_path):
+    """The operator's way to the program's timeline (obs/annotate.py):
+    a dump writes the newest ``PHASE_SPANS`` closed spans as
+    ``phase.span`` lines — tag, start, duration, seq, parent, key —
+    after the events, each a valid event."""
+    import time
+
+    from flexflow_tpu.obs import flight
+    from flexflow_tpu.obs.annotate import PHASE_PREFIX, phase_span, timeline
+    from flexflow_tpu.obs.events import validate_event
+
+    FLIGHT.reset()
+    BUS.emit("search.log", msg="before")
+    since = time.perf_counter_ns()
+    for k in range(flight.PHASE_SPANS):  # 2 spans each: the first fall out
+        with phase_span(PHASE_PREFIX + "t.dump_root", key=k):
+            with phase_span(PHASE_PREFIX + "t.dump_child"):
+                pass
+    path = str(tmp_path / "dump.jsonl")
+    wall = time.time()
+    assert FLIGHT.dump(path, reason="test") == path
+    rows = [json.loads(ln) for ln in open(path)]
+    assert rows[0]["phase_spans"] == flight.PHASE_SPANS
+    kinds = [r["kind"] for r in rows]
+    assert kinds == (["flight.meta", "search.log"]
+                     + ["phase.span"] * flight.PHASE_SPANS)
+    spans = [r for r in rows if r["kind"] == "phase.span"]
+    assert all(validate_event(r) == [] for r in spans)
+    want = timeline(since)[-flight.PHASE_SPANS:]
+    assert [(r["seq"], r["parent"], r["tag"], r["key"]) for r in spans] == [
+        (seq, parent, tag, key) for seq, parent, tag, _, _, key in want]
+    for r, (_, _, _, t0, t1, _) in zip(spans, want):
+        assert r["start_s"] == pytest.approx(t0 * 1e-9, abs=1e-9)
+        assert r["dur_s"] == pytest.approx((t1 - t0) * 1e-9, abs=1e-12)
+        assert wall - 60 < r["ts"] <= wall + 1  # the close, on the wall clock
+    child, root = spans[-2:]
+    assert child["parent"] == root["seq"] and root["parent"] == 0
+    assert child["key"] == root["key"] == flight.PHASE_SPANS - 1
+
+
 def test_flight_disabled_is_a_true_noop(tmp_path):
     FLIGHT.reset()
     FLIGHT.enabled = False
@@ -402,3 +442,57 @@ def test_ffobs_trace_renders_and_flags_orphans(tmp_path):
         capture_output=True, text=True, timeout=120)
     assert proc.returncode == 1
     assert "ORPHAN" in proc.stdout
+
+
+def test_ffobs_trace_renders_the_phase_spans_of_a_flight_dump(tmp_path):
+    """``phase.span`` lines come out as the program's timeline: trees by
+    ``parent``, oldest first, a root with its key; a span whose parent
+    the dump does not hold (it was still open) stands as a root and says
+    so — beside the request trees, and alone."""
+    import subprocess
+    import sys
+
+    def span(seq, parent, tag, start, dur, key):
+        return {"ts": 100.0 + start + dur, "kind": "phase.span",
+                "tag": "ff.phase/" + tag, "start_s": start, "dur_s": dur,
+                "seq": seq, "parent": parent, "key": key}
+
+    rows = [
+        {"ts": 1.0, "kind": "flight.meta", "reason": "t", "events": 0,
+         "dropped": 0, "phase_spans": 5},
+        span(12, 10, "serve.dispatch", 5.0020, 0.0015, 7),
+        span(13, 12, "call.decode_frame", 5.0022, 0.0011, 7),
+        span(11, 10, "serve.admit", 5.0001, 0.0004, 7),
+        span(10, 0, "decode_frame", 5.0, 0.004, 7),
+        span(21, 20, "serve.admit", 5.0051, 0.0002, 8),
+    ]
+    log = tmp_path / "dump.jsonl"
+    log.write_text("".join(json.dumps(r) + "\n" for r in rows))
+    ffobs = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "ffobs.py")
+    proc = subprocess.run(
+        [sys.executable, ffobs, "trace", str(log)],
+        capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr
+    assert proc.stdout.splitlines()[:6] == [
+        "program timeline  (5 spans)",
+        "  ff.phase/decode_frame  4.000 ms  key=7",
+        "    ff.phase/serve.admit  0.400 ms",
+        "    ff.phase/serve.dispatch  1.500 ms",
+        "      ff.phase/call.decode_frame  1.100 ms",
+        "  ff.phase/serve.admit  0.200 ms  key=8  "
+        "(inside span 20, still open)",
+    ]
+    assert "no trace.span events" not in proc.stdout
+    # beside a request tree, after it
+    rows.append({"ts": 1.0, "kind": "trace.span", "trace_id": "r0#1",
+                 "span": "request", "span_id": 1, "parent_id": None,
+                 "start_s": 0.0, "end_s": 1.0, "dur_s": 1.0})
+    log.write_text("".join(json.dumps(r) + "\n" for r in rows))
+    proc = subprocess.run(
+        [sys.executable, ffobs, "trace", str(log)],
+        capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr
+    out = proc.stdout
+    assert out.index("trace r0#1") < out.index("program timeline")
+    assert "0 orphan span(s)" in out

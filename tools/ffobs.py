@@ -878,12 +878,48 @@ def _span_label(e: dict) -> str:
     return "  ".join(bits)
 
 
+def _phase_span_lines(events: List[dict]) -> List[str]:
+    """The program's own timeline out of a flight-recorder dump
+    (``phase.span`` lines: the last closed ``phase_span``s,
+    obs/annotate.py), each tree indented under its root, oldest first.
+    A span closes before the span around it, so a parent the dump does
+    not hold was still OPEN when it was written: its children stand as
+    roots and say so."""
+    spans = sorted((e for e in events if e.get("kind") == "phase.span"),
+                   key=lambda e: e["start_s"])
+    if not spans:
+        return []
+    held = {e["seq"] for e in spans}
+    children: Dict[int, List[dict]] = defaultdict(list)
+    lines = [f"program timeline  ({len(spans)} spans)"]
+
+    def walk(e: dict, depth: int) -> None:
+        bits = [e["tag"], f"{e['dur_s'] * 1e3:.3f} ms"]
+        if depth == 1:
+            if e.get("key") is not None:
+                bits.append(f"key={e['key']}")
+            if e["parent"]:
+                bits.append(f"(inside span {e['parent']}, still open)")
+        lines.append("  " * depth + "  ".join(bits))
+        for c in children.get(e["seq"], ()):
+            walk(c, depth + 1)
+
+    roots = []
+    for e in spans:
+        (children[e["parent"]] if e["parent"] in held else roots).append(e)
+    for r in roots:
+        walk(r, 1)
+    lines.append("")
+    return lines
+
+
 def render_trace_trees(events: List[dict],
                        trace_id: Optional[str] = None,
                        limit: int = 0) -> str:
     """Span forests as indented trees — from a bus JSONL
     (``trace.span`` events) or a flight-recorder dump (``trace.span``
-    + ``trace.open`` lines).  Orphan spans (a ``parent_id`` the log
+    + ``trace.open`` lines, then the program's timeline: its
+    ``phase.span`` lines).  Orphan spans (a ``parent_id`` the log
     holds no span for) are listed per trace as validation failures."""
     from flexflow_tpu.obs.tracing import span_forest
 
@@ -934,6 +970,8 @@ def render_trace_trees(events: List[dict],
             lines.append(f"  ORPHAN (parent {o.get('parent_id')} "
                          f"missing): {_span_label(o)}")
         lines.append("")
+    if trace_id is None:
+        lines += _phase_span_lines(events)
     if not lines:
         return ("no trace.span events (arm the tracer: "
                 "FLEXFLOW_TPU_TRACE=1 with the bus on, or read a "

@@ -119,6 +119,10 @@ _LIVE_PAGES = METRICS.counter("decode.live_pages")
 _PAGE_SLOTS = METRICS.counter("decode.page_slots")
 _TOKENS_GENERATED = METRICS.counter("decode.tokens_generated")
 _PREFILL_CHUNKS = METRICS.counter("decode.prefill_chunks")
+# calls of the prefill program: one an admission that prefills, however
+# many chunks its prompt takes (÷ into ``decode.prefill_chunks``: the
+# chunks a call runs)
+_PREFILL_CALLS = METRICS.counter("decode.prefill_calls")
 _PREFILL_TOKENS = METRICS.counter("decode.prefill_tokens")
 _PROMPT_TOKENS = METRICS.counter("decode.prompt_tokens")
 _PREFIX_HIT_TOKENS = METRICS.counter("decode.prefix_hit_tokens")
@@ -596,6 +600,7 @@ class ContinuousBatchingExecutor:
         self.total_expired = 0
         self.total_preempted = 0
         self.prefill_chunks = 0  # chunked-prefill passes run
+        self.prefill_calls = 0  # prefill_fn calls: one a prefilled prompt
         self.prefill_tokens = 0  # prompt tokens written by the lane
         # prefix-sharing roll-up (all zero while sharing is off)
         self.prefix_hits = 0     # admissions that claimed a cached prefix
@@ -725,7 +730,8 @@ class ContinuousBatchingExecutor:
     def _run_prefill(self, live: _Live, obs: bool) -> None:
         """The chunked prefill lane: write the sequence's first
         ``len(tokens) - 1`` cached-to-be tokens through the batched
-        chunk writer (``run_chunked_prefill``, runtime/prefill.py), so
+        chunk writer (``run_chunked_prefill``, runtime/prefill.py: ONE
+        ``prefill_fn`` call for all of the prompt's chunks), so
         the decode loop starts at the LAST token and produces the first
         generated token in its first frame.  Under prefix sharing the
         first ``live.cached`` tokens are already in claimed/copied
@@ -743,8 +749,10 @@ class ContinuousBatchingExecutor:
             start=start)
         live.cached = n_pre
         self.prefill_chunks += chunks
+        self.prefill_calls += 1
         self.prefill_tokens += n_pre - start
         _PREFILL_CHUNKS.inc(chunks)
+        _PREFILL_CALLS.inc()
         _PREFILL_TOKENS.inc(n_pre - start)
         if obs:
             BUS.emit("decode.prefill", rid=live.req.rid,
@@ -1401,8 +1409,10 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
     writer over the SAME graph, params and state (runtime/prefill.py —
     one parameter set by construction, the cache scatter lands in the
     placed state arrays), attached as
-    ``step.prefill(ids [1,C], positions [1,C], page_table [1,P])`` for
-    the executor's ``prefill_fn``.
+    ``step.prefill(ids [1,n·C], positions [1,n·C], page_table [1,P])``
+    for the executor's ``prefill_fn``: a run of n chunks
+    (``run_chunked_prefill``'s) in ONE call of one program, which loops
+    over them on the device.
 
     The weights are NOT ``model.params``: ``step.weights`` is the
     served tree — every op's ``Operator.serving_weights`` of its own
@@ -1423,7 +1433,10 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
     ``step.state["state"]`` is the state the step hands it: the
     model's, and ``last_tokens``; over a state without that leaf every
     id is taken as given), ``step.chunk_fn`` the jitted
-    prefill chunk (``(weights, state, ids, positions, page_table)``);
+    prefill program (``(weights, state, ids, positions, page_table,
+    n_chunks)``: the first ``n_chunks`` C-slices of ids and positions
+    [B, L], in order — ``step.prefill`` pads every run to L = the
+    context in whole chunks);
     either takes ``step.weights`` — the program ``step()`` and
     ``step.prefill()`` run — or ``model.params``, whose fp32 [E, H, D]
     leaves it then converts and fuses inside the call (the same
@@ -1641,11 +1654,15 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
 
     step.copy_page = copy_page
     if prefill_chunk:
-        from flexflow_tpu.runtime.prefill import build_chunk_forward
+        from flexflow_tpu.runtime.prefill import build_run_forward
 
-        pf = jax.jit(build_chunk_forward(model.graph,
-                                         compiled.compute_dtype),
+        pf = jax.jit(build_run_forward(model.graph, compiled.compute_dtype,
+                                       prefill_chunk),
                      donate_argnums=(1,))
+        # every run is padded to ONE width, the context in whole chunks:
+        # one program for every prompt length
+        cap = decode_ops[0].max_seq_len
+        width = -(-cap // prefill_chunk) * prefill_chunk
 
         # the layers whose chunks walk alike, as {kind: (one of them, how
         # many)}: the host prices a chunk's walk once a kind, not a layer
@@ -1656,13 +1673,24 @@ def compiled_decode_step(model, prefill_chunk: int = 0) -> Callable:
         table_keys = sum(op.max_seq_len for op in decode_ops)
 
         def prefill(ids, positions, page_table):
-            model.state = call("prefill_chunk", pf, weights(),
-                               model.state, ids, positions, page_table)
-            positions = np.asarray(positions)
-            _PREFILL_KEYS_WALKED.inc(sum(
-                n * op.chunk_keys_walked(positions)
-                for op, n in walks.values()))
-            _PREFILL_KEYS_TABLE.inc(table_keys * len(positions))
+            ids = np.asarray(ids, np.int32)
+            positions = np.asarray(positions, np.int32)
+            rows, sent = ids.shape
+            n = sent // prefill_chunk
+            assert n * prefill_chunk == sent and 0 < sent <= width, (
+                f"a run of {sent} positions is not 1 to "
+                f"{width // prefill_chunk} chunks of {prefill_chunk}")
+            run_ids = np.zeros((rows, width), np.int32)
+            run_pos = np.full((rows, width), cap - 1, np.int32)
+            run_ids[:, :sent], run_pos[:, :sent] = ids, positions
+            model.state = call("prefill_chunk", pf, weights(), model.state,
+                               run_ids, run_pos, page_table, np.int32(n))
+            for c0 in range(0, sent, prefill_chunk):
+                _PREFILL_KEYS_WALKED.inc(sum(
+                    k * op.chunk_keys_walked(
+                        positions[:, c0:c0 + prefill_chunk])
+                    for op, k in walks.values()))
+            _PREFILL_KEYS_TABLE.inc(table_keys * rows * n)
 
         prefill.needs_slot_aligned = bool(windows)
         step.prefill = prefill

@@ -7,7 +7,10 @@ graph: one full decode frame per prompt token, so TTFT paid
 ``len(prompt)`` frame dispatches.  This module builds the batched KV
 writer: the prompt's causal forward runs once per C-token CHUNK (C a
 config knob, ``FFConfig.prefill_chunk``) and scatters the chunk's K/V
-directly into the sequence's page-pool pages, after which the sequence
+directly into the sequence's page-pool pages — all of a prompt's chunks
+in ONE program call, a loop over them on the device
+(``build_run_forward``), so the host pays one dispatch an admission and
+not one a chunk — after which the sequence
 joins the decode loop at its LAST prompt token — the first generated
 token still comes out of the decode graph, so the chunked path is
 token-identical to the prefill-via-decode oracle (test-enforced across
@@ -32,7 +35,8 @@ Positions past the prompt (the fixed-shape chunk's pad tail) are
 clamped into the sequence's own page allotment: a pad write lands at a
 FUTURE position, and the decode loop rewrites every position in the
 frame that first reads it, so pad garbage is dead by construction — no
-masking, no dynamic shapes, one compiled program per chunk size.
+masking, no dynamic shapes, one compiled program per chunk size (the
+run is padded to the context and its chunk count is a traced scalar).
 """
 
 from __future__ import annotations
@@ -52,8 +56,9 @@ def run_chunked_prefill(prefill_fn: Callable, tokens: Sequence[int],
                         start: int = 0) -> int:
     """Drive the chunk writer over a prompt: write ``tokens[start:-1]``
     into the sequence's pages in ``ceil((len-1-start)/chunk)``
-    fixed-shape passes (the decode loop then starts at the LAST
-    token).  Returns the number of chunk passes paid.
+    fixed-shape chunks, all of them sent in ONE ``prefill_fn`` call (the
+    decode loop then starts at the LAST token).  Returns the number of
+    chunks.
 
     ``start`` is the prefix-sharing skip-ahead (runtime/decode.py):
     the first ``start`` tokens already live in pages the admission
@@ -62,29 +67,29 @@ def run_chunked_prefill(prefill_fn: Callable, tokens: Sequence[int],
     shift, positions stay absolute, and the already-cached pages are
     never touched.
 
-    Every pass sends the positions ``DecodeAttentionOp.forward_chunk``
-    states its contract on — the one place it is written: ONE
-    contiguous run ``c0 … c0 + chunk − 1`` clamped at ``cap - 1``, the
-    prompt's tokens first, the pad tail after them — which is what
-    lets the op write the run page by page and walk its keys only to
-    the run's last position.
+    The call is sent a RUN of n chunks: ids and positions ``[1, n·C]``,
+    the positions ``c0 … c0 + n·C − 1`` clamped at ``cap − 1``, the
+    prompt's tokens first and the pad tail after them.  Each C-slice of
+    the run is exactly what ``DecodeAttentionOp.forward_chunk`` states
+    its contract on — the one place it is written: ONE contiguous run
+    ``c0 … c0 + C − 1`` clamped at ``cap − 1`` — which is what lets the
+    op write the slice page by page and walk its keys only to the
+    slice's last position.  The writer runs the slices in order, slice
+    i + 1 attending to slice i's K/V (``build_run_forward``).
 
-    Each pass is one ``ff.phase/prefill_chunk`` span of the program's
+    The call is one ``ff.phase/prefill_chunk`` span of the program's
     timeline (``obs/annotate.py``), under the ``ff.phase/serve.admit``
     of the frame that admitted the request."""
     n_pre = len(tokens) - 1
     if n_pre - start <= 0:
         return 0
+    chunks = -(-(n_pre - start) // chunk)
+    ids = np.zeros((1, chunks * chunk), np.int32)
+    ids[0, :n_pre - start] = tokens[start:n_pre]
+    pos = np.minimum(start + np.arange(chunks * chunk), cap - 1)
     table = np.asarray(pages, np.int32)[None, :]  # [1, P]
-    chunks = 0
-    for c0 in range(start, n_pre, chunk):
-        ids = np.zeros((1, chunk), np.int32)
-        valid = min(chunk, n_pre - c0)
-        ids[0, :valid] = tokens[c0:c0 + valid]
-        pos = np.minimum(c0 + np.arange(chunk), cap - 1)
-        with phase_span(PREFILL_PHASE):  # the chunk's dispatch
-            prefill_fn(ids, pos[None, :].astype(np.int32), table)
-        chunks += 1
+    with phase_span(PREFILL_PHASE):  # the run's dispatch
+        prefill_fn(ids, pos[None, :].astype(np.int32), table)
     return chunks
 
 
@@ -119,12 +124,21 @@ def build_chunk_forward(graph, compute_dtype) -> Callable:
     page_table [B, P]) -> new_state`` lowering the decode graph for a
     C-token chunk.  Position-wise ops run their ordinary ``forward``;
     the seq_lens->pos_ids reshape becomes identity (positions already
-    arrive [B, C]); decode attention takes its chunk twin.  Everything
-    downstream of the last cache write (final LN, lm_head) is dead code
-    the jit prunes — prefill produces STATE, not logits."""
+    arrive [B, C]); decode attention takes its chunk twin.  Prefill
+    produces STATE, not logits: only the ops a state write depends on
+    run, so nothing past the last one (the last layer's tail, the final
+    LN, lm_head) is traced or handed its weights — also inside
+    ``build_run_forward``'s loop, whose body XLA would not prune of
+    them."""
     tok_guid, pt_guid, sl_guid = prefill_io_nodes(graph)
     dec_guids = set(_decode_guids(graph))
-    topo = graph.topo_order()
+    needed = set()  # an op that may write state, and what it reads
+    for node in reversed(graph.topo_order()):
+        if (node.guid in needed or node.op.writes_state
+                or getattr(node.op, "state_specs", None) is not None):
+            needed.add(node.guid)
+            needed.update(e.src for e in graph.in_edges[node.guid])
+    topo = [n for n in graph.topo_order() if n.guid in needed]
     for node in topo:  # fail at build time, not inside the jit
         ot = node.op.op_type
         if ot == OperatorType.RESHAPE:
@@ -161,6 +175,36 @@ def build_chunk_forward(graph, compute_dtype) -> Callable:
         new_state = dict(state)
         new_state.update(ctx.state_out)
         return new_state
+
+    return fwd
+
+
+def build_run_forward(graph, compute_dtype, chunk: int) -> Callable:
+    """A pure function ``(params, state, ids [B, L], positions [B, L],
+    page_table [B, P], n_chunks) -> new_state`` that runs the first
+    ``n_chunks`` C-slices of a run (``run_chunked_prefill``'s) in ONE
+    program: a device-side loop whose trip count is traced, each pass
+    ``build_chunk_forward``'s chunk on slice i, the state carried — so
+    slice i + 1 attends to slice i's K/V exactly as a second call of
+    the chunk would, and the state after the loop is, bit for bit, the
+    state after n such calls.  The caller fixes L (a multiple of C at
+    least the context), so one program serves every prompt length."""
+    import jax
+
+    chunk_fwd = build_chunk_forward(graph, compute_dtype)
+
+    # named ``fwd``: a device trace shows the program as ``jit_fwd(..)``,
+    # the module the benchmark's readers look for
+    def fwd(params, state, ids, positions, page_table, n_chunks):
+        def one(i, state):
+            return chunk_fwd(
+                params, state,
+                jax.lax.dynamic_slice_in_dim(ids, i * chunk, chunk, axis=1),
+                jax.lax.dynamic_slice_in_dim(positions, i * chunk, chunk,
+                                             axis=1),
+                page_table)
+
+        return jax.lax.fori_loop(0, n_chunks, one, state)
 
     return fwd
 

@@ -155,6 +155,50 @@ def test_served_logits_equal_the_reference_past_window_and_wrap(
                       "layer3_attn_window/k_cache": SLOTS * 6}
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_one_call_over_a_prompts_chunks_equals_a_call_a_chunk(dtype):
+    """The prefill program's loop over a prompt's chunks, over ring
+    pages: a 93-token prompt (12 chunks of 8, the last with 4 pad rows)
+    crosses the window (32) and wraps the window layers' ring of 6
+    pages; sent as ONE run it leaves every state leaf — the global
+    pool, the rings, the experts' device counters — bit for bit as the
+    same chunks sent one call each, from a state seeded everywhere."""
+    from flexflow_tpu.runtime.prefill import run_chunked_prefill
+
+    model = build(dtype)
+    step = compiled_decode_step(model, prefill_chunk=CHUNK)
+    rng = np.random.default_rng(9)
+    seeded = {}
+    for key, val in sorted(model.state.items()):
+        draw = (rng.integers(0, 1000, val.shape)
+                if jnp.issubdtype(val.dtype, jnp.integer)
+                else rng.normal(0, 1, val.shape))
+        seeded[key] = np.asarray(draw).astype(val.dtype)
+    tokens = rng.integers(1, 128, size=93).tolist()
+    slot = 1  # the ring is the slot's: page_table[0] // PPS
+    pages = list(range(slot * PPS, (slot + 1) * PPS))
+
+    def a_call_a_chunk(ids, positions, page_table):
+        for c0 in range(0, ids.shape[1], CHUNK):
+            step.prefill(ids[:, c0:c0 + CHUNK], positions[:, c0:c0 + CHUNK],
+                         page_table)
+
+    left = []
+    for prefill in (step.prefill, a_call_a_chunk):
+        model.state = {k: jnp.asarray(v) for k, v in seeded.items()}
+        assert run_chunked_prefill(prefill, tokens, pages, chunk=CHUNK,
+                                   cap=PAGE * PPS) == 12
+        left.append({k: np.asarray(v) for k, v in model.state.items()})
+    assert left[0].keys() == left[1].keys() == seeded.keys()
+    for key, want in left[1].items():
+        np.testing.assert_array_equal(left[0][key], want, err_msg=key)
+    # the run wrote the rings and the global pool, and counted its chunks
+    moved = {k for k in seeded if np.any(left[1][k] != seeded[k])}
+    assert {"layer0_attn_window/k_cache", "layer2_attn_global/v_cache",
+            "layer3_attn_window/v_cache"} <= moved
+    assert any("/obs/" in k for k in moved)
+
+
 def test_a_zero_bias_reference_differs_so_the_bias_is_in_the_choice():
     """The control of (a)'s non-zero bias: the reference WITHOUT it does
     not match, so the comparison sees choice-by-(s + b)."""

@@ -240,15 +240,17 @@ def test_decode_frame_and_chunk_update_the_pool_in_place(
         v5e_device, monkeypatch):
     """THE structural guard of the in-place KV pool: at the serving
     cell's head geometry (16 x 64, page 32, 16 slots x 32 pages) the
-    frame and the prefill chunk, compiled for the described v5e, alias
-    every pool leaf to its output, hold no copy / transpose / convert
-    (or any other op but the scatter's in-place update) that produces
-    a pool-sized array, and need less than one pool leaf of
-    temporaries.  A [P, page, H, 64] pool failed all three: XLA:TPU
+    frame and the prefill program (a loop over a prompt's chunks),
+    compiled for the described v5e, alias every pool leaf to its
+    output, hold no copy / transpose / convert (or any other op but the
+    scatter's in-place update) that produces a pool-sized array, and
+    need less than one pool leaf of temporaries — the loop no more than
+    the program of one chunk.  A [P, page, H, 64] pool failed all three: XLA:TPU
     stores it page-minor and transposes it in and out on every call."""
     import flexflow_tpu as ff
     from flexflow_tpu.models import build_gpt_decode
     from flexflow_tpu.runtime.decode import compiled_decode_step
+    from flexflow_tpu.runtime.prefill import build_chunk_forward
 
     slots, chunk, layers = 16, 64, 2
     kw = dict(vocab=512, num_layers=layers, hidden=1024, num_heads=16,
@@ -286,8 +288,11 @@ def test_decode_frame_and_chunk_update_the_pool_in_place(
         params = described(tree)
         programs["frame" + tag] = step.frame_fn.lower(
             params, state, [ints(slots, 1), ints(slots, 32), ints(slots)])
+        # the prefill program: a prompt's chunks in one loop, over runs
+        # padded to the context (32 chunks of 64), the count traced
         programs["chunk" + tag] = step.chunk_fn.lower(
-            params, state, ints(1, chunk), ints(1, chunk), ints(1, 32))
+            params, state, ints(1, 1024), ints(1, 1024), ints(1, 32),
+            ints())
     pool_elems = int(np.prod(leaf.shape))
     for name, lowered in programs.items():
         compiled = lowered.compile()
@@ -321,6 +326,18 @@ def test_decode_frame_and_chunk_update_the_pool_in_place(
         assert temp < pool_elems * leaf.dtype.itemsize, (name, temp)
         if name.startswith("frame"):
             assert hlo.count(f'custom_call_target="{MOSAIC}"') == layers
+        if name == "chunk":
+            # the loop over a prompt's chunks holds what ONE chunk's
+            # program holds: arguments and temporaries within 1 %
+            one = jax.jit(build_chunk_forward(
+                model.graph, model.compiled.compute_dtype),
+                donate_argnums=(1,)).lower(
+                    described(step.weights), state, ints(1, chunk),
+                    ints(1, chunk), ints(1, 32)).compile()
+            held = [c.memory_analysis() for c in (compiled, one)]
+            loop_b, one_b = (m.argument_size_in_bytes + m.temp_size_in_bytes
+                             for m in held)
+            assert loop_b <= 1.01 * one_b, (loop_b, one_b)
 
 
 def test_sharded_flash_lowers_and_matches_on_cpu_mesh(mesh8, monkeypatch):

@@ -180,7 +180,7 @@ def test_chunk_state_is_the_same_over_the_served_tree(compute_dtype):
     def over(tree):
         def run(ids, positions, page_table):
             m.state = step.chunk_fn(tree, m.state, ids, positions,
-                                    page_table)
+                                    page_table, np.int32(1))
         return run
 
     served = write(over(step.weights))
@@ -189,6 +189,164 @@ def test_chunk_state_is_the_same_over_the_served_tree(compute_dtype):
         for key, val in served.items():
             np.testing.assert_array_equal(val, other[key], err_msg=key)
     assert all(np.any(v[:2] != 0) for v in served.values())
+
+
+# ---------------------------------------------------------------------------
+# one call a prompt: the prefill program loops over the prompt's chunks
+# ---------------------------------------------------------------------------
+def _seeded_state(model, seed):
+    """Every state leaf filled with seeded values of its dtype (no zero
+    in a pool), so a row a run writes, a row it leaves and a row of a
+    page it never touches all show in a comparison."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    state = {}
+    for key, val in sorted(model.state.items()):
+        if jnp.issubdtype(val.dtype, jnp.integer):
+            draw = rng.integers(-127, 128, val.shape)
+        elif key.endswith("_scale"):
+            draw = rng.uniform(0.004, 0.012, val.shape)
+        else:
+            draw = rng.normal(0, 1, val.shape)
+        state[key] = jnp.asarray(draw, val.dtype)
+    return state
+
+
+def _chunk_by_chunk(step, chunk):
+    """``step.prefill`` handed a run one chunk at a time: the same
+    program at n = 1, called once a chunk — what the lane did before
+    a prompt's chunks ran in one call."""
+    def prefill(ids, positions, page_table):
+        for c0 in range(0, ids.shape[1], chunk):
+            step.prefill(ids[:, c0:c0 + chunk],
+                         positions[:, c0:c0 + chunk], page_table)
+    return prefill
+
+
+# (prompt length, start): ragged; one chunk exactly; a last chunk that
+# reaches ``cap - 1`` (cap 32: 30 prefilled tokens, positions 24..31);
+# ``start > 0`` (prefix sharing's skip-ahead), starting inside a page
+RUNS = {"ragged": (12, 0), "one_chunk": (9, 0), "to_cap": (31, 0),
+        "four_chunks_from_5": (30, 5)}
+
+
+@pytest.mark.parametrize("pool", ["fp32", "int8"])
+@pytest.mark.parametrize("compute_dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("run", RUNS)
+def test_one_call_over_n_chunks_leaves_the_state_of_n_calls(
+        run, compute_dtype, pool):
+    """THE equality of the loop: a prompt's chunks sent as ONE run and
+    prefilled by one call leave every state leaf, bit for bit, as the
+    same chunks sent one call each — the chunk i + 1 of the loop
+    attends to the K/V chunk i wrote, as the next call's did — over a
+    pool seeded everywhere, for ragged lengths, a run clamped at
+    ``cap - 1``, a run that starts past a shared prefix, and the int8
+    pool with its scales."""
+    from flexflow_tpu.models import build_gpt_decode
+    from flexflow_tpu.runtime.prefill import run_chunked_prefill
+
+    chunk = 8
+    lane = (dict(objective="serve", kv_precision="int8")
+            if pool == "int8" else {})
+    cfg = ff.FFConfig(batch_size=4, num_devices=1, cost_cache_file="",
+                      compute_dtype=compute_dtype, **lane)
+    m = build_gpt_decode(cfg, **SMALL_KW)
+    m.compile(loss_type="sparse_categorical_crossentropy", metrics=[],
+              comp_mode="inference")
+    assert {str(v.dtype) for k, v in m.state.items()
+            if k.endswith("k_cache")} == {"int8" if lane else "float32"}
+    step = compiled_decode_step(m, prefill_chunk=chunk)
+    length, start = RUNS[run]
+    cap = SMALL_KW["page_size"] * SMALL_KW["pages_per_seq"]
+    tokens = np.random.default_rng(length).integers(1, 255, size=length)
+    pages = np.random.default_rng(7).permutation(32)[:8].tolist()
+    left = {}
+    for how, prefill in (("one call", step.prefill),
+                         ("a call a chunk", _chunk_by_chunk(step, chunk))):
+        m.state = _seeded_state(m, seed=len(run))
+        sent = run_chunked_prefill(prefill, tokens.tolist(), pages,
+                                   chunk=chunk, cap=cap, start=start)
+        assert sent == -(-(length - 1 - start) // chunk)
+        left[how] = {k: np.asarray(v) for k, v in m.state.items()}
+    assert sent > 1 or run == "one_chunk"
+    seeded = _seeded_state(m, seed=len(run))
+    for key, want in left["a call a chunk"].items():
+        np.testing.assert_array_equal(left["one call"][key], want,
+                                      err_msg=key)
+        if key.endswith("_cache"):  # the run wrote its pages, only them
+            assert np.any(want != np.asarray(seeded[key])), key
+
+
+def test_the_executor_calls_the_prefill_program_once_a_prompt(small_model):
+    """Engagement: behind ``ContinuousBatchingExecutor`` every admitted
+    prompt that has tokens to prefill is ONE ``prefill_fn`` call —
+    counted by ``decode.prefill_calls`` — while ``decode.prefill_chunks``
+    counts its chunks as before; a one-token prompt makes no call."""
+    from flexflow_tpu.obs.metrics import METRICS
+
+    chunk = 8
+    lengths = (1, 2, 9, 12, 17, 23, 31)
+    step = compiled_decode_step(small_model, prefill_chunk=chunk)
+    runs = []
+
+    def prefill(ids, positions, page_table):
+        runs.append(ids.shape)
+        step.prefill(ids, positions, page_table)
+
+    counters = ("decode.prefill_calls", "decode.prefill_chunks")
+    before = [METRICS.counter(c).value for c in counters]
+    ex = ContinuousBatchingExecutor(
+        step, max_seqs=4, page_size=SMALL_KW["page_size"],
+        pages_per_seq=SMALL_KW["pages_per_seq"], prefill_fn=prefill,
+        prefill_chunk=chunk)
+    rng = np.random.default_rng(2)
+    out = ex.run([DecodeRequest(rid=f"r{i}", max_new_tokens=1,
+                                prompt=rng.integers(1, 255, L).tolist())
+                  for i, L in enumerate(lengths)], max_frames=200)
+    assert len(out) == len(lengths)
+    chunks = [-(-(L - 1) // chunk) for L in lengths if L > 1]
+    assert sorted(runs) == sorted((1, n * chunk) for n in chunks)
+    assert ex.prefill_calls == len(chunks) and ex.prefill_chunks == sum(chunks)
+    assert [METRICS.counter(c).value - b for c, b in zip(counters, before)] \
+        == [len(chunks), sum(chunks)]
+
+
+def test_prompts_of_every_chunk_count_run_one_program():
+    """Prompts of 1 to the context's chunks (the largest clamped at
+    ``cap - 1``) are padded to one width and run by ONE compiled
+    program: once the program has been called, no prompt length makes
+    a compile request.  (It is called twice first: the first call takes
+    the state ``init_params`` made, the second the program's own output,
+    committed to its device — jax keys its cache by that too, whatever
+    the chunk count; the serving cells' warm-up and probe make both.)"""
+    from flexflow_tpu.obs.metrics import METRICS
+    from flexflow_tpu.runtime.compile_cache import watch_jax_compiles
+    from flexflow_tpu.runtime.prefill import run_chunked_prefill
+
+    watch_jax_compiles()
+    chunk = 8
+    cap = SMALL_KW["page_size"] * SMALL_KW["pages_per_seq"]
+    m = _compiled_small()
+    step = compiled_decode_step(m, prefill_chunk=chunk)
+    requests = METRICS.counter("jax.compile_requests")
+    ours = METRICS.counter("jax.compile_requests|fun=jit(fwd)")
+    table = list(range(SMALL_KW["pages_per_seq"]))
+    rng = np.random.default_rng(4)
+
+    def prompt(chunks):
+        return rng.integers(1, 255, chunks * chunk + 1).tolist()
+
+    for _ in range(2):
+        run_chunked_prefill(step.prefill, prompt(1), table, chunk=chunk,
+                            cap=cap)
+    assert ours.value >= 1
+    before = requests.value
+    for n in range(1, cap // chunk + 1):
+        tokens = prompt(n)[:cap - 1]  # n = 4: 30 tokens, clamped at 31
+        assert run_chunked_prefill(step.prefill, tokens, table, chunk=chunk,
+                                   cap=cap) == n
+    assert requests.value == before
 
 
 # ---------------------------------------------------------------------------
@@ -498,9 +656,12 @@ def test_prefill_keys_counters_count_what_the_chunk_walks():
         by_hand = sum((c0 + chunk - 1) // block + 1
                       for c0 in range(0, length - 1, chunk)) * block
         assert walked == len(ops) * by_hand
-        # and by the op: the trip count ``forward_chunk`` loops to
-        trips = sum(int(op.chunk_walk(jnp.asarray(p))[1])
-                    for op in ops for p in sent)
+        # and by the op: the trip count ``forward_chunk`` loops to, a
+        # chunk of the ONE run each prompt is sent
+        (run,) = sent
+        assert run.shape == (1, chunks * chunk)
+        trips = sum(int(op.chunk_walk(jnp.asarray(run[:, c0:c0 + chunk]))[1])
+                    for op in ops for c0 in range(0, run.shape[1], chunk))
         assert walked == trips * block
     assert walked < table  # the last prompt: 14 chunks, 2 to 4 blocks of 4
 

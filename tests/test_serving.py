@@ -847,8 +847,8 @@ def test_donated_state_has_one_live_owner():
     for lowered in (
             chunked.frame_fn.lower(m.params, m.state,
                                    [ints(4, 1), ints(4, 4), ints(4)]),
-            chunked.chunk_fn.lower(m.params, m.state, ints(1, 4),
-                                   ints(1, 4), ints(1, 4))):
+            chunked.chunk_fn.lower(m.params, m.state, ints(1, 16),
+                                   ints(1, 16), ints(1, 4), np.int32(1))):
         p_info, s_info = lowered.args_info[0][:2]
         assert all(a.donated for a in jax.tree.leaves(s_info))
         assert not any(a.donated for a in jax.tree.leaves(p_info))
@@ -1004,8 +1004,13 @@ def _over_master(model, step):
         return logits
 
     def prefill(ids, positions, page_table):
-        model.state = step.chunk_fn(model.params, model.state, ids,
-                                    positions, page_table)
+        # the run padded to the context in whole chunks of 4, as
+        # ``step.prefill`` pads it: one program for every run
+        width = SERVED_KW["page_size"] * SERVED_KW["pages_per_seq"]
+        run = [np.zeros((1, width), np.int32) for _ in range(2)]
+        run[0][:, :ids.shape[1]], run[1][:, :ids.shape[1]] = ids, positions
+        model.state = step.chunk_fn(model.params, model.state, *run,
+                                    page_table, np.int32(ids.shape[1] // 4))
 
     frame.prefill, frame.copy_page = prefill, step.copy_page
     frame.attention_path = step.attention_path
@@ -1139,14 +1144,16 @@ def test_served_tree_holds_matmul_leaves_as_the_matmuls_read_them(
 
 def _weight_converts(lowered_text: str):
     """(shape, from, to) of every ``convert`` in ``main`` applied
-    straight to an argument of rank 2 or more."""
+    straight to an argument of rank 2 or more — of ``main``, or of the
+    prefill program's loop over chunks, which hands the weights into
+    its body as loop values."""
     import re
 
     main = lowered_text[lowered_text.index("func.func public @main"):]
     main = main.split("func.func private")[0]
     return [m.groups() for m in re.finditer(
-        r"stablehlo\.convert %arg\d+ : \(tensor<((?:\d+x){2,})(\w+)>\) -> "
-        r"tensor<(?:\d+x)+(\w+)>", main)]
+        r"stablehlo\.convert %(?:arg|iterArg)[_\d]+ : "
+        r"\(tensor<((?:\d+x){2,})(\w+)>\) -> tensor<(?:\d+x)+(\w+)>", main)]
 
 
 def test_frame_over_the_served_tree_converts_no_weight(monkeypatch):
@@ -1154,9 +1161,10 @@ def test_frame_over_the_served_tree_converts_no_weight(monkeypatch):
     are: lowered over ``step.weights`` the frame and the chunk hold NO
     convert of a weight argument, where over ``model.params`` they hold
     one for every matmul leaf it reads (six a layer and the head; the
-    chunk ends at the last layer's cache write, so that layer's wo, its
-    FFN and the head are pruned).  Both still lower, with the same
-    Mosaic calls."""
+    prefill program ends at the last layer's cache write, so that
+    layer's FFN and the head are never traced — its wo is, inside the
+    loop over chunks, for an output nothing reads, and the compiler
+    drops it).  Both still lower, with the same Mosaic calls."""
     import jax
 
     from flexflow_tpu.runtime.decode import compiled_decode_step
@@ -1168,7 +1176,7 @@ def test_frame_over_the_served_tree_converts_no_weight(monkeypatch):
     layers = SERVED_KW["num_layers"]
     ints = lambda *shape: np.zeros(shape, np.int32)  # noqa: E731
     frame_ins = [ints(4, 1), ints(4, 4), ints(4)]
-    chunk_ins = (ints(1, 4), ints(1, 4), ints(1, 4))
+    chunk_ins = (ints(1, 32), ints(1, 32), ints(1, 4), np.int32(1))
     for tree, per_layer in ((step.weights, 0), (m.params, 6)):
         frame = step.frame_fn.lower(tree, m.state, frame_ins).as_text()
         chunk = step.chunk_fn.lower(tree, m.state, *chunk_ins).as_text()
@@ -1176,7 +1184,7 @@ def test_frame_over_the_served_tree_converts_no_weight(monkeypatch):
         assert len(got) == (per_layer * layers + 1 if per_layer else 0), got
         assert all(src == "f32" and dst == "bf16" for _, src, dst in got)
         assert len(_weight_converts(chunk)) == (
-            per_layer * layers - 3 if per_layer else 0)
+            per_layer * layers - 2 if per_layer else 0)
     # lowered FOR the chip (the kernel picks the interpreter off-TPU by
     # the default backend, when it is traced: a step of its own), either
     # tree holds the kernel

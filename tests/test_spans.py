@@ -168,8 +168,10 @@ def test_every_serve_phase_has_one_sample_a_frame_inside_the_frame_span():
     assert both <= sum(ex.frame_seconds) <= hist("serve.step_s").sum
     assert hist("decode.frame_s").sum == pytest.approx(
         sum(ex.frame_seconds))
-    # the chunk lane's dispatches are spans of their own, inside admit
-    assert hist("serve.prefill_chunk_s").count == ex.prefill_chunks > 0
+    # the chunk lane's dispatches are spans of their own, inside admit:
+    # one a prompt, whose chunks it runs in one call
+    assert hist("serve.prefill_chunk_s").count == ex.prefill_calls \
+        == ex.prefill_chunks // 2 > 0
     assert hist("serve.prefill_chunk_s").sum <= hist("serve.admit_s").sum
 
 

@@ -67,8 +67,10 @@ class CostedStep:
         return logits
 
     def prefill(self, ids, positions, table):
-        self.now += self.chunk_s
-        self.chunks += 1
+        # one call is sent a prompt's whole run of chunks
+        n = np.shape(ids)[1] // self.config["prefill_chunk"]
+        self.now += self.chunk_s * n
+        self.chunks += n
 
 
 def replay(cell, order_seed, costs, frame_scale=1.0, chunk_scale=1.0):

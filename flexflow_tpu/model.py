@@ -359,6 +359,40 @@ class FFModel:
             qk_norm_initializer=qk_norm_initializer)
         return self._add_op(op, [hidden, page_table, seq_lens])[0]
 
+    def sparse_decode_attention(self, hidden: Tensor, page_table: Tensor,
+                                seq_lens: Tensor, num_heads: int,
+                                num_kv_heads: int, head_dim: int,
+                                index_heads: int, index_head_dim: int,
+                                index_topk: int,
+                                index_rope_dim: Optional[int] = None,
+                                index_block: int = 512,
+                                index_norm_eps: float = 1e-6,
+                                page_size: int = 16, pages_per_seq: int = 8,
+                                num_pages: int = 0,
+                                rope_theta: Optional[float] = None,
+                                qk_norm_eps: Optional[float] = None,
+                                kv_dtype: str = "bf16",
+                                kernel_initializer=None,
+                                name=None) -> Tensor:
+        """Grouped decode attention behind a learned indexer that chooses
+        ``index_topk`` cached positions a query (ops/decode_attention.py
+        ``SparseDecodeAttentionOp``); projections in
+        ``config.param_dtype``."""
+        op = O.SparseDecodeAttentionOp(
+            self._fresh_name("decode_attention", name),
+            [self._shape_of(hidden), self._shape_of(page_table),
+             self._shape_of(seq_lens)],
+            num_heads=num_heads, num_kv_heads=num_kv_heads,
+            head_dim=head_dim, index_heads=index_heads,
+            index_head_dim=index_head_dim, index_topk=index_topk,
+            index_rope_dim=index_rope_dim, index_block=index_block,
+            index_norm_eps=index_norm_eps, page_size=page_size,
+            pages_per_seq=pages_per_seq, num_pages=num_pages,
+            rope_theta=rope_theta, qk_norm_eps=qk_norm_eps,
+            kv_dtype=kv_dtype, param_dtype=self.config.param_dtype,
+            kernel_initializer=kernel_initializer)
+        return self._add_op(op, [hidden, page_table, seq_lens])[0]
+
     def batch_matmul(self, A: Tensor, B: Tensor, a_seq_length_dim: int = -1,
                      b_seq_length_dim: int = -1, name=None) -> Tensor:
         op = O.BatchMatmulOp(self._fresh_name("bmm", name),
@@ -455,11 +489,15 @@ class FFModel:
     # an expert layer that is told which experts it holds (ops/moe.py)
     def moe_router(self, input: Tensor, n_experts: int, k: int,
                    scale: float = 1.0, experts_held: Optional[int] = None,
-                   kernel_initializer=None, name=None) -> Tuple[Tensor, Tensor]:
+                   kernel_initializer=None, score: str = "sigmoid",
+                   name=None) -> Tuple[Tensor, Tensor]:
+        """(weights, experts) of ``ops/moe.py MoERouterOp``; ``score``
+        ``"sigmoid"`` (bias state, ``scale``) or ``"softmax"`` (the
+        chosen probabilities renormalised)."""
         op = O.MoERouterOp(self._fresh_name("moe_router", name),
                            [self._shape_of(input)], n_experts=n_experts, k=k,
                            scale=scale, experts_held=experts_held,
-                           kernel_initializer=kernel_initializer)
+                           kernel_initializer=kernel_initializer, score=score)
         outs = self._add_op(op, [input])
         return outs[0], outs[1]
 

@@ -25,6 +25,7 @@ from flexflow_tpu.models.moe import build_moe
 from flexflow_tpu.models.joyai_flash import build_joyai_flash
 from flexflow_tpu.models.ouro import build_ouro
 from flexflow_tpu.models.afmoe import build_afmoe_decode
+from flexflow_tpu.models.keye_vl import build_keye_vl_decode
 from flexflow_tpu.models.mlp import build_mlp_unify
 from flexflow_tpu.models.synthetic import build_moe_trunk, build_multibranch
 
@@ -51,6 +52,7 @@ __all__ = [
     "build_joyai_flash",
     "build_ouro",
     "build_afmoe_decode",
+    "build_keye_vl_decode",
     "build_moe_trunk",
     "build_multibranch",
     "build_mlp_unify",

@@ -48,12 +48,18 @@ def gated_ffn(model, x, width: int, hidden: int, name: str,
 
 def held_experts_ffn(model, x, name: str, *, hidden, expert_ff_dim,
                      n_routed_experts, experts_held, expert_offset,
-                     experts_per_token, expert_rows, routed_scaling_factor):
+                     experts_per_token, expert_rows, routed_scaling_factor,
+                     score: str = "sigmoid"):
     """The routed part of an expert layer: what the experts held here add
-    for the tokens routed to them."""
+    for the tokens routed to them.  ``score`` is the router's
+    (``ops/moe.py MoERouterOp``): ``"sigmoid"`` chooses by score + bias
+    and weighs by the chosen scores over their sum times
+    ``routed_scaling_factor``; ``"softmax"`` chooses the largest
+    probabilities of a softmax over all ``n_routed_experts`` and weighs
+    by them over their sum (``routed_scaling_factor`` then 1)."""
     weights, experts = model.moe_router(
         x, n_routed_experts, experts_per_token, scale=routed_scaling_factor,
-        experts_held=experts_held, name=f"{name}_router")
+        experts_held=experts_held, score=score, name=f"{name}_router")
     rows, source, sizes = model.expert_dispatch(
         x, experts, n_routed_experts, experts_held, expert_rows,
         expert_offset=expert_offset, name=f"{name}_dispatch")

@@ -35,6 +35,7 @@ from flexflow_tpu.ops.attention import BatchMatmulOp, MultiHeadAttentionOp
 from flexflow_tpu.ops.decode_attention import (
     DecodeAttentionOp,
     GroupedDecodeAttentionOp,
+    SparseDecodeAttentionOp,
 )
 from flexflow_tpu.ops.reductions import GatherOp, MeanOp, TopKOp
 from flexflow_tpu.ops.latent_attention import LatentAttentionOp
@@ -91,6 +92,7 @@ __all__ = [
     "BatchMatmulOp",
     "DecodeAttentionOp",
     "GroupedDecodeAttentionOp",
+    "SparseDecodeAttentionOp",
     "MultiHeadAttentionOp",
     "GatherOp",
     "MeanOp",

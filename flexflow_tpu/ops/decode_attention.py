@@ -786,6 +786,7 @@ class GroupedDecodeAttentionOp(DecodeAttentionOp):
         param_dtype: str = "float32",
         kernel_initializer: Initializer | None = None,
         qk_norm_initializer: Initializer | None = None,
+        **extra,
     ):
         assert num_heads % num_kv_heads == 0, (num_heads, num_kv_heads)
         assert kv_dtype in ("fp32", "bf16"), kv_dtype
@@ -811,7 +812,7 @@ class GroupedDecodeAttentionOp(DecodeAttentionOp):
             ring_pages=int(ring_pages), rope_theta=rope_theta,
             qk_norm_eps=qk_norm_eps, gated=bool(gated),
             use_kernel=use_kernel, kv_dtype=kv_dtype,
-            param_dtype=param_dtype)
+            param_dtype=param_dtype, **extra)
 
     @property
     def head_dim(self) -> int:
@@ -1087,3 +1088,362 @@ class GroupedDecodeAttentionOp(DecodeAttentionOp):
         b = max(mv.dim_degrees[0], 1) if mv.dim_degrees else 1
         return (self.pool_pages * self.attrs["page_size"]
                 * self._kv_payload_bytes_per_token() / b)
+
+
+def _layer_norm(x, gain, bias, eps: float):
+    """float32 LayerNorm of the last axis."""
+    x = x.astype(jnp.float32)
+    mean = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
+    return ((x - mean) * jax.lax.rsqrt(var + eps) * gain.astype(jnp.float32)
+            + bias.astype(jnp.float32))
+
+
+class SparseDecodeAttentionOp(GroupedDecodeAttentionOp):
+    """Grouped decode attention behind a learned INDEXER that chooses, for
+    every query, the ``index_topk`` cached positions it attends to
+    (DeepSeek-V3.2's sparse attention, as Keye-VL-2.0's ``sa_config``
+    publishes it):
+
+        q = Nq(x Wq) [Hq, D], k = Nk(x Wk), v = x Wv [Hkv, D]; q, k turn
+          by their positions (half-split rotary, ``rope_theta``)
+        qI = x WqI [Hi, Di];  kI = LN(x WkI) [Di], cached a position;
+          the leading ``index_rope_dim`` of qI and kI turn by position
+        wI = (x Ww) [Hi] / sqrt(Hi)
+        I[t, s] = sum_j wI[t, j] ReLU(qI[t, j] . kI[s]) / sqrt(Di), s <= t
+        S_t = the ``index_topk`` s <= t of largest I[t, s]; one set for
+          every head
+        o_t = softmax_{s in S_t}(q_t . k_s / sqrt(D)) v_s;  y = o Wo
+
+    Global attention only (no window, no ring, no gate).  THREE pools
+    under one page table: K and V [pages, page_size, Hkv·D] and the
+    index keys ``index_cache`` [pages, page_size / F, F·Di], F positions
+    a 128-lane row (``index_fold``: 2 at Di = 64 — a 64-wide minor axis
+    would make XLA:TPU store the pool page-minor and turn it over on
+    every write and read), all in the pool dtype, written at the same
+    (page, slot).  The indexer's weights are
+    its own, declared fused in ``param_dtype``: ``wq_index`` [E, Hi·Di],
+    ``wk_index`` [E, Di], ``w_index`` [E, Hi], and the LayerNorm's
+    ``k_index_norm`` / ``k_index_bias`` [Di] in float32.
+
+    A frame (``forward``) scores every cached position of a row, keeps
+    its top ``index_topk`` with ``lax.top_k`` and reads those K/V rows
+    ALONE (``kernels/sparse_attention.py``).  A prefill chunk
+    (``forward_chunk``) writes its three rows, scores the table in key
+    blocks of ``index_block`` positions up to its last position, chooses
+    each query's set exactly (``lax.top_k``) and attends in
+    [chunk, ``index_block``] blocks under the choice, SKIPPING a key
+    block no query of the chunk chose.  Index scores accumulate in
+    float32.  Device counters ``sparse.keys_attended`` (the chosen
+    positions, a query and a layer) and ``sparse.keys_cached`` (the
+    positions it could have read) are added in both."""
+
+    COUNTERS = ("sparse.keys_attended", "sparse.keys_cached")
+
+    def __init__(
+        self,
+        name,
+        input_shapes,
+        num_heads: int,
+        num_kv_heads: int,
+        head_dim: int,
+        index_heads: int,
+        index_head_dim: int,
+        index_topk: int,
+        index_rope_dim: int | None = None,
+        index_block: int = 512,
+        index_norm_eps: float = 1e-6,
+        page_size: int = 16,
+        pages_per_seq: int = 8,
+        num_pages: int = 0,
+        rope_theta: float | None = None,
+        qk_norm_eps: float | None = None,
+        kv_dtype: str = "bf16",
+        param_dtype: str = "float32",
+        kernel_initializer: Initializer | None = None,
+    ):
+        rope = index_head_dim // 2 if index_rope_dim is None else index_rope_dim
+        assert 0 <= rope <= index_head_dim and rope % 2 == 0, rope
+        assert index_block % page_size == 0, (index_block, page_size)
+        super().__init__(
+            name, input_shapes, num_heads=num_heads,
+            num_kv_heads=num_kv_heads, head_dim=head_dim,
+            page_size=page_size, pages_per_seq=pages_per_seq,
+            num_pages=num_pages, rope_theta=rope_theta,
+            qk_norm_eps=qk_norm_eps, use_kernel=False, kv_dtype=kv_dtype,
+            param_dtype=param_dtype, kernel_initializer=kernel_initializer,
+            index_heads=int(index_heads), index_head_dim=int(index_head_dim),
+            index_topk=int(index_topk), index_rope_dim=int(rope),
+            index_block=int(index_block), index_norm_eps=float(index_norm_eps))
+        self.scope = "ff.attn.sparse"
+
+    @property
+    def topk(self) -> int:
+        """Positions a query attends to at most."""
+        return min(self.attrs["index_topk"], self.max_seq_len)
+
+    @property
+    def chunk_block_pages(self) -> int:
+        return self.attrs["index_block"] // self.attrs["page_size"]
+
+    def weight_specs(self) -> Sequence[WeightSpec]:
+        a = self.attrs
+        e, hi, di = a["embed_dim"], a["index_heads"], a["index_head_dim"]
+        pd = DataType.from_any(a["param_dtype"])
+        return list(super().weight_specs()) + [
+            WeightSpec("wq_index", (e, hi * di), pd, self._kernel_init),
+            WeightSpec("wk_index", (e, di), pd, self._kernel_init),
+            WeightSpec("w_index", (e, hi), pd, self._kernel_init),
+            WeightSpec("k_index_norm", (di,), DataType.FLOAT32,
+                       ConstantInitializer(1.0)),
+            WeightSpec("k_index_bias", (di,), DataType.FLOAT32,
+                       ConstantInitializer(0.0))]
+
+    @property
+    def index_fold(self) -> int:
+        """Positions a row of the index pool holds (``fold_rows``)."""
+        from flexflow_tpu.kernels.sparse_attention import fold_rows
+
+        fold = fold_rows(self.attrs["index_head_dim"])
+        assert self.attrs["page_size"] % fold == 0, (self.attrs, fold)
+        return fold
+
+    def state_specs(self):
+        f = self.index_fold
+        shape = (self.pool_pages, self.attrs["page_size"] // f,
+                 f * self.attrs["index_head_dim"])
+        return (list(super().state_specs())
+                + [("index_cache", shape, self.pool_dtype, 0)]
+                + [(f"obs/{c}", (), jnp.int32, 0) for c in self.COUNTERS])
+
+    def attention_path(self, multi_device: bool) -> str:
+        return "xla"  # kernels/sparse_attention.py: XLA gathers and products
+
+    def serving_weights(self, weights, compute_dtype):
+        """Matrices in the compute dtype, norm gains and biases as they
+        are."""
+        return {n: (w if w.ndim == 1 else w.astype(compute_dtype))
+                for n, w in weights.items()}
+
+    # ---- lowering --------------------------------------------------------
+    def _project_index(self, x, positions, weights, cd):
+        """x [B, S, E] (compute dtype) -> qI [B, S, Hi, Di] and kI
+        [B, S, Di] float32 (kI normed; both turned on their leading
+        ``index_rope_dim``), wI [B, S, Hi] float32 (over sqrt(Hi))."""
+        from flexflow_tpu.ops.attention import half_split_rotary
+
+        a = self.attrs
+        w = self.serving_weights(weights, cd)
+        prods = jax.lax.optimization_barrier(
+            tuple(jnp.dot(x, w[n]) for n in ("wq_index", "wk_index",
+                                              "w_index")))
+        lead, hi, di = x.shape[:2], a["index_heads"], a["index_head_dim"]
+        qi = prods[0].astype(jnp.float32).reshape(*lead, hi, di)
+        ki = _layer_norm(prods[1], w["k_index_norm"], w["k_index_bias"],
+                         a["index_norm_eps"])[:, :, None, :]
+        r = a["index_rope_dim"]
+        if r and a["rope_theta"] is not None:
+            def turn(t):
+                return jnp.concatenate(
+                    [half_split_rotary(t[..., :r], a["rope_theta"], positions),
+                     t[..., r:]], axis=-1)
+            qi, ki = turn(qi), turn(ki)
+        wi = prods[2].astype(jnp.float32) / math.sqrt(hi)
+        return qi, ki[:, :, 0, :], wi
+
+    def _write(self, ctx, page_table, positions, x, weights, cd, writes):
+        """Project the fresh rows and write K, V and the index key;
+        returns (q [B, S, Hq, D], qI, wI, k pool, v pool, index pool)."""
+        from flexflow_tpu.kernels.sparse_attention import scatter_folded
+
+        q, k_rows, v_rows, _ = self._project(x, positions, weights, cd)
+        qi, ki, wi = self._project_index(x, positions, weights, cd)
+        k_cache, v_cache = self._scatter(ctx, page_table, positions, k_rows,
+                                         v_rows, writes)
+        ps = self.attrs["page_size"]
+        page = jnp.where(writes, self._pages_of(page_table, positions // ps),
+                         self.pool_pages)  # past the pool: dropped
+        key = f"{self.name}/index_cache"
+        index_cache = scatter_folded(ctx.state_in[key], page, positions % ps,
+                                     ki)
+        ctx.state_out[key] = index_cache
+        return q, qi, wi, k_cache, v_cache, index_cache
+
+    def _count(self, ctx, cached, writes):
+        """Add the chosen and the cachable positions of the queries
+        ``writes`` keeps (``cached`` [..] tokens each could read)."""
+        cached = jnp.where(writes, cached, 0)
+        counted = {"sparse.keys_attended": jnp.minimum(cached, self.topk),
+                   "sparse.keys_cached": cached}
+        for c in self.COUNTERS:
+            key = f"{self.name}/obs/{c}"
+            ctx.state_out[key] = ctx.state_in[key] + jnp.sum(
+                counted[c], dtype=jnp.int32)
+
+    def forward(self, ctx: LoweringContext, inputs, weights):
+        from flexflow_tpu.kernels import sparse_attention as sa
+
+        a = self.attrs
+        hidden, page_table, seq_lens = inputs
+        page_table = page_table.astype(jnp.int32)
+        seq_lens = seq_lens.astype(jnp.int32)
+        cd = ctx.compute_dtype
+        b, ps, cap = hidden.shape[0], a["page_size"], self.max_seq_len
+        positions = seq_lens[:, None]  # the fresh token's
+        writes = positions < cap
+        q, qi, wi, k_cache, v_cache, index_cache = self._write(
+            ctx, page_table, positions, hidden.astype(cd), weights, cd,
+            writes)
+        lens = seq_lens + 1  # the fresh token is its own key too
+        with jax.named_scope(sa.INDEX_SCOPE):
+            rows = index_cache[page_table]  # [B, pages, rows, F·Di]
+            keys = rows.reshape(b, -1, rows.shape[-1])
+        scores = sa.index_scores(qi, wi, keys,
+                                 1.0 / math.sqrt(a["index_head_dim"]))[:, 0]
+        valid = jnp.arange(cap, dtype=jnp.int32)[None, :] < lens[:, None]
+        idx, chosen = sa.topk_indices(scores, valid, self.topk)
+        hkv, d = self.kv_heads, self.head_dim
+        with jax.named_scope(sa.ATTEND_SCOPE):
+            out = sa.sparse_attend(
+                q[:, 0].reshape(b, hkv, -1, d),
+                sa.gather_rows(k_cache, page_table, idx, ps),
+                sa.gather_rows(v_cache, page_table, idx, ps),
+                chosen, 1.0 / math.sqrt(d), cd)
+        self._count(ctx, lens, writes[:, 0])
+        return [self._finish(out.reshape(b, 1, -1), None, weights, cd,
+                             hidden.dtype)]
+
+    def forward_chunk(self, ctx: LoweringContext, inputs, weights):
+        """C prompt tokens a sequence (``DecodeAttentionOp.forward_chunk``'s
+        contract): the chunk's K, V and index rows are written first;
+        the indexer then scores the table in key blocks of
+        ``index_block`` positions up to the chunk's last position into a
+        [B, C, table] float32 block, each query's set is chosen exactly
+        from it (``lax.top_k``), and attention runs over the same key
+        blocks with an online softmax under the choice — a block that no
+        query of the chunk chose is skipped (``lax.cond``)."""
+        from flexflow_tpu.kernels import sparse_attention as sa
+        from flexflow_tpu.kernels.ragged_paged_attention import NEG_INF
+
+        a = self.attrs
+        hidden, page_table, positions = inputs
+        page_table = page_table.astype(jnp.int32)
+        positions = positions.astype(jnp.int32)
+        cd = ctx.compute_dtype
+        b, c = hidden.shape[:2]
+        ps, cap, di = a["page_size"], self.max_seq_len, a["index_head_dim"]
+        writes = positions < cap - 1
+        q, qi, wi, k_cache, v_cache, index_cache = self._write(
+            ctx, page_table, positions, hidden.astype(cd), weights, cd,
+            writes)
+        bp = self.chunk_block_pages
+        blk = bp * ps  # positions a key block
+        width = -(-cap // blk) * blk
+        _, blocks = self.chunk_walk(positions)
+
+        def pages_of_block(i):
+            logical = i * bp + jnp.arange(bp, dtype=jnp.int32)
+            return self._pages_of(page_table,
+                                  jnp.broadcast_to(logical, (b, bp)))
+
+        def score(i, buf):
+            with jax.named_scope(sa.INDEX_SCOPE):
+                rows = index_cache[pages_of_block(i)]
+                keys = rows.reshape(b, -1, rows.shape[-1])
+            s = sa.index_scores(qi, wi, keys, 1.0 / math.sqrt(di))
+            return jax.lax.dynamic_update_slice_in_dim(buf, s, i * blk,
+                                                       axis=2)
+
+        buf = jax.lax.fori_loop(
+            0, blocks, score, jnp.full((b, c, width), -jnp.inf, jnp.float32))
+        key_pos = jnp.arange(width, dtype=jnp.int32)
+        valid = ((key_pos[None, None, :] <= positions[:, :, None])
+                 & (key_pos < cap)[None, None, :])
+        chosen = sa.topk_mask(buf, valid, self.topk)  # [B, C, width]
+
+        hkv, d = self.kv_heads, self.head_dim
+        g = a["num_heads"] // hkv
+        qg = q.reshape(b, c, hkv, g, d).astype(cd)
+        scale = 1.0 / math.sqrt(d)
+
+        def attend(i, carry):
+            seen = jax.lax.dynamic_slice_in_dim(chosen, i * blk, blk, axis=2)
+
+            def block(carry):
+                m, l, acc = carry
+                pages = pages_of_block(i)
+                kb = k_cache[pages].reshape(b, blk, hkv, d).astype(cd)
+                vb = v_cache[pages].reshape(b, blk, hkv, d).astype(cd)
+                s = jnp.einsum("bchgd,bkhd->bhgck", qg, kb,
+                               preferred_element_type=jnp.float32) * scale
+                on = seen[:, None, None]
+                m_new = jnp.maximum(
+                    m, jnp.max(jnp.where(on, s, NEG_INF), axis=-1))
+                p = jnp.where(on, jnp.exp(s - m_new[..., None]), 0.0)
+                alpha = jnp.exp(m - m_new)
+                l = l * alpha + jnp.sum(p, axis=-1)
+                acc = acc * alpha[..., None] + jnp.einsum(
+                    "bhgck,bkhd->bhgcd", p.astype(cd), vb,
+                    preferred_element_type=jnp.float32)
+                return m_new, l, acc
+
+            return jax.lax.cond(jnp.any(seen), block, lambda c: c, carry)
+
+        stat = jnp.zeros((b, hkv, g, c), jnp.float32)
+        with jax.named_scope(sa.ATTEND_SCOPE):
+            _, l, acc = jax.lax.fori_loop(
+                0, blocks, attend,
+                (stat + NEG_INF, stat,
+                 jnp.zeros((b, hkv, g, c, d), jnp.float32)))
+        out = acc / jnp.maximum(l, 1e-30)[..., None]
+        out = out.transpose(0, 3, 1, 2, 4).reshape(b, c, -1)
+        self._count(ctx, positions + 1, writes)
+        return [self._finish(out, None, weights, cd, hidden.dtype)]
+
+    # ---- cost hooks ------------------------------------------------------
+    def _index_bytes_per_token(self) -> float:
+        return (self.attrs["index_head_dim"]
+                * jnp.dtype(self.pool_dtype).itemsize)
+
+    def flops(self) -> float:
+        """Projections (attention and indexer), the indexer over every
+        cached position, attention over the chosen ones: a frame at
+        full occupancy."""
+        a = self.attrs
+        e, d = a["embed_dim"], self.head_dim
+        hq, hkv = a["num_heads"] * d, self.kv_heads * d
+        hi, di = a["index_heads"], a["index_head_dim"]
+        proj = 2.0 * self.max_seqs * e * (2 * hq + 2 * hkv + hi * di + di + hi)
+        index = 2.0 * self.max_seqs * hi * di * self.attended_len
+        return proj + index + 4.0 * self.max_seqs * hq * self.topk
+
+    def bytes_accessed(self) -> float:
+        """Activations and weights, the index keys of every cached
+        position and the K/V rows of the chosen ones."""
+        base = Operator.bytes_accessed(self)
+        return base + self.max_seqs * (
+            self.attended_len * self._index_bytes_per_token()
+            + self.topk * self._kv_payload_bytes_per_token())
+
+    def sharded_bytes_accessed(self, mv: MachineView,
+                               serving=None) -> float:
+        """``bytes_accessed`` over a split of the sequence slots (the one
+        split this op takes): each part streams the weights whole and
+        its own rows' index keys and chosen K/V."""
+        b = max(mv.dim_degrees[0], 1) if mv.dim_degrees else 1
+        act = sum(s.num_bytes for s in self.input_shapes)
+        act += sum(s.num_bytes for s in self.output_shapes)
+        wbytes = sum(float(np.prod(ws.shape)) * ws.dtype.itemsize
+                     for ws in self._weight_specs)
+        reads = self.max_seqs * (
+            self.attended_len * self._index_bytes_per_token()
+            + self.topk * self._kv_payload_bytes_per_token()) / b
+        if serving is not None:
+            reads *= serving.load_factor(b)
+        return act / b + wbytes + reads
+
+    def kv_cache_bytes(self, mv: MachineView, serving=None) -> float:
+        b = max(mv.dim_degrees[0], 1) if mv.dim_degrees else 1
+        return (self.pool_pages * self.attrs["page_size"]
+                * (self._kv_payload_bytes_per_token()
+                   + self._index_bytes_per_token()) / b)

@@ -248,9 +248,20 @@ _DISPATCH_COUNTERS = ("moe.assignments", "moe.assignments_dropped",
 class MoERouterOp(Operator):
     """x [..., D] -> (weights [..., k] float32, experts [..., k] int32).
 
-    ``s = sigmoid(x W)``; the top ``k`` of ``s + b`` are CHOSEN and
-    weighed by ``s`` itself: ``s_i / (sum of the chosen s + 1e-20)``
-    times ``scale``.  In float32 with the matmul at
+    Two score functions, by ``score``:
+
+    * ``"sigmoid"`` (the default; DeepSeek-V3 / Trinity): ``s =
+      sigmoid(x W)``; the top ``k`` of ``s + b`` are CHOSEN and weighed
+      by ``s`` itself: ``s_i / (sum of the chosen s + 1e-20)`` times
+      ``scale``.
+    * ``"softmax"`` (Qwen3-MoE / Keye, ``norm_topk_prob``): ``p =
+      softmax(x W)`` over all ``n_experts``; the top ``k`` of ``p`` are
+      chosen and weighed ``p_i / sum of the chosen p``.  No bias state
+      and no ``scale``: neither exists in that family.  The attr is
+      present only for this form, so a sigmoid router's attrs — and its
+      signature and cost-cache key — are what they were before it.
+
+    In float32 with the matmul at
     ``HIGHEST`` whatever the compute dtype, as published implementations
     cast their gate to float32: the choice is discrete.
 
@@ -274,11 +285,20 @@ class MoERouterOp(Operator):
 
     def __init__(self, name, input_shapes, n_experts: int, k: int,
                  scale: float = 1.0, experts_held: int | None = None,
-                 kernel_initializer=None):
+                 kernel_initializer=None, score: str = "sigmoid"):
+        assert score in ("sigmoid", "softmax"), score
+        assert score == "sigmoid" or scale == 1.0, (
+            "a softmax router has no scale")
         self._kernel_init = kernel_initializer or DEFAULT_WEIGHT_INIT
         held = n_experts if experts_held is None else experts_held
+        extra = {} if score == "sigmoid" else {"score": score}
         super().__init__(name, input_shapes, n_experts=int(n_experts),
-                         k=int(k), scale=float(scale), experts_held=int(held))
+                         k=int(k), scale=float(scale), experts_held=int(held),
+                         **extra)
+
+    @property
+    def score(self) -> str:
+        return self.attrs.get("score", "sigmoid")
 
     def infer(self) -> Sequence[ParallelTensorShape]:
         lead = self.input_shapes[0].sizes[:-1] + (self.attrs["k"],)
@@ -290,17 +310,26 @@ class MoERouterOp(Operator):
         return (WeightSpec("kernel", (d, e), DataType.FLOAT32, self._kernel_init),)
 
     def state_specs(self):
+        if self.score == "softmax":
+            return ()
         return (("bias", (self.attrs["n_experts"],), jnp.float32, 0.0),)
 
     def forward(self, ctx: LoweringContext, inputs, weights):
         a = self.attrs
-        scores = jax.nn.sigmoid(jnp.dot(
+        logits = jnp.dot(
             inputs[0].astype(jnp.float32), weights["kernel"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
-        _, chosen = jax.lax.top_k(
-            scores + ctx.state_in[f"{self.name}/bias"], a["k"])
-        w = jnp.take_along_axis(scores, chosen, axis=-1)
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+            precision=jax.lax.Precision.HIGHEST)
+        if self.score == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+            _, chosen = jax.lax.top_k(scores, a["k"])
+            w = jnp.take_along_axis(scores, chosen, axis=-1)
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        else:
+            scores = jax.nn.sigmoid(logits)
+            _, chosen = jax.lax.top_k(
+                scores + ctx.state_in[f"{self.name}/bias"], a["k"])
+            w = jnp.take_along_axis(scores, chosen, axis=-1)
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
         if a["experts_held"] < a["n_experts"]:
             w = jax.lax.stop_gradient(w)
         return [w * a["scale"], chosen.astype(jnp.int32)]

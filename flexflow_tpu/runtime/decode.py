@@ -1361,7 +1361,7 @@ class _LiveState:
         return self._live_state()
 
 
-_KV_LEAVES = ("k_cache", "v_cache", "k_scale", "v_scale")
+_KV_LEAVES = ("k_cache", "v_cache", "k_scale", "v_scale", "index_cache")
 # the state leaf that carries a frame's chosen tokens [B] into the next
 # frame (``compiled_decode_step``): not an op's state, so no "/" in it
 _LAST_TOKENS = "last_tokens"

@@ -19,7 +19,10 @@ from a chip run (PERF.md section 4 has the long-context cell's):
 
 A frame costs ``frame_ms`` + the K/V bytes its rows read (the
 configuration's ``work.attention_kernel_bytes``) at ``kv_gbps``; a prefill
-chunk ``chunk_ms``.  Numbers printed here are a replay's, never a device's.
+chunk ``chunk_ms`` + ``chunk_ms_per_kkey`` for every 1,024 positions
+behind its last one (0 by default: a window layer's chunk walks a fixed
+count; a global or sparse layer's walks every key block behind it).
+Numbers printed here are a replay's, never a device's.
 """
 
 from __future__ import annotations
@@ -45,13 +48,15 @@ class CostedStep:
 
     attention_path = "replay"
 
-    def __init__(self, config, frame_s, s_per_kv_byte, chunk_s):
+    def __init__(self, config, frame_s, s_per_kv_byte, chunk_s,
+                 chunk_s_per_key=0.0):
         from benchmarks.harness.spec import resolve_module
 
         self.config, self.now = config, 0.0
         self.work = resolve_module(config["work"])
         self.frame_s, self.s_per_byte, self.chunk_s = (
             frame_s, s_per_kv_byte, chunk_s)
+        self.chunk_s_per_key = chunk_s_per_key
         self.frames = self.chunks = 0
 
     def clock(self):
@@ -68,8 +73,11 @@ class CostedStep:
 
     def prefill(self, ids, positions, table):
         # one call is sent a prompt's whole run of chunks
-        n = np.shape(ids)[1] // self.config["prefill_chunk"]
-        self.now += self.chunk_s * n
+        c = self.config["prefill_chunk"]
+        n = np.shape(ids)[1] // c
+        behind = np.asarray(positions).reshape(-1, n, c)[0, :, -1] + 1
+        self.now += self.chunk_s * n + self.chunk_s_per_key * float(
+            behind.sum())
         self.chunks += n
 
 
@@ -78,9 +86,11 @@ def replay(cell, order_seed, costs, frame_scale=1.0, chunk_scale=1.0):
     returns the window's reading and where the run stood at its end."""
     from benchmarks.harness import serve
 
-    frame_s, s_per_byte, chunk_s = costs
+    # (frame, a K/V byte, a chunk[, a position behind a chunk]): seconds
+    frame_s, s_per_byte, chunk_s, chunk_per_key = (tuple(costs) + (0.0,))[:4]
     step = CostedStep(cell.config, frame_s * frame_scale,
-                      s_per_byte * frame_scale, chunk_s * chunk_scale)
+                      s_per_byte * frame_scale, chunk_s * chunk_scale,
+                      chunk_per_key * chunk_scale)
     ex = serve.new_executor(cell.config, step)
     run = serve.drive(ex, dict(cell.traffic, order_seed=order_seed), 2, 0,
                       cell.run_seconds, clock=step.clock)
@@ -134,13 +144,15 @@ def main(argv=None):
     ap.add_argument("--frame-ms", type=float, required=True)
     ap.add_argument("--kv-gbps", type=float, required=True)
     ap.add_argument("--chunk-ms", type=float, required=True)
+    ap.add_argument("--chunk-ms-per-kkey", type=float, default=0.0)
     ap.add_argument("--workers", type=int, default=4)
     args = ap.parse_args(argv)
     cell = spec.resolve_cell(ROOT, args.workload)
     if cell.traffic.get("order") != "fixed":
         raise SystemExit(f"{args.workload}'s traffic draws its order from "
                          "the run's seed: there is no order_seed to pick")
-    costs = (args.frame_ms * 1e-3, 1e-9 / args.kv_gbps, args.chunk_ms * 1e-3)
+    costs = (args.frame_ms * 1e-3, 1e-9 / args.kv_gbps, args.chunk_ms * 1e-3,
+             args.chunk_ms_per_kkey * 1e-3 / 1024)
     summary, rows = rank(cell, args.orders, costs, args.workers)
     print(json.dumps(summary))
     for row in rows:

@@ -18,8 +18,9 @@ products.
 Selection is EXACT at token level: ``lax.top_k`` over each query's
 scores.  ``topk_indices`` (a decode frame: one query a row) hands back
 the chosen positions; ``topk_mask`` (a prefill chunk: a [C, context]
-block of scores that only needs the chosen SET) scatters them into a
-mask.
+block of scores that only needs the chosen SET) reads the set off the
+k-th value and its position — one compare over the block, no gather or
+scatter of the k positions.
 """
 
 from __future__ import annotations
@@ -92,15 +93,29 @@ def topk_indices(scores, valid, k: int):
         return idx.astype(jnp.int32), jnp.take_along_axis(valid, idx, axis=-1)
 
 
+def _total_order(x):
+    """float32 -> int32 keys in ``lax.top_k``'s order of floats: -0.0
+    below +0.0 (which ``==`` calls equal), -inf lowest."""
+    b = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return b ^ ((b >> 31) & 0x7FFFFFFF)
+
+
 def topk_mask(scores, valid, k: int):
     """``scores`` [..., S] float32, ``valid`` [..., S] -> [..., S] bool:
     each row's ``k`` valid positions of largest score (every valid one
-    where there are fewer) — ``topk_indices``' set, scattered into a
-    mask."""
-    idx, chosen = topk_indices(scores, valid, k)
+    where there are fewer) — ``topk_indices``' set.  ``lax.top_k`` ranks
+    in a total order, equal values lower position first, so the set is
+    the positions above its k-th value ``v`` and, of those equal to
+    ``v``, the ones at or below the k-th's position: read off ``v`` and
+    that position by one compare over the block, in the same order."""
     with jax.named_scope(SELECT_SCOPE):
-        return jnp.put_along_axis(jnp.zeros(scores.shape, bool), idx, chosen,
-                                  axis=-1, inplace=False)
+        masked = jnp.where(valid, scores, -jnp.inf)
+        vals, idx = jax.lax.top_k(masked, k)
+        key, kth = _total_order(masked), _total_order(vals[..., k - 1:k])
+        pos = jax.lax.broadcasted_iota(jnp.int32, masked.shape,
+                                       masked.ndim - 1)
+        return valid & ((key > kth)
+                        | ((key == kth) & (pos <= idx[..., k - 1:k])))
 
 
 def gather_rows(pool, page_table, positions, page_size: int):

@@ -260,6 +260,74 @@ def test_fewer_valid_positions_than_the_choice_keeps_them_all():
     assert not got[1, 7:].any()
 
 
+def scatter_mask(scores, valid, k):
+    """top_k's positions scattered into a mask: the direct form of the
+    chunk's choice, the oracle of the compare-only one."""
+    idx, chosen = sa.topk_indices(scores, valid, k)
+    return jnp.put_along_axis(jnp.zeros(scores.shape, bool), idx, chosen,
+                              axis=-1, inplace=False)
+
+
+def straddled(rng, shape):
+    """A few 3s above many 2s: each row's k-th value is a 2 with 2s on
+    both sides of the cut."""
+    return rng.choice([3.0, 2.0, 1.0], size=shape, p=[0.1, 0.4, 0.5])
+
+
+def signed_zeros(rng, shape):
+    """A few positive scores, then +0.0 and -0.0 at random (a position
+    whose ReLU terms are all 0 under negative head weights scores -0.0),
+    then negatives: the cut falls among the zeros."""
+    u = rng.random(shape)
+    zero = np.where(rng.random(shape) < 0.5, 0.0, -0.0)
+    return np.where(u < 0.1, 1.0 + u, np.where(u < 0.7, zero, -u))
+
+
+# (scores of shape [B, Q, S], the first query's position, k) — each query
+# sees the positions up to its own, as in a chunk
+SETS = {
+    "ties-straddle": lambda r: (straddled(r, (2, 8, 96)), 80, TOPK),
+    "all-zero": lambda r: (np.zeros((2, 8, 64)), 40, TOPK),
+    "signed-zeros": lambda r: (signed_zeros(r, (2, 8, 64)), 40, TOPK),
+    # rows that see 0, 1, .., TOPK + 1 positions: 0, k-1, k and k+1 among them
+    "valid-0-to-k+1": lambda r: (r.normal(size=(2, TOPK + 2, 40)), -1, TOPK),
+    "k-is-width": lambda r: (np.round(r.normal(size=(2, 4, TOPK))), 12, TOPK),
+    # forward_chunk's block: [1, C, width], -inf past the walked key blocks
+    "chunk-layout": lambda r: (np.where(np.arange(PPS * PAGE) < 48,
+                                        r.normal(size=(1, CHUNK, PPS * PAGE)),
+                                        -np.inf), 40, TOPK),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SETS))
+def test_the_chunks_mask_is_top_ks_set_to_the_position(case):
+    """Read off the k-th value and its position, the chunk's mask is
+    top_k's set exactly: ties cut where top_k cuts them (lower position
+    first) and -0.0 ranked below +0.0, as top_k ranks it."""
+    scores, start, k = SETS[case](np.random.default_rng(43))
+    scores = jnp.asarray(scores, jnp.float32)
+    q, s = scores.shape[1:]
+    rows = start + np.arange(q)[:, None]
+    valid = jnp.asarray(np.arange(s)[None, :] <= rows)[None]
+    got = np.asarray(sa.topk_mask(scores, valid, k))
+    np.testing.assert_array_equal(
+        got, np.asarray(keye.chosen_positions(scores, start, k)))
+    np.testing.assert_array_equal(got, np.asarray(scatter_mask(scores,
+                                                               valid, k)))
+    assert (got.sum(-1) == np.clip(rows[:, 0] + 1, 0, k)).all()
+
+
+def test_the_chunks_choice_lowers_to_no_gather_and_no_scatter():
+    """The chunk's choice is top_k and one compare over the block: the
+    k positions are never gathered from ``valid`` nor scattered into a
+    mask."""
+    block = jax.ShapeDtypeStruct((1, 64, 4096), jnp.float32)
+    text = jax.jit(sa.topk_mask, static_argnums=2).lower(
+        block, jax.ShapeDtypeStruct(block.shape, bool), 2048).as_text()
+    assert "top_k" in text
+    assert "scatter" not in text and "gather" not in text
+
+
 # ---- a key block no query of the chunk chose is skipped ---------------------
 def test_a_key_block_no_query_chose_is_skipped():
     """The indexer's head weights at 0 score every position 0: each
